@@ -119,9 +119,15 @@ class ClassNLLCriterion(Criterion):
         valid = t >= 0
         picked = jnp.take_along_axis(output, jnp.maximum(t, 0)[:, None],
                                      axis=1)[:, 0]
+        # the loss is reduced in float32 at least, whatever the compute
+        # dtype: summed in bfloat16 it saturates (TimeDistributedCriterion
+        # over 512 steps of a loss near 10 stops growing at 4096, and the
+        # LM's first loss read 8.0 on the v5e where ln(vocab) is 10.37)
+        picked = picked.astype(jnp.promote_types(picked.dtype, jnp.float32))
         if self.label_smoothing:
             eps = self.label_smoothing
-            uniform = -jnp.mean(output, axis=-1)  # -E_uniform[log p]
+            uniform = -jnp.mean(output, axis=-1,  # -E_uniform[log p]
+                                dtype=picked.dtype)
             smoothed = jnp.where(valid,
                                  (1 - eps) * (-picked) + eps * uniform, 0.0)
             if self.size_average:

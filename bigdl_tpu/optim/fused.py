@@ -16,8 +16,9 @@ split back.
 Because every shipped update rule (SGD/Adam/Adagrad/Adadelta/Adamax/
 RMSprop/EMA) is `jax.tree.map` of elementwise lambdas, running it over
 concatenated buffers computes the identical scalar expression per element —
-the fused path is **bit-identical** to the per-leaf path (pinned by
-tests/test_fused_update.py).  L-BFGS opts out (`supports_fused = False`):
+the fused path agrees with the per-leaf path to float tolerance (pinned by
+tests/test_fused_update.py; bit-identical only where XLA happens to
+contract both programs' multiply-adds alike, which jax 0.9.0's does not).  L-BFGS opts out (`supports_fused = False`):
 its state ravels the parameter pytree itself, so re-fusing would reorder
 the flat history vectors.
 

@@ -997,6 +997,9 @@ class Optimizer:
         the counter is diagnostics, never a crash."""
         from ..utils import flops as flops_mod
         self._mfu_denom = 0.0
+        # outside the guard below: a TPU whose kind has no row in the peaks
+        # table is an error, not a counter quietly disarmed
+        peak, src = flops_mod.device_peak_flops(jax.devices()[0])
         try:
             fn = getattr(step_fn, "raw", None)
             if fn is None:
@@ -1004,7 +1007,6 @@ class Optimizer:
             # fresh lambda: make_jaxpr caches by function identity
             self._step_flops = flops_mod.jaxpr_flops(
                 jax.make_jaxpr(lambda *a: fn(*a))(*example_args))
-            peak, src = flops_mod.device_peak_flops(jax.devices()[0])
             if self._step_flops and peak > 0:
                 self._mfu_denom = peak * mesh.size
                 logger.info(
@@ -2400,14 +2402,6 @@ class _ShardedForward:
             fields = dict(aot_mod.base_fingerprint(mesh))
             fields["kind"] = "forward"
             fields["model"] = self._aot_fp
-            if self._pin_mesh is not None:
-                # a serialized executable is bound to its device
-                # assignment: a subset-pinned engine (topology router)
-                # must never hit an entry compiled for a DIFFERENT
-                # subset of the same shape — the device ids join the key
-                # (the default Engine.mesh() path keeps its stable key)
-                fields["devices"] = [int(d.id)
-                                     for d in mesh.devices.flat]
             fields["args"] = aot_mod.aval_fingerprint(
                 (params, net_state, placed))
 

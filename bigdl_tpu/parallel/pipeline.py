@@ -219,12 +219,7 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x, *,
                   if a and a in mesh.axis_names) or None
     pspec = jax.tree.map(lambda _: P(pipe_axis), stacked_params)
     xspec = P(batch)
-    from ..utils.compat import has_vma_marking, shard_map_unchecked
-    # jax < 0.5: the GPipe cond branches mix replicated zeros with varying
-    # microbatches and there is no pvary/pcast to annotate them — the
-    # replication checker cannot be satisfied, so it runs unchecked there
-    wrap = shard_map if has_vma_marking() else shard_map_unchecked
-    fn = wrap(
+    fn = shard_map(
         partial(_pipe_local, stage_fn=stage_fn, axis_name=pipe_axis,
                 num_microbatches=num_microbatches, remat=remat,
                 vary_axes=batch or ()),
@@ -395,13 +390,11 @@ def pipeline_apply_scheduled(stage_fn: Callable, stacked_params, x, *,
                   if a and a in mesh.axis_names) or None
     pspec = jax.tree.map(lambda _: P(pipe_axis), stacked_params)
     xspec = P(batch)
-    from ..utils.compat import has_vma_marking, shard_map_unchecked
-    wrap = shard_map if has_vma_marking() else shard_map_unchecked
     fwd_fn = stage_fn
     if remat:
         fwd_fn = jax.checkpoint(stage_fn)
     fwd_tbl = build_schedule("gpipe", n, num_microbatches, v)
-    fwd_sm = wrap(
+    fwd_sm = shard_map(
         partial(_sched_fwd_local, tbl=fwd_tbl, stage_fn=fwd_fn,
                 axis_name=pipe_axis, vary_axes=batch or ()),
         mesh=mesh, in_specs=(pspec, xspec), out_specs=xspec)
@@ -409,7 +402,7 @@ def pipeline_apply_scheduled(stage_fn: Callable, stacked_params, x, *,
         return fwd_sm(stacked_params, x)
 
     bwd_tbl = build_schedule("1f1b", n, num_microbatches, v)
-    bwd_sm = wrap(
+    bwd_sm = shard_map(
         partial(_sched_fwd_bwd_local, tbl=bwd_tbl, stage_fn=stage_fn,
                 axis_name=pipe_axis, vary_axes=batch or ()),
         mesh=mesh, in_specs=(pspec, xspec, xspec),

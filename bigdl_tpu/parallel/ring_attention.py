@@ -45,14 +45,7 @@ def _pvary(x, axes):
     axes = tuple(a for a in axes if a not in already)
     if not axes:
         return x
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axes)
-    # jax < 0.5 (e.g. 0.4.x): shard_map has no varying-manual-axes
-    # bookkeeping, so there is nothing to mark — the value is already
-    # usable on every device of the axis
-    return x
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def _pvary_like(x, ref):

@@ -45,8 +45,7 @@ Chaos drill (utils/chaos.py): ``deploy.publish`` fires once per release
 entry write and a ``corrupt@N`` schedule mutates the FRAMED bytes — the
 controller must skip the entry typed and deploy the next good one.
 ``tools/continuous_smoke.py`` drills the whole loop (corrupt publish,
-host loss mid-train, canary regression) exit-coded as runbook cpu-smoke
-stage 2o.
+host loss mid-train, canary regression), exit-coded.
 
 Knobs (utils/config tier; constructor args override):
 

@@ -262,6 +262,13 @@ def condemned_generation(fleet_dir: str, index: int) -> int:
 # spawning
 # ---------------------------------------------------------------------------
 
+#: exit code of a worker whose device is definitely not there (sysexits'
+#: EX_CONFIG; tools/serve_worker.no_such_device): a set-up fault, not a
+#: crash — respawning it would fail the same way, so the supervisor degrades
+#: the slot at once.  A busy chip is NOT this: that worker just exits != 0
+EXIT_NO_DEVICE = 78
+
+
 def default_spawner(fleet_dir: str, *, model: str = "linear",
                     extra_args: tuple = (), env: Optional[dict] = None,
                     python: Optional[str] = None) -> Callable:
@@ -270,7 +277,9 @@ def default_spawner(fleet_dir: str, *, model: str = "linear",
     own spawner (per-member chaos env, virtual devices); this is the
     production default: inherit the environment — the shared
     ``BIGDL_TPU_AOT_CACHE`` dir rides along, which is what makes a
-    respawn warm."""
+    respawn warm.  Workers run on the CPU unless ``extra_args`` carries
+    ``--platform tpu``: a chip belongs to one process, so a fleet on
+    chips needs one chip per worker and a parent that stays off JAX."""
     repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     worker = os.path.join(repo_root, "tools", "serve_worker.py")
@@ -447,13 +456,23 @@ class FleetSupervisor:
     def _handle_loss(self, index: int) -> None:
         with self._lock:
             slot = self._slots[index]
-            slot.restarts += 1
+            proc = slot.proc
+            no_device = (proc is not None
+                         and proc.poll() == EXIT_NO_DEVICE)
+            if no_device:
+                # not a crash: the worker said it cannot have its device.
+                # The budget is spent at once — no respawn, no back-off
+                slot.restarts = self.restart_budget + 1
+            else:
+                slot.restarts += 1
             restarts = slot.restarts
             generation = slot.generation
-            proc = slot.proc
             err = MemberLostError(
-                f"fleet: member {index} (generation {generation}) went "
-                f"publication-silent past {self.lost_after_s:.1f}s",
+                f"fleet: member {index} (generation {generation}) "
+                + ("could not get its device (its stderr says why; a "
+                   "fleet needs one chip per worker)" if no_device else
+                   f"went publication-silent past "
+                   f"{self.lost_after_s:.1f}s"),
                 index=index, generation=generation,
                 retry_after_s=self.backoff_s * (2 ** max(restarts - 1, 0)))
             slot.last_error = err

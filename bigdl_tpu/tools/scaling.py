@@ -1,8 +1,8 @@
 """Scaling-efficiency measurement on a virtual device mesh.
 
-BASELINE.md's scaling target ("linear, 8 -> 64 chips") cannot be measured on
-this image (one real chip), so this tool produces the best available
-evidence (round-2 verdict demand #4):
+BASELINE.md's scaling target ("linear, 8 -> 64 chips") cannot be measured
+without that many chips, so this tool produces a simulated form on virtual
+CPU devices (counts and proxies, never device numbers):
 
 1. **Collective introspection** — compile the real distributed train step
    (Optimizer._build_step) over an n-device mesh and count the XLA
@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import re
 import sys
 
 
@@ -34,12 +33,15 @@ _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
 
 
 def collective_counts(hlo_text: str) -> dict:
-    """Count collective ops in optimized HLO text."""
+    """Collective instructions in optimized HLO text, by opcode.  A result
+    of any shape counts (XLA combines many gradients' all-reduces into one
+    instruction with a tuple result); an async `-start`/`-done` pair counts
+    once."""
+    from ..utils.hlostats import op_histogram
+    hist = op_histogram(hlo_text)
     counts = {}
     for name in _COLLECTIVES:
-        # match op instructions like '%all-reduce.3 = ' or 'all-reduce-start'
-        n = len(re.findall(rf"= \S* ?{name}[.\-(]", hlo_text)) or \
-            len(re.findall(rf"{name}[.\d]* =", hlo_text))
+        n = hist.get(name, 0) + hist.get(name + "-start", 0)
         if n:
             counts[name] = n
     return counts

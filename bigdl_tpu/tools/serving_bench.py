@@ -1,6 +1,6 @@
 """Serving microbench: KV-cache decode vs full re-forward, float vs int8.
 
-Run on the real chip (one JSON line per config, bench.py conventions):
+Run on a TPU, in one process (one JSON line per config, bench.py conventions):
 
     python -m bigdl_tpu.tools.serving_bench [--d-model 512 --num-layers 8
         --max-len 1024 --batch 8 --num-tokens 64]

@@ -1,35 +1,16 @@
-"""Version-compat shims shared across the package.
+"""`shard_map` as the supported JAX (0.9.0) spells it, in one place.
 
-`shard_map` moved from jax.experimental to the jax namespace, and its
-replication-check kwarg was renamed check_rep -> check_vma along the way;
-this is the one place that knows both spellings (previously copy-pasted
-per module).
+`shard_map_unchecked` is shard_map with the varying-manual-axes check off:
+custom_vjp + psum bodies (the Pallas BN / ConvBN shard_map routes) trip the
+checker.
 """
 
-import inspect
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(shard_map).parameters else "check_rep")
-
-# jax < 0.5 has neither lax.pcast nor lax.pvary: a shard_map body that mixes
-# replicated and device-varying values (cond branches, ppermute rings) cannot
-# annotate its replication for the checker and must run unchecked there
-def has_vma_marking() -> bool:
-    import jax
-    return hasattr(jax.lax, "pcast") or hasattr(jax.lax, "pvary")
+from jax import shard_map
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """shard_map with the replication/VMA check disabled, under whichever
-    keyword this jax version spells it (custom_vjp + psum bodies trip the
-    checker on some versions)."""
     return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **{_CHECK_KW: False})
+                     check_vma=False)
 
 
-__all__ = ["shard_map", "shard_map_unchecked", "has_vma_marking"]
+__all__ = ["shard_map", "shard_map_unchecked"]

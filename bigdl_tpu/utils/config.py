@@ -19,7 +19,7 @@ CLIs live in models/run.py and tools/.
 | BIGDL_TPU_PREEMPTION_CHECKPOINT | (net-new: SIGTERM -> final snapshot) | 1 |
 | BIGDL_TPU_DEVICE_TIMEOUT  | (net-new: Engine.init device-discovery watchdog, seconds) | 0 (off) |
 | BIGDL_TPU_RNN_HOIST_MAX_ELEMENTS | (net-new: ConvLSTM hoist cap) | 2^28 |
-| BIGDL_TPU_XLA_CACHE / _DIR | (net-new: persistent compile cache) | 1 / ~/.cache/bigdl_tpu/xla |
+| BIGDL_TPU_XLA_CACHE | (net-new: persistent compile cache on/off; it lives at JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache — utils/platform.py) | 1 |
 | BIGDL_TPU_CONV_PAD_MIN_CIN | (net-new: tiny-channel conv pad, nn/conv.py) | 8 |
 | BIGDL_TPU_BN_IMPL / _FUSED_VJP / _STAT_ROWS | (net-new: BN variants, nn/normalization.py) | off |
 | BIGDL_TPU_BN_BATCH | (net-new: bn_experiment batch) | 256 |
@@ -60,7 +60,7 @@ CLIs live in models/run.py and tools/.
 | BIGDL_TPU_SERVE_TENANT_BURST | (net-new: per-tenant token-bucket depth; 0 = 2x qps, min 1) | 0 (auto) |
 | BIGDL_TPU_AOT_CACHE | (net-new: AOT executable-cache dir, utils/aot.py — serialized compiled executables; warm start = cache read, zero XLA compiles; empty/0 = off) | off |
 | BIGDL_TPU_AOT_CACHE_TAG | (net-new: free-form AOT fingerprint salt; bump to invalidate every entry at once) | "" |
-| BIGDL_TPU_PEAK_FLOPS | (net-new: per-device MFU denominator override, FLOP/s — utils/flops.device_peak_flops; default TPU table / 1e12 CPU-nominal) | 0 (auto) |
+| BIGDL_TPU_PEAK_FLOPS | (net-new: per-device MFU denominator override, FLOP/s — utils/flops.device_peak_flops; default TPU table / 1e12 CPU-nominal; a TPU kind missing from the table is an error) | 0 (auto) |
 | BIGDL_TPU_FUSED_UPDATE | (net-new: multi-tensor fused optimizer update, optim/fused.py — flatten grad/param/slot trees into dtype-homogeneous 1-D buffers; bit-identical to the per-leaf path) | 0 (off) |
 | BIGDL_TPU_WIRE_BUCKET_MB | (net-new: max wire-dtype MB per gradient bucket, parallel/wire.py; 0 = per-leaf wire cast) | 0 (per-leaf) |
 | BIGDL_TPU_OVERLAP_FLAGS | (net-new: latency-hiding-scheduler / async-collective LIBTPU flags, utils/platform.enable_overlap_flags; 0 disables) | 1 |

@@ -45,20 +45,26 @@ def device_peak_flops(device=None):
     """(peak_flops, source) for the MFU denominator.
 
     source is ``"env"`` (BIGDL_TPU_PEAK_FLOPS override), ``"table"`` (TPU
-    device-kind match), or ``"nominal"`` (CPU/unknown fallback,
+    device-kind match), or ``"nominal"`` (a non-TPU device,
     :data:`CPU_NOMINAL_PEAK`).  Callers that refuse to report MFU against
-    a made-up denominator (bench.py) gate on ``source != "nominal"``."""
+    a made-up denominator (bench.py) gate on ``source != "nominal"``.  A
+    device on platform ``tpu`` whose kind matches no row of the table is
+    an error: a utilisation against the CPU's nominal peak would look like
+    a measurement."""
     from . import config
     env = config.get_float("PEAK_FLOPS", 0.0)
     if env > 0:
         return env, "env"
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    if "tpu" in kind or "tpu" in getattr(device, "platform", ""):
+    if device.platform == "tpu":
+        kind = device.device_kind.lower()
         for key, val in _TPU_PEAK_BF16:
             if key in kind:
                 return val, "table"
+        raise ValueError(
+            f"no bf16 peak known for TPU device_kind "
+            f"{device.device_kind!r}: add it to flops._TPU_PEAK_BF16")
     return CPU_NOMINAL_PEAK, "nominal"
 
 

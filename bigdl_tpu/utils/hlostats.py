@@ -136,12 +136,14 @@ def cards_dir() -> Optional[str]:
 # HLO text analysis (pure functions; unit-testable without a backend)
 # ----------------------------------------------------------------------
 
-# optimized-HLO instruction: `%name = f32[8,8]{1,0} opcode(...)` — the
-# result type may be a tuple `(f32[...], s32[...])`; opcodes are
-# lowercase with dashes (all-reduce, custom-call)
+# optimized-HLO instruction, one a line: `%name = f32[8,8]{1,0} opcode(...)`.
+# The result type may be a tuple `(f32[...], s32[...])` and, on a TPU, carry
+# tiled layouts with parentheses of their own (`{1,0:T(8,128)(2,1)}`), so it
+# is skipped, not parsed: the opcode is the first lowercase-with-dashes word
+# (all-reduce, custom-call) that follows a blank and opens a parenthesis
 _HLO_OP_RE = re.compile(
-    r"=\s*(?:\([^)]*\)|[a-z0-9]+\[[^\]]*\](?:\{[^}]*\})?)\s*"
-    r"([a-z][a-z0-9\-]*)\(")
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\S.*?\s([a-z][a-z0-9\-]*)\(",
+    re.M)
 # StableHLO op: `%4 = stablehlo.convert %3 : ...`
 _SHLO_OP_RE = re.compile(r"=\s*stablehlo\.([a-z_]+)")
 # convert with visible operand type: `bf16[...] convert(f32[...] %x)`

@@ -8,7 +8,9 @@ host-side runtime: CRC32C (hardware SSE4.2 when available), BDRecord file IO,
 bf16 wire conversion, and batch-assembly kernels.
 
 Pure-Python fallbacks exist for every entry point — the framework works
-without the compiled library, just slower on the host paths.
+without the compiled library, just slower on the host paths.  The binary is
+built from csrc/ (never committed): `Engine.init()` calls :func:`build` once,
+and `is_native_loaded()` says which side a process is on.
 """
 
 from __future__ import annotations
@@ -104,10 +106,23 @@ def _bind(cdll: ctypes.CDLL) -> None:
         return cdll.bigdl_crc32c(data, len(data))
 
 
+def _is_fresh(so_path: str) -> bool:
+    """True when `so_path` is at least as new as every source under csrc/
+    (or there are no sources to compare with: an installed wheel).  A
+    binary older than its sources was built from some other tree and is
+    never loaded; :func:`build` replaces it."""
+    if not os.path.isdir(_csrc_dir):
+        return True
+    built = os.path.getmtime(so_path)
+    return all(os.path.getmtime(os.path.join(_csrc_dir, f)) <= built
+               for f in os.listdir(_csrc_dir)
+               if f.endswith((".cc", ".h")) or f == "Makefile")
+
+
 def _try_load() -> None:
     global lib
     for _p in _candidates:
-        if os.path.exists(_p):
+        if os.path.exists(_p) and _is_fresh(_p):
             try:
                 cdll = ctypes.CDLL(_p)
                 _bind(cdll)
@@ -118,25 +133,38 @@ def _try_load() -> None:
 
 
 _try_load()
+_build_failed = False
 
 
 def build(quiet: bool = True) -> bool:
     """Compile csrc/ with make and load the result.  Returns True if the
     native library is loaded afterwards (reference analog: BigDL-core's
-    Maven native build producing libjmkl.so)."""
+    Maven native build producing libjmkl.so).  `Engine.init()` calls this
+    once per process, so a checkout made from git — which carries no
+    binary — builds its own from the committed sources; an up-to-date
+    binary is loaded at import and costs nothing here.  Concurrent
+    processes (test workers, fleet workers) serialise on a lock file."""
+    global _build_failed
     if lib is not None:
         return True
-    if not os.path.isdir(_csrc_dir):
+    if _build_failed or not os.path.isdir(_csrc_dir):
         return False
+    import fcntl
+    _build_failed = True  # until the load below says otherwise: a host with
+    # no compiler must not pay for a failing make at every Engine.init
     try:
-        subprocess.run(
-            ["make", "-C", _csrc_dir, "-j"],
-            check=True,
-            stdout=subprocess.DEVNULL if quiet else None,
-            stderr=subprocess.DEVNULL if quiet else None)
+        os.makedirs(os.path.join(_csrc_dir, "build"), exist_ok=True)
+        with open(os.path.join(_csrc_dir, "build", ".lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            subprocess.run(
+                ["make", "-C", _csrc_dir, "-j"],
+                check=True,
+                stdout=subprocess.DEVNULL if quiet else None,
+                stderr=subprocess.DEVNULL if quiet else None)
     except (OSError, subprocess.CalledProcessError):
         return False
     _try_load()
+    _build_failed = lib is None
     return lib is not None
 
 

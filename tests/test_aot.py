@@ -255,17 +255,22 @@ def test_train_bit_identical_cache_off_cold_warm(aot_cache, monkeypatch):
         np.testing.assert_array_equal(o, w)
 
 
-def test_composes_with_xla_persistent_cache(aot_cache, tmp_path):
+def test_composes_with_xla_persistent_cache(aot_cache, tmp_path, monkeypatch):
     """Satellite: the AOT layer composes with, not fights, the XLA
     persistent cache — with both armed, a cold run stores an AOT entry
     (its compile having gone THROUGH the XLA cache, which fills too) and
     a warm run hits the AOT layer without consulting XLA at all."""
     from bigdl_tpu.utils.platform import enable_compilation_cache
-    Engine.init()
     xla_dir = str(tmp_path / "xla")
     prior = jax.config.jax_compilation_cache_dir
+    # what jax does at import when the variable is set: the function then
+    # sets no directory of its own
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", xla_dir)
+    jax.config.update("jax_compilation_cache_dir", xla_dir)
+    monkeypatch.setenv("BIGDL_TPU_XLA_CACHE", "1")  # conftest turns it off
     try:
-        assert enable_compilation_cache(xla_dir) == xla_dir
+        Engine.init()  # arms it
+        assert enable_compilation_cache() == xla_dir
 
         def f(x):
             return jnp.sin(x) @ jnp.cos(x).T
@@ -279,6 +284,16 @@ def test_composes_with_xla_persistent_cache(aot_cache, tmp_path):
         aot.cached_compile(jax.jit(f).lower(x), label="t.compose",
                            example_args=(x,))
         assert aot.stats()["hits"] == 1
+        # an AOT miss that XLA serves out of ITS cache is not stored again
+        # one level up: re-serialized, that executable loses its kernels
+        (key,) = aot.get_cache().entries()
+        os.remove(aot.get_cache()._path(key))
+        jax.clear_caches()
+        out = aot.cached_compile(jax.jit(f).lower(x), label="t.compose",
+                                 example_args=(x,))
+        assert aot.stats()["stores"] == 1 and not aot.get_cache().entries()
+        np.testing.assert_allclose(np.asarray(out(x)), np.asarray(f(x)),
+                                   rtol=1e-6)
     finally:
         # fully un-latch: restore the config AND drop the initialized
         # cache object, or the rest of the suite keeps writing into this
@@ -286,6 +301,57 @@ def test_composes_with_xla_persistent_cache(aot_cache, tmp_path):
         jax.config.update("jax_compilation_cache_dir", prior)
         from jax._src import compilation_cache as _cc
         _cc.reset_cache()
+
+
+def test_rejected_executable_is_warned_once_not_quarantined(
+        aot_cache, monkeypatch, caplog):
+    """An intact entry the runtime refuses to load is NOT bit rot: it
+    stays on disk, is logged once at WARNING with the runtime's error,
+    counts under `errors`, and the caller compiles instead."""
+    import logging
+
+    from jax.experimental import serialize_executable as se
+
+    def f(x):
+        return x * 3 + 1
+
+    x = jnp.ones((5,))
+    aot.cached_compile(jax.jit(f).lower(x), label="t.rej", example_args=(x,))
+    (key,) = aot.get_cache().entries()
+    path = aot.get_cache()._path(key)
+
+    def refuse(*a, **kw):
+        raise RuntimeError("runtime says no")
+
+    monkeypatch.setattr(se, "deserialize_and_load", refuse)
+    monkeypatch.setattr(aot, "_rejected_warned", False)
+    with caplog.at_level(logging.WARNING, logger="bigdl_tpu"):
+        for _ in range(2):
+            jax.clear_caches()
+            out = aot.cached_compile(jax.jit(f).lower(x), label="t.rej",
+                                     example_args=(x,))
+            np.testing.assert_array_equal(np.asarray(out(x)), 4.0)
+    s = aot.stats()
+    assert s["errors"] == 2 and s["corrupt"] == 0 and s["hits"] == 0
+    assert os.path.exists(path) and not os.path.exists(path + ".corrupt")
+    warned = [r for r in caplog.records if "rejected" in r.getMessage()]
+    assert len(warned) == 1 and "runtime says no" in warned[0].getMessage()
+
+
+def test_entry_records_its_device_assignment(aot_cache):
+    """A one-device executable stored from an 8-device process loads bound
+    to ITS device, not to every device of the process."""
+    dev = jax.devices()[3]
+    x = jax.device_put(jnp.ones((4,)), dev)
+    cold = aot.cached_compile(jax.jit(lambda v: v + 1).lower(x),
+                              label="t.dev", example_args=(x,))
+    assert aot._device_ids(cold) == [dev.id]
+    jax.clear_caches()
+    warm = aot.cached_compile(jax.jit(lambda v: v + 1).lower(x),
+                              label="t.dev", example_args=(x,))
+    assert aot.stats()["hits"] == 1
+    assert aot._device_ids(warm) == [dev.id]
+    assert warm(x).devices() == {dev}
 
 
 # ----------------------------------------------------------------------
@@ -352,7 +418,7 @@ _ACCEPTANCE = textwrap.dedent("""
     from bigdl_tpu.utils.platform import force_cpu
     force_cpu(8)
     os.environ["BIGDL_TPU_AOT_CACHE"] = {cache!r}
-    os.environ["BIGDL_TPU_XLA_CACHE"] = "0"
+    os.environ["BIGDL_TPU_XLA_CACHE"] = {xla!r}
     os.environ["BIGDL_TPU_TRACE"] = {trace!r}
     import numpy as np
     import bigdl_tpu.nn as nn
@@ -396,7 +462,8 @@ def test_second_process_warm_starts_with_zero_compiles(tmp_path):
 
     def run(tag):
         trace = str(tmp_path / f"trace_{tag}")
-        code = _ACCEPTANCE.format(repo=_REPO_ROOT, cache=cache, trace=trace)
+        code = _ACCEPTANCE.format(repo=_REPO_ROOT, cache=cache, trace=trace,
+                                  xla="0")
         r = subprocess.run([sys.executable, "-c", code],
                            capture_output=True, text=True, timeout=600,
                            env={**os.environ, "JAX_PLATFORMS": "cpu"})
@@ -424,6 +491,38 @@ def test_second_process_warm_starts_with_zero_compiles(tmp_path):
     assert samples[-1]["hits"] >= 1
     assert not any(e.get("name") == "compile" for e in events
                    if e.get("ph") == "X"), "warm process compiled"
+
+
+def test_engine_init_xla_cache_under_the_aot_layer(tmp_path):
+    """Both caches on, as a chip process with BIGDL_TPU_AOT_CACHE gets them
+    from `Engine.init()`: an AOT miss whose executable XLA read back from
+    its persistent cache is NOT stored one level up (re-serialized, an
+    XLA:CPU executable fails at its first call with `Function ... not
+    found`), so every later process still loads only entries that came
+    from a fresh compile, and runs them."""
+    xla_dir = str(tmp_path / "xla")
+
+    def run(aot_dir, tag):
+        code = _ACCEPTANCE.format(
+            repo=_REPO_ROOT, cache=str(tmp_path / aot_dir),
+            trace=str(tmp_path / f"trace_{tag}"), xla="1")
+        r = subprocess.run([sys.executable, "-c", code],
+                           capture_output=True, text=True, timeout=600,
+                           env={**os.environ, "JAX_PLATFORMS": "cpu",
+                                "JAX_COMPILATION_CACHE_DIR": xla_dir})
+        assert r.returncode == 0, r.stderr[-2000:]
+        return json.loads(r.stdout.strip().splitlines()[-1])
+
+    cold = run("aot_a", "cold")             # both caches cold: fresh compiles
+    assert cold["stores"] == cold["compiles"] >= 2
+    assert os.listdir(xla_dir), "Engine.init() did not arm the XLA cache"
+    xla_warm = run("aot_b", "xla_warm")     # AOT cold, XLA warm
+    assert xla_warm["compiles"] == cold["compiles"]
+    assert xla_warm["stores"] == 0, xla_warm
+    again = run("aot_b", "again")           # nothing to trip over in aot_b
+    assert again["hits"] == 0 and again["stores"] == 0, again
+    warm = run("aot_a", "aot_warm")         # AOT warm: loads, and runs
+    assert warm["compiles"] == 0 and warm["hits"] >= cold["stores"] - 1, warm
 
 
 # ----------------------------------------------------------------------

@@ -1,0 +1,176 @@
+"""The main path's Pallas kernels, compiled by the TPU's compiler for a
+described v5e at the real widths — no chip, nothing runs.
+
+Interpret mode (every other kernel test) cannot see what the chip's compiler
+refuses: a slice off the tiling, too much fast memory, a kernel that cannot
+be partitioned.  These compiles can, in about two seconds each.  A compile
+that passes is not a chip run; chip_smoke.py is.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load libtpu, and under pytest-xdist every worker
+imports this file but only one runs it.  JAX's persistent compile cache is
+off around the compiles (an entry written for a described device cannot be
+read back without one).  `ops/attention.flash_attention` asks
+`jax.default_backend()` and would take the jnp reference on this CPU host,
+so the tests steer it (`use_pallas=True`) themselves.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here, or it is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prior = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prior)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *avals):
+    compiled = jax.jit(fn).lower(*avals).compile()
+    return compiled.as_text()
+
+
+def _aval(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# ------------------------------------------------------- flash attention
+
+_ATTN_SHAPES = [
+    pytest.param((8, 8, 2048, 64), jnp.bfloat16, id="8x8x2048x64-bf16"),
+    pytest.param((4, 8, 512, 64), jnp.float32, id="4x8x512x64-f32"),
+    pytest.param((2, 16, 4096, 128), jnp.bfloat16, id="2x16x4096x128-bf16"),
+]
+
+
+@pytest.mark.parametrize("shape,dtype", _ATTN_SHAPES)
+def test_flash_attention_forward_and_grad_compile(one_chip, shape, dtype):
+    from bigdl_tpu.ops.attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, use_pallas=True)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    q = _aval(shape, dtype, one_chip)
+    assert "tpu_custom_call" in _compile(fwd, q, q, q)
+    # value_and_grad keeps the kernel's forward alive beside the chunked
+    # backward (a bare grad of a sum would dead-code it away)
+    assert "tpu_custom_call" in _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
+
+
+def test_flash_attention_inside_shard_map_compiles(topo):
+    """parallel/ring_attention.ulysses_attention calls the kernel inside a
+    shard_map body: the pallas_call's output and the backward scan's carry
+    must say which mesh axes they vary over."""
+    import functools
+
+    import bigdl_tpu.ops.attention as att
+    from bigdl_tpu.parallel.ring_attention import ulysses_attention
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "seq"))
+    sh = NamedSharding(mesh, P("data", None, "seq", None))
+    q = _aval((2, 8, 4096, 64), jnp.bfloat16, sh)
+    real = att.flash_attention
+    att.flash_attention = functools.partial(real, use_pallas=True)
+    try:
+        def attn(q, k, v):
+            return ulysses_attention(q, k, v, mesh=mesh, causal=True)
+
+        def loss(q, k, v):
+            return attn(q, k, v).astype(jnp.float32).sum()
+
+        text = _compile(attn, q, q, q)
+        assert "tpu_custom_call" in text and "all-to-all" in text
+        assert "tpu_custom_call" in _compile(
+            jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
+    finally:
+        att.flash_attention = real
+
+
+# ------------------------------------------------------------ batch norm
+
+_BN_SHAPES = [
+    pytest.param((256, 56, 56, 64), id="256x56x56x64"),
+    pytest.param((256, 112, 112, 64), id="256x112x112x64"),
+    pytest.param((256, 7, 7, 2048), id="256x7x7x2048"),
+]
+
+
+@pytest.mark.parametrize("shape", _BN_SHAPES)
+def test_bn_train_forward_and_grad_compile(one_chip, shape):
+    """ResNet-50's BN shapes at batch 256, bf16 activations."""
+    from bigdl_tpu.ops.batchnorm import bn_train
+
+    c = shape[-1]
+
+    def fwd(x, w, b):
+        return bn_train(x, w, b, 1e-5)
+
+    def loss(x, w, b):
+        return fwd(x, w, b)[0].astype(jnp.float32).sum()
+
+    x = _aval(shape, jnp.bfloat16, one_chip)
+    w = _aval((c,), jnp.float32, one_chip)
+    assert "tpu_custom_call" in _compile(fwd, x, w, w)
+    assert "tpu_custom_call" in _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), x, w, w)
+
+
+# ------------------------------------------------- conv-epilogue BN stats
+
+_CONVBN_SHAPES = [
+    pytest.param((802816, 64, 256), id="802816x64x256"),
+    pytest.param((12544, 2048, 512), id="12544x2048x512"),
+    pytest.param((50176, 1024, 256), id="50176x1024x256"),
+    pytest.param((802816, 64, 64), id="802816x64x64"),
+]
+
+
+@pytest.mark.parametrize("mkn", _CONVBN_SHAPES)
+def test_fused_conv_bn_forward_and_grad_compile(one_chip, mkn):
+    """ResNet-50's 1x1 convs as [M, K] x [K, N] with the BN statistics in
+    the matmul's epilogue (ops/convbn.py), bf16."""
+    from bigdl_tpu.ops.convbn import fused_conv_bn_train, matmul_stats
+
+    m, k, n = mkn
+    x = _aval((m, k), jnp.bfloat16, one_chip)
+    w = _aval((k, n), jnp.bfloat16, one_chip)
+    g = _aval((n,), jnp.float32, one_chip)
+    assert "tpu_custom_call" in _compile(
+        lambda x, w: matmul_stats(x, w), x, w)
+
+    def fwd(x, w, g, b):
+        return fused_conv_bn_train(x, w, None, g, b, 1e-5)
+
+    def loss(x, w, g, b):
+        return fwd(x, w, g, b)[0].astype(jnp.float32).sum()
+
+    assert "tpu_custom_call" in _compile(fwd, x, w, g, g)
+    assert "tpu_custom_call" in _compile(
+        jax.grad(loss, argnums=(0, 1, 2, 3)), x, w, g, g)

@@ -1,9 +1,9 @@
 """Persistent XLA compilation cache (utils/platform.enable_compilation_cache).
 
-The mitigation for this backend's pathological remote compiles
-(docs/benchmarking.md): entries must be written to the configured dir and
-reused across processes. Driven in subprocesses so the cache config lands
-before any compile, as in real bench runs.
+Where it goes: `JAX_COMPILATION_CACHE_DIR` when set (the code then sets no
+directory of its own), else one fixed path inside the checkout.  Entries must
+be written there and reused across processes.  Driven in subprocesses so the
+cache config lands before any compile, as in real runs.
 """
 
 import json
@@ -12,61 +12,94 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
 
-def _run(cache_dir, repo):
-    code = textwrap.dedent(f"""
-        import json, os, sys, time
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-        sys.path.insert(0, {repo!r})
-        os.environ["BIGDL_TPU_XLA_CACHE_DIR"] = {cache_dir!r}
-        from bigdl_tpu.utils.platform import enable_compilation_cache
-        path = enable_compilation_cache()
-        assert path == {cache_dir!r}, path
-        import jax.numpy as jnp
-        @jax.jit
-        def f(x):
-            return jnp.tanh(x @ x) * 2 + 1
-        x = jnp.ones((333, 333))
-        t0 = time.time()
-        float(f(x).sum())
-        print(json.dumps({{"seconds": time.time() - t0}}))
-    """)
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=180, env=env)
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(code, env_extra, drop=()):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", **env_extra}
+    for k in ("BIGDL_TPU_XLA_CACHE",) + tuple(drop):  # conftest's "0"
+        env.pop(k, None)
+    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                       capture_output=True, text=True, timeout=180, env=env)
     assert r.returncode == 0, r.stderr[-1500:]
     return json.loads(r.stdout.strip().splitlines()[-1])
 
 
+_COMPILE = f"""
+    import json, os, sys, time
+    sys.path.insert(0, {REPO!r})
+    import jax
+    from bigdl_tpu.utils.platform import enable_compilation_cache
+    path = enable_compilation_cache()
+    import jax.numpy as jnp
+    @jax.jit
+    def f(x):
+        return jnp.tanh(x @ x) * 2 + 1
+    float(f(jnp.ones((333, 333))).sum())
+    print(json.dumps({{"path": path,
+                      "config": jax.config.jax_compilation_cache_dir}}))
+"""
+
+
 def test_cache_written_and_reused_across_processes(tmp_path):
     cache = str(tmp_path / "xla")
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    _run(cache, repo)
+    env = {"JAX_COMPILATION_CACHE_DIR": cache}
+    assert _run(_COMPILE, env)["path"] == cache
     entries = os.listdir(cache)
     assert entries, "no cache entries written"
     mtimes = {e: os.path.getmtime(os.path.join(cache, e)) for e in entries}
-    _run(cache, repo)  # second process: must REUSE, not rewrite, the entry
-    # Only the "-cache" payload files hold the compiled executable; newer
-    # jax (>=0.4.36 LRUCache) also writes a "-atime" bookkeeping sidecar
-    # that is REWRITTEN on every hit by design — asserting on it would
-    # fail exactly when the cache works.  Older jax wrote bare entries:
-    # fall back to all jit_f files when no "-cache" suffix exists.
-    jit_entries = [e for e in os.listdir(cache) if e.startswith("jit_f")]
-    payload = [e for e in jit_entries if e.endswith("-cache")] or jit_entries
+    _run(_COMPILE, env)  # second process: must REUSE, not rewrite, the entry
+    # Only the "-cache" payload files hold the compiled executable; the
+    # "-atime" bookkeeping sidecar is REWRITTEN on every hit by design —
+    # asserting on it would fail exactly when the cache works.
+    payload = [e for e in os.listdir(cache)
+               if e.startswith("jit_f") and e.endswith("-cache")]
     assert payload
     for e in payload:
         assert os.path.getmtime(os.path.join(cache, e)) == mtimes.get(e), \
             "jit_f cache entry rewritten on warm run"
 
 
-def test_cache_disabled_by_env(tmp_path):
-    env_backup = dict(os.environ)
-    try:
-        os.environ["BIGDL_TPU_XLA_CACHE"] = "0"
-        from bigdl_tpu.utils.platform import enable_compilation_cache
-        assert enable_compilation_cache(str(tmp_path / "nope")) is None
-        assert not (tmp_path / "nope").exists()
-    finally:
-        os.environ.clear()
-        os.environ.update(env_backup)
+@pytest.mark.parametrize("env_set", [True, False],
+                         ids=["env_set", "env_unset"])
+def test_where_the_cache_goes(tmp_path, env_set):
+    """env set -> jax keeps the directory it read from the environment and
+    the code sets none; env unset -> the one fixed path in the checkout."""
+    code = f"""
+        import json, sys
+        sys.path.insert(0, {REPO!r})
+        import jax
+        calls = []
+        real = jax.config.update
+        def spy(name, val):
+            calls.append(name)
+            return real(name, val)
+        jax.config.update = spy
+        from bigdl_tpu.utils import platform
+        path = platform.enable_compilation_cache()
+        print(json.dumps({{
+            "path": path, "config": jax.config.jax_compilation_cache_dir,
+            "fixed": platform.CHECKOUT_CACHE_DIR,
+            "set_dir": "jax_compilation_cache_dir" in calls}}))
+    """
+    if env_set:
+        d = str(tmp_path / "from_env")
+        out = _run(code, {"JAX_COMPILATION_CACHE_DIR": d})
+        assert out["path"] == d and out["config"] == d
+        assert not out["set_dir"], "code set a directory of its own"
+    else:
+        out = _run(code, {}, drop=("JAX_COMPILATION_CACHE_DIR",))
+        assert out["fixed"] == os.path.join(REPO, ".jax_cache")
+        assert out["path"] == out["fixed"] == out["config"]
+        assert out["set_dir"]
+
+
+def test_cache_disabled_by_env(monkeypatch):
+    import jax
+    monkeypatch.setenv("BIGDL_TPU_XLA_CACHE", "0")
+    prior = jax.config.jax_compilation_cache_dir
+    from bigdl_tpu.utils.platform import enable_compilation_cache
+    assert enable_compilation_cache() is None
+    assert jax.config.jax_compilation_cache_dir == prior

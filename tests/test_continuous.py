@@ -597,8 +597,7 @@ def test_continuous_drill_end_to_end(tmp_path):
     interrupting the release feed, the latency-inflated canary rolled
     back exactly once, the LAST release promoted, the served model
     bit-matching its snapshot, and zero dropped requests — driven
-    through tools/continuous_smoke.py, the exact artifact runbook
-    cpu-smoke stage 2o runs."""
+    through tools/continuous_smoke.py, the CPU drill itself."""
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO_ROOT, "tools",
                                       "continuous_smoke.py"),

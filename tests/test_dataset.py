@@ -307,7 +307,8 @@ class TestStreamingRecordDataSet:
         from bigdl_tpu.dataset import DataSet
         from bigdl_tpu.utils import native
 
-        if not (native.is_native_loaded() and native.has_prefetch()):
+        # a checkout carries no binary: build it as Engine.init would
+        if not (native.build() and native.has_prefetch()):
             pytest.skip("native prefetch unavailable")
         paths = self._shards(tmp_path)
         ds = DataSet.record_stream(paths, num_threads=3)

@@ -15,6 +15,7 @@ import pytest
 
 import bigdl_tpu.nn as nn
 from bigdl_tpu import Engine
+from bigdl_tpu.common import set_seed
 from bigdl_tpu.dataset import DataSet, Sample, SampleToMiniBatch
 from bigdl_tpu.models import LeNet5
 from bigdl_tpu.optim import (Adam, SGD, Optimizer, Trigger, Top1Accuracy,
@@ -35,6 +36,10 @@ def synthetic_mnist(n=512, seed=0):
 
 
 def make_optimizer(strategy=None, batch_size=64, samples=None):
+    # the global stream is whatever this worker's previous test left behind
+    # (final losses 0.77 to 1.0013 over a handful of seeds, PR 22): seed it,
+    # so the thresholds below test the code and not the order of the files
+    set_seed(0)
     model = LeNet5(10)
     ds = DataSet.array(samples or synthetic_mnist()) \
         .transform(SampleToMiniBatch(batch_size, drop_last=True))

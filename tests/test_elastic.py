@@ -795,8 +795,7 @@ def test_elastic_drill_two_ranks_end_to_end(tmp_path):
     shrinks to world=1 with the global batch preserved, resumes from the
     negotiated entry with elastic.* events in its trace, and its final
     loss bit-matches a clean world-1 run from the same entry.  Driven
-    through tools/elastic_smoke.py — the exact artifact the runbook's
-    cpu-smoke stage 2i runs."""
+    through tools/elastic_smoke.py, the CPU drill itself."""
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO_ROOT, "tools",
                                       "elastic_smoke.py"),
@@ -829,7 +828,7 @@ def test_elastic_grow_drill_two_ranks_end_to_end(tmp_path):
     down).  The release feed must stay gap-free across BOTH resizes with
     promotions after the grow, and both ranks must bit-match a clean
     world-2 run resumed from the join snapshot.  Driven through
-    tools/elastic_smoke.py --grow — the runbook's cpu-smoke stage 2p."""
+    tools/elastic_smoke.py --grow, the CPU drill itself."""
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO_ROOT, "tools",
                                       "elastic_smoke.py"),

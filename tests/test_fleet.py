@@ -277,6 +277,69 @@ def test_supervisor_degrades_past_restart_budget(tmp_path):
     assert st["slots"]["0"]["degraded"] and st["live"] == 0
 
 
+def test_supervisor_degrades_at_once_when_a_worker_has_no_device(tmp_path):
+    """A worker that exits EXIT_NO_DEVICE did not crash: it could not get
+    the device it was told to use.  No respawn, no back-off — the slot
+    degrades at its first loss and the error says what to look at."""
+    d = str(tmp_path)
+    spawns = []
+
+    def spawn(index, generation):
+        spawns.append(generation)
+        proc = _FakeProc()
+        proc.returncode = fleet.EXIT_NO_DEVICE
+        return proc
+
+    sup = FleetSupervisor(d, spawn, members=1, lost_after_s=0.05,
+                          poll_s=0.02, backoff_s=0.01, grace_s=5.0,
+                          restart_budget=5)
+    sup.start()
+    try:
+        _wait(lambda: sup.stats()["degraded"] == 1)
+        time.sleep(0.1)
+    finally:
+        sup.stop(terminate=False)
+    assert spawns == [1]
+    assert "could not get its device" in str(sup.last_error)
+
+
+def test_worker_that_cannot_get_its_device_exits_with_the_error(tmp_path):
+    """tools/serve_worker.py: the platform is explicit (cpu by default); a
+    platform jax cannot start ends the process with EXIT_NO_DEVICE and the
+    reason on stderr, before anything is published."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    r = subprocess.run(
+        [sys.executable, os.path.join(repo, "tools", "serve_worker.py"),
+         "--fleet-dir", str(tmp_path), "--index", "0",
+         "--platform", "no_such_platform"],
+        capture_output=True, text=True, timeout=120, env=env)
+    assert r.returncode == fleet.EXIT_NO_DEVICE, r.stderr[-800:]
+    assert "cannot get a 'no_such_platform' device" in r.stderr
+    assert fleet.read_registry(str(tmp_path)) == {}
+
+
+def test_only_a_device_that_is_definitely_absent_skips_the_back_off(
+        monkeypatch, tmp_path):
+    """EXIT_NO_DEVICE is for what waiting cannot cure.  A TPU that is busy
+    or still held by a dying predecessor raises at start-up too, and that
+    worker must leave through the ordinary crash path (respawn, back-off)."""
+    from tools import serve_worker
+    busy = RuntimeError("Unable to initialize backend 'tpu': UNAVAILABLE: "
+                        "TPU is already in use by process with pid 41")
+    vendor = tmp_path / "vendor"
+    monkeypatch.setattr(serve_worker.glob, "glob", lambda pat: [str(vendor)])
+    vendor.write_text("0x1ae0\n")      # a Google accelerator on the bus
+    assert not serve_worker.no_such_device("tpu", busy)
+    vendor.write_text("0x8086\n")      # nothing but someone else's devices
+    assert serve_worker.no_such_device("tpu", busy)
+    assert not serve_worker.no_such_device("cpu", RuntimeError("boom"))
+    assert serve_worker.no_such_device("cpu", RuntimeError(
+        "Backend 'x' is not in the list of known backends: ['cpu']"))
+
+
 def test_supervisor_spawns_past_ghost_heartbeat(tmp_path):
     """A returning supervisor must outrank BOTH the condemnation floor
     and any frozen heartbeat a previous run left behind (the elastic

@@ -2,13 +2,15 @@
 update (optim/fused.py) and the bucketed bf16 gradient wire
 (parallel/wire.py).
 
-The contract under test is BIT-parity: fusing changes the compiled
-program's granularity (a handful of large kernels instead of one per
-leaf), never the scalar expression each element sees.  The one documented
-exception: under ZeRO (ShardedDataParallel) on a multi-device axis the
-bucket/buffer sharding constraints change how GSPMD decomposes the
-cross-device gradient reduction, reassociating the float sum — parity
-there is ~1e-7 relative (pinned below), not bitwise.
+The contract under test: fusing changes the compiled program's granularity
+(a handful of large kernels instead of one per leaf), never the scalar
+expression each element sees.  One update of each method is BIT-identical
+(test_method_fused_update_bitwise).  A whole 5-step training run agrees to
+float tolerance, not bitwise: XLA contracts multiply-adds differently in
+differently shaped loops, and under ZeRO (ShardedDataParallel) the
+bucket/buffer sharding constraints also change how GSPMD decomposes the
+cross-device gradient reduction (`_assert_parity` says what is held to
+what).
 
 Also pins the wire/clip ORDERING: clipping always sees wire-rounded
 gradients (compress-then-aggregate, docs/performance.md "Step arithmetic
@@ -244,18 +246,83 @@ def _fresh_engine():
     Engine.reset()
 
 
+_LR, _STEPS = 1e-3, 5   # what _train runs: Adam(1e-3), five steps
+
+#: the block model's conv biases that sit in front of a BatchNorm.  BN takes
+#: the batch mean out again, so their true gradient is ZERO and what reaches
+#: Adam is rounding noise (test_noise_leaves_are_the_conv_biases_before_bn);
+#: Adam's m/sqrt(v) turns noise of any size into steps of +-lr.  Two programs
+#: that round differently therefore move these three leaves apart by whole
+#: Adam steps while every other leaf, and the loss, agrees to float tolerance.
+_NOISE_LEAVES = {
+    "_resnet_block_model": ("[0]['bias']", "[3][0][0][0]['bias']",
+                            "[3][0][0][3]['bias']"),
+}
+
+
+def _leaf_names(model_fn):
+    """Key paths of the model's parameter leaves, in `_train`'s order."""
+    params = model_fn().build(jax.random.PRNGKey(0)).params
+    return [jax.tree_util.keystr(path) for path, _ in
+            jax.tree_util.tree_flatten_with_path(params)[0]]
+
+
+def _assert_parity(model_fn, losses1, params1, losses0, params0):
+    """Fused against per-leaf after `_STEPS` of Adam(`_LR`): every leaf to
+    rtol 1e-4 / atol 1e-5 and the losses to 1e-5.
+
+    Not bitwise: the two programs hand XLA differently shaped elementwise
+    loops (one fused buffer against one loop a leaf), and whether it
+    contracts a multiply-add differs between them and between compiler
+    versions.  The named `_NOISE_LEAVES` alone are held to nothing tighter
+    than the distance Adam can travel, 2 * lr * steps."""
+    np.testing.assert_allclose(losses1, losses0, rtol=1e-5)
+    names = _leaf_names(model_fn)
+    noise = _NOISE_LEAVES.get(model_fn.__name__, ())
+    assert set(noise) <= set(names) and len(names) == len(params1)
+    for name, a, b in zip(names, params1, params0):
+        a, b = a.astype(np.float32), b.astype(np.float32)
+        if name in noise:
+            assert np.abs(a - b).max() <= 2 * _LR * _STEPS, name
+        else:
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5,
+                                       err_msg=name)
+
+
+def test_noise_leaves_are_the_conv_biases_before_bn():
+    """Why `_assert_parity` lets three leaves go: their gradient is rounding
+    noise, orders of magnitude under every other leaf's, so there is no
+    signal in them for the two programs to agree on."""
+    model = _resnet_block_model().build(jax.random.PRNGKey(0))
+    batch = _samples()[:32]
+    x = jnp.stack([s.feature for s in batch])
+    y = jnp.asarray([int(s.label) for s in batch])
+    criterion = nn.ClassNLLCriterion()
+
+    def loss(params):
+        out, _ = model.apply(params, model.state, x, training=True)
+        return criterion.forward(out, y)
+
+    grads = jax.grad(loss)(model.params)
+    size = {jax.tree_util.keystr(path): float(jnp.abs(g).max()) for path, g
+            in jax.tree_util.tree_flatten_with_path(grads)[0]}
+    noise = _NOISE_LEAVES["_resnet_block_model"]
+    assert max(size[n] for n in noise) < 1e-4
+    assert min(v for n, v in size.items() if n not in noise) > 1e-2
+
+
 @pytest.mark.parametrize("model_fn", [_lenet, _resnet_block_model],
                          ids=["lenet", "resnet_block"])
 def test_fused_update_parity_data_parallel(model_fn, monkeypatch):
     """Acceptance: 5-step LeNet and a ResNet-block model, pure DP — the
-    fused update is bit-identical to the per-leaf path."""
+    fused update agrees with the per-leaf path to float tolerance
+    (bit-identical under some XLA versions, not under jax 0.9.0's: step 4
+    of LeNet reads 2.7044744 against 2.7044747)."""
     Engine.init()
     losses0, params0 = _train(model_fn)
     monkeypatch.setenv("BIGDL_TPU_FUSED_UPDATE", "1")
     losses1, params1 = _train(model_fn)
-    assert losses1 == losses0
-    for a, b in zip(params1, params0):
-        np.testing.assert_array_equal(a, b)
+    _assert_parity(model_fn, losses1, params1, losses0, params0)
 
 
 @pytest.mark.parametrize("model_fn", [_lenet, _resnet_block_model],
@@ -264,18 +331,14 @@ def test_fused_update_parity_zero(model_fn, monkeypatch):
     """Acceptance: the same runs under ZeRO (ShardedDataParallel).  The
     fused buffers' P('data') sharding constraint changes how GSPMD
     decomposes the cross-device reduction, so parity is the documented
-    float tolerance (reassociation-level, ~1e-7 relative), not bitwise."""
+    float tolerance (reassociation-level), not bitwise."""
     Engine.init()
     losses0, params0 = _train(
         model_fn, strategy=ShardedDataParallel(min_size=1))
     monkeypatch.setenv("BIGDL_TPU_FUSED_UPDATE", "1")
     losses1, params1 = _train(
         model_fn, strategy=ShardedDataParallel(min_size=1))
-    np.testing.assert_allclose(losses1, losses0, rtol=1e-5)
-    for a, b in zip(params1, params0):
-        np.testing.assert_allclose(
-            a.astype(np.float32), b.astype(np.float32),
-            rtol=1e-4, atol=1e-5)
+    _assert_parity(model_fn, losses1, params1, losses0, params0)
 
 
 def test_bucketed_wire_parity_and_clip_ordering(monkeypatch):

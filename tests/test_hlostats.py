@@ -442,7 +442,7 @@ def test_perf_gate_baseline_committed_and_wellformed():
 def test_perf_gate_cli_passes_on_clean_head():
     """The acceptance run: the gate against the committed baseline must
     exit 0 with every metric OK (the pad-forced regression demo is
-    exercised by runbook stage 2l and test_perf_gate_check_logic)."""
+    exercised by test_perf_gate_check_logic)."""
     env = {k: v for k, v in os.environ.items()
            if not k.startswith("BIGDL_TPU_")}
     proc = subprocess.run(

@@ -268,9 +268,8 @@ class TestPipelineTraining:
 
         The XLA persistent cache is un-latched for the duration (the
         test_serve/lenet_cold attribution discipline): an executable
-        loaded from the XLA disk cache serializes into an unloadable AOT
-        entry on CPU (quarantined + recompiled — correct, but it would
-        make this ledger lie)."""
+        XLA read back from its disk cache is not stored by the AOT layer,
+        so with that cache warm this ledger would show a miss."""
         from jax._src import compilation_cache as _cc
 
         from bigdl_tpu.utils import aot

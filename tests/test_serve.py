@@ -571,9 +571,9 @@ def test_replica_restart_rewarms_ladder_from_aot_cache(tmp_path,
 
     The XLA persistent cache is un-latched for the duration (same
     attribution discipline as tools/lenet_cold.py --aot-cache): an
-    executable that was itself loaded from the XLA disk cache serializes
-    into an unloadable AOT entry on CPU (quarantined + recompiled — the
-    system stays correct, but the zero-fresh-lowers ledger would lie)."""
+    executable that XLA read back from its disk cache is not stored by the
+    AOT layer (utils/aot._compile_timed), so with that cache warm the
+    zero-fresh-lowers ledger would show misses."""
     from jax._src import compilation_cache as _cc
 
     from bigdl_tpu.utils import aot

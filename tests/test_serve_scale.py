@@ -407,9 +407,8 @@ def test_router_scale_up_is_aot_cache_reads(tmp_path, monkeypatch):
 
     The XLA persistent cache is un-latched for the duration (same
     attribution discipline as the restart x AOT test in test_serve.py):
-    an executable itself loaded from the XLA disk cache serializes into
-    an unloadable AOT entry on CPU — quarantined + recompiled, correct
-    but ledger-skewing."""
+    an executable XLA read back from its disk cache is not stored by the
+    AOT layer, so with that cache warm the ledger would show misses."""
     from jax._src import compilation_cache as _cc
 
     from bigdl_tpu.utils import aot

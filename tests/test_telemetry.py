@@ -344,7 +344,7 @@ def test_trace_report_cli(tmp_path):
     bd = json.loads(res.stdout)
     assert bd["phases"]["step"]["count"] == 4
     assert merged_out.exists()
-    # empty dir -> non-zero exit (the runbook smoke asserts on this)
+    # empty dir -> non-zero exit
     empty = tmp_path / "empty"
     empty.mkdir()
     res2 = subprocess.run(

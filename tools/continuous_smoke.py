@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Continuous train->serve drill: the optimizer->canary loop end-to-end
 with trainer and server as SEPARATE processes sharing only a lineage
-directory (runbook cpu-smoke stage 2o; the tier-1 acceptance test in
+directory (a CPU drill; the tier-1 acceptance test in
 tests/test_continuous.py drives the same artifact).
 
 Orchestration:
@@ -125,7 +125,7 @@ def _spawn(args, rank: int, extra_env: dict):
                                 "BIGDL_TPU_TRACE", "BIGDL_TPU_SUPERVISE",
                                 "BIGDL_TPU_DEPLOY"))}
     env.update({"PYTHONPATH": _REPO_ROOT,
-                "JAX_PLATFORMS": args.platform or "cpu",
+                "JAX_PLATFORMS": args.platform,
                 "BIGDL_TPU_PREFETCH_DEPTH": "0",
                 **extra_env})
     wargs = ["--worker", "--rank", str(rank),
@@ -190,7 +190,9 @@ class _Traffic:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--platform", default=None)
+    ap.add_argument("--platform", default="cpu",
+                    help="a CPU drill with several ranks: a chip belongs "
+                         "to one process, so this never defaults to it")
     ap.add_argument("--worker", action="store_true")
     ap.add_argument("--rank", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None)

@@ -34,8 +34,8 @@ Pacing: ``min_step_s`` pins the per-tick floor (6 ms), so the
 continuous-vs-static comparison is a schedule property, not a CPU-load
 coin flip (the scale_smoke.py discipline).
 
-Wired into tools/tpu_runbook_r05.sh cpu-smoke stage 2r; safe anywhere
-(tiny model, seconds of wall clock, no accelerator needed).
+A CPU drill (``--platform`` defaults to cpu); safe anywhere (tiny
+model, seconds of wall clock, no accelerator needed).
 """
 
 from __future__ import annotations
@@ -155,8 +155,10 @@ def _child(cache_dir: str) -> int:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--platform", default=None,
-                    help="force a jax platform (e.g. cpu)")
+    ap.add_argument("--platform", default="cpu",
+                    help="jax platform (default cpu: the steady-state leg "
+                         "starts a second process, and a chip belongs to "
+                         "one)")
     ap.add_argument("--cache-dir", default=None,
                     help="shared AOT cache dir (default: a fresh "
                          "tempdir)")

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Elastic host-loss drill: prove detect->negotiate->re-form->resume
-end-to-end with REAL processes (the runbook's cpu-smoke stage 2i and the
-tier-1 acceptance test both drive this).
+end-to-end with REAL processes (a CPU drill; the tier-1 acceptance test
+drives this too).
 
 Orchestration (default mode):
 
@@ -22,7 +22,7 @@ Orchestration (default mode):
    sequences are identical).
 
 ``--grow`` runs the full preemption-AND-reclamation drill instead
-(runbook cpu-smoke stage 2p; parallel/elastic step 4):
+(parallel/elastic step 4):
 
 1. Same kill: rank 1 dies at epoch 1 (exit 117), rank 0 shrinks to
    world=1 / batch 32 — but rank 0 also PUBLISHES a release entry per
@@ -62,7 +62,7 @@ import sys
 import tempfile
 
 # runnable as `python tools/elastic_smoke.py` from the repo root (the
-# runbook's invocation): sys.path[0] is tools/, so add the repo root
+# usual invocation): sys.path[0] is tools/, so add the repo root
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO_ROOT not in sys.path:
     sys.path.insert(0, _REPO_ROOT)
@@ -147,7 +147,7 @@ def _spawn(args, rank: int, extra_env: dict, worker_args: list):
            if not k.startswith(("BIGDL_TPU_ELASTIC", "BIGDL_TPU_CHAOS",
                                 "BIGDL_TPU_TRACE", "BIGDL_TPU_SUPERVISE"))}
     env.update({"PYTHONPATH": _REPO_ROOT,
-                "JAX_PLATFORMS": args.platform or "cpu",
+                "JAX_PLATFORMS": args.platform,
                 "BIGDL_TPU_PREFETCH_DEPTH": "0",  # sync data path: the
                 # faulted and clean runs must be bit-comparable
                 **extra_env})
@@ -207,7 +207,7 @@ def _grow_drill(args, ckpt: str, trace: str) -> int:
                     wargs)
         procs.append(p1)
         def _keep(tag, stdout, stderr):
-            # worker logs beside the lineage: the runbook captures only
+            # worker logs beside the lineage: a caller captures only
             # the orchestrator's one JSON line, so a failing stage needs
             # these for the post-mortem
             try:
@@ -383,7 +383,9 @@ def _grow_drill(args, ckpt: str, trace: str) -> int:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--platform", default=None)
+    ap.add_argument("--platform", default="cpu",
+                    help="a CPU drill with several ranks: a chip belongs "
+                         "to one process, so this never defaults to it")
     ap.add_argument("--worker", action="store_true")
     ap.add_argument("--rank", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None)
@@ -397,7 +399,7 @@ def main(argv=None) -> int:
     ap.add_argument("--grow", action="store_true",
                     help="kill-then-RETURN drill: rank 1 rejoins at "
                          "epoch 2 and the cluster widens back to "
-                         "world=2 (runbook stage 2p)")
+                         "world=2")
     ap.add_argument("--return-at", default="2:2",
                     help="epoch:iteration join gate for the re-spawned "
                          "rank 1 (chaos host.return@1=join@E:I, fires "

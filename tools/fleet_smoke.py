@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Fleet drill: supervised worker PROCESSES under kill -9, a wedged
-zombie, and a stale registry entry — in ONE run (runbook cpu-smoke
-stage 2q; tests/test_fleet.py drives the same modules in-process).
+zombie, and a stale registry entry — in ONE run (a CPU drill;
+tests/test_fleet.py drives the same modules in-process).
 
 Orchestration:
 
@@ -114,7 +114,9 @@ class _Traffic:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--platform", default=None)
+    ap.add_argument("--platform", default="cpu",
+                    help="a CPU drill: parent and workers each start jax, "
+                         "and a chip belongs to one process")
     ap.add_argument("--members", type=int, default=3)
     ap.add_argument("--requests", type=int, default=150)
     ap.add_argument("--heartbeat-s", type=float, default=0.1)
@@ -180,7 +182,7 @@ def main(argv=None) -> int:
                                         "BIGDL_TPU_DEPLOY",
                                         "BIGDL_TPU_FLEET"))}
             env.update({"PYTHONPATH": _REPO_ROOT,
-                        "JAX_PLATFORMS": args.platform or "cpu",
+                        "JAX_PLATFORMS": args.platform,
                         "BIGDL_TPU_PREFETCH_DEPTH": "0",
                         "BIGDL_TPU_AOT_CACHE": aot_dir,
                         "BIGDL_TPU_TRACE": trace_dir,

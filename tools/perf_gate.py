@@ -80,8 +80,7 @@ run ``--update-baseline`` and commit the result (structural values are
 overwritten with the measured program; ratio bounds are preserved).
 
 Prints a readable per-metric diff, then ONE JSON line
-(``metric=perf_gate``), and exits non-zero on any regression — runbook
-cpu-smoke stage 2l asserts on it.
+(``metric=perf_gate``), and exits non-zero on any regression.
 """
 
 from __future__ import annotations
@@ -153,11 +152,15 @@ DEFAULT_RATIO_BOUNDS = {
                 "~3-10us; catches a cache-bypass regression that would "
                 "re-list the registry per request)"},
     "fleet.dispatch_traced_ratio": {
-        "value": 10.0, "match": "max",
+        "value": 10.0, "match": "max", "slack": 3.0,
         "note": "the same _pick loop with request tracing ARMED (id "
                 "minted + admit/send/done flow events per pick) over the "
-                "untraced fleet.dispatch_us (measured ~1.5-3x; catches a "
-                "flow path that flushes or allocates per event)"},
+                "untraced fleet.dispatch_us; catches a flow path that "
+                "flushes or allocates per event (100x and more).  A ratio "
+                "of two host-clock loops of milliseconds: 5.0-9.9 over 8 "
+                "readings on an idle sandbox CPU, up to 21.4 beside 12 "
+                "busy processes on its 8 cores (PR 22), hence this row's "
+                "own slack; no other row has one"},
     "metrics.render_us": {
         "value": 5000.0, "match": "max",
         "note": "MetricsRegistry.render() host microseconds over a "
@@ -373,7 +376,7 @@ def measure(batch_size=64):
 
     # ---- proxy 3: AOT cold vs warm -----------------------------------
     cache_dir = tempfile.mkdtemp(prefix="perf_gate_aot_")
-    _fresh({"BIGDL_TPU_AOT_CACHE": cache_dir, "BIGDL_TPU_XLA_CACHE": "0"})
+    _fresh({"BIGDL_TPU_AOT_CACHE": cache_dir})
     aot.reset()
 
     def compile_cost(before, after):
@@ -396,7 +399,7 @@ def measure(batch_size=64):
                       "hits": int(s2["hits"]), "misses": int(s2["misses"]),
                       "stores": int(s2["stores"]),
                       "cache_dir": cache_dir}
-    _fresh({"BIGDL_TPU_AOT_CACHE": None, "BIGDL_TPU_XLA_CACHE": None})
+    _fresh({"BIGDL_TPU_AOT_CACHE": None})
 
     # ---- proxy 7: generative decode (serve/decode.py, ISSUE 18) ------
     # (a) the KV-cache fast-path claim as serving_bench's
@@ -782,6 +785,10 @@ def main(argv=None) -> int:
     # operator pointed BIGDL_TPU_COMPILE_CARDS at a dir already)
     os.environ.setdefault("BIGDL_TPU_COMPILE_CARDS", "1")
     os.environ.pop("BIGDL_TPU_AOT_CACHE", None)  # proxy 3 owns its dir
+    # the gate reads what the compiler makes of each program and times its
+    # compiles: nothing may come out of a persistent cache that an earlier
+    # run, or an earlier proxy of this run, filled (Engine.init arms it)
+    os.environ["BIGDL_TPU_XLA_CACHE"] = "0"
 
     from bigdl_tpu.utils import config as _config
 

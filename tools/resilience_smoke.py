@@ -28,7 +28,7 @@ Prints ONE JSON line::
     {"metric": "resilience_smoke", "ok": true,
      "restart": {...}, "canary": {...}}
 
-Wired into tools/tpu_runbook_r05.sh cpu-smoke stage 2k; safe anywhere
+A CPU drill; safe anywhere
 (tiny model, seconds of wall clock, 8 virtual CPU devices).
 """
 

@@ -23,7 +23,7 @@ the 8-virtual-CPU-device mesh, exit-coded, ONE JSON line:
      fresh lowers (``aot`` ledger — spawn is cache reads), and after
      the traffic drains the pool must SHRINK back to min.
 
-Wired into tools/tpu_runbook_r05.sh cpu-smoke stage 2n; safe anywhere
+A CPU drill; safe anywhere
 (tiny model, seconds of wall clock, no accelerator needed).
 """
 

@@ -30,12 +30,22 @@ Lifecycle (the member state machine docs/serving.md draws):
 
 Usage (normally spawned by fleet.FleetSupervisor, runnable by hand):
     python tools/serve_worker.py --fleet-dir /tmp/fleet --index 0 \
-        --generation 1 --model linear --platform cpu
+        --generation 1 --model linear [--platform tpu]
+
+``--platform`` defaults to ``cpu``.  A chip belongs to one process, so a
+fleet on chips needs one chip per worker; a worker whose device is
+definitely not there (:func:`no_such_device`) exits
+``fleet.EXIT_NO_DEVICE`` with the error on stderr and the supervisor
+degrades its slot instead of respawning it.  Any other start-up error —
+a chip that is busy, or still held by the dying predecessor — leaves
+through the ordinary non-zero exit, and respawn with back-off gets its
+chance.
 """
 
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import os
 import signal
@@ -47,6 +57,23 @@ import time
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO_ROOT not in sys.path:
     sys.path.insert(0, _REPO_ROOT)
+
+
+_GOOGLE_PCI_VENDOR = "0x1ae0"  # every TPU chip is a PCI device of this vendor
+
+
+def no_such_device(platform: str, err: Exception) -> bool:
+    """True only where waiting cannot help: jax does not know `platform`,
+    or a TPU was asked for on a host with no Google accelerator on its PCI
+    bus.  A chip that another process holds, or that is still being handed
+    over, is not that — its error is transient and is not judged here."""
+    if "not in the list of known backends" in str(err):
+        return True
+    if platform == "tpu":
+        vendors = glob.glob("/sys/bus/pci/devices/*/vendor")
+        return not any(open(v).read().strip() == _GOOGLE_PCI_VENDOR
+                       for v in vendors)
+    return False
 
 
 def main(argv=None):
@@ -64,17 +91,15 @@ def main(argv=None):
     ap.add_argument("--replicas", type=int, default=None)
     ap.add_argument("--max-batch", type=int, default=None)
     ap.add_argument("--heartbeat-s", type=float, default=None)
-    ap.add_argument("--platform", default=None)
+    ap.add_argument("--platform", default="cpu",
+                    help="jax platform of this worker (default cpu).  "
+                         "'tpu' takes the chip for this process alone: "
+                         "one chip per worker, and a parent that never "
+                         "touches jax")
     args = ap.parse_args(argv)
 
-    if args.platform:
-        import jax
-        try:
-            jax.config.update("jax_platforms", args.platform)
-        except RuntimeError:
-            pass
-
     import jax
+    jax.config.update("jax_platforms", args.platform)
 
     from bigdl_tpu.serve import default_buckets, fleet
     from bigdl_tpu.serve.server import InferenceServer
@@ -92,7 +117,17 @@ def main(argv=None):
         telemetry.set_active(tracer)
         telemetry.thread_name(f"fleet member {args.index}")
 
-    Engine.init()
+    try:
+        Engine.init()
+    except Exception as e:  # noqa: BLE001 — judged, or raised again
+        if not no_such_device(args.platform, e):
+            raise  # maybe transient: the supervisor's back-off handles it
+        # a set-up fault, not a crash: the supervisor must not respawn
+        # this with back-off as if it had died (fleet.EXIT_NO_DEVICE)
+        print(f"serve_worker {args.index}: cannot get a "
+              f"{args.platform!r} device: {type(e).__name__}: {e}",
+              file=sys.stderr, flush=True)
+        return fleet.EXIT_NO_DEVICE
     model, sample = build_model(args.model)
     server = InferenceServer(model, example=sample,
                              replicas=args.replicas,

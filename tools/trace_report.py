@@ -43,7 +43,7 @@ total-time B/A ratios and per-series last-value deltas, the "what did
 this change do to the run" view `tools/perf_gate.py` automates for the
 committed proxies.  Exit status is non-zero when an input dir holds no
 trace files or the breakdown is empty (no spans) — the error names the
-offending path — and the runbook's smoke stage asserts on it.
+offending path.
 
 The heavy lifting (merge + breakdown + diff + formatting) lives in
 ``bigdl_tpu.utils.telemetry`` so tests exercise it directly; this file is
