@@ -119,10 +119,14 @@ class PrefetchIterator:
                 if self._pre_fire is not None:
                     self._pre_fire()
                 t0 = time.perf_counter()
-                try:
-                    item = next(self._source)
-                except StopIteration:
-                    break
+                # the chain alone; what `transform` adds (the Optimizer's
+                # `prefetch.stage`) reads apart inside `prefetch.item`
+                with telemetry.span("prefetch.produce") as produce_span:
+                    try:
+                        item = next(self._source)
+                    except StopIteration:
+                        produce_span.drop()
+                        break
                 if self._transform is not None:
                     item = self._transform(item)
                 telemetry.complete("prefetch.item",

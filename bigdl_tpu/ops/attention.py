@@ -162,6 +162,9 @@ def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        # the kernel's name for the device trace (where it reaches it:
+        # benchmark/layer_metrics/flash_fwd_ms.train.py)
+        name="flash_fwd",
     )(qr, kr, vr)
     return out.reshape(B, H, Tqp, D)[:, :, :Tq, :]
 

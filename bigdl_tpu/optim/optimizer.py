@@ -677,8 +677,11 @@ class Optimizer:
             batch = chaos.transform("data.batch", batch)
             staged = None
             if stage:
-                staged = _put_batch((batch.get_input(), batch.get_target()),
-                                    data_sh)
+                # the copy to the device, apart from the chain
+                # (`prefetch.produce`) inside the worker's `prefetch.item`
+                with telemetry.span("prefetch.stage"):
+                    staged = _put_batch(
+                        (batch.get_input(), batch.get_target()), data_sh)
             return batch, staged
 
         pipe = prefetch_mod.PrefetchIterator(
@@ -1706,195 +1709,230 @@ class Optimizer:
             data_iter, pipe = self._open_data_pipeline(data_sh)
             self._active_pipe = pipe
             while True:
-                # publish the driver position for '@epoch:iteration'
-                # chaos addressing (one dict store — free when unused)
-                chaos.at_position(state["epoch"], state["neval"])
-                beat("data")
-                if pipe is None:
-                    # chaos: a deterministic hang in the input pipeline —
-                    # the supervisor's 'data' deadline must catch it (with
-                    # prefetch on, the worker fires it instead and its
-                    # supervision channel trips the same deadline)
-                    chaos.fire("data.stall")
-                qdepth = pipe.queue_depth() if pipe is not None else None
-                data_t0 = time.perf_counter()
-                item = next(data_iter, None)
-                if item is None or self.end_trigger(state):
-                    break
-                if pipe is None:
-                    # chaos fault point: one count per training minibatch
-                    # — a fail@ schedule lands in the retry loop like any
-                    # transient data-pipeline failure (the reference's
-                    # ExceptionTest); a corrupt@/nan@ schedule NaN-poisons
-                    # the batch features, which the non-finite-loss
-                    # sentinel must catch.  The prefetch worker runs the
-                    # same transform (same counts, same order) before
-                    # staging.
-                    batch = chaos.transform("data.batch", item)
-                    staged = None
-                else:
-                    batch, staged = item
-                data_wait = time.perf_counter() - data_t0
-                self.metrics.add("get batch time average", data_wait)
-                telemetry.complete("data", data_wait,
-                                   neval=state["neval"])
-                if self._straggler_check(data_wait, state["neval"],
-                                         queue_depth=qdepth):
-                    continue
-                beat("compile" if first_step else "step")
-                first_step = False
-                # chaos: a deterministic hang in the device step (lost
-                # RPC / wedged collective) — the 'step' deadline's case
-                chaos.fire("step.stall")
-                # chaos: host loss drill — only a schedule addressed to
-                # THIS rank engages (exit/wedge; parallel/elastic)
-                chaos.fire(host_lost_point)
-                iter_start = time.perf_counter()
-                lr = float(optim.get_learning_rate(state))
-                # double-buffered path: the worker already device_put this
-                # batch (under the same sharding) while the previous step
-                # was executing
-                inp, tgt = staged if staged is not None else _put_batch(
-                    (batch.get_input(), batch.get_target()), data_sh)
-                rng = next_rng_key()
-                if self._mfu_denom is None and telemetry.enabled():
-                    # arm the per-step mfu counter BEFORE the first step
-                    # consumes (donates) these params
-                    self._arm_mfu(step_fn, (params, net_state, opt_state,
-                                            inp, tgt, jnp.float32(lr), rng),
-                                  mesh)
-                if self._collective_s is None and telemetry.enabled():
-                    self._arm_collective(mesh)
-                params, net_state, opt_state, loss = step_fn(
-                    params, net_state, opt_state, inp, tgt,
-                    jnp.float32(lr), rng)
-                # Resolve the PREVIOUS step's loss (already computed on device,
-                # so this never stalls the pipeline) — triggers like min_loss
-                # therefore act on a 1-iteration-stale value instead of forcing
-                # a device sync every step.
-                if pending_loss is not None:
-                    state["loss"] = self._observe_loss(
-                        float(pending_loss), state)
-                pending_loss = loss
-                n = batch.size()
-                epoch_records += n
-                neval = state["neval"]
-                if neval % self.log_interval == 0:
-                    lossf = self._observe_loss(float(loss), state)
-                    state["loss"] = lossf
-                    pending_loss = None
-                    dt = time.perf_counter() - iter_start
-                    self.metrics.add("computing time average", dt)
-                    logger.info(
-                        "Epoch %d [iteration %d] loss %.6f lr %.5g "
-                        "throughput %.1f records/s",
-                        state["epoch"], neval, lossf, lr, n / max(dt, 1e-9))
-                    if self.train_summary is not None:
-                        # reference parity: Loss + LearningRate + Throughput
-                        # every logged iteration (TrainSummary.scala tags,
-                        # written at DistriOptimizer.scala:345-363)
-                        self.train_summary.add_scalar("Loss", lossf, neval)
-                        self.train_summary.add_scalar("LearningRate", lr, neval)
-                        self.train_summary.add_scalar(
-                            "Throughput", n / max(dt, 1e-9), neval)
-                # per-step telemetry: the host-side step span (dispatch,
-                # plus the loss fetch on logged iterations) and the counter
-                # track the trace_report phase breakdown reads
-                step_dur = time.perf_counter() - iter_start
-                telemetry.complete("step", step_dur, neval=neval)
-                counters = {"data_wait_s": data_wait, "step_s": step_dur,
+                # one span for the whole pass and, inside it, spans that
+                # together cover it (data, prepare, dispatch, loss_fetch,
+                # summary, triggers): what is left is the iteration's self
+                # time.  All are one call and one `is None` test when no
+                # tracer is active
+                with telemetry.span("iteration",
+                                    neval=state["neval"]) as it_span:
+                    # publish the driver position for '@epoch:iteration'
+                    # chaos addressing (one dict store — free when unused)
+                    chaos.at_position(state["epoch"], state["neval"])
+                    beat("data")
+                    if pipe is None:
+                        # chaos: a deterministic hang in the input pipeline —
+                        # the supervisor's 'data' deadline must catch it (with
+                        # prefetch on, the worker fires it instead and its
+                        # supervision channel trips the same deadline)
+                        chaos.fire("data.stall")
+                    qdepth = pipe.queue_depth() if pipe is not None else None
+                    with telemetry.span("data",
+                                        neval=state["neval"]) as data_span:
+                        data_t0 = time.perf_counter()
+                        item = next(data_iter, None)
+                        if item is None or self.end_trigger(state):
+                            # the epoch's end, not an iteration: no events
+                            data_span.drop()
+                            it_span.drop()
+                            break
+                        if pipe is None:
+                            # chaos fault point: one count per training
+                            # minibatch — a fail@ schedule lands in the retry
+                            # loop like any transient data-pipeline failure
+                            # (the reference's ExceptionTest); a corrupt@/nan@
+                            # schedule NaN-poisons the batch features, which
+                            # the non-finite-loss sentinel must catch. The
+                            # prefetch worker runs the same transform (same
+                            # counts, same order) before staging.
+                            batch = chaos.transform("data.batch", item)
+                            staged = None
+                        else:
+                            batch, staged = item
+                        data_wait = time.perf_counter() - data_t0
+                    with telemetry.span("prepare", neval=state["neval"]):
+                        self.metrics.add("get batch time average", data_wait)
+                        if self._straggler_check(data_wait, state["neval"],
+                                                 queue_depth=qdepth):
+                            continue
+                        beat("compile" if first_step else "step")
+                        first_step = False
+                        # chaos: a deterministic hang in the device step (lost
+                        # RPC / wedged collective) — the 'step' deadline's case
+                        chaos.fire("step.stall")
+                        # chaos: host loss drill — only a schedule addressed to
+                        # THIS rank engages (exit/wedge; parallel/elastic)
+                        chaos.fire(host_lost_point)
+                        iter_start = time.perf_counter()
+                        lr = float(optim.get_learning_rate(state))
+                        # double-buffered path: the worker already device_put
+                        # this batch (under the same sharding) while the
+                        # previous step was executing
+                        inp, tgt = staged if staged is not None else \
+                            _put_batch((batch.get_input(),
+                                        batch.get_target()), data_sh)
+                        rng = next_rng_key()
+                        if self._mfu_denom is None and telemetry.enabled():
+                            # arm the per-step mfu counter BEFORE the first
+                            # step consumes (donates) these params
+                            self._arm_mfu(
+                                step_fn, (params, net_state, opt_state, inp,
+                                          tgt, jnp.float32(lr), rng), mesh)
+                        if self._collective_s is None and telemetry.enabled():
+                            self._arm_collective(mesh)
+                    neval = state["neval"]
+                    with telemetry.span("dispatch", neval=neval):
+                        params, net_state, opt_state, loss = step_fn(
+                            params, net_state, opt_state, inp, tgt,
+                            jnp.float32(lr), rng)
+                    # Resolve the PREVIOUS step's loss (already computed on
+                    # device, so this never stalls the pipeline) — triggers
+                    # like min_loss therefore act on a 1-iteration-stale value
+                    # instead of forcing a device sync every step.
+                    if pending_loss is not None:
+                        with telemetry.span("loss_fetch", neval=neval):
+                            # the host blocked on the device
+                            lossf = float(pending_loss)
+                        state["loss"] = self._observe_loss(lossf, state)
+                    pending_loss = loss
+                    n = batch.size()
+                    epoch_records += n
+                    logged = neval % self.log_interval == 0
+                    if logged:
+                        with telemetry.span("loss_fetch", neval=neval):
+                            lossf = float(loss)
+                    with telemetry.span("summary", neval=neval):
+                        if logged:
+                            lossf = self._observe_loss(lossf, state)
+                            state["loss"] = lossf
+                            pending_loss = None
+                            dt = time.perf_counter() - iter_start
+                            self.metrics.add("computing time average", dt)
+                            logger.info(
+                                "Epoch %d [iteration %d] loss %.6f lr %.5g "
+                                "throughput %.1f records/s",
+                                state["epoch"], neval, lossf, lr,
+                                n / max(dt, 1e-9))
+                            if self.train_summary is not None:
+                                # reference parity: Loss + LearningRate +
+                                # Throughput every logged iteration
+                                # (TrainSummary.scala tags, written at
+                                # DistriOptimizer.scala:345-363)
+                                ts = self.train_summary
+                                ts.add_scalar("Loss", lossf, neval)
+                                ts.add_scalar("LearningRate", lr, neval)
+                                ts.add_scalar("Throughput",
+                                              n / max(dt, 1e-9), neval)
+                        # per-step telemetry: the host-side step span
+                        # (dispatch, plus the loss fetch on logged iterations)
+                        # and the counter track the trace_report phase
+                        # breakdown reads
+                        step_dur = time.perf_counter() - iter_start
+                        telemetry.complete("step", step_dur, neval=neval)
+                        counters = {
+                            "data_wait_s": data_wait, "step_s": step_dur,
                             "records_per_sec": n / max(step_dur, 1e-9),
                             "prefetch_queue_depth": float(qdepth or 0)}
-                if self._mfu_denom:
-                    # steady-state host step wall ~= device step time (the
-                    # next dispatch blocks on this step's donated buffers),
-                    # so flops/wall/peak tracks true MFU except on the
-                    # compile step, which shows as an honest dip
-                    counters["mfu"] = (self._step_flops / max(step_dur, 1e-9)
-                                       / self._mfu_denom)
-                    counters["model_flops_per_step"] = self._step_flops
-                if self._collective_s is not None:
-                    # standalone (unoverlapped) wire cost beside the step
-                    # wall: when the scheduler hides the collective, step_s
-                    # stays ~compute while collective_fraction shows what
-                    # WOULD have been added serialized
-                    counters["collective_s"] = self._collective_s
-                    counters["collective_fraction"] = min(
-                        1.0, self._collective_s / max(step_dur, 1e-9))
-                if self._pipe_info is not None:
-                    # the idle fraction of the schedule the step actually
-                    # baked in: (n-1)/(m+n-1) under gpipe, the measured
-                    # table fraction under 1f1b / virtual stages
-                    # (parallel/schedule.py) — microbatch knob clamped to
-                    # divide the local batch
-                    from ..parallel import pipeline as pipe_mod
-                    n_pipe, pmod = self._pipe_info
-                    self._refresh_pipe_effective()
-                    if pmod._last_bubble is not None:
-                        bubble = pmod._last_bubble
-                    else:
-                        mb = (pmod._last_microbatches
-                              or pmod.num_microbatches
-                              or pipe_mod.pipe_microbatches())
-                        bubble = pipe_mod.bubble_fraction(
-                            n_pipe, mb, pmod.schedule or
-                            pipe_mod.pipe_schedule(), pmod.virtual_stages)
-                    counters["pipe_bubble_fraction"] = round(bubble, 4)
-                telemetry.counter("train", **counters)
-                # per-parameter histograms when a "Parameters" trigger is set
-                # (reference: DistriOptimizer.saveSummary :426-456 — off by
-                # default because it pulls every weight to host)
-                if self.train_summary is not None:
-                    ptrig = getattr(self.train_summary,
-                                    "get_summary_trigger", lambda _n: None)(
-                                        "Parameters")
-                    if ptrig is not None and ptrig(state):
-                        for kp, leaf in jax.tree_util.tree_flatten_with_path(
-                                params)[0]:
-                            name = "/".join(
-                                str(getattr(k, "key",
-                                            getattr(k, "idx",
-                                                    getattr(k, "name", k))))
-                                for k in kp)
-                            # multi-host: process-sharded leaves are not
-                            # host-fetchable directly (shared helper skips
-                            # replicated leaves, which np.asarray reads
-                            # locally)
-                            leaf = self._host_fetchable(leaf)
-                            self.train_summary.add_histogram(
-                                name, np.asarray(leaf), neval)
-                state["neval"] = neval + 1
-                state["evalCounter"] = state.get("evalCounter", 0) + 1
-                # preemption skips validation (the eviction grace period is
-                # for the snapshot); otherwise validation runs FIRST so
-                # score-reading checkpoint triggers (max_score, plateau)
-                # see this boundary's fresh result — reference order
-                preempt = self._global_preempted()
-                if not preempt:
-                    self._maybe_validate(params, net_state, state)
-                preempt, fire = self._checkpoint_decision(state,
-                                                          force=preempt)
-                if fire:
-                    self._write_checkpoint(params, net_state, state,
-                                           opt_state, preempt=preempt)
-                if preempt:
-                    self._drain_ckpt_futures()
-                    logger.warning("preemption signal observed: final "
-                                   "checkpoint written, stopping")
-                    raise TrainingPreempted(
-                        "SIGTERM: final checkpoint written at iteration "
-                        f"{state['neval'] - 1}; resume with "
-                        "Optimizer.resume_from or the retry loop of the "
-                        "next incarnation")
-                if fire:
-                    # grow gate: returning hosts are admitted ONLY at a
-                    # checkpoint boundary — the snapshot just written is
-                    # the one the joiner adopts (parallel/elastic step 4)
-                    self._check_join(state)
+                        if self._mfu_denom:
+                            # steady-state host step wall ~= device step time
+                            # (the next dispatch blocks on this step's donated
+                            # buffers), so flops/wall/peak tracks true MFU
+                            # except on the compile step, which shows as an
+                            # honest dip
+                            counters["mfu"] = (
+                                self._step_flops / max(step_dur, 1e-9)
+                                / self._mfu_denom)
+                            counters["model_flops_per_step"] = self._step_flops
+                        if self._collective_s is not None:
+                            # standalone (unoverlapped) wire cost beside the
+                            # step wall: when the scheduler hides the
+                            # collective, step_s stays ~compute while
+                            # collective_fraction shows what WOULD have been
+                            # added serialized
+                            counters["collective_s"] = self._collective_s
+                            counters["collective_fraction"] = min(
+                                1.0, self._collective_s / max(step_dur, 1e-9))
+                        if self._pipe_info is not None:
+                            # the idle fraction of the schedule the step
+                            # actually baked in: (n-1)/(m+n-1) under gpipe, the
+                            # measured table fraction under 1f1b / virtual
+                            # stages (parallel/schedule.py) — microbatch knob
+                            # clamped to divide the local batch
+                            from ..parallel import pipeline as pipe_mod
+                            n_pipe, pmod = self._pipe_info
+                            self._refresh_pipe_effective()
+                            if pmod._last_bubble is not None:
+                                bubble = pmod._last_bubble
+                            else:
+                                mb = (pmod._last_microbatches
+                                      or pmod.num_microbatches
+                                      or pipe_mod.pipe_microbatches())
+                                bubble = pipe_mod.bubble_fraction(
+                                    n_pipe, mb, pmod.schedule or
+                                    pipe_mod.pipe_schedule(),
+                                    pmod.virtual_stages)
+                            counters["pipe_bubble_fraction"] = round(bubble, 4)
+                        telemetry.counter("train", **counters)
+                        # per-parameter histograms when a "Parameters" trigger
+                        # is set (reference: DistriOptimizer.saveSummary
+                        # :426-456 — off by default because it pulls every
+                        # weight to host)
+                        if self.train_summary is not None:
+                            ptrig = getattr(
+                                self.train_summary, "get_summary_trigger",
+                                lambda _n: None)("Parameters")
+                            if ptrig is not None and ptrig(state):
+                                leaves = jax.tree_util.tree_flatten_with_path(
+                                    params)[0]
+                                for kp, leaf in leaves:
+                                    name = "/".join(
+                                        str(getattr(k, "key", getattr(
+                                            k, "idx", getattr(k, "name", k))))
+                                        for k in kp)
+                                    # multi-host: process-sharded leaves are
+                                    # not host-fetchable directly (shared
+                                    # helper skips replicated leaves, which
+                                    # np.asarray reads locally)
+                                    leaf = self._host_fetchable(leaf)
+                                    self.train_summary.add_histogram(
+                                        name, np.asarray(leaf), neval)
+                    with telemetry.span("triggers", neval=neval):
+                        state["neval"] = neval + 1
+                        state["evalCounter"] = state.get("evalCounter", 0) + 1
+                        # preemption skips validation (the eviction grace
+                        # period is for the snapshot); otherwise validation
+                        # runs FIRST so score-reading checkpoint triggers
+                        # (max_score, plateau) see this boundary's fresh result
+                        # — reference order
+                        preempt = self._global_preempted()
+                        if not preempt:
+                            self._maybe_validate(params, net_state, state)
+                        preempt, fire = self._checkpoint_decision(
+                            state, force=preempt)
+                        if fire:
+                            self._write_checkpoint(params, net_state, state,
+                                                   opt_state, preempt=preempt)
+                        if preempt:
+                            self._drain_ckpt_futures()
+                            logger.warning("preemption signal observed: final "
+                                           "checkpoint written, stopping")
+                            raise TrainingPreempted(
+                                "SIGTERM: final checkpoint written at "
+                                f"iteration {state['neval'] - 1}; resume with "
+                                "Optimizer.resume_from or the retry loop of "
+                                "the next incarnation")
+                        if fire:
+                            # grow gate: returning hosts are admitted ONLY at a
+                            # checkpoint boundary — the snapshot just written
+                            # is the one the joiner adopts (parallel/elastic
+                            # step 4)
+                            self._check_join(state)
             self._close_data_pipeline()
             if pending_loss is not None:
-                state["loss"] = self._observe_loss(float(pending_loss),
-                                                   state)
+                # outside any iteration: no `neval`
+                with telemetry.span("loss_fetch"):
+                    lossf = float(pending_loss)
+                state["loss"] = self._observe_loss(lossf, state)
                 pending_loss = None
 
             wall = time.perf_counter() - epoch_start

@@ -94,7 +94,8 @@ class PendingRequest:
     server recorded (RequestTimeout / ServerOverloaded at dequeue /
     ChaosFault / StallError...)."""
 
-    __slots__ = ("payload", "enqueued", "deadline", "tenant", "priority",
+    __slots__ = ("payload", "enqueued", "admitted", "first_token",
+                 "deadline", "tenant", "priority",
                  "version", "latency_s", "rid", "rid_owner",
                  "_event", "_result", "_error")
 
@@ -104,6 +105,11 @@ class PendingRequest:
                  rid: Optional[str] = None, rid_owner: bool = False):
         self.payload = payload
         self.enqueued = enqueued
+        # the decode engine's stamps, on the clock of `enqueued`: taken
+        # into a slot, and its first token sampled (None: not yet, or a
+        # one-shot request, which has neither)
+        self.admitted = None
+        self.first_token = None
         self.deadline = deadline
         self.tenant = tenant     # quota/accounting tag (control plane)
         self.priority = int(priority)  # higher = shed later
@@ -125,12 +131,18 @@ class PendingRequest:
         status = type(error).__name__ if error is not None else "ok"
         if now is not None:
             self.latency_s = max(now - self.enqueued, 0.0)
-            if self.rid is None:
+            if telemetry.get_active() is not None:
+                args = {"status": status}
+                if self.rid is not None:
+                    args["req"] = self.rid
+                if self.admitted is not None:
+                    args["queue_wait_ms"] = \
+                        (self.admitted - self.enqueued) * 1e3
+                if self.first_token is not None:
+                    args["ttft_ms"] = \
+                        (self.first_token - self.enqueued) * 1e3
                 telemetry.complete("serve.request", self.latency_s,
-                                   cat="serve", status=status)
-            else:
-                telemetry.complete("serve.request", self.latency_s,
-                                   cat="serve", status=status, req=self.rid)
+                                   cat="serve", **args)
             reg = metrics_export._REGISTRY
             if reg is not None:
                 reg.observe_request(self.latency_s, status)

@@ -298,6 +298,11 @@ class DecodeEngine:
         self.seqs_failed = 0
         self.cache_grows = 0
         self._busy_s = 0.0
+        # request stamps, summed (always on: two clock reads a request)
+        self.admitted = 0
+        self.queue_wait_s = 0.0      # enqueued -> admitted to a slot
+        self.first_tokens = 0
+        self.ttft_s = 0.0            # enqueued -> first token sampled
 
     # -- lifecycle ------------------------------------------------------
 
@@ -396,7 +401,11 @@ class DecodeEngine:
 
     # -- executables (AOT-keyed like _ShardedForward buckets) -----------
 
-    def _key_fields(self, kind: str, **dims) -> dict:
+    def _key_fields(self, kind: str, jitted, **dims) -> dict:
+        """The AOT key of one executable.  The jitted function's name is
+        part of it: it is the program's name on the device trace, so a
+        warm AOT directory cannot hand back an executable that still runs
+        under an older name."""
         fields = dict(aot_mod.base_fingerprint(self._mesh))
         if self._module_fp is None:
             self._module_fp = aot_mod.module_fingerprint(self.model)
@@ -404,6 +413,7 @@ class DecodeEngine:
         fields["params"] = aot_mod.aval_fingerprint(
             (self._params, self._state))
         fields["kind"] = kind
+        fields["program"] = "jit_" + jitted.__name__
         fields.update(dims)
         return fields
 
@@ -425,8 +435,10 @@ class DecodeEngine:
             return exe
         model, S = self.model, self.slots
 
+        # named for the device trace: its ``XLA Modules`` line shows this
+        # program as ``jit_decode_step``
         @partial(jax.jit, donate_argnums=(2,))
-        def fn(params, state, caches, tok, pos):
+        def decode_step(params, state, caches, tok, pos):
             x = tok[:, None]          # [S, 1] token ids
             caches = list(caches)
             y, _ = _slot_step(model, params, state, x, caches, 0, pos)
@@ -434,10 +446,12 @@ class DecodeEngine:
 
         ivec = jax.ShapeDtypeStruct((S,), jnp.int32)
         exe = aot_mod.get_or_compile(
-            self._key_fields("decode.step", slots=S, cache_len=cache_len,
+            self._key_fields("decode.step", decode_step, slots=S,
+                             cache_len=cache_len,
                              dtype=jnp.dtype(self.cache_dtype).name),
-            lambda: fn.lower(self._params, self._state,
-                             self._cache_avals(cache_len), ivec, ivec),
+            lambda: decode_step.lower(
+                self._params, self._state, self._cache_avals(cache_len),
+                ivec, ivec),
             label="decode.step",
             card_extra={"slots": S, "cache_len": cache_len})
         self._exe[memo] = exe
@@ -456,8 +470,9 @@ class DecodeEngine:
             return exe
         model = self.model
 
+        # ``jit_decode_prefill`` on the device trace's ``XLA Modules`` line
         @partial(jax.jit, donate_argnums=(2,))
-        def fn(params, state, caches, toks, slot, t0):
+        def decode_prefill(params, state, caches, toks, slot, t0):
             # slice this slot's [1, H, L, D] cache views out, run the
             # rows=1 incremental step over the prompt, write back — the
             # other slots' caches pass through untouched
@@ -481,11 +496,11 @@ class DecodeEngine:
             return logits[0], new          # [V] logits of last position
 
         exe = aot_mod.get_or_compile(
-            self._key_fields("decode.prefill", slots=self.slots,
-                             cache_len=cache_len,
+            self._key_fields("decode.prefill", decode_prefill,
+                             slots=self.slots, cache_len=cache_len,
                              prompt_bucket=prompt_bucket,
                              dtype=jnp.dtype(self.cache_dtype).name),
-            lambda: fn.lower(
+            lambda: decode_prefill.lower(
                 self._params, self._state, self._cache_avals(cache_len),
                 jax.ShapeDtypeStruct((prompt_bucket,), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32),
@@ -593,6 +608,27 @@ class DecodeEngine:
         self._slots[s] = None
         self.seqs_done += 1
 
+    def _stamp_admitted(self, req: PendingRequest) -> None:
+        req.admitted = self.clock()
+        wait = max(req.admitted - req.enqueued, 0.0)
+        self.admitted += 1
+        self.queue_wait_s += wait
+        reg = metrics_export._REGISTRY
+        if reg is not None:
+            reg.observe("bigdl_decode_queue_wait_seconds", wait,
+                        help="submit to admission into a slot, seconds")
+
+    def _stamp_first_token(self, req: PendingRequest) -> None:
+        req.first_token = self.clock()
+        ttft = max(req.first_token - req.enqueued, 0.0)
+        self.first_tokens += 1
+        self.ttft_s += ttft
+        reg = metrics_export._REGISTRY
+        if reg is not None:
+            reg.observe("bigdl_decode_ttft_seconds", ttft,
+                        help="time to first token (submit to the first "
+                             "sampled token), seconds")
+
     def _sample(self, seq: _Seq, logits_row: np.ndarray) -> int:
         tok, seq.rng = sample_next(logits_row[None], seq.temperature,
                                    seq.top_k, seq.rng)
@@ -606,6 +642,8 @@ class DecodeEngine:
         seq.buf[seq.pos] = tok
         seq.emitted += 1
         self.tokens_out += 1
+        if seq.emitted == 1:
+            self._stamp_first_token(seq.req)
         if seq.req.rid is not None:
             # one flow step per emitted token: the per-token decode ticks
             # become arrows on the request's chain in Perfetto
@@ -624,6 +662,7 @@ class DecodeEngine:
         seq = _Seq(req, prompt, p["max_tokens"], p.get("eos"),
                    p.get("temperature", 0.0), p.get("top_k", 0), rng)
         self._slots[s] = seq
+        self._stamp_admitted(req)
         if req.rid is not None:
             telemetry.flow_step(req.rid, hop="decode.admit", slot=s,
                                 prompt_len=t0)
@@ -633,19 +672,22 @@ class DecodeEngine:
             self._fail_slot(s, e)
             return
         pb = _prompt_bucket(t0)
-        toks = np.zeros(pb, np.int32)
-        toks[:t0] = prompt
-        exe = self._prefill_exe(pb, self._cache_len)
-        try:
-            logits, self._caches = exe(
-                self._params, self._state, self._caches,
-                jnp.asarray(toks), jnp.int32(s), jnp.int32(t0))
-        except Exception as e:  # noqa: BLE001
-            self._fail_slot(s, SlotFault(f"decode: prefill failed in "
-                                         f"slot {s}: {e!r}"))
-            return
-        self.prefill_steps += 1
-        self._advance(s, self._sample(seq, np.asarray(logits)))
+        # the prefill call, the fetch of its logits and the first sample
+        with telemetry.span("decode.admit", cat="serve", prompt_len=t0,
+                            bucket=pb, slot=s):
+            toks = np.zeros(pb, np.int32)
+            toks[:t0] = prompt
+            exe = self._prefill_exe(pb, self._cache_len)
+            try:
+                logits, self._caches = exe(
+                    self._params, self._state, self._caches,
+                    jnp.asarray(toks), jnp.int32(s), jnp.int32(t0))
+            except Exception as e:  # noqa: BLE001
+                self._fail_slot(s, SlotFault(f"decode: prefill failed in "
+                                             f"slot {s}: {e!r}"))
+                return
+            self.prefill_steps += 1
+            self._advance(s, self._sample(seq, np.asarray(logits)))
 
     def _tick(self) -> bool:
         """One loop iteration: admit into free slots, decode all active
@@ -661,6 +703,14 @@ class DecodeEngine:
                 return False
             q.wait_for_work(DecodeQueue._SLICE)
             return True
+        with telemetry.span("decode.tick", cat="serve", active=n_active,
+                            admitted=len(incoming)):
+            self._work(q, free, n_active, incoming)
+        return True
+
+    def _work(self, q, free, n_active, incoming) -> None:
+        """What one tick does once there is something to do (the
+        ``decode.tick`` span): admissions, one decode step, the counters."""
         t_start = self.clock()
         tokens_before = self.tokens_out
         if incoming:
@@ -681,26 +731,36 @@ class DecodeEngine:
                 self._fail_slot(s, e)
                 active.remove(s)
         if active:
-            tok = np.zeros(self.slots, np.int32)
-            pos = np.zeros(self.slots, np.int32)
-            for s in active:
-                seq = self._slots[s]
-                tok[s] = seq.buf[seq.pos]
-                pos[s] = seq.pos
-            exe = self._step_exe(self._cache_len)
-            logits, self._caches = exe(self._params, self._state,
-                                       self._caches, jnp.asarray(tok),
-                                       jnp.asarray(pos))
-            logits = np.asarray(logits)
+            # the step's call and the fetch of its logits: the host
+            # blocked on the device
+            with telemetry.span("decode.step", cat="serve",
+                                active=len(active)):
+                tok = np.zeros(self.slots, np.int32)
+                pos = np.zeros(self.slots, np.int32)
+                for s in active:
+                    seq = self._slots[s]
+                    tok[s] = seq.buf[seq.pos]
+                    pos[s] = seq.pos
+                exe = self._step_exe(self._cache_len)
+                logits, self._caches = exe(self._params, self._state,
+                                           self._caches, jnp.asarray(tok),
+                                           jnp.asarray(pos))
+                logits = np.asarray(logits)
             self.decode_steps += 1
-            for s in active:
-                self._advance(s, self._sample(self._slots[s], logits[s]))
+            with telemetry.span("decode.sample", cat="serve",
+                                active=len(active)):
+                for s in active:
+                    self._advance(s, self._sample(self._slots[s],
+                                                  logits[s]))
         dt = self.clock() - t_start
         if self.min_step_s > 0 and dt < self.min_step_s:
             time.sleep(self.min_step_s - dt)
             dt = self.min_step_s
         self._busy_s += dt
         q.note_service(max(self.tokens_out - tokens_before, 1), dt)
+        if telemetry.get_active() is None \
+                and metrics_export._REGISTRY is None:
+            return      # nothing reads the track: compute none of it
         n_active = sum(1 for s in self._slots if s is not None)
         steps = self.prefill_steps + self.decode_steps
         telemetry.counter(
@@ -711,7 +771,6 @@ class DecodeEngine:
             decode_frac=self.decode_steps / max(steps, 1),
             cache_bytes_per_slot=self.cache_bytes_per_slot(),
             cache_len=self._cache_len)
-        return True
 
     # -- introspection --------------------------------------------------
 
@@ -733,6 +792,10 @@ class DecodeEngine:
             "tokens_per_s": round(self.tokens_per_s(), 3),
             "seqs_done": self.seqs_done,
             "seqs_failed": self.seqs_failed,
+            "admitted": self.admitted,
+            "queue_wait_s": self.queue_wait_s,
+            "first_tokens": self.first_tokens,
+            "ttft_s": self.ttft_s,
             "queue": self.queue.stats(),
             "quota": self.quotas.stats(),
             "aot": {k: int(s[k]) for k in ("hits", "misses", "stores",

@@ -17,12 +17,19 @@ profiling splits into two tools matching the two execution modes:
    reference's `getTimes()` contract.
 
 2. `trace_steps` — the compiled path: wraps N executions of the real train
-   step in `jax.profiler.trace`, producing a TensorBoard-loadable xplane
+   step in a profiler session, producing a TensorBoard-loadable xplane
    trace where XLA's own per-op breakdown lives (SURVEY.md §7.6).
+
+`profiler_session` is the one way this package starts the JAX profiler, and
+`xplane_rows` reads what it wrote back as plain rows
+(`telemetry.idle_by_cause`, `tools/trace_report.py --xplane`).
 """
 
 from __future__ import annotations
 
+import contextlib
+import glob
+import os
 import time
 from typing import Any, Dict, List, Tuple
 
@@ -30,7 +37,8 @@ import jax
 
 from .timing import fetch_scalar
 
-__all__ = ["ModuleProfiler", "trace_steps"]
+__all__ = ["ModuleProfiler", "trace_steps", "profiler_session",
+           "xplane_rows"]
 
 
 def _sync(x) -> None:
@@ -167,9 +175,56 @@ def trace_steps(run, n: int, logdir: str):
     xprof on `logdir`.
     """
     out = None
-    with jax.profiler.trace(logdir):
+    with profiler_session(logdir):
         for _ in range(n):
             out = run()
         if out is not None:
             _sync(out)
     return logdir
+
+
+@contextlib.contextmanager
+def profiler_session(logdir: str):
+    """A JAX profiler session that does not stall the host: the Python
+    tracer off and no HLO protos (with the defaults the host stopped for a
+    second at a time on a v5e and the device read 85 % idle; PERF.md, PR
+    24), the host tracer at level 1, which records the
+    ``TraceAnnotation`` every open telemetry span holds (``bigdl:<name>``)
+    and so puts the program's spans on the device trace's clock.  The
+    host tracer is not free either: a loop that copies a 154 MB batch to
+    the device every step ran four times slower inside such a session
+    (ResNet-50, PERF.md, PR 25), one with small batches as before."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    opts.enable_hlo_proto = False
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+    try:
+        yield logdir
+    finally:
+        jax.profiler.stop_trace()
+
+
+def xplane_rows(trace_dir: str) -> list:
+    """Events of the newest ``*.xplane.pb`` under ``trace_dir`` as rows
+    ``[plane, line, name, start_ns, duration_ns]``: every event of the
+    device planes, and of the host's planes the program's own spans
+    (names that start with ``telemetry.ANNOTATION_PREFIX``).  Raises
+    FileNotFoundError where no trace file is."""
+    from .telemetry import ANNOTATION_PREFIX
+    paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                             recursive=True))
+    if not paths:
+        raise FileNotFoundError(f"{trace_dir}: no *.xplane.pb file found")
+    rows = []
+    for plane in jax.profiler.ProfileData.from_file(paths[-1]).planes:
+        device = plane.name.startswith("/device:")
+        for i, line in enumerate(plane.lines):
+            # a host line is one thread, and the trace names every thread
+            # of a Python process alike: the line's place tells them apart
+            label = line.name if device else f"{line.name}#{i}"
+            for ev in line.events:
+                if device or ev.name.startswith(ANNOTATION_PREFIX):
+                    rows.append([plane.name, label, ev.name,
+                                 float(ev.start_ns), float(ev.duration_ns)])
+    return rows

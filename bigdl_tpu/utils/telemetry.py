@@ -27,6 +27,11 @@ Core pieces
 - Timestamps are wall-clock-anchored (epoch micros, advanced by the
   monotonic clock) so traces from different hosts line up on one timeline
   after :func:`merge_traces`; the clock pair is injectable for tests.
+- One clock with the device trace: every open span also holds a
+  ``jax.profiler.TraceAnnotation`` named ``bigdl:<span>``, so a profiler
+  session whose host tracer is on (``utils/profiling.profiler_session``)
+  carries the program's spans beside the device's operations;
+  :func:`idle_by_cause` puts the device's idle gaps down to them.
 - :func:`merge_traces` + :func:`phase_breakdown` + :func:`format_report`
   are the analysis core behind ``tools/trace_report.py``: merge
   ``trace.*.json`` of all ranks, compute per-phase p50/p95/max, the
@@ -37,9 +42,15 @@ Who emits what (all through the module-level helpers, so everything is
 inert until a tracer is active):
 
 - the Optimizer train loop: ``data``/``step``/``checkpoint``/
-  ``validation`` spans + a per-step counter track;
+  ``validation`` spans + a per-step counter track; one ``iteration`` span
+  a pass with children that cover it (``data``, ``prepare``, ``dispatch``,
+  ``loss_fetch``, ``summary``, ``triggers``);
 - the prefetch worker (dataset/prefetch.py): its own named thread track
-  with per-item ``prefetch.item`` spans;
+  with per-item ``prefetch.item`` spans, split into ``prefetch.produce``
+  (the chain) and ``prefetch.stage`` (the copy to the device);
+- the decode engine (serve/decode.py): ``decode.tick`` with
+  ``decode.admit``/``decode.step``/``decode.sample`` inside it, and the
+  ``serve.decode`` counter track;
 - file_io: ``ckpt.write``/``ckpt.read`` spans (write+verify),
   ``ckpt.retention`` spans, and an ``io.retry`` instant per remote-IO
   retry attempt;
@@ -77,6 +88,7 @@ __all__ = ["Tracer", "enabled", "trace_dir", "maybe_start", "set_active",
            "format_report", "diff_breakdowns", "format_diff",
            "flow_start", "flow_step", "flow_finish", "mint_request_id",
            "request_breakdown", "format_requests",
+           "idle_by_cause", "format_idle", "ANNOTATION_PREFIX",
            "REQUEST_ID_HEADER", "TRACE_FILE_RE"]
 
 #: the train loop's phase spans — the names phase_breakdown() ranks first
@@ -88,6 +100,11 @@ TRACE_FILE_RE = r"trace\.(\d+)\.json"
 #: s/t/f phases into one arrow chain only when (name, cat, id) all match
 FLOW_NAME = "request"
 FLOW_CAT = "req"
+
+#: prefix of the ``jax.profiler.TraceAnnotation`` every open span holds:
+#: the program's spans on the profiler's timeline are the host events whose
+#: names start with it
+ANNOTATION_PREFIX = "bigdl:"
 
 #: the HTTP header the fleet front uses to propagate a request id to the
 #: member that serves it (and that members echo back in every response)
@@ -106,27 +123,48 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def drop(self):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "cat", "args", "_t0")
+    """One open span.  Besides the tracer's own "X" event it holds a
+    ``jax.profiler.TraceAnnotation`` named ``bigdl:<name>`` open for as
+    long, so a profiler session whose host tracer is on carries the span
+    on the profiler's own clock, beside the device's operations
+    (:func:`idle_by_cause` reads them back).  Without a profiler session
+    the annotation is one inactive native call."""
+
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_ann", "_dropped")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, args):
         self._tr = tr
         self.name = name
         self.cat = cat
         self.args = args
+        self._dropped = False
 
     def __enter__(self):
+        self._ann = self._tr._annotation(ANNOTATION_PREFIX + self.name)
+        self._ann.__enter__()
         self._t0 = self._tr._now_us()
         return self
 
     def __exit__(self, *exc):
-        self._tr._emit_complete(self.name, self.cat, self._t0,
-                                self._tr._now_us() - self._t0, self.args)
+        dur = self._tr._now_us() - self._t0
+        self._ann.__exit__(*exc)
+        if not self._dropped:
+            self._tr._emit_complete(self.name, self.cat, self._t0, dur,
+                                    self.args)
         return False
+
+    def drop(self):
+        """Leave no event behind: for a span opened before the code knew
+        there was nothing to do (the ``next()`` that ends an epoch)."""
+        self._dropped = True
 
 
 class Tracer:
@@ -166,6 +204,10 @@ class Tracer:
         self._closed = False
         import socket
         self._host = socket.gethostname()
+        # what every open span also holds (class _Span); importing the
+        # profiler module starts no backend and no profiler session
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self._meta.append({"ph": "M", "name": "process_name",
                            "pid": self.rank, "tid": 0,
                            "args": {"name": f"rank {self.rank} "
@@ -721,6 +763,139 @@ def format_report(breakdown: dict, merged: Optional[dict] = None) -> str:
     if breakdown["instants"]:
         lines.append("instant events: " + ", ".join(
             f"{k} x{v}" for k, v in sorted(breakdown["instants"].items())))
+    return "\n".join(lines)
+
+
+# ---------------------------------------------------------------------------
+# device idle gaps by cause (trace_report --xplane)
+# ---------------------------------------------------------------------------
+# The same window and the same gaps as benchmark/trace_reduce.py, which the
+# program cannot import (the benchmark reads the program, never the other
+# way): tests/test_telemetry_spans.py holds the two to one total on the
+# benchmark's recorded trace.
+
+_DEVICE_PREFIX = "/device:"
+_OP_LINE = "XLA Ops"
+_NOT_OPS = ("Steps", "XLA Modules", "XLA TraceMe", "Framework Ops",
+            "Framework Name Scope", "Source code")
+#: the spans only the thread that drives the device opens
+_DISPATCH_SPANS = ("dispatch", "decode.step")
+
+
+def _device_gaps(rows, trim: float) -> List[List[tuple]]:
+    """One list of idle intervals ``(start_ns, end_ns)`` for each device
+    plane: what the union of its operations leaves of the trimmed window."""
+    by_plane: Dict[str, Dict[str, list]] = {}
+    for plane, line, _name, start, dur in rows:
+        if plane.startswith(_DEVICE_PREFIX) and dur > 0:
+            by_plane.setdefault(plane, {}).setdefault(line, []).append(
+                (start, start + dur))
+    out = []
+    for lines in by_plane.values():
+        evs = lines.get(_OP_LINE) or [e for ln, es in lines.items()
+                                      if ln not in _NOT_OPS for e in es]
+        if not evs:
+            continue
+        first = min(s for s, _e in evs)
+        last = max(e for _s, e in evs)
+        lo = first + trim * (last - first)
+        hi = last - trim * (last - first)
+        gaps, cursor = [], lo
+        for s, e in sorted(evs):
+            s, e = max(s, lo), min(e, hi)
+            if e <= s:
+                continue
+            if s > cursor:
+                gaps.append((cursor, s))
+            cursor = max(cursor, e)
+        if hi > cursor:
+            gaps.append((cursor, hi))
+        out.append(gaps)
+    return out
+
+
+def _deepest_segments(spans) -> List[tuple]:
+    """``[(start, end, name)]`` without overlap, in time order: each
+    stretch of one thread's nested spans under the name of the deepest
+    span open there."""
+    out, stack, cursor = [], [], 0.0
+
+    def close_until(t):
+        nonlocal cursor
+        while stack and stack[-1][1] <= t:
+            _s, end, name = stack.pop()
+            if end > cursor:
+                out.append((cursor, end, name))
+                cursor = end
+
+    for s, e, name in sorted(spans, key=lambda x: (x[0], -x[1])):
+        close_until(s)
+        if stack:
+            e = min(e, stack[-1][1])     # a child ends with its parent
+            if s > cursor:
+                out.append((cursor, s, stack[-1][2]))
+        if e > s:
+            stack.append((s, e, name))
+            cursor = s
+    close_until(float("inf"))
+    return out
+
+
+def idle_by_cause(rows, trim: float = 0.1) -> List[list]:
+    """The device's idle time put down to what the program was doing.
+
+    ``rows`` are a profiler trace's events as ``[plane, line, name,
+    start_ns, duration_ns]`` (``utils/profiling.xplane_rows``; the shape
+    of ``benchmark/trace_reduce.read_xplane`` with the host's planes kept).
+    The idle gaps are what the union of a device's ``XLA Ops`` leaves of
+    the window, ``trim`` of the trace cut from each side: they sum to
+    ``window_s - busy_s`` of ``trace_reduce.reduce_rows``.  Each stretch of
+    a gap goes to the deepest ``bigdl:`` span (every span open while a
+    ``Tracer`` is active, :class:`_Span`) that covers it on the thread
+    that drives the device, the one that holds ``dispatch`` or
+    ``decode.step`` spans; what no span covers is ``unattributed`` and is
+    never spread over its neighbours.  Returns ``[[cause, seconds], ...]``,
+    largest first, averaged over the devices."""
+    def dispatches(spans):
+        return sum(1 for _s, _e, n in spans if n in _DISPATCH_SPANS)
+
+    threads: Dict[tuple, list] = {}
+    for plane, line, name, start, dur in rows:
+        if name.startswith(ANNOTATION_PREFIX) \
+                and not plane.startswith(_DEVICE_PREFIX):
+            threads.setdefault((plane, line), []).append(
+                (start, start + dur, name[len(ANNOTATION_PREFIX):]))
+    driver = max(threads.values(), default=[], key=dispatches)
+    segments = _deepest_segments(driver) if dispatches(driver) else []
+    devices = _device_gaps(rows, trim)
+    by_cause: Dict[str, float] = {}
+    for gaps in devices:
+        i = 0
+        for a, b in gaps:
+            covered = 0.0
+            while i < len(segments) and segments[i][1] <= a:
+                i += 1
+            j = i
+            while j < len(segments) and segments[j][0] < b:
+                s, e, name = segments[j]
+                ns = min(e, b) - max(s, a)
+                by_cause[name] = by_cause.get(name, 0.0) + ns
+                covered += ns
+                j += 1
+            by_cause["unattributed"] = by_cause.get("unattributed", 0.0) \
+                + (b - a) - covered
+    n = max(len(devices), 1)
+    return sorted(([cause, ns / n / 1e9] for cause, ns in by_cause.items()
+                   if ns > 0), key=lambda x: -x[1])
+
+
+def format_idle(causes: List[list]) -> str:
+    """Human-readable rendering of :func:`idle_by_cause`."""
+    total = sum(s for _c, s in causes)
+    lines = [f"device idle in the trimmed window: {total:.6f} s",
+             f"{'cause':<24}{'seconds':>12}{'share':>9}"]
+    for cause, s in causes:
+        lines.append(f"{cause:<24}{s:>12.6f}{s / max(total, 1e-12):>9.1%}")
     return "\n".join(lines)
 
 

@@ -1,0 +1,385 @@
+"""The spans of the two host loops, the names of the device programs, the
+request stamps and the device's idle time by cause (ISSUE 25).
+
+- the optimizer loop's ``iteration`` span is covered by its children to
+  within its self time, every span carries ``neval``, ``data`` is what it
+  was;
+- with no tracer active neither loop creates a span or an annotation;
+- the decode engine's two programs have names and AOT keys of their own;
+  a tick's children; ``enqueued <= admitted <= first_token <= resolve``;
+- ``idle_by_cause`` keeps ``trace_reduce``'s total on the benchmark's
+  recorded trace and names synthetic host spans, the rest ``unattributed``.
+"""
+
+import gzip
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import jax
+import pytest
+
+import bigdl_tpu.nn as nn
+from bigdl_tpu.dataset import DataSet, Sample, SampleToMiniBatch
+from bigdl_tpu.models.transformer_lm import TransformerLM
+from bigdl_tpu.optim import Adam, Optimizer, Trigger
+from bigdl_tpu.serve import DecodeEngine
+from bigdl_tpu.utils import aot as aot_mod
+from bigdl_tpu.utils import metrics_export, profiling, telemetry
+from bigdl_tpu.utils.telemetry import Tracer, idle_by_cause
+
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LOOP_CHILDREN = ("data", "prepare", "dispatch", "loss_fetch", "summary",
+                 "triggers")
+
+
+@pytest.fixture(autouse=True)
+def _clean_telemetry(monkeypatch):
+    monkeypatch.delenv("BIGDL_TPU_TRACE", raising=False)
+    telemetry.set_active(None)
+    yield
+    telemetry.set_active(None)
+
+
+@pytest.fixture
+def tracer(tmp_path):
+    tr = Tracer(str(tmp_path / "trace"), flush_every=0, ring=1 << 16)
+    telemetry.set_active(tr)
+    return tr
+
+
+def _optimizer(n=96, batch=16, epochs=2):
+    rng = np.random.default_rng(0)
+    samples = [Sample(rng.standard_normal(6).astype(np.float32),
+                      np.float32(i % 2)) for i in range(n)]
+    ds = DataSet.array(samples).transform(
+        SampleToMiniBatch(batch, drop_last=True))
+    return (Optimizer(nn.Sequential().add(nn.Linear(6, 2)), ds,
+                      nn.CrossEntropyCriterion())
+            .set_optim_method(Adam(1e-2))
+            .set_end_when(Trigger.max_epoch(epochs)))
+
+
+def _spans(tr, name=None):
+    return [e for e in tr.events_tail(1 << 16) if e["ph"] == "X"
+            and (name is None or e["name"] == name)]
+
+
+@pytest.fixture(scope="module")
+def lm():
+    return TransformerLM(vocab_size=64, max_len=64, d_model=32,
+                         num_heads=2, num_layers=2).build(jax.random.key(0))
+
+
+def _prompts(n, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 64, size=int(rng.integers(3, 10)))
+            .astype(np.int32) for _ in range(n)]
+
+
+# ---------------------------------------------------------------------------
+# the optimizer loop
+# ---------------------------------------------------------------------------
+
+def test_loop_spans_cover_each_iteration_and_carry_neval(tracer):
+    _optimizer().optimize()
+    steps = 12                      # 2 epochs x 96 / 16
+    whole = {e["args"]["neval"]: e for e in _spans(tracer, "iteration")}
+    assert sorted(whole) == list(range(1, steps + 1))
+    kids = {}
+    for e in _spans(tracer):
+        if e["name"] in LOOP_CHILDREN:
+            kids.setdefault(e["args"]["neval"], []).append(e)
+    for n, it in whole.items():
+        mine = sorted(kids[n], key=lambda e: e["ts"])
+        assert [e["name"] for e in mine] == list(LOOP_CHILDREN)
+        # nested in the iteration, in order, without overlap
+        edges = [it["ts"]] + [t for e in mine
+                              for t in (e["ts"], e["ts"] + e["dur"])] \
+            + [it["ts"] + it["dur"]]
+        assert all(b - a >= -0.2 for a, b in zip(edges, edges[1:])), (n, edges)
+        # what no child covers: under a millisecond, on any machine
+        self_us = it["dur"] - sum(e["dur"] for e in mine)
+        assert 0 <= self_us < 1000, (n, self_us)
+        # same thread
+        assert {e["tid"] for e in mine} == {it["tid"]}
+    # `step` is what it was: one an iteration, from inside `prepare` (the
+    # learning rate is part of it) to the end of the log line
+    step = {e["args"]["neval"]: e for e in _spans(tracer, "step")}
+    assert sorted(step) == sorted(whole)
+    # the epochs' ends (the `next()` that finds nothing) leave no event
+    assert len(_spans(tracer, "data")) == steps
+
+
+def test_data_span_is_the_blocking_next(tracer, monkeypatch):
+    """``data`` as before this PR: one for each iteration, ``neval``, and
+    as long as the loop waited for its batch (``data_wait_s`` of the
+    counter track is the same wait on the loop's own clock)."""
+    monkeypatch.setenv("BIGDL_TPU_PREFETCH_DEPTH", "0")
+    _optimizer(epochs=1).optimize()
+    data = {e["args"]["neval"]: e for e in _spans(tracer, "data")}
+    assert sorted(data) == list(range(1, 7))
+    assert all(set(e["args"]) == {"neval"} and e["cat"] == "phase"
+               for e in data.values())
+    waits = [e["args"]["data_wait_s"] for e in tracer.events_tail(1 << 16)
+             if e["ph"] == "C" and e["name"] == "train"]
+    assert len(waits) == 6
+    for e, wait in zip(sorted(data.values(), key=lambda e: e["ts"]), waits):
+        assert abs(e["dur"] / 1e6 - wait) < 2e-3
+
+
+def test_prefetch_item_splits_into_produce_and_stage(tracer):
+    _optimizer(epochs=1).optimize()
+    item, produce, stage = (_spans(tracer, "prefetch." + n)
+                            for n in ("item", "produce", "stage"))
+    assert len(item) == len(produce) == len(stage) == 6
+    loop_tid = _spans(tracer, "iteration")[0]["tid"]
+    for it, pr, st in zip(item, produce, stage):
+        assert it["tid"] == pr["tid"] == st["tid"] != loop_tid
+        assert pr["dur"] + st["dur"] <= it["dur"] + 1.0
+        assert pr["ts"] + pr["dur"] <= st["ts"] + 0.2
+
+
+class _Boom:
+    def __init__(self, *a, **kw):
+        raise AssertionError("created with no tracer active")
+
+
+def test_no_tracer_no_span_and_no_annotation(monkeypatch, lm):
+    """Tracing off: the loop and ``_tick`` allocate nothing, neither a
+    ``_Span`` nor a ``TraceAnnotation``."""
+    monkeypatch.setattr(telemetry, "_Span", _Boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Boom)
+    assert telemetry.get_active() is None
+    _optimizer(epochs=1).optimize()
+    with DecodeEngine(lm, slots=2, page=16) as eng:
+        out = eng.generate(_prompts(1)[0], 4)
+    assert len(out) >= 4
+    assert telemetry.span("x", neval=1) is telemetry._NULL_SPAN
+
+
+def test_span_holds_an_annotation_the_profiler_records(tracer, tmp_path):
+    """Every open span holds ``bigdl:<name>`` open for as long: a profiler
+    session with the host tracer on carries it on the profiler's clock."""
+    logdir = str(tmp_path / "prof")
+    with profiling.profiler_session(logdir):
+        with telemetry.span("outer", neval=3):
+            with telemetry.span("inner"):
+                jax.block_until_ready(jax.numpy.ones((8, 8)) @
+                                      jax.numpy.ones((8, 8)))
+    rows = profiling.xplane_rows(logdir)
+    named = {r[2]: r for r in rows if r[2].startswith("bigdl:")}
+    assert set(named) == {"bigdl:outer", "bigdl:inner"}
+    outer, inner = named["bigdl:outer"], named["bigdl:inner"]
+    assert outer[:2] == inner[:2]                       # one thread's line
+    assert outer[3] <= inner[3] and \
+        inner[3] + inner[4] <= outer[3] + outer[4]      # nested
+    # and the tracer's own events are as before
+    assert [e["name"] for e in _spans(tracer)] == ["inner", "outer"]
+    with pytest.raises(FileNotFoundError):
+        profiling.xplane_rows(str(tmp_path / "nothing"))
+
+
+# ---------------------------------------------------------------------------
+# the decode engine
+# ---------------------------------------------------------------------------
+
+def test_decode_programs_have_their_own_names_and_aot_keys(lm, monkeypatch):
+    seen = {}
+    real = aot_mod.get_or_compile
+
+    def spy(key_fields, lower_fn, *, label, card_extra=None):
+        seen[label] = (dict(key_fields, label=label),
+                       lower_fn().as_text())
+        return real(key_fields, lower_fn, label=label,
+                    card_extra=card_extra)
+
+    monkeypatch.setattr(aot_mod, "get_or_compile", spy)
+    eng = DecodeEngine(lm, slots=2, page=16)
+    eng._step_exe(16)
+    eng._prefill_exe(8, 16)
+    step_fields, step_text = seen["decode.step"]
+    pre_fields, pre_text = seen["decode.prefill"]
+    assert "jit_decode_step" in step_text and "jit_fn" not in step_text
+    assert "jit_decode_prefill" in pre_text and "jit_fn" not in pre_text
+    assert step_fields["program"] == "jit_decode_step"
+    assert pre_fields["program"] == "jit_decode_prefill"
+    assert aot_mod.fingerprint(step_fields) != aot_mod.fingerprint(pre_fields)
+    # the name is part of the key: an executable stored under another
+    # name (`jit_fn`, before this PR) cannot be handed back
+    assert aot_mod.fingerprint(dict(step_fields, program="jit_fn")) \
+        != aot_mod.fingerprint(step_fields)
+
+
+def test_decode_tick_has_admit_step_and_sample_children(tracer, lm):
+    prompts = _prompts(5, seed=1)
+    with DecodeEngine(lm, slots=2, page=16) as eng:
+        reqs = [eng.submit(p, 5) for p in prompts]
+        for r in reqs:
+            r.result(120)
+    ticks = _spans(tracer, "decode.tick")
+    admits = _spans(tracer, "decode.admit")
+    steps = _spans(tracer, "decode.step")
+    samples = _spans(tracer, "decode.sample")
+    assert len(admits) == 5 and ticks
+    assert len(steps) == len(samples) == eng.decode_steps
+    assert sum(t["args"]["admitted"] for t in ticks) == 5
+    assert all(0 <= t["args"]["active"] <= 2 for t in ticks)
+    by_len = sorted(len(p) for p in prompts)
+    assert sorted(a["args"]["prompt_len"] for a in admits) == by_len
+    assert all(a["args"]["bucket"] >= a["args"]["prompt_len"]
+               and a["args"]["slot"] in (0, 1) for a in admits)
+    tid = {e["tid"] for e in ticks}
+    assert len(tid) == 1
+    for child in admits + steps + samples:
+        assert child["tid"] in tid
+        assert any(t["ts"] - 0.2 <= child["ts"] and child["ts"]
+                   + child["dur"] <= t["ts"] + t["dur"] + 0.2
+                   for t in ticks), child
+    for t in ticks:     # a tick's children cover it but for its self time
+        inside = [c for c in admits + steps + samples
+                  if t["ts"] - 0.2 <= c["ts"] <= t["ts"] + t["dur"]]
+        assert sum(c["dur"] for c in inside) <= t["dur"] + 1.0
+    # the request's span carries its two stamps
+    done = _spans(tracer, "serve.request")
+    assert len(done) == 5
+    for e in done:
+        a = e["args"]
+        assert 0 <= a["queue_wait_ms"] <= a["ttft_ms"] <= e["dur"] / 1e3 + 1e-6
+
+
+def test_request_stamps_are_ordered_and_summed(lm, monkeypatch):
+    # a fresh registry, whatever ran before in this process (put back after)
+    monkeypatch.setattr(metrics_export, "_REGISTRY", None)
+    reg = metrics_export.arm()
+    try:
+        with DecodeEngine(lm, slots=2, page=16) as eng:
+            before = eng.stats()
+            reqs = [eng.submit(p, 4) for p in _prompts(6, seed=2)]
+            for r in reqs:
+                r.result(120)
+            resolved = eng.clock()
+            after = eng.stats()
+    finally:
+        metrics_export.disarm()
+    assert metrics_export._REGISTRY is None
+    assert before["admitted"] == 0 and before["ttft_s"] == 0.0
+    for r in reqs:
+        assert r.enqueued <= r.admitted <= r.first_token \
+            <= r.enqueued + r.latency_s + 1e-9 <= resolved + 1e-9
+    assert after["admitted"] == after["first_tokens"] == 6
+    assert after["queue_wait_s"] == pytest.approx(
+        sum(r.admitted - r.enqueued for r in reqs))
+    assert after["ttft_s"] == pytest.approx(
+        sum(r.first_token - r.enqueued for r in reqs))
+    text = reg.render()
+    for name in ("bigdl_decode_queue_wait_seconds",
+                 "bigdl_decode_ttft_seconds", "bigdl_decode_ttlt_seconds"):
+        assert f"{name}_count 6" in text, text[-1500:]
+
+
+def test_decode_counter_arguments_wait_for_a_reader(lm, monkeypatch):
+    """The per-tick track's arguments (a sum over every cache array) are
+    computed only when a tracer or a registry will read them."""
+    calls = []
+    real = DecodeEngine.cache_bytes_per_slot
+    monkeypatch.setattr(DecodeEngine, "cache_bytes_per_slot",
+                        lambda self: calls.append(1) or real(self))
+    with DecodeEngine(lm, slots=2, page=16) as eng:
+        eng.generate(_prompts(1)[0], 4)
+    assert not calls
+    tr = Tracer("memory://unused", flush_every=0)
+    telemetry.set_active(tr)
+    with DecodeEngine(lm, slots=2, page=16) as eng:
+        eng.generate(_prompts(1)[0], 4)
+    assert calls
+    assert any(e["ph"] == "C" and e["name"] == "serve.decode"
+               for e in tr.events_tail(4096))
+
+
+# ---------------------------------------------------------------------------
+# the device's idle time by cause
+# ---------------------------------------------------------------------------
+
+def _recorded_rows():
+    path = os.path.join(_REPO_ROOT, "tests", "benchmark",
+                        "recorded_trace_rows.json.gz")
+    with gzip.open(path, "rt") as f:
+        return json.load(f)["rows"]
+
+
+def _host(name, start, end, line="loop"):
+    return ["/host:CPU", line, "bigdl:" + name, float(start),
+            float(end - start)]
+
+
+def test_idle_by_cause_names_spans_and_keeps_the_total():
+    """The recorded trace holds two runs of the ResNet-50 step and the
+    29.7 ms between them (the step ends at 110.38 ms, the next starts at
+    140.07 ms, three tiny programs in between)."""
+    if _REPO_ROOT not in sys.path:
+        sys.path.insert(0, _REPO_ROOT)
+    from benchmark import trace_reduce
+    rows = _recorded_rows()
+    reduced = trace_reduce.reduce_rows(rows)
+    idle_s = reduced["window_s"] - reduced["busy_s"]
+    assert idle_s > 0.02
+    # no host spans: one unattributed total, the harness's line
+    alone = idle_by_cause(rows)
+    assert [c for c, _s in alone] == ["unattributed"]
+    assert alone[0][1] == pytest.approx(idle_s, rel=1e-9)
+    ms = 1e6
+    host = [
+        _host("iteration", 100 * ms, 138 * ms),
+        _host("loss_fetch", 100 * ms, 111 * ms),     # 0.62 ms of the gap
+        _host("summary", 111 * ms, 114 * ms),
+        _host("triggers", 114 * ms, 115 * ms),
+        # 115-116: the iteration's self time
+        _host("iteration", 116 * ms, 260 * ms),
+        _host("data", 116 * ms, 124 * ms),
+        _host("prepare", 124 * ms, 129 * ms),
+        _host("dispatch", 129 * ms, 137 * ms),
+        # from 137 ms to the next step's start at 140.07: nothing open but
+        # the iteration
+        _host("loss_fetch", 137.5 * ms, 255 * ms),
+        # another thread's spans never count
+        _host("prefetch.item", 100 * ms, 140 * ms, line="worker"),
+        _host("prefetch.stage", 120 * ms, 139 * ms, line="worker"),
+    ]
+    causes = dict(idle_by_cause(rows + host))
+    assert sum(causes.values()) == pytest.approx(idle_s, rel=1e-9)
+    assert not any(c.startswith("prefetch") for c in causes)
+    assert causes["summary"] == pytest.approx(3e-3, rel=1e-6)
+    assert causes["triggers"] == pytest.approx(1e-3, rel=1e-6)
+    assert causes["dispatch"] == pytest.approx(8e-3, abs=2e-5)
+    assert causes["prepare"] == pytest.approx(5e-3, abs=2e-5)
+    assert 7.9e-3 < causes["data"] <= 8e-3
+    assert 0 < causes["loss_fetch"] < 4e-3
+    # the stretches of both iterations that no child covers
+    assert 1e-3 <= causes["iteration"] < 2e-3
+    # sorted, largest first
+    listed = idle_by_cause(rows + host)
+    assert [s for _c, s in listed] == sorted((s for _c, s in listed),
+                                             reverse=True)
+    # spans that cover only part of the gaps leave the rest unattributed:
+    # never spread over the neighbours
+    part = dict(idle_by_cause(rows + [_host("dispatch", 129 * ms, 137 * ms)]))
+    assert part["dispatch"] == pytest.approx(causes["dispatch"])
+    assert part["unattributed"] == pytest.approx(idle_s - part["dispatch"])
+    # no thread that dispatches: nobody's spans explain the device
+    assert [c for c, _s in idle_by_cause(rows + host[1:4])] == ["unattributed"]
+
+
+def test_trace_report_xplane_cli(tmp_path):
+    tool = os.path.join(_REPO_ROOT, "tools", "trace_report.py")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, tool, "--xplane", str(tmp_path)],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert p.returncode == 2 and "no *.xplane.pb" in p.stderr
+    p = subprocess.run([sys.executable, tool], capture_output=True,
+                       text=True, env=env, timeout=300)
+    assert p.returncode == 2 and "--xplane" in p.stderr
+    assert "device idle" in telemetry.format_idle(
+        [["dispatch", 0.008], ["unattributed", 0.002]])

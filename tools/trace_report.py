@@ -27,6 +27,13 @@ Usage::
     python tools/trace_report.py <trace-dir> [--out merged.json] [--json]
     python tools/trace_report.py <trace-dir> --requests [--slowest N]
     python tools/trace_report.py --diff <trace-dir-A> <trace-dir-B> [--json]
+    python tools/trace_report.py --xplane <profiler-dir> [--json]
+
+``--xplane`` reads a JAX profiler trace (``utils/profiling.profiler_session``
+writes one whose host events include the ``bigdl:<span>`` annotation every
+open span holds) and prints the device's idle time by cause
+(``telemetry.idle_by_cause``): each idle gap under the deepest span that
+covered it on the thread that drives the device, the rest ``unattributed``.
 
 ``--requests`` reconstructs per-request critical paths from the flow
 events (``ph:"s"/"t"/"f"``, one chain per ``X-BigDL-Request-Id``) the
@@ -77,9 +84,13 @@ def _load_breakdown(telemetry, trace_dir):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("trace_dir",
+    ap.add_argument("trace_dir", nargs="?",
                     help="dir holding trace.<rank>.json files (any file_io "
                          "scheme: local, memory://, gs://, ...)")
+    ap.add_argument("--xplane", default=None, metavar="PROFILER_DIR",
+                    help="a JAX profiler trace dir instead: print the "
+                         "device's idle time by cause (the program's spans "
+                         "on the profiler's clock)")
     ap.add_argument("--diff", default=None, metavar="TRACE_DIR_B",
                     help="compare TWO runs: trace_dir is the baseline (A), "
                          "this dir the new run (B); prints per-phase B/A "
@@ -98,6 +109,24 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from bigdl_tpu.utils import telemetry
+
+    if args.xplane:
+        from bigdl_tpu.utils import profiling
+        try:
+            rows = profiling.xplane_rows(args.xplane)
+        except FileNotFoundError as e:
+            print(f"trace_report: {e}", file=sys.stderr)
+            return 2
+        if not any(r[0].startswith("/device:") for r in rows):
+            print(f"trace_report: {args.xplane}: the trace holds no device "
+                  "operations", file=sys.stderr)
+            return 3
+        causes = telemetry.idle_by_cause(rows)
+        print(json.dumps(causes) if args.json
+              else telemetry.format_idle(causes))
+        return 0
+    if not args.trace_dir:
+        ap.error("a trace dir, or --xplane <profiler-dir>, is required")
 
     breakdown, merged = _load_breakdown(telemetry, args.trace_dir)
     if breakdown is None:
