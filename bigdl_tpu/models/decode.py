@@ -59,6 +59,22 @@ def _mha_modules(module):
     return _modules_of_type(module, MultiHeadAttention)
 
 
+def _cache_sharding(mesh, shape):
+    """Where a [rows, H, L, D] cache tensor lives on a canonical layout
+    mesh: the ``kv_cache`` role (rows over data x fsdp, heads over tp).
+    None without a mesh."""
+    if mesh is None:
+        return None
+    from jax.sharding import NamedSharding
+    from ..parallel import layout as _layout
+    lay = _layout.MeshLayout.of_mesh(mesh)
+    if lay is None:
+        raise ValueError(
+            "init_kv_cache: mesh lacks the canonical layout axes "
+            "(build it with parallel/layout.MeshLayout.build_mesh)")
+    return NamedSharding(mesh, lay.spec_for("kv_cache", shape, min_size=0))
+
+
 def init_kv_cache(model, batch: int, max_len: int, dtype=jnp.float32,
                   mesh=None):
     """One {k, v} buffer of shape [B, H, max_len, D] per attention layer.
@@ -69,23 +85,13 @@ def init_kv_cache(model, batch: int, max_len: int, dtype=jnp.float32,
     tp-sharded model decodes against caches that already match its
     column-parallel q/k/v kernels: each device holds exactly the 1/tp
     of the cache its heads produce, no per-step resharding."""
-    lay = None
-    if mesh is not None:
-        from ..parallel import layout as _layout
-        lay = _layout.MeshLayout.of_mesh(mesh)
-        if lay is None:
-            raise ValueError(
-                "init_kv_cache: mesh lacks the canonical layout axes "
-                "(build it with parallel/layout.MeshLayout.build_mesh)")
     caches = []
     for mha in _mha_modules(model):
         shape = (batch, mha.num_heads, max_len, mha.head_dim)
         k = jnp.zeros(shape, dtype)
         v = jnp.zeros(shape, dtype)
-        if lay is not None:
-            from jax.sharding import NamedSharding
-            sh = NamedSharding(mesh, lay.spec_for("kv_cache", shape,
-                                                  min_size=0))
+        sh = _cache_sharding(mesh, shape)
+        if sh is not None:
             k, v = jax.device_put(k, sh), jax.device_put(v, sh)
         caches.append({"k": k, "v": v})
     return caches
@@ -148,6 +154,91 @@ def _step(module, params, state, x, caches, slot, pos):
         return y, slot
     raise NotImplementedError(
         f"cached decoding: unsupported container {type(module).__name__}")
+
+
+def _prefill_attention(mha, params, x, cache, slot):
+    """x: [1, P, E], a whole prompt from position 0 entering the fresh
+    cache row `slot`; returns ([1, P, E], new_cache).
+
+    The prompt attends causally over itself with `_cached_attention`'s
+    float32 score path and exact-zero masked weights; k and v of all P
+    positions go into the cache by one write each."""
+    if not mha.causal:
+        raise NotImplementedError(
+            "cached decoding requires causal attention "
+            "(MultiHeadAttention(causal=False) found)")
+    _, P, E = x.shape
+    H, D = mha.num_heads, mha.head_dim
+    split = lambda y: y.reshape(1, P, H, D).transpose(0, 2, 1, 3)
+    q, k, v = (split(mha._proj(params, x, n)) for n in "qkv")
+    # attend over what the cache will hold: k and v in the cache's dtype
+    k, v = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+    ck = jax.lax.dynamic_update_slice(cache["k"], k, (slot, 0, 0, 0))
+    cv = jax.lax.dynamic_update_slice(cache["v"], v, (slot, 0, 0, 0))
+    scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
+                        k.astype(jnp.float32)) / (D ** 0.5)
+    mask = jnp.arange(P)[None, :] <= jnp.arange(P)[:, None]
+    scores = jnp.where(mask, scores, -jnp.inf)
+    w = jax.nn.softmax(scores, axis=-1)
+    o = jnp.einsum("bhql,bhld->bhqd", w, v.astype(jnp.float32))
+    o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(1, P, E)
+    return mha._proj(params, o, "o"), {"k": ck, "v": cv}
+
+
+def _prefill_walk(module, params, state, x, caches, layer, slot, last,
+                  in_table=False):
+    """`_step`'s walk with all positions of one prompt at once: x is
+    [1, P] token ids at the root; returns (y, next_layer).  Row `slot` of
+    `caches[layer]` takes each attention layer's k and v (`caches` is
+    mutated in place, as in `_step`).
+
+    Past the last attention layer everything is position-wise, so a
+    Sequential outside any ConcatTable keeps only position `last` from
+    there on: the rest of the model (for a TransformerLM the final
+    LayerNorm, the head and LogSoftMax) runs on [1, 1, E]."""
+    if isinstance(module, MultiHeadAttention):
+        y, caches[layer] = _prefill_attention(module, params, x,
+                                              caches[layer], slot)
+        return y, layer + 1
+    if isinstance(module, Sequential):
+        for m, p, s in zip(module.modules, params, state):
+            before = layer
+            x, layer = _prefill_walk(m, p, s, x, caches, layer, slot, last,
+                                     in_table)
+            if before < layer == len(caches) and not in_table:
+                x = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(
+                    a, last, 1, axis=1), x)
+        return x, layer
+    if isinstance(module, ConcatTable):
+        outs = []
+        for m, p, s in zip(module.modules, params, state):
+            o, layer = _prefill_walk(m, p, s, x, caches, layer, slot, last,
+                                     True)
+            outs.append(o)
+        return outs, layer
+    if not isinstance(module, Container):
+        # position-wise leaves (and PositionalEmbedding, which adds rows
+        # 0..P-1) run their own eval apply on all positions at once
+        y, _ = module.apply(params, state, x, training=False, rng=None)
+        return y, layer
+    raise NotImplementedError(
+        f"cached decoding: unsupported container {type(module).__name__}")
+
+
+def _prefill(model, params, state, toks, caches, slot, t0):
+    """One-pass prefill of one sequence into cache row `slot`: `toks` is
+    the prompt, [P] with pads past its `t0` real tokens (P no longer than
+    the cache).  Returns the [V] logits of position t0 - 1 and the caches.
+
+    Rows t0..P-1 of the slot take the pads' k and v: finite, and masked by
+    `<= pos` in every later step until the sequence overwrites them, like
+    a previous occupant's stale rows."""
+    caches = list(caches)
+    y, _ = _prefill_walk(model, params, state, toks[None], caches, 0, slot,
+                         t0 - 1)
+    if y.shape[1] != 1:  # nothing follows the last attention layer
+        y = jax.lax.dynamic_slice_in_dim(y, t0 - 1, 1, axis=1)
+    return y[0, 0], tuple(caches)
 
 
 def _get_step(model, rows: int, max_len: int, dtype):

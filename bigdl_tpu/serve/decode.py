@@ -19,11 +19,16 @@ took; PAPERS.md):
   compile cards and AOT cache entries, keyed like the
   ``_ShardedForward`` buckets (module fingerprint + base fingerprint +
   shape dims through utils/aot.get_or_compile).  Prefill admits one new
-  sequence into a free KV-cache slot: a ``fori_loop`` over the prompt
-  positions inside ONE executable (traced trip count — one compile per
-  (prompt-bucket, slots, cache-page), not per prompt length), reusing
-  the exact per-position math of models/decode so greedy outputs
-  bit-match the ``cached_generate`` oracle.
+  sequence into a free KV-cache slot in ONE pass: the padded prompt
+  bucket goes through the model as ``[1, bucket, E]`` (every weight
+  read once a prompt, every product matrix-matrix), each attention
+  layer writes k and v of all positions into the slot by one in-place
+  update, and past the last attention layer only the prompt's last
+  real position goes on to the head (models/decode ``_prefill``; one
+  compile per (prompt-bucket, slots, cache-page), the prompt's length
+  traced).  ``cached_generate`` keeps its position-by-position walk
+  and shares no prefill code with the engine: it is the oracle the
+  engine's greedy tokens are held to, token for token, by test.
 - The bucket ladder extends to **(batch-slots, cache-page)** pages:
   cache length is allocated in power-of-2 multiples of
   ``BIGDL_TPU_DECODE_PAGE`` (models/decode.init_kv_cache buffers), so a
@@ -40,8 +45,9 @@ took; PAPERS.md):
   learns seconds/token so ``retry_after_s`` scales with the queued
   token budget.
 - Telemetry: the ``serve.decode`` counter track emits tokens/s,
-  active-slot fill, prefill-vs-decode step fractions and cache
-  bytes/slot — promoted to a ``decode:`` trace_report section like
+  active-slot fill, prefill-vs-decode step fractions, the share of the
+  prefills' positions that were padding (``prefill_pad_frac``) and
+  cache bytes/slot — promoted to a ``decode:`` trace_report section like
   ``aot``/``autoscale`` (utils/telemetry.phase_breakdown).
 - Chaos: ``serve.decode@<slot>`` fires once per tick for every slot
   that participates (prefill or decode).  A faulted slot fails ITS
@@ -123,7 +129,7 @@ def page_ladder(page: int, max_len: int) -> tuple:
 # models/decode._cached_attention serves ONE position shared by every
 # row; continuous batching needs every slot at its OWN position.  The
 # math per slot is identical (same projections, same f32 score path,
-# exact-zero masked softmax weights), so greedy tokens bit-match the
+# exact-zero masked softmax weights), so greedy tokens match the
 # cached_generate oracle per sequence.
 
 def _slot_attention(mha, params, x, cache, pos):
@@ -292,6 +298,8 @@ class DecodeEngine:
         self._lock = threading.Lock()
         # cumulative counters (stats(); serve.decode telemetry track)
         self.prefill_steps = 0
+        self.prompt_tokens = 0       # real prompt tokens prefilled
+        self.prefill_positions = 0   # positions computed for them (pads too)
         self.decode_steps = 0
         self.tokens_out = 0
         self.seqs_done = 0
@@ -421,9 +429,10 @@ class DecodeEngine:
         out = []
         for mha in kv._mha_modules(self.model):
             shape = (self.slots, mha.num_heads, cache_len, mha.head_dim)
-            out.append({
-                "k": jax.ShapeDtypeStruct(shape, self.cache_dtype),
-                "v": jax.ShapeDtypeStruct(shape, self.cache_dtype)})
+            aval = jax.ShapeDtypeStruct(
+                shape, self.cache_dtype,
+                sharding=kv._cache_sharding(self._mesh, shape))
+            out.append({"k": aval, "v": aval})
         return tuple(out)
 
     def _step_exe(self, cache_len: int):
@@ -459,11 +468,13 @@ class DecodeEngine:
 
     def _prefill_exe(self, prompt_bucket: int, cache_len: int):
         """The prefill executable for the (prompt_bucket, slots,
-        cache_len) bucket: one new sequence enters ONE slot via a traced
-        fori_loop over its prompt positions (trip count t0 is traced, so
-        every prompt length in the bucket shares this compile).  Reuses
-        models/decode._step per position — greedy outputs bit-match the
-        cached_generate oracle by construction."""
+        cache_len) bucket: one new sequence enters ONE slot in one pass
+        (models/decode._prefill).  The padded bucket, cut to the cache
+        where it is longer, goes through the model as [1, P, E]: every
+        weight is read once a prompt, each attention layer's k and v land
+        in the slot by one write, and past the last attention layer only
+        the prompt's last real position goes on to the head.  Every prompt
+        length in the bucket shares this compile (t0 is traced)."""
         memo = ("prefill", prompt_bucket, self.slots, cache_len)
         exe = self._exe.get(memo)
         if exe is not None:
@@ -473,36 +484,19 @@ class DecodeEngine:
         # ``jit_decode_prefill`` on the device trace's ``XLA Modules`` line
         @partial(jax.jit, donate_argnums=(2,))
         def decode_prefill(params, state, caches, toks, slot, t0):
-            # slice this slot's [1, H, L, D] cache views out, run the
-            # rows=1 incremental step over the prompt, write back — the
-            # other slots' caches pass through untouched
-            sub = tuple(
-                {n: jax.lax.dynamic_slice_in_dim(c[n], slot, 1, axis=0)
-                 for n in c} for c in caches)
+            return kv._prefill(model, params, state, toks, caches, slot, t0)
 
-            def run_pos(i, sub_t):
-                sub_l = list(sub_t)
-                x = toks[i][None, None]     # [1, 1]
-                y, _ = kv._step(model, params, state, x, sub_l, 0, i)
-                return tuple(sub_l), y[:, -1]
-
-            sub, logits = run_pos(0, sub)
-            sub, logits = jax.lax.fori_loop(
-                1, t0, lambda i, c: run_pos(i, c[0]), (sub, logits))
-            new = tuple(
-                {n: jax.lax.dynamic_update_slice(c[n], s[n],
-                                                 (slot, 0, 0, 0))
-                 for n in c} for c, s in zip(caches, sub))
-            return logits[0], new          # [V] logits of last position
-
+        # ``body``: the key holds the program's name, not its text, and the
+        # per-position prefill before this one had the same name
         exe = aot_mod.get_or_compile(
             self._key_fields("decode.prefill", decode_prefill,
                              slots=self.slots, cache_len=cache_len,
-                             prompt_bucket=prompt_bucket,
+                             prompt_bucket=prompt_bucket, body="one_pass",
                              dtype=jnp.dtype(self.cache_dtype).name),
             lambda: decode_prefill.lower(
                 self._params, self._state, self._cache_avals(cache_len),
-                jax.ShapeDtypeStruct((prompt_bucket,), jnp.int32),
+                jax.ShapeDtypeStruct((min(prompt_bucket, cache_len),),
+                                     jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32)),
             label="decode.prefill",
@@ -547,13 +541,9 @@ class DecodeEngine:
                 grown.append(pad)
             self._caches = tuple(grown)
             if self._mesh is not None:
-                from jax.sharding import NamedSharding
-                from ..parallel import layout as _layout
-                lay = _layout.MeshLayout.of_mesh(self._mesh)
                 self._caches = tuple(
-                    {n: jax.device_put(arr, NamedSharding(
-                        self._mesh, lay.spec_for("kv_cache", arr.shape,
-                                                 min_size=0)))
+                    {n: jax.device_put(arr, kv._cache_sharding(
+                        self._mesh, arr.shape))
                      for n, arr in c.items()} for c in self._caches)
             self._cache_len = want
             self.cache_grows += 1
@@ -675,7 +665,8 @@ class DecodeEngine:
         # the prefill call, the fetch of its logits and the first sample
         with telemetry.span("decode.admit", cat="serve", prompt_len=t0,
                             bucket=pb, slot=s):
-            toks = np.zeros(pb, np.int32)
+            # the bucket, cut to the cache where it is longer (t0 fits)
+            toks = np.zeros(min(pb, self._cache_len), np.int32)
             toks[:t0] = prompt
             exe = self._prefill_exe(pb, self._cache_len)
             try:
@@ -687,6 +678,8 @@ class DecodeEngine:
                                              f"slot {s}: {e!r}"))
                 return
             self.prefill_steps += 1
+            self.prompt_tokens += t0
+            self.prefill_positions += len(toks)
             self._advance(s, self._sample(seq, np.asarray(logits)))
 
     def _tick(self) -> bool:
@@ -768,6 +761,8 @@ class DecodeEngine:
             tokens_per_s=self.tokens_out / max(self._busy_s, 1e-9),
             fill=n_active / self.slots,
             prefill_frac=self.prefill_steps / max(steps, 1),
+            prefill_pad_frac=1.0 - self.prompt_tokens
+            / max(self.prefill_positions, 1),
             decode_frac=self.decode_steps / max(steps, 1),
             cache_bytes_per_slot=self.cache_bytes_per_slot(),
             cache_len=self._cache_len)
@@ -787,6 +782,8 @@ class DecodeEngine:
             "cache_bytes_per_slot": self.cache_bytes_per_slot(),
             "cache_grows": self.cache_grows,
             "prefill_steps": self.prefill_steps,
+            "prompt_tokens": self.prompt_tokens,
+            "prefill_positions": self.prefill_positions,
             "decode_steps": self.decode_steps,
             "tokens_out": self.tokens_out,
             "tokens_per_s": round(self.tokens_per_s(), 3),

@@ -12,6 +12,10 @@ decode"):
     the footprint is exact and observable (``cache_bytes_per_slot``);
   - prefill and decode are SEPARATE jitted executables with separate
     compile cards (``decode.prefill`` / ``decode.step``);
+  - the prefill is ONE pass over the padded prompt bucket (no loop in the
+    program, the head on one row); its tokens equal the per-position
+    oracle's at every edge of a bucket, and its logits agree within a
+    float32 tolerance (ISSUE 26);
   - admission is a per-sequence ``DecodeQueue``: bounded, deadline =
     time-to-last-token (shed typed at dequeue), tenant token buckets;
   - a ``serve.decode@<slot>`` chaos fault fails ONE sequence typed and
@@ -26,19 +30,30 @@ import numpy as np
 import jax
 import pytest
 
+from bigdl_tpu.models import decode as kv
 from bigdl_tpu.models.decode import cached_generate, init_kv_cache
 from bigdl_tpu.models.transformer_lm import TransformerLM
 from bigdl_tpu.serve import (DecodeEngine, DecodeQueue, QuotaExceeded,
                              RequestTimeout, ServeError, SlotFault,
                              TraceEvent, page_ladder, pad_rows, read_trace,
                              write_trace)
-from bigdl_tpu.utils import chaos
+from bigdl_tpu.utils import aot as aot_mod
+from bigdl_tpu.utils import chaos, hlostats
 
 
 @pytest.fixture(scope="module")
 def lm():
     return TransformerLM(vocab_size=64, max_len=64, d_model=32,
                          num_heads=2, num_layers=2).build(jax.random.key(0))
+
+
+@pytest.fixture(scope="module")
+def lm_odd():
+    # a max_len that is no power of two (a prompt bucket can exceed the
+    # cache and the model's own positions) and a vocabulary whose size no
+    # other axis of the model has
+    return TransformerLM(vocab_size=80, max_len=40, d_model=32,
+                         num_heads=2, num_layers=2).build(jax.random.key(3))
 
 
 def _prompts(n, lo=3, hi=10, seed=0):
@@ -145,6 +160,156 @@ def test_continuous_batching_bit_matches_oracle(lm):
     assert st["seqs_done"] == 5 and st["seqs_failed"] == 0
     assert st["prefill_steps"] == 5  # one prefill per admitted sequence
     assert st["tokens_out"] == sum(budgets)
+
+
+# ---------------------------------------------------------------------------
+# the one-pass admission prefill (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("t0,pb", [(1, 8), (7, 8), (8, 8), (9, 16),
+                                   (15, 16), (16, 16), (17, 32)])
+def test_prefill_matches_oracle_at_bucket_edges(lm, t0, pb):
+    # a prompt of one token, one that fills its bucket, one short of it,
+    # and the first of the next bucket
+    prompt = np.random.default_rng(100 + t0).integers(
+        1, 64, size=t0).astype(np.int32)
+    with DecodeEngine(lm, slots=2, page=8) as eng:
+        out = eng.generate(prompt, 6)
+        st = eng.stats()
+    np.testing.assert_array_equal(out, _oracle(lm, prompt, 6))
+    assert (st["prompt_tokens"], st["prefill_positions"]) == (t0, pb)
+
+
+@pytest.mark.parametrize("t0", [17, 33, 37])
+def test_prefill_bucket_longer_than_the_cache(lm_odd, t0):
+    # max_len 40: buckets 32 and 64 meet caches of 32 and 40 positions,
+    # and the model has no position past 39; only the cache's length runs
+    prompt = np.random.default_rng(200 + t0).integers(
+        1, 80, size=t0).astype(np.int32)
+    with DecodeEngine(lm_odd, slots=2, page=8) as eng:
+        assert eng.ladder == (8, 16, 32, 40)
+        out = eng.generate(prompt, 3)
+        st = eng.stats()
+    np.testing.assert_array_equal(out, _oracle(lm_odd, prompt, 3))
+    assert st["prefill_positions"] == min(64 if t0 > 32 else 32,
+                                          st["cache_len"])
+
+
+def test_slot_reused_by_a_shorter_prompt_after_a_longer_one(lm):
+    # one slot: the second sequence finds the first one's k and v (and its
+    # own bucket's pads) in the rows beyond its prompt; they weigh nothing
+    long, short = _prompts(2, lo=13, hi=15, seed=12)
+    short = short[:3]
+    with DecodeEngine(lm, slots=1, page=32) as eng:
+        first = eng.submit(long, 10)
+        second = eng.submit(short, 12)
+        outs = first.result(120.0), second.result(120.0)
+        st = eng.stats()
+    np.testing.assert_array_equal(outs[0], _oracle(lm, long, 10))
+    np.testing.assert_array_equal(outs[1], _oracle(lm, short, 12))
+    assert st["cache_len"] == 32 and st["prefill_steps"] == 2
+
+
+@pytest.mark.parametrize("t0", [1, 5, 8])
+def test_prefill_logits_and_cache_match_the_per_position_oracle(lm, t0):
+    # the same products as [8, E] x [E, .] and as [1, E] x [E, .] may
+    # differ in the last bits: float32 tolerance, stated
+    prompt = np.random.default_rng(300 + t0).integers(
+        1, 64, size=t0).astype(np.int32)
+    step = kv._get_step(lm, 1, 16, np.float32)
+    ref_caches = tuple(init_kv_cache(lm, 1, 16, np.float32))
+    for pos in range(t0):
+        ref, ref_caches = step(lm.params, lm.state, ref_caches,
+                               prompt[pos:pos + 1], pos)
+    eng = DecodeEngine(lm, slots=3, page=16, cache_dtype=np.float32)
+    toks = np.zeros(8, np.int32)
+    toks[:t0] = prompt
+    logits, caches = eng._prefill_exe(8, 16)(
+        eng._params, eng._state, eng._fresh_caches(16), toks,
+        np.int32(1), np.int32(t0))
+    assert logits.shape == (64,)
+    np.testing.assert_allclose(logits, ref[0], rtol=1e-5, atol=1e-5)
+    for got, want in zip(caches, ref_caches):
+        for n in "kv":
+            arr = np.asarray(got[n])
+            np.testing.assert_allclose(arr[1, :, :t0],
+                                       np.asarray(want[n])[0, :, :t0],
+                                       rtol=1e-5, atol=1e-5)
+            # the pads' rows are written and finite, nothing beyond the
+            # bucket is, and the other slots are untouched
+            assert np.isfinite(arr).all()
+            assert not arr[1, :, 8:].any() and not arr[[0, 2]].any()
+
+
+def test_prefill_program_has_no_loop_and_one_row_at_the_head(lm_odd,
+                                                             monkeypatch):
+    seen = {}
+    real = aot_mod.get_or_compile
+
+    def spy(key_fields, lower_fn, *, label, card_extra=None):
+        seen[label] = (dict(key_fields), lower_fn().as_text())
+        return real(key_fields, lower_fn, label=label,
+                    card_extra=card_extra)
+
+    monkeypatch.setattr(aot_mod, "get_or_compile", spy)
+    eng = DecodeEngine(lm_odd, slots=2, page=16)
+    eng._prefill_exe(8, 16)
+    fields, text = seen["decode.prefill"]
+    hist = hlostats.op_histogram(text)
+    assert "while" not in hist and hist["dot_general"] > 0
+    # every product over the vocabulary (80 wide, like no other axis)
+    heads = [ln for ln in text.splitlines()
+             if "dot_general" in ln and "x80x" in ln]
+    assert len(heads) == 1, heads
+    assert heads[0].rstrip().endswith("-> tensor<1x1x80xf32>")
+    # ... while the blocks' products run over the bucket's 8 positions
+    assert any("dot_general" in ln and "-> tensor<1x8x32x" in ln
+               for ln in text.splitlines())
+    # the key tells this program from the per-position one of the same name
+    assert fields["body"] == "one_pass"
+    assert aot_mod.fingerprint(fields) != aot_mod.fingerprint(
+        {k: v for k, v in fields.items() if k != "body"})
+
+
+def test_stats_count_prompt_tokens_and_prefill_positions(lm):
+    lens = [1, 3, 8, 9, 16, 20]
+    prompts = [np.random.default_rng(400 + n).integers(
+        1, 64, size=n).astype(np.int32) for n in lens]
+    with DecodeEngine(lm, slots=2, page=32) as eng:
+        for h in [eng.submit(p, 2) for p in prompts]:
+            h.result(120.0)
+        st = eng.stats()
+    assert st["prefill_steps"] == len(lens)
+    assert st["prompt_tokens"] == sum(lens) == 57
+    assert st["prefill_positions"] == 8 + 8 + 8 + 16 + 16 + 32
+
+
+def test_moe_model_prefills_in_one_pass():
+    # MoEFFN is a leaf: it takes its experts' capacity from the tokens it
+    # sees, and in one pass those are the bucket's positions, pads too
+    # (one at a time, as the oracle runs, nothing ever drops).  With room
+    # for every token the two agree; docs/serving.md has the caveat.
+    from bigdl_tpu.parallel.expert import MoEFFN
+    moe = TransformerLM(vocab_size=64, max_len=64, d_model=32, num_heads=2,
+                        num_layers=2, num_experts=4).build(jax.random.key(1))
+    for ffn in kv._modules_of_type(moe, MoEFFN):
+        ffn.capacity_factor = 4.0     # C = T: no token can overflow
+    prompts = _prompts(3, lo=3, hi=12, seed=13)
+    with DecodeEngine(moe, slots=2, page=8) as eng:
+        outs = [h.result(120.0)
+                for h in [eng.submit(p, 5) for p in prompts]]
+    for p, out in zip(prompts, outs):
+        np.testing.assert_array_equal(out, _oracle(moe, p, 5))
+
+
+def test_prefill_raises_on_an_unknown_container(lm):
+    from bigdl_tpu.nn.module import Container
+
+    class Odd(Container):
+        pass
+
+    with pytest.raises(NotImplementedError, match="unsupported container"):
+        kv._prefill(Odd(), (), (), np.zeros(8, np.int32), (), 0, 1)
 
 
 def test_eos_frees_slot_same_step(lm):
@@ -300,6 +465,27 @@ def test_tp_sharded_kv_cache_halves_per_device(lm):
             assert len(arr.sharding.device_set) == 2
             shard_bytes = {s.data.nbytes for s in arr.addressable_shards}
             assert shard_bytes == {arr.nbytes // 2}
+
+
+@pytest.mark.skipif(jax.device_count() < 2, reason="needs >= 2 devices")
+def test_tp_sharded_engine_matches_single_device_and_keeps_sharding(lm):
+    from bigdl_tpu.parallel import MeshLayout
+    mesh = MeshLayout(1, 1, 2).build_mesh(jax.devices()[:2])
+    prompts = _prompts(3, lo=3, hi=12, seed=14)
+    with DecodeEngine(lm, slots=2, page=32, mesh=mesh) as eng:
+        outs = [h.result(120.0)
+                for h in [eng.submit(p, 5) for p in prompts]]
+        caches = eng._caches
+    for p, out in zip(prompts, outs):
+        np.testing.assert_array_equal(out, _oracle(lm, p, 5))
+    want = init_kv_cache(lm, 2, 32, caches[0]["k"].dtype, mesh=mesh)
+    for got, ref in zip(caches, want):
+        for n in "kv":
+            # after three admissions and their steps: still heads over tp,
+            # half the bytes on each device
+            assert got[n].sharding.is_equivalent_to(ref[n].sharding, 4)
+            assert {s.data.nbytes for s in got[n].addressable_shards} \
+                == {got[n].nbytes // 2}
 
 
 # ---------------------------------------------------------------------------

@@ -283,6 +283,8 @@ def test_request_stamps_are_ordered_and_summed(lm, monkeypatch):
 def test_decode_counter_arguments_wait_for_a_reader(lm, monkeypatch):
     """The per-tick track's arguments (a sum over every cache array) are
     computed only when a tracer or a registry will read them."""
+    # no registry, whatever ran before in this process (put back after)
+    monkeypatch.setattr(metrics_export, "_REGISTRY", None)
     calls = []
     real = DecodeEngine.cache_bytes_per_slot
     monkeypatch.setattr(DecodeEngine, "cache_bytes_per_slot",
