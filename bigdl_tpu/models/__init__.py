@@ -6,6 +6,7 @@ from .inception import (Inception_Layer_v1, Inception_Layer_v2,
                         Inception_v1, Inception_v1_NoAuxClassifier,
                         Inception_v2, Inception_v2_NoAuxClassifier)
 from .decode import beam_generate, cached_generate, init_kv_cache
+from .deepseek import DeepSeekV2LM
 from .lenet import LeNet5
 from .resnet import ResNet, ShortcutType
 from .rnn import PTBModel, SimpleRNN
@@ -18,7 +19,7 @@ from .vit import ViT
 from .widedeep import WideDeep
 
 __all__ = [
-    "AlexNet", "Autoencoder", "Inception_Layer_v1", "Inception_Layer_v2",
+    "AlexNet", "Autoencoder", "DeepSeekV2LM", "Inception_Layer_v1", "Inception_Layer_v2",
     "Inception_v1", "Inception_v1_NoAuxClassifier", "Inception_v2",
     "Inception_v2_NoAuxClassifier", "LeNet5", "PTBModel",
     "PositionalEmbedding", "ResNet", "ShortcutType", "SimpleRNN",

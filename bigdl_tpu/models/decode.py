@@ -17,6 +17,18 @@ forward uses (Sequential / residual ConcatTable+CAddTable / LayerNorm /
 MoEFFN / MultiHeadAttention...), so a model trained through the Optimizer
 decodes with its own modules — no weight surgery.  Unrecognized module
 types raise rather than silently mis-decode.
+
+What is kept between steps is each layer's own declaration
+(``Module.decode_state``: leaves, each leaf's length axis, its layout
+role): ``MultiHeadAttention`` keeps ``{k, v}`` of ``[rows, H, L, D]``,
+``LatentAttention`` ``{c_kv, k_rope}`` of ``[rows, L, width]``,
+``PositionalEmbedding`` nothing but needs the position.  ``init_kv_cache``
+asks the layers, and ``decode_walk`` is the one walk the serving engine's
+two programs (prefill, step) share: it hands every declaring layer its
+leaves through ``decode_prefill`` or ``decode_step`` and applies every
+other leaf to the positions in hand.  ``cached_generate`` keeps a walk of
+its own (``_step``, one position shared by all rows, written out for
+``MultiHeadAttention``): it is the oracle the engine's tokens are held to.
 """
 
 from __future__ import annotations
@@ -30,7 +42,7 @@ import jax.numpy as jnp
 
 from ..nn.attention import MultiHeadAttention
 from ..nn.containers import ConcatTable, Sequential
-from ..nn.module import Container, Module
+from ..nn.module import Container
 from .transformer_lm import PositionalEmbedding, sample_next
 
 __all__ = ["init_kv_cache", "cached_generate", "beam_generate"]
@@ -59,10 +71,24 @@ def _mha_modules(module):
     return _modules_of_type(module, MultiHeadAttention)
 
 
-def _cache_sharding(mesh, shape):
-    """Where a [rows, H, L, D] cache tensor lives on a canonical layout
-    mesh: the ``kv_cache`` role (rows over data x fsdp, heads over tp).
-    None without a mesh."""
+def _stateful_modules(model, rows: int = 1, length: int = 1):
+    """The layers that keep something between decode steps, in traversal
+    order (== the order of the cache list), each with its declaration."""
+    def leaves(module):
+        if isinstance(module, Container):
+            for m in module.modules:
+                yield from leaves(m)
+        else:
+            yield module
+
+    return [(m, spec) for m in leaves(model)
+            for spec in [m.decode_state(rows, length)] if spec]
+
+
+def _cache_sharding(mesh, shape, role: str = "kv_cache"):
+    """Where one leaf of the decode state lives on a canonical layout
+    mesh: its declared role (``kv_cache``: rows over data x fsdp, heads
+    over tp; ``latent_cache``: rows alone).  None without a mesh."""
     if mesh is None:
         return None
     from jax.sharding import NamedSharding
@@ -72,29 +98,121 @@ def _cache_sharding(mesh, shape):
         raise ValueError(
             "init_kv_cache: mesh lacks the canonical layout axes "
             "(build it with parallel/layout.MeshLayout.build_mesh)")
-    return NamedSharding(mesh, lay.spec_for("kv_cache", shape, min_size=0))
+    return NamedSharding(mesh, lay.spec_for(role, shape, min_size=0))
+
+
+def cache_avals(model, rows: int, length: int, dtype, mesh=None):
+    """The decode state's shapes, dtypes and shardings, as the layers
+    declare them: one dict a stateful layer."""
+    return tuple(
+        {n: jax.ShapeDtypeStruct(
+            leaf.shape, dtype,
+            sharding=_cache_sharding(mesh, leaf.shape, leaf.role))
+         for n, leaf in spec.items()}
+        for _m, spec in _stateful_modules(model, rows, length))
 
 
 def init_kv_cache(model, batch: int, max_len: int, dtype=jnp.float32,
                   mesh=None):
-    """One {k, v} buffer of shape [B, H, max_len, D] per attention layer.
+    """Zeroed decode state for ``batch`` rows of ``max_len`` positions: one
+    dict of buffers for each layer that declares some
+    (``Module.decode_state``), e.g. ``{k, v}`` of [B, H, max_len, D] for
+    every ``MultiHeadAttention``.
 
     ``mesh``: optional canonical layout mesh (parallel/layout
-    ``build_mesh``) — cache tensors are then placed through the
-    ``kv_cache`` role (rows over data x fsdp, heads over tp), so a
+    ``build_mesh``) — each leaf is then placed through its declared role
+    (``kv_cache``: rows over data x fsdp, heads over tp), so a
     tp-sharded model decodes against caches that already match its
     column-parallel q/k/v kernels: each device holds exactly the 1/tp
     of the cache its heads produce, no per-step resharding."""
     caches = []
-    for mha in _mha_modules(model):
-        shape = (batch, mha.num_heads, max_len, mha.head_dim)
-        k = jnp.zeros(shape, dtype)
-        v = jnp.zeros(shape, dtype)
-        sh = _cache_sharding(mesh, shape)
-        if sh is not None:
-            k, v = jax.device_put(k, sh), jax.device_put(v, sh)
-        caches.append({"k": k, "v": v})
+    for aval in cache_avals(model, batch, max_len, dtype, mesh):
+        c = {}
+        for n, a in aval.items():
+            z = jnp.zeros(a.shape, a.dtype)
+            c[n] = z if a.sharding is None else jax.device_put(z, a.sharding)
+        caches.append(c)
     return caches
+
+
+def grow_cache(model, caches, length: int, mesh=None):
+    """``caches`` padded with zeros to ``length`` positions along each
+    leaf's own length axis (masked positions carry exact-zero weight, so
+    rows in flight decode on unchanged)."""
+    grown = []
+    rows = jax.tree.leaves(caches)[0].shape[0]
+    for c, (_m, spec) in zip(caches, _stateful_modules(model, rows, length)):
+        out = {}
+        for n, arr in c.items():
+            ax, shape = spec[n].length_axis, spec[n].shape
+            pad = jnp.zeros(arr.shape[:ax] + (shape[ax] - arr.shape[ax],)
+                            + arr.shape[ax + 1:], arr.dtype)
+            out[n] = jnp.concatenate([arr, pad], axis=ax)
+            sh = _cache_sharding(mesh, shape, spec[n].role)
+            if sh is not None:
+                out[n] = jax.device_put(out[n], sh)
+        grown.append(out)
+    return tuple(grown)
+
+
+class _Walk:
+    """One pass of some positions through a module tree with decode state
+    (module docstring).  ``visit(module, params, x, cache) -> (y, cache)``
+    serves the layers that declare state; ``caches`` (a list) is updated in
+    place, and ``tally`` gathers what the layers without leaves report of
+    the call (an expert layer: the tokens each held expert took).  With
+    ``last`` set (a prefill: all positions of one prompt at once),
+    everything past the last layer that keeps leaves is position-wise, so a
+    Sequential outside any ConcatTable keeps only position ``last`` from
+    there on: the rest of the model (the last block's MLP, a final norm,
+    the head, LogSoftMax) runs on [1, 1, E]."""
+
+    def __init__(self, caches, visit, last=None):
+        self.caches, self.visit = caches, visit
+        self.last = last
+        self.tally = []
+
+    def counts(self):
+        """The reports of the call summed over the layers that made one
+        (None where none did)."""
+        return sum(self.tally[1:], self.tally[0]) if self.tally else None
+
+    def walk(self, module, params, state, x, layer=0, in_table=False):
+        """Returns (y, next_layer)."""
+        spec = module.decode_state(1, 1)
+        if spec is not None:
+            if not spec:                      # no leaves: maybe a report
+                y, report = self.visit(module, params, x, None)
+                if report is not None:
+                    self.tally.append(report)
+                return y, layer
+            y, self.caches[layer] = self.visit(module, params, x,
+                                               self.caches[layer])
+            return y, layer + 1
+        if isinstance(module, Sequential):
+            for m, p, s in zip(module.modules, params, state):
+                before = layer
+                x, layer = self.walk(m, p, s, x, layer, in_table)
+                if self.last is not None and not in_table \
+                        and before < layer == len(self.caches):
+                    x = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(
+                        a, self.last, 1, axis=1), x)
+            return x, layer
+        if isinstance(module, ConcatTable):
+            outs = []
+            for m, p, s in zip(module.modules, params, state):
+                o, layer = self.walk(m, p, s, x, layer, True)
+                outs.append(o)
+            return outs, layer
+        if not isinstance(module, Container):
+            # every other leaf (norms, Linear, activations, CAddTable, ...)
+            # is position-independent: its own eval apply, on whatever
+            # positions are in hand
+            y, _ = module.apply(params, state, x, training=False, rng=None)
+            return y, layer
+        raise NotImplementedError(
+            f"cached decoding: unsupported container "
+            f"{type(module).__name__}")
 
 
 def _cached_attention(mha, params, x, cache, pos):
@@ -137,6 +255,13 @@ def _step(module, params, state, x, caches, slot, pos):
     if isinstance(module, PositionalEmbedding):
         return x + jax.lax.dynamic_slice_in_dim(
             params["weight"], pos, 1, axis=0).astype(x.dtype)[None], slot
+    if module.decode_state(1, 1):
+        # any other layer with state has no second form written out here:
+        # its own step, every row at the one position
+        y, caches[slot] = module.decode_step(
+            params, x, caches[slot],
+            jnp.full((x.shape[0],), pos, jnp.int32))
+        return y, slot + 1
     if isinstance(module, Sequential):
         for m, p, s in zip(module.modules, params, state):
             x, slot = _step(m, p, s, x, caches, slot, pos)
@@ -156,89 +281,34 @@ def _step(module, params, state, x, caches, slot, pos):
         f"cached decoding: unsupported container {type(module).__name__}")
 
 
-def _prefill_attention(mha, params, x, cache, slot):
-    """x: [1, P, E], a whole prompt from position 0 entering the fresh
-    cache row `slot`; returns ([1, P, E], new_cache).
-
-    The prompt attends causally over itself with `_cached_attention`'s
-    float32 score path and exact-zero masked weights; k and v of all P
-    positions go into the cache by one write each."""
-    if not mha.causal:
-        raise NotImplementedError(
-            "cached decoding requires causal attention "
-            "(MultiHeadAttention(causal=False) found)")
-    _, P, E = x.shape
-    H, D = mha.num_heads, mha.head_dim
-    split = lambda y: y.reshape(1, P, H, D).transpose(0, 2, 1, 3)
-    q, k, v = (split(mha._proj(params, x, n)) for n in "qkv")
-    # attend over what the cache will hold: k and v in the cache's dtype
-    k, v = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
-    ck = jax.lax.dynamic_update_slice(cache["k"], k, (slot, 0, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache["v"], v, (slot, 0, 0, 0))
-    scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) / (D ** 0.5)
-    mask = jnp.arange(P)[None, :] <= jnp.arange(P)[:, None]
-    scores = jnp.where(mask, scores, -jnp.inf)
-    w = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bhql,bhld->bhqd", w, v.astype(jnp.float32))
-    o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(1, P, E)
-    return mha._proj(params, o, "o"), {"k": ck, "v": cv}
-
-
-def _prefill_walk(module, params, state, x, caches, layer, slot, last,
-                  in_table=False):
-    """`_step`'s walk with all positions of one prompt at once: x is
-    [1, P] token ids at the root; returns (y, next_layer).  Row `slot` of
-    `caches[layer]` takes each attention layer's k and v (`caches` is
-    mutated in place, as in `_step`).
-
-    Past the last attention layer everything is position-wise, so a
-    Sequential outside any ConcatTable keeps only position `last` from
-    there on: the rest of the model (for a TransformerLM the final
-    LayerNorm, the head and LogSoftMax) runs on [1, 1, E]."""
-    if isinstance(module, MultiHeadAttention):
-        y, caches[layer] = _prefill_attention(module, params, x,
-                                              caches[layer], slot)
-        return y, layer + 1
-    if isinstance(module, Sequential):
-        for m, p, s in zip(module.modules, params, state):
-            before = layer
-            x, layer = _prefill_walk(m, p, s, x, caches, layer, slot, last,
-                                     in_table)
-            if before < layer == len(caches) and not in_table:
-                x = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(
-                    a, last, 1, axis=1), x)
-        return x, layer
-    if isinstance(module, ConcatTable):
-        outs = []
-        for m, p, s in zip(module.modules, params, state):
-            o, layer = _prefill_walk(m, p, s, x, caches, layer, slot, last,
-                                     True)
-            outs.append(o)
-        return outs, layer
-    if not isinstance(module, Container):
-        # position-wise leaves (and PositionalEmbedding, which adds rows
-        # 0..P-1) run their own eval apply on all positions at once
-        y, _ = module.apply(params, state, x, training=False, rng=None)
-        return y, layer
-    raise NotImplementedError(
-        f"cached decoding: unsupported container {type(module).__name__}")
-
-
 def _prefill(model, params, state, toks, caches, slot, t0):
     """One-pass prefill of one sequence into cache row `slot`: `toks` is
     the prompt, [P] with pads past its `t0` real tokens (P no longer than
-    the cache).  Returns the [V] logits of position t0 - 1 and the caches.
+    the cache).  Returns the [V] logits of position t0 - 1, the caches,
+    and the expert token counts of the t0 real tokens (None for a model
+    that counts none).
 
-    Rows t0..P-1 of the slot take the pads' k and v: finite, and masked by
+    Rows t0..P-1 of the slot take the pads' state: finite, and masked by
     `<= pos` in every later step until the sequence overwrites them, like
     a previous occupant's stale rows."""
-    caches = list(caches)
-    y, _ = _prefill_walk(model, params, state, toks[None], caches, 0, slot,
-                         t0 - 1)
-    if y.shape[1] != 1:  # nothing follows the last attention layer
+    w = _Walk(list(caches),
+              lambda m, p, x, c: m.decode_prefill(p, x, c, slot, t0),
+              last=t0 - 1)
+    y, _ = w.walk(model, params, state, toks[None])
+    if y.shape[1] != 1:  # nothing follows the last stateful layer
         y = jax.lax.dynamic_slice_in_dim(y, t0 - 1, 1, axis=1)
-    return y[0, 0], tuple(caches)
+    return y[0, 0], tuple(w.caches), w.counts()
+
+
+def _slot_step(model, params, state, tok, caches, pos):
+    """Every row one position forward, each at its own: `tok` and `pos`
+    are [S]; a row with `pos` < 0 is idle (it computes position 0 of
+    token `tok`, is counted nowhere, and what it writes a prefill
+    overwrites).  Returns ([S, V] logits, caches, expert token counts)."""
+    w = _Walk(list(caches),
+              lambda m, p, x, c: m.decode_step(p, x, c, pos))
+    y, _ = w.walk(model, params, state, tok[:, None])
+    return y[:, -1], tuple(w.caches), w.counts()
 
 
 def _get_step(model, rows: int, max_len: int, dtype):
@@ -380,10 +450,12 @@ def cached_generate(model, prompt, num_tokens: int, max_len: int,
     cache instead of a full [B, max_len] re-forward.
 
     Greedy outputs are bit-identical to greedy_generate (parity-tested).
-    MoE caveat: MoEFFN capacity is computed from the live token count, so
-    with a large batch an expert can overflow in one mode but not the other
-    (both drop per the capacity contract); raise capacity_factor on the
-    model if exact MoE parity at scale matters.
+    MoE caveat, for the capacity-routed ``parallel/expert.MoEFFN`` only:
+    its capacity is computed from the live token count, so with a large
+    batch an expert can overflow in one mode but not the other (both drop
+    per the capacity contract); raise capacity_factor on the model if
+    exact parity at scale matters.  ``GatedMoE`` has no capacity and drops
+    nothing in either mode.
 
     ``mesh``: optional canonical layout mesh — params are placed through
     the role table (parallel/layout.assign_shardings) and caches through

@@ -54,6 +54,18 @@ class PositionalEmbedding(Module):
             raise ValueError(f"sequence length {t} > max_len {self.max_len}")
         return x + params["weight"][:t].astype(x.dtype)
 
+    # incremental decoding: nothing is kept, but the position matters
+
+    def decode_state(self, rows: int, length: int):
+        return {}
+
+    def decode_prefill(self, params, x, cache, slot, length):
+        return self._apply(params, x), cache      # rows 0..P-1
+
+    def decode_step(self, params, x, cache, pos):
+        w = jnp.take(params["weight"], jnp.maximum(pos, 0), axis=0)  # [S, E]
+        return x + w[:, None].astype(x.dtype), cache
+
 
 def _residual(branch: Module) -> Sequential:
     """y = x + branch(x), via the library's table algebra."""

@@ -12,7 +12,7 @@ from .graph import Graph, Input, ModuleNode
 from .activation import (ReLU, ReLU6, PReLU, RReLU, LeakyReLU, ELU, GELU,
                          Tanh, TanhShrink, Sigmoid, SoftMax, SoftMin,
                          SoftPlus, SoftSign, SoftShrink, HardShrink, HardTanh,
-                         Threshold, LogSoftMax, LogSigmoid)
+                         Threshold, LogSoftMax, LogSigmoid, SiLU)
 from .linear import (Linear, Bilinear, CMul, CAdd, Mul, Add, MulConstant,
                      AddConstant)
 from .conv import (SpatialConvolution, SpatialDilatedConvolution,
@@ -24,7 +24,7 @@ from .pooling import (SpatialMaxPooling, SpatialAveragePooling,
 from .detection import Nms
 from .tree import TreeLSTM, BinaryTreeLSTM
 from .normalization import (BatchNormalization, SpatialBatchNormalization,
-                            LayerNorm, Normalize, SpatialCrossMapLRN,
+                            LayerNorm, Normalize, RMSNorm, SpatialCrossMapLRN,
                             SpatialWithinChannelLRN,
                             SpatialSubtractiveNormalization,
                             SpatialDivisiveNormalization,
@@ -54,5 +54,5 @@ from .criterion import (
     MultiMarginCriterion, ParallelCriterion, SmoothL1Criterion,
     SmoothL1CriterionWithWeights, SoftMarginCriterion, SoftmaxWithCriterion,
     TimeDistributedCriterion)
-from .attention import MultiHeadAttention
+from .attention import LatentAttention, MultiHeadAttention
 from .fused import ConvBN, ConvBNAddReLU, fuse_conv_bn

@@ -23,7 +23,7 @@ from .module import Module
 __all__ = ["ReLU", "ReLU6", "PReLU", "RReLU", "LeakyReLU", "ELU", "Tanh",
            "TanhShrink", "Sigmoid", "SoftMax", "SoftMin", "SoftPlus", "SoftSign",
            "SoftShrink", "HardShrink", "HardTanh", "Threshold", "LogSoftMax",
-           "LogSigmoid"]
+           "LogSigmoid", "SiLU"]
 
 
 class ReLU(Module):
@@ -192,6 +192,14 @@ class LogSoftMax(Module):
 class LogSigmoid(Module):
     def _apply(self, params, x):
         return jax.nn.log_sigmoid(x)
+
+
+class SiLU(Module):
+    """x * sigmoid(x): the activation of a gated MLP
+    (``ConcatTable(Sequential(Linear, SiLU), Linear)`` -> ``CMulTable``)."""
+
+    def _apply(self, params, x):
+        return jax.nn.silu(x)
 
 
 class GELU(Module):

@@ -9,6 +9,15 @@ instead reuses a MeshLayout's 'tp' axis as the sequence axis: on a tp>1
 mesh whose sequence length divides |tp|, the attention core rings over
 'tp' — long contexts shard across the tensor-parallel group with no extra
 mesh axis (parity-pinned on the CPU mesh, tests/test_pipeline_expert.py).
+
+LatentAttention (MLA, DeepSeek-V2): queries and keys/values go through
+low-rank projections, and what a decoder keeps of a position is the shared
+latent ``c_kv`` and one rotary key, not a key and a value for every head.
+
+Incremental decoding: each layer declares what it keeps (``decode_state``),
+how a prompt fills a row (``decode_prefill``) and how one position a row
+advances it (``decode_step``); models/decode.py and serve/decode.py walk any
+model through that interface.
 """
 
 from __future__ import annotations
@@ -20,9 +29,11 @@ import jax.numpy as jnp
 
 from ..common import get_policy
 from .initialization import compute_fans, default_weight_init
-from .module import Module
+from .module import Module, StateLeaf
+from .normalization import rms_norm
+from .rotary import apply_rope, rope_angles, rope_inv_freq, yarn_mscale
 
-__all__ = ["MultiHeadAttention"]
+__all__ = ["MultiHeadAttention", "LatentAttention"]
 
 
 class MultiHeadAttention(Module):
@@ -111,3 +122,303 @@ class MultiHeadAttention(Module):
             o = flash_attention(q, k, v, causal=self.causal)
         o = o.transpose(0, 2, 1, 3).reshape(B, T, E)
         return self._proj(params, o, "o")
+
+    # -- incremental decoding ------------------------------------------
+
+    def _require_causal(self):
+        if not self.causal:
+            # a KV cache presumes causal attention; fail loudly instead of
+            # silently masking a bidirectional model into different outputs
+            raise NotImplementedError(
+                "cached decoding requires causal attention "
+                "(MultiHeadAttention(causal=False) found)")
+
+    def decode_state(self, rows: int, length: int):
+        """A key and a value for every head and position:
+        ``[rows, H, length, D]`` each."""
+        shape = (rows, self.num_heads, length, self.head_dim)
+        return {"k": StateLeaf(shape, 2, "kv_cache"),
+                "v": StateLeaf(shape, 2, "kv_cache")}
+
+    def decode_prefill(self, params, x, cache, slot, length):
+        """x: [1, P, E], a whole prompt from position 0 entering the fresh
+        cache row `slot`; returns ([1, P, E], new_cache).  (`length`, the
+        prompt's real positions, is not needed here: the pads are computed
+        and masked later.)
+
+        The prompt attends causally over itself with `decode_step`'s
+        float32 score path and exact-zero masked weights; k and v of all P
+        positions go into the cache by one write each."""
+        self._require_causal()
+        _, P, E = x.shape
+        H, D = self.num_heads, self.head_dim
+        split = lambda y: y.reshape(1, P, H, D).transpose(0, 2, 1, 3)
+        q, k, v = (split(self._proj(params, x, n)) for n in "qkv")
+        # attend over what the cache will hold: k and v in the cache's dtype
+        k, v = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+        ck = jax.lax.dynamic_update_slice(cache["k"], k, (slot, 0, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cache["v"], v, (slot, 0, 0, 0))
+        scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
+                            k.astype(jnp.float32)) / (D ** 0.5)
+        mask = jnp.arange(P)[None, :] <= jnp.arange(P)[:, None]
+        scores = jnp.where(mask, scores, -jnp.inf)
+        w = jax.nn.softmax(scores, axis=-1)
+        o = jnp.einsum("bhql,bhld->bhqd", w, v.astype(jnp.float32))
+        o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(1, P, E)
+        return self._proj(params, o, "o"), {"k": ck, "v": cv}
+
+    def decode_step(self, params, x, cache, pos):
+        """x: [S, 1, E], pos: [S] int32, every row at its own position;
+        returns ([S, 1, E], new_cache)."""
+        self._require_causal()
+        pos = jnp.maximum(pos, 0)                 # an idle row: position 0
+        S, _, E = x.shape
+        H, D = self.num_heads, self.head_dim
+        split = lambda y: y.reshape(S, 1, H, D).transpose(0, 2, 1, 3)
+        q, k, v = (split(self._proj(params, x, n)) for n in "qkv")
+
+        def upd(c, u, p):  # c: [H, L, D], u: [H, 1, D], p: scalar
+            return jax.lax.dynamic_update_slice(c, u, (0, p, 0))
+
+        ck = jax.vmap(upd)(cache["k"], k.astype(cache["k"].dtype), pos)
+        cv = jax.vmap(upd)(cache["v"], v.astype(cache["v"].dtype), pos)
+        L = ck.shape[2]
+        scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
+                            ck.astype(jnp.float32)) / (D ** 0.5)
+        # per-row causal horizon; positions past a row's pos get EXACT
+        # zero softmax weight (exp(-inf)), so stale cache rows from a
+        # previous occupant of the slot contribute exactly nothing
+        mask = jnp.arange(L)[None, None, None, :] <= pos[:, None, None, None]
+        scores = jnp.where(mask, scores, -jnp.inf)
+        w = jax.nn.softmax(scores, axis=-1)
+        o = jnp.einsum("bhql,bhld->bhqd", w, cv.astype(jnp.float32))
+        o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(S, 1, E)
+        return self._proj(params, o, "o"), {"k": ck, "v": cv}
+
+
+class LatentAttention(Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) over
+    [B, T, hidden], causal.
+
+    ``c_Q = RMSNorm(x W_DQ)``; a head's query is ``[q_nope, q_rope] = c_Q
+    W_UQ``.  ``[c_KV, k_r] = x W_DKV``, ``c_KV = RMSNorm(c_KV)``; a head's
+    ``[k_nope, v] = c_KV W_UKV``; ``k_rope = RoPE(k_r)`` is one vector for
+    all heads.  Scores are ``(q_nope . k_nope + RoPE(q_rope) . k_rope) *
+    scale`` with ``scale = (nope + rope)^-0.5 * mscale^2``, softmax in
+    float32, output ``concat_heads(P v) W_O``.
+
+    ``num_heads`` is the number of heads held here: a tensor-parallel share
+    of a wider layer holds some heads' columns of ``W_UQ`` and ``W_UKV`` and
+    rows of ``W_O``, and its output is that share's term of the sum.
+
+    The full-sequence ``_apply`` expands keys and values (the training
+    shape; prefill uses it).  ``decode_step`` is the same mathematics with
+    ``W_UK`` absorbed into the query and ``W_UV`` into the output, so a
+    position costs a read of ``c_KV`` and ``k_rope`` and nothing a head:
+    those two are all the state kept.
+    """
+
+    #: what every tensor-parallel share computes alike stays whole;
+    #: the per-head matrices split their head axis over tp
+    PARAM_ROLES = {"wdq": "kernel_whole", "wdkv": "kernel_whole",
+                   "wuq": "kernel_in", "wukv": "kernel_in",
+                   "wo": "kernel_in", "*": "norm_scale"}
+
+    #: queries of a long sequence attend in blocks of this many, so the
+    #: float32 scores are [H, block, <= T] and never [H, T, T]
+    QUERY_BLOCK = 512
+
+    def __init__(self, hidden: int, num_heads: int, q_lora_rank: int,
+                 kv_lora_rank: int, qk_nope_head_dim: int,
+                 qk_rope_head_dim: int, v_head_dim: int,
+                 rope_theta: float = 10000.0, rope_scaling=None,
+                 eps: float = 1e-6):
+        super().__init__()
+        self.hidden, self.num_heads = hidden, num_heads
+        self.q_lora_rank, self.kv_lora_rank = q_lora_rank, kv_lora_rank
+        self.nope, self.rope, self.v_dim = (qk_nope_head_dim,
+                                            qk_rope_head_dim, v_head_dim)
+        self.eps = eps
+        self.inv_freq = rope_inv_freq(qk_rope_head_dim, rope_theta,
+                                      rope_scaling)
+        m = 1.0
+        if rope_scaling and rope_scaling.get("mscale_all_dim"):
+            m = yarn_mscale(rope_scaling["factor"],
+                            rope_scaling["mscale_all_dim"])
+        # the cos/sin scale mscale(mscale) / mscale(mscale_all_dim) is 1
+        # wherever the two are published equal, and is not applied
+        self.score_scale = (qk_nope_head_dim + qk_rope_head_dim) ** -0.5 \
+            * m * m
+
+    def _init(self, rng):
+        ks = jax.random.split(rng, 5)
+        dt = get_policy().param_dtype
+        winit = self.weight_initializer or default_weight_init
+
+        def w(k, shape):
+            fi, fo = compute_fans(shape)
+            return winit(k, shape, fi, fo, dt)
+
+        H = self.num_heads
+        return {"wdq": w(ks[0], (self.hidden, self.q_lora_rank)),
+                "q_norm": jnp.ones((self.q_lora_rank,), dt),
+                "wuq": w(ks[1], (self.q_lora_rank,
+                                 H * (self.nope + self.rope))),
+                "wdkv": w(ks[2], (self.hidden,
+                                  self.kv_lora_rank + self.rope)),
+                "kv_norm": jnp.ones((self.kv_lora_rank,), dt),
+                "wukv": w(ks[3], (self.kv_lora_rank,
+                                  H * (self.nope + self.v_dim))),
+                "wo": w(ks[4], (H * self.v_dim, self.hidden))}
+
+    @staticmethod
+    def _mm(x, w):
+        """x @ w over the last axis: compute-dtype operands, float32
+        accumulation, compute-dtype result."""
+        c = get_policy().compute_dtype
+        return jax.lax.dot_general(
+            x.astype(c), w.astype(c), (((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(c)
+
+    def _project(self, params, x, pos):
+        """x [..., hidden] at positions ``pos [...]`` -> the heads' queries
+        ``q_nope [..., H, nope]``, ``q_rope [..., H, rope]`` (rotated), and
+        what a cache keeps: ``c_kv [..., kv_lora]`` (normed) and ``k_rope
+        [..., rope]`` (rotated)."""
+        H = self.num_heads
+        cq = rms_norm(self._mm(x, params["wdq"]), params["q_norm"], self.eps)
+        q = self._mm(cq, params["wuq"]).reshape(
+            x.shape[:-1] + (H, self.nope + self.rope))
+        q_nope, q_rope = q[..., :self.nope], q[..., self.nope:]
+        kv = self._mm(x, params["wdkv"])
+        c_kv = rms_norm(kv[..., :self.kv_lora_rank], params["kv_norm"],
+                        self.eps)
+        cos, sin = rope_angles(pos, self.inv_freq)
+        k_rope = apply_rope(kv[..., self.kv_lora_rank:], cos, sin)
+        q_rope = apply_rope(q_rope, cos[..., None, :], sin[..., None, :])
+        return q_nope, q_rope, c_kv, k_rope
+
+    def _up(self, params):
+        """``W_UKV`` as ``[kv_lora, H, nope + v]``: a head's ``W_UK`` is
+        ``[..., :nope]`` and its ``W_UV`` the rest."""
+        return params["wukv"].reshape(self.kv_lora_rank, self.num_heads,
+                                      self.nope + self.v_dim)
+
+    def _expanded(self, params, q_nope, q_rope, c_kv, k_rope, length=None):
+        """Causal attention of [B, T] positions with keys and values
+        expanded from the latent; returns [B, T, hidden].  ``length``
+        (traced): only the first ``length`` positions are real, as in a
+        padded prompt; query blocks that hold none are not computed."""
+        c = get_policy().compute_dtype
+        B, T = c_kv.shape[:2]
+        H = self.num_heads
+        kv = jnp.einsum("btc,chd->bhtd", c_kv.astype(c),
+                        self._up(params).astype(c),
+                        preferred_element_type=jnp.float32).astype(c)
+        k = jnp.concatenate(
+            [kv[..., :self.nope],
+             jnp.broadcast_to(k_rope.astype(c)[:, None],
+                              (B, H, T, self.rope))], axis=-1)
+        v = kv[..., self.nope:]
+        q = jnp.concatenate([q_nope, q_rope], axis=-1).astype(c) \
+            .transpose(0, 2, 1, 3)                      # [B, H, T, d]
+
+        def attend(start, stop):
+            # queries start..stop-1 over the keys they may see, 0..stop-1
+            s = jnp.einsum("bhqd,bhkd->bhqk", q[:, :, start:stop],
+                           k[:, :, :stop],
+                           preferred_element_type=jnp.float32) \
+                * self.score_scale
+            qi = jnp.arange(start, stop)[:, None]
+            s = jnp.where(jnp.arange(stop)[None, :] <= qi, s, -jnp.inf)
+            w = jax.nn.softmax(s, axis=-1)
+            return jnp.einsum("bhqk,bhkd->bhqd", w.astype(c), v[:, :, :stop],
+                              preferred_element_type=jnp.float32).astype(c)
+
+        n = self.QUERY_BLOCK
+        if T > n and T % n == 0:
+            # blocks of queries, one after another, each over the keys up
+            # to its own end: the float32 scores are [H, n, <= T] at a
+            # time, and the half above the diagonal is never computed
+            blocks = []
+            for start in range(0, T, n):
+                if length is None:
+                    blocks.append(attend(start, start + n))
+                else:
+                    blocks.append(jax.lax.cond(
+                        start < length,
+                        lambda s=start: attend(s, s + n),
+                        lambda: jnp.zeros((B, H, n, self.v_dim), c)))
+            o = jnp.concatenate(blocks, axis=2)
+        else:
+            o = attend(0, T)
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, H * self.v_dim)
+        return self._mm(o, params["wo"])
+
+    def _apply(self, params, x):
+        B, T, _ = x.shape
+        pos = jnp.broadcast_to(jnp.arange(T), (B, T))
+        return self._expanded(params, *self._project(params, x, pos))
+
+    # -- incremental decoding ------------------------------------------
+
+    def decode_state(self, rows: int, length: int):
+        """The latent and the rotary key of every position, shared by all
+        heads: ``[rows, length, kv_lora]`` and ``[rows, length, rope]``."""
+        return {"c_kv": StateLeaf((rows, length, self.kv_lora_rank), 1,
+                                  "latent_cache"),
+                "k_rope": StateLeaf((rows, length, self.rope), 1,
+                                    "latent_cache")}
+
+    def decode_prefill(self, params, x, cache, slot, length):
+        """x: [1, P, hidden], a whole prompt from position 0 of which
+        ``length`` positions are real: the expanded form over what the
+        cache will hold, and one write a leaf."""
+        P = x.shape[1]
+        q_nope, q_rope, c_kv, k_rope = self._project(
+            params, x, jnp.arange(P)[None])
+        c_kv = c_kv.astype(cache["c_kv"].dtype)
+        k_rope = k_rope.astype(cache["k_rope"].dtype)
+        new = {"c_kv": jax.lax.dynamic_update_slice(
+                   cache["c_kv"], c_kv, (slot, 0, 0)),
+               "k_rope": jax.lax.dynamic_update_slice(
+                   cache["k_rope"], k_rope, (slot, 0, 0))}
+        return self._expanded(params, q_nope, q_rope, c_kv, k_rope,
+                              length), new
+
+    def decode_step(self, params, x, cache, pos):
+        """x: [S, 1, hidden], pos: [S]: the absorbed form.  Each row's
+        ``c_kv`` and ``k_rope`` land at its own position by one scatter of S
+        rows (in place under the step's donation), not by a pass over the
+        cache; scores and the weighted sum take compute-dtype operands from
+        the cache as it is, with float32 accumulation."""
+        c = get_policy().compute_dtype
+        S = x.shape[0]
+        pos = jnp.maximum(pos, 0)                 # an idle row: position 0
+        q_nope, q_rope, c_kv, k_rope = self._project(params, x[:, 0], pos)
+        rows = jnp.arange(S)
+        cc = cache["c_kv"].at[rows, pos].set(
+            c_kv.astype(cache["c_kv"].dtype))
+        ck = cache["k_rope"].at[rows, pos].set(
+            k_rope.astype(cache["k_rope"].dtype))
+        up = self._up(params).astype(c)
+        q_lat = jnp.einsum("shn,chn->shc", q_nope.astype(c),
+                           up[..., :self.nope],
+                           preferred_element_type=jnp.float32).astype(c)
+        scores = (jnp.einsum("shc,slc->shl", q_lat, cc.astype(c),
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("shr,slr->shl", q_rope.astype(c),
+                               ck.astype(c),
+                               preferred_element_type=jnp.float32)) \
+            * self.score_scale
+        L = cc.shape[1]
+        # positions past a row's pos get exact-zero weight: a previous
+        # occupant's stale rows, and a prompt's pads, add exactly nothing
+        mask = jnp.arange(L)[None, None, :] <= pos[:, None, None]
+        w = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+        o_lat = jnp.einsum("shl,slc->shc", w.astype(c), cc.astype(c),
+                           preferred_element_type=jnp.float32).astype(c)
+        o = jnp.einsum("shc,chv->shv", o_lat, up[..., self.nope:],
+                       preferred_element_type=jnp.float32).astype(c)
+        y = self._mm(o.reshape(S, self.num_heads * self.v_dim), params["wo"])
+        return y[:, None], {"c_kv": cc, "k_rope": ck}

@@ -32,7 +32,7 @@ any pytree (array, list, dict, Table) is a valid input/output.
 from __future__ import annotations
 
 import itertools
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +40,17 @@ import numpy as np
 
 from ..common import get_policy, next_rng_key
 
-__all__ = ["Module", "Container", "Criterion"]
+__all__ = ["Module", "Container", "Criterion", "StateLeaf"]
+
+
+class StateLeaf(NamedTuple):
+    """One array a layer keeps between decode steps (``Module.decode_state``):
+    its shape for ``rows`` sequences of ``length`` positions, which axis is
+    the length (the one a cache grows along), and the layout role that
+    places it on a mesh (parallel/layout.ROLES)."""
+    shape: tuple
+    length_axis: int
+    role: str
 
 _uid_counter = itertools.count()
 
@@ -81,7 +91,10 @@ class Module:
         # facade state
         self.params = None   # pytree of parameters (None until build())
         self.state = None    # pytree of non-trained state (e.g. BN running stats)
-        self.grads = None    # accumulated parameter gradients (accGradParameters)
+        # accumulated parameter gradients (accGradParameters): zeros the
+        # size of the weights, made when first read (the ``grads`` property)
+        self._grads = None
+        self._grads_due = False
         self.output = None
         self.grad_input = None
         self._last_rng = None
@@ -92,6 +105,23 @@ class Module:
         # initializer overrides (nn/abstractnn/Initializable.scala:23)
         self.weight_initializer = None
         self.bias_initializer = None
+
+    @property
+    def grads(self):
+        """The accumulated gradients.  After ``build()`` or ``attach()`` they
+        are zeros like the weights; those zeros are made here, on the first
+        read, so a model that only serves never holds them."""
+        if getattr(self, "_grads", None) is None \
+                and getattr(self, "_grads_due", False) \
+                and self.params is not None:
+            self._grads = _tree_zeros_like(self.params)
+            self._grads_due = False
+        return getattr(self, "_grads", None)
+
+    @grads.setter
+    def grads(self, value):
+        self._grads = value
+        self._grads_due = False
 
     # scale_w/scale_b are properties so that DIRECT attribute assignment
     # (m.scale_w = 2.0) also bumps the scale epoch — otherwise a cached
@@ -142,6 +172,35 @@ class Module:
     def has_params(self) -> bool:
         return len(jax.tree.leaves(self.init(jax.random.key(0))[0])) > 0
 
+    # -- incremental decoding (models/decode.py, serve/decode.py) ---------
+    # A layer whose output at one position depends on earlier positions, or
+    # on the position itself, says so here; every other layer is applied to
+    # the new position alone by its own ``apply``.
+
+    def decode_state(self, rows: int, length: int):
+        """What this layer keeps for ``rows`` sequences of ``length``
+        positions: ``{leaf name: StateLeaf}``.  None (the default): nothing,
+        and the position does not matter.  An empty dict: no state, but the
+        layer needs the position, or which tokens are real
+        (``decode_prefill``/``decode_step``); what such a layer returns in
+        the cache's place is its report of the call (a small vector that a
+        walk sums over the layers, e.g. tokens an expert took) or None."""
+        return None
+
+    def decode_prefill(self, params, x, cache, slot, length):
+        """A whole prompt from position 0, ``x [1, P, ...]`` of which the
+        first ``length`` positions are real and the rest pads, entering
+        row ``slot`` of ``cache`` (this layer's leaves); returns (y,
+        cache)."""
+        raise NotImplementedError(type(self).__name__)
+
+    def decode_step(self, params, x, cache, pos):
+        """One position a row: ``x [rows, 1, ...]`` at positions ``pos
+        [rows]``; returns (y, cache) with ``pos`` written.  A row whose
+        ``pos`` is negative is idle: it computes position 0, is counted
+        nowhere, and what it writes a prefill overwrites."""
+        raise NotImplementedError(type(self).__name__)
+
     def param_roles(self):
         """name -> role map for THIS module's own parameters (see
         PARAM_ROLES; containers are never asked — the layout assigner
@@ -158,7 +217,7 @@ class Module:
         if rng is None:
             rng = next_rng_key()
         self.params, self.state = self.init(rng)
-        self.grads = _tree_zeros_like(self.params)
+        self._grads, self._grads_due = None, True
         return self
 
     def set_init_method(self, weight_init=None, bias_init=None):
@@ -306,8 +365,8 @@ class Module:
         return self
 
     def zero_grad_parameters(self):
-        if self.grads is not None:
-            self.grads = _tree_zeros_like(self.grads)
+        if self._grads is not None:   # zeros not made yet are zeros already
+            self.grads = _tree_zeros_like(self._grads)
 
     def update_parameters(self, learning_rate: float):
         """w -= lr * gradW (BigDL: AbstractModule.updateParameters)."""
@@ -370,8 +429,8 @@ class Module:
         from ..utils import file_io
         to_np = lambda t: jax.tree.map(_np.asarray, t) if t is not None \
             else None
-        detached = (self.params, self.state, self.grads, self.output,
-                    self.grad_input)
+        detached = (self.params, self.state, self._grads, self._grads_due,
+                    self.output, self.grad_input)
         self.params = self.state = self.grads = None
         self.output = self.grad_input = None
         try:
@@ -379,8 +438,8 @@ class Module:
                     "params": to_np(detached[0]), "state": to_np(detached[1])}
             file_io.save(blob, path, overwrite=overwrite)
         finally:
-            (self.params, self.state, self.grads, self.output,
-             self.grad_input) = detached
+            (self.params, self.state, self._grads, self._grads_due,
+             self.output, self.grad_input) = detached
         return self
 
     @staticmethod
@@ -396,14 +455,14 @@ class Module:
 
     def attach(self, params, state=None):
         """Install externally-produced params (checkpoint/interop load) into
-        the stateful facade, keeping grads consistent with build()."""
+        the stateful facade, keeping grads consistent with build(): zeros
+        like ``params``, made when ``grads`` is first read."""
         self.params = params
         if state is not None:
             self.state = state
         elif self.state is None:
             _, self.state = self.init(jax.random.key(0))
-        self.grads = (_tree_zeros_like(params)
-                      if params is not None else None)
+        self._grads, self._grads_due = None, params is not None
         return self
 
     # -- modes ---------------------------------------------------------

@@ -32,6 +32,7 @@ from ..utils import config
 from .module import Module
 
 __all__ = ["BatchNormalization", "SpatialBatchNormalization", "Normalize",
+           "RMSNorm",
            "SpatialCrossMapLRN", "SpatialWithinChannelLRN",
            "SpatialSubtractiveNormalization", "SpatialDivisiveNormalization",
            "SpatialContrastiveNormalization"]
@@ -341,6 +342,34 @@ class LayerNorm(Module):
             y = y * params["weight"].astype(jnp.float32) + \
                 params["bias"].astype(jnp.float32)
         return y.astype(x.dtype)
+
+
+class RMSNorm(Module):
+    """x / sqrt(mean(x^2) + eps) * weight over the last axis: LayerNorm
+    without the mean and the shift.  Statistics in float32 whatever the
+    compute dtype, like LayerNorm."""
+
+    PARAM_ROLES = {"weight": "norm_scale"}
+
+    def __init__(self, n_output: int, eps: float = 1e-6):
+        super().__init__()
+        self.n_output = n_output
+        self.eps = eps
+
+    def _init(self, rng):
+        return {"weight": jnp.ones((self.n_output,),
+                                   get_policy().param_dtype)}
+
+    def _apply(self, params, x):
+        return rms_norm(x, params["weight"], self.eps)
+
+
+def rms_norm(x, weight, eps: float):
+    """RMSNorm's arithmetic, for layers that norm a projection inside
+    themselves (nn/attention.LatentAttention)."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (y * weight.astype(jnp.float32)).astype(x.dtype)
 
 
 class Normalize(Module):

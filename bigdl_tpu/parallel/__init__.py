@@ -9,7 +9,8 @@ manager (SURVEY.md §2.5); TP/SP/PP here are net-new TPU capabilities (§7):
 - ring_attention: sequence/context parallelism (shard_map + ppermute ring)
 - ulysses_attention: all-to-all sequence parallelism
 - pipeline: GPipe-style microbatched stage parallelism
-- expert: capacity-routed MoE over the `expert` axis (GSPMD + shard_map)
+- expert: capacity-routed MoE over the `expert` axis (GSPMD + shard_map),
+  and the dropless `GatedMoE` (shared experts, a held share of the experts)
 - elastic: coordinated host-loss recovery (detect -> negotiate ->
   re-form -> resume; docs/robustness.md "Elasticity")
 """
@@ -25,7 +26,8 @@ from .pipeline import (pipeline_apply, pipeline_apply_scheduled,
                        pipe_microbatches, pipe_schedule,
                        pipe_virtual_stages, bubble_fraction)
 from .schedule import ScheduleTable, build_schedule
-from .expert import (MoEFFN, expert_parallel_ffn, top_k_routing,
+from .expert import (GatedMoE, MoEFFN, expert_parallel_ffn,
+                     group_limited_top_k, top_k_routing,
                      load_balancing_loss)
 from .elastic import PeerLostError, ElasticNegotiationError
 
@@ -39,4 +41,5 @@ __all__ = ["ShardingStrategy", "DataParallel", "ShardedDataParallel",
            "pipe_microbatches", "pipe_schedule", "pipe_virtual_stages",
            "bubble_fraction", "ScheduleTable", "build_schedule", "MoEFFN",
            "expert_parallel_ffn", "top_k_routing", "load_balancing_loss",
+           "GatedMoE", "group_limited_top_k",
            "PeerLostError", "ElasticNegotiationError"]

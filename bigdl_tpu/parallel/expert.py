@@ -22,6 +22,13 @@ This module adds the real thing, TPU-first, in the GShard/Switch style:
     `lax.all_to_all` dispatch→compute→combine, for when the collective
     schedule must be pinned (and as the parity oracle for the GSPMD path).
 
+A third layer, `GatedMoE`, is the dropless one that serving needs: gated
+(SwiGLU) experts beside shared experts, softmax scores with group-limited
+top-k, and a share `held=(first, count)` of the experts: it routes over all
+of them and adds the terms of those it holds.  Tokens are sorted by expert
+and each held expert multiplies its own run of rows (`lax.ragged_dot`), so
+there is no capacity and no token is dropped at any skew.
+
 The Switch load-balancing auxiliary loss (num_experts * sum(fraction_e *
 mean_prob_e)) is exposed via `load_balancing_loss`.
 """
@@ -41,7 +48,7 @@ from ..common import get_policy
 from ..nn.module import Module
 
 __all__ = ["MoEFFN", "expert_parallel_ffn", "top_k_routing",
-           "load_balancing_loss"]
+           "load_balancing_loss", "GatedMoE", "group_limited_top_k"]
 
 
 def top_k_routing(gate_logits, capacity: int, k: int = 1):
@@ -292,3 +299,163 @@ def expert_parallel_ffn(mesh, params, x, *, k: int = 1,
     pw = (params["gate"], params["w1"], params["b1"], params["w2"],
           params["b2"])
     return fn(x, pw)
+
+
+# ---------------------------------------------------------------------------
+# dropless gated experts with shared experts and a held share
+# ---------------------------------------------------------------------------
+
+def group_limited_top_k(scores, n_group: int, topk_group: int, k: int):
+    """Group-limited greedy top-k (DeepSeek-V2 ``group_limited_greedy``).
+
+    scores: [T, E] non-negative.  The experts are ``n_group`` runs of ``E /
+    n_group``; a group's score is its largest; of the ``topk_group`` best
+    groups' experts the ``k`` best are chosen.  Returns (weights [T, k],
+    indices [T, k]); the weights are the chosen scores as they are.  Ties go
+    to the lower index, among groups and among experts."""
+    T, E = scores.shape
+    if E % n_group:
+        raise ValueError(f"{E} experts do not divide into {n_group} groups")
+    per = E // n_group
+    if k > topk_group * per:
+        raise ValueError(f"k={k} > {topk_group} groups x {per} experts")
+    group_best = scores.reshape(T, n_group, per).max(axis=-1)
+    _, best = lax.top_k(group_best, topk_group)
+    kept = jnp.sum(jax.nn.one_hot(best, n_group, dtype=jnp.int32), axis=1)
+    allowed = jnp.repeat(kept > 0, per, axis=1)
+    # a score outside the kept groups competes as -1: below every score
+    return lax.top_k(jnp.where(allowed, scores, -1.0), k)
+
+
+class GatedMoE(Module):
+    """``y = Shared(x) + scale * sum_i s_i Expert_i(x)`` over the chosen
+    experts this layer holds (module docstring).
+
+    Every expert and the shared block are gated MLPs, ``W_down(SiLU(W_gate
+    x) * W_up x)``, without biases.  ``s = softmax(x W_g)`` over all
+    ``num_experts`` in float32; the choice is ``group_limited_top_k``; the
+    weights are the chosen ``s`` (not renormalised) times ``scale``.
+
+    ``held = (first, count)``: the stacked tables hold experts ``first ..
+    first + count - 1`` (default: all).  The router keeps every output, and
+    what the absent experts would add is left out: the layer's output is this
+    share's term, with the shared block's, of the whole layer's sum.
+
+    The state carries ``expert_tokens``, int32 ``[count + 1]``: how many of
+    the call's tokens each held expert took, and last how many choices went
+    to experts held elsewhere.  A decoder's walk gets the same vector, of
+    the call's real tokens only, from ``decode_prefill`` and
+    ``decode_step``: a prompt's pads and a decode batch's idle rows go to no
+    expert and count nowhere; their output is the shared experts' alone.
+    """
+
+    PARAM_ROLES = {"gate": "kernel_whole", "w_gate": "expert_table",
+                   "w_up": "expert_table", "w_down": "expert_table",
+                   "shared_gate": "kernel_in", "shared_up": "kernel_in",
+                   "shared_down": "kernel_in"}
+
+    def __init__(self, d_model: int, d_expert: int, num_experts: int,
+                 k: int, n_group: int = 1, topk_group: int = 1,
+                 n_shared: int = 0, scale: float = 1.0, held=None):
+        super().__init__()
+        self.d_model, self.d_expert = d_model, d_expert
+        self.num_experts, self.k = num_experts, k
+        self.n_group, self.topk_group = n_group, topk_group
+        self.n_shared, self.scale = n_shared, float(scale)
+        self.first, self.count = held if held is not None \
+            else (0, num_experts)
+        if not (0 <= self.first and self.count >= 1
+                and self.first + self.count <= num_experts):
+            raise ValueError(f"held={held} outside 0..{num_experts}")
+
+    def _init(self, rng):
+        dt = get_policy().param_dtype
+        ks = jax.random.split(rng, 7)
+        D, H, E = self.d_model, self.d_expert, self.count
+        n = lambda k, shape, fan: jax.random.normal(k, shape, dt) \
+            * (1.0 / fan) ** 0.5
+        p = {"gate": jax.random.normal(ks[0], (D, self.num_experts), dt)
+             * 0.02,
+             "w_gate": n(ks[1], (E, D, H), D), "w_up": n(ks[2], (E, D, H), D),
+             "w_down": n(ks[3], (E, H, D), H)}
+        if self.n_shared:
+            S = self.n_shared * H
+            p.update(shared_gate=n(ks[4], (D, S), D),
+                     shared_up=n(ks[5], (D, S), D),
+                     shared_down=n(ks[6], (S, D), S))
+        return p
+
+    def _init_state(self):
+        return {"expert_tokens": jnp.zeros((self.count + 1,), jnp.int32)}
+
+    def route(self, params, xt):
+        """xt [T, D] -> (weights [T, k] float32 with the scale on, expert
+        indices [T, k]).  The router's product runs in float32."""
+        logits = jnp.matmul(xt.astype(jnp.float32),
+                            params["gate"].astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        w, idx = group_limited_top_k(jax.nn.softmax(logits, axis=-1),
+                                     self.n_group, self.topk_group, self.k)
+        return w * self.scale, idx
+
+    def apply(self, params, state, x, *, training=False, rng=None):
+        y, counts = self._forward(params, x)
+        return y, {"expert_tokens": counts}
+
+    # incremental decoding: nothing is kept, but which tokens are real
+    # matters (Module.decode_state)
+
+    def decode_state(self, rows: int, length: int):
+        return {}
+
+    def decode_prefill(self, params, x, cache, slot, length):
+        """x [1, P, D]: a prompt's positions 0..P-1 of which the first
+        ``length`` are real, or (P == 1) its last real position alone."""
+        return self._forward(params, x,
+                             (jnp.arange(x.shape[1]) < length)[None])
+
+    def decode_step(self, params, x, cache, pos):
+        return self._forward(params, x, (pos >= 0)[:, None])
+
+    def _forward(self, params, x, live=None):
+        """x [..., D] -> (y, counts [count + 1]); ``live`` (boolean, of
+        ``x.shape[:-1]``) marks the real tokens, all of them without it."""
+        c = get_policy().compute_dtype
+        f32 = jnp.float32
+        D, E, k = self.d_model, self.count, self.k
+        # the router sees its input as it comes (a float32 residual stream
+        # stays float32 here); the experts multiply in the compute dtype
+        xr = x.reshape((-1, D))
+        w, idx = self.route(params, xr)
+        xt = xr.astype(c)
+        T = xt.shape[0]
+        local = idx - self.first
+        # the sort key: a held expert's own number, E for one held
+        # elsewhere, E + 1 for a token that is not live
+        key = jnp.where((local >= 0) & (local < E), local, E)
+        if live is not None:
+            key = jnp.where(live.reshape(T, 1), key, E + 1)
+        key = key.reshape(T * k)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.bincount(key, length=E + 2).astype(jnp.int32)
+        held = sizes[:E]
+        # rows 0..sum(held)-1 of the sorted tokens are the held experts'
+        # runs, one after another; what follows belongs to no group
+        xs = jnp.take(xt, order // k, axis=0)
+        dot = lambda a, b: lax.ragged_dot(a, b.astype(c), held,
+                                          preferred_element_type=f32)
+        h = jax.nn.silu(dot(xs, params["w_gate"])) * dot(xs, params["w_up"])
+        out = dot(h.astype(c), params["w_down"])             # [T k, D] f32
+        # back to token order, weighted; rows past the runs hold nothing
+        # that may be read, so they are selected away, not multiplied
+        inv = jnp.argsort(order)
+        own = (jnp.arange(T * k) < jnp.sum(held))[inv]
+        picked = jnp.where(own[:, None], jnp.take(out, inv, axis=0), 0.0)
+        y = jnp.sum(picked.reshape(T, k, D) * w[:, :, None], axis=1)
+        if self.n_shared:
+            mm = lambda a, b: jnp.matmul(a, b.astype(c),
+                                         preferred_element_type=f32)
+            hs = jax.nn.silu(mm(xt, params["shared_gate"])) \
+                * mm(xt, params["shared_up"])
+            y = y + mm(hs.astype(c), params["shared_down"])
+        return y.astype(c).reshape(x.shape), sizes[:E + 1]

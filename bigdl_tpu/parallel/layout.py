@@ -95,6 +95,10 @@ ROLES: Dict[str, Tuple[Optional[int], Optional[int]]] = {
     # in-major kernels, e.g. RNN/attention (in, out): tp splits the
     # trailing output axis, fsdp slices the input axis before it
     "kernel_in": (-1, -2),
+    # in-major kernels that every tensor-parallel share applies whole (a
+    # latent projection all heads read, a router): never split over tp,
+    # fsdp slices the input axis
+    "kernel_whole": (None, -2),
     # HWIO/DHWIO conv kernels (.., cin, cout): tp on cout, fsdp on cin
     "conv_kernel": (-1, -2),
     # (vocab, emb) tables: rows over fsdp x tp together (see _spec_for)
@@ -118,6 +122,11 @@ ROLES: Dict[str, Tuple[Optional[int], Optional[int]]] = {
     # matching its attention kernels' sharding forces a resharding
     # collective per decode step)
     "kv_cache": (None, None),
+    # decode caches with no head axis, [slots, cache_len, width]: what all
+    # heads of a latent-attention layer share (nn/attention.LatentAttention).
+    # Slots over data x fsdp as for kv_cache; every tp share holds the whole
+    # latent, as it applies the latent projections whole
+    "latent_cache": (None, None),
 }
 
 
@@ -285,7 +294,7 @@ class MeshLayout:
                         parts[ax] = FSDP_AXIS
                         break
             return P(*parts)
-        if role == "kv_cache" and ndim >= 2:
+        if role in ("kv_cache", "latent_cache") and ndim >= 2:
             # [slots, heads, cache_len, head_dim]: slots ride the batch
             # axes (data x fsdp, degrading like embedding_row when the
             # slot count does not divide the product), heads ride tp so
@@ -298,7 +307,8 @@ class MeshLayout:
                     parts[0] = DATA_AXIS
                 elif self.fsdp > 1 and shape[0] % self.fsdp == 0:
                     parts[0] = FSDP_AXIS
-            if self.tp > 1 and shape[1] % self.tp == 0:
+            if role == "kv_cache" and self.tp > 1 \
+                    and shape[1] % self.tp == 0:
                 parts[1] = TP_AXIS
             return P(*parts)
         if role == "embedding_row" and ndim >= 1:

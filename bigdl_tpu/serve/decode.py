@@ -21,24 +21,29 @@ took; PAPERS.md):
   shape dims through utils/aot.get_or_compile).  Prefill admits one new
   sequence into a free KV-cache slot in ONE pass: the padded prompt
   bucket goes through the model as ``[1, bucket, E]`` (every weight
-  read once a prompt, every product matrix-matrix), each attention
-  layer writes k and v of all positions into the slot by one in-place
-  update, and past the last attention layer only the prompt's last
+  read once a prompt, every product matrix-matrix), each layer with
+  decode state writes all positions into the slot by one in-place
+  update a leaf, and past the last such layer only the prompt's last
   real position goes on to the head (models/decode ``_prefill``; one
   compile per (prompt-bucket, slots, cache-page), the prompt's length
-  traced).  ``cached_generate`` keeps its position-by-position walk
-  and shares no prefill code with the engine: it is the oracle the
-  engine's greedy tokens are held to, token for token, by test.
+  traced).  Both programs are one walk (models/decode ``_Walk``) over
+  what each layer declares (``Module.decode_state``, ``decode_prefill``,
+  ``decode_step``): ``MultiHeadAttention`` keeps ``{k, v}`` a head,
+  ``LatentAttention`` a latent and one rotary key for all heads, and the
+  engine knows neither.  ``cached_generate`` keeps its
+  position-by-position walk and shares no prefill code with the engine:
+  it is the oracle the engine's greedy tokens are held to, token for
+  token, by test.
 - The bucket ladder extends to **(batch-slots, cache-page)** pages:
   cache length is allocated in power-of-2 multiples of
   ``BIGDL_TPU_DECODE_PAGE`` (models/decode.init_kv_cache buffers), so a
   17-token prompt neither compiles nor pays HBM for ``max_len``.  The
-  cache grows to the next page when a longer sequence is admitted and
-  shrinks back when the engine drains idle.  Under a canonical layout
-  mesh the cache tensors carry the ``kv_cache`` role
-  (parallel/layout.py: slots over data x fsdp, heads over tp), so
-  tp-sharded models serve decode through the existing mesh machinery
-  unchanged.
+  cache grows to the next page (each leaf along its own length axis)
+  when a longer sequence is admitted and shrinks back when the engine
+  drains idle.  Under a canonical layout mesh each leaf carries its
+  declared role (parallel/layout.py ``kv_cache``: slots over data x
+  fsdp, heads over tp; ``latent_cache``: slots alone), so tp-sharded
+  models serve decode through the existing mesh machinery unchanged.
 - Admission rides :class:`~bigdl_tpu.serve.batcher.DecodeQueue`:
   bounded queue, per-sequence deadline (= time-to-LAST-token), priority
   eviction and tenant quotas all apply per-sequence; ``note_service``
@@ -48,7 +53,12 @@ took; PAPERS.md):
   active-slot fill, prefill-vs-decode step fractions, the share of the
   prefills' positions that were padding (``prefill_pad_frac``) and
   cache bytes/slot — promoted to a ``decode:`` trace_report section like
-  ``aot``/``autoscale`` (utils/telemetry.phase_breakdown).
+  ``aot``/``autoscale`` (utils/telemetry.phase_breakdown).  A model with
+  routed experts that count their tokens (parallel/expert.GatedMoE)
+  returns one small count vector beside the logits of every call:
+  ``expert_tokens`` (choices that went to experts held here),
+  ``expert_tokens_elsewhere`` and ``expert_tokens_max`` (the busiest held
+  expert's), so load balance reads as max over mean.
 - Chaos: ``serve.decode@<slot>`` fires once per tick for every slot
   that participates (prefill or decode).  A faulted slot fails ITS
   sequence typed (:class:`SlotFault`/ChaosFault), frees the slot, and
@@ -89,9 +99,6 @@ import jax.numpy as jnp
 
 from ..models import decode as kv
 from ..models.transformer_lm import PositionalEmbedding, sample_next
-from ..nn.attention import MultiHeadAttention
-from ..nn.containers import ConcatTable, Sequential
-from ..nn.module import Container
 from ..utils import aot as aot_mod
 from ..utils import chaos, config, hlostats, metrics_export, telemetry
 from .batcher import DecodeQueue, PendingRequest, ServeError
@@ -121,71 +128,6 @@ def page_ladder(page: int, max_len: int) -> tuple:
         c *= 2
     sizes.append(int(max_len))
     return tuple(sizes)
-
-
-# ---------------------------------------------------------------------------
-# per-slot-position decode step (vmapped cache write, per-slot mask)
-# ---------------------------------------------------------------------------
-# models/decode._cached_attention serves ONE position shared by every
-# row; continuous batching needs every slot at its OWN position.  The
-# math per slot is identical (same projections, same f32 score path,
-# exact-zero masked softmax weights), so greedy tokens match the
-# cached_generate oracle per sequence.
-
-def _slot_attention(mha, params, x, cache, pos):
-    """x: [S, 1, E], pos: [S] int32; returns ([S, 1, E], new_cache)."""
-    if not mha.causal:
-        raise NotImplementedError(
-            "cached decoding requires causal attention "
-            "(MultiHeadAttention(causal=False) found)")
-    S, _, E = x.shape
-    H, D = mha.num_heads, mha.head_dim
-    split = lambda y: y.reshape(S, 1, H, D).transpose(0, 2, 1, 3)
-    q, k, v = (split(mha._proj(params, x, n)) for n in "qkv")
-
-    def upd(c, u, p):  # c: [H, L, D], u: [H, 1, D], p: scalar
-        return jax.lax.dynamic_update_slice(c, u, (0, p, 0))
-
-    ck = jax.vmap(upd)(cache["k"], k.astype(cache["k"].dtype), pos)
-    cv = jax.vmap(upd)(cache["v"], v.astype(cache["v"].dtype), pos)
-    L = ck.shape[2]
-    scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
-                        ck.astype(jnp.float32)) / (D ** 0.5)
-    # per-slot causal horizon; positions past a slot's pos get EXACT
-    # zero softmax weight (exp(-inf)), so stale cache rows from a
-    # previous occupant of the slot contribute exactly nothing
-    mask = jnp.arange(L)[None, None, None, :] <= pos[:, None, None, None]
-    scores = jnp.where(mask, scores, -jnp.inf)
-    w = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bhql,bhld->bhqd", w, cv.astype(jnp.float32))
-    o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(S, 1, E)
-    return mha._proj(params, o, "o"), {"k": ck, "v": cv}
-
-
-def _slot_step(module, params, state, x, caches, slot, pos):
-    """models/decode._step with a per-slot position vector ``pos``."""
-    if isinstance(module, MultiHeadAttention):
-        y, caches[slot] = _slot_attention(module, params, x, caches[slot],
-                                          pos)
-        return y, slot + 1
-    if isinstance(module, PositionalEmbedding):
-        w = jnp.take(params["weight"], pos, axis=0)  # [S, E]
-        return x + w[:, None].astype(x.dtype), slot
-    if isinstance(module, Sequential):
-        for m, p, s in zip(module.modules, params, state):
-            x, slot = _slot_step(m, p, s, x, caches, slot, pos)
-        return x, slot
-    if isinstance(module, ConcatTable):
-        outs = []
-        for m, p, s in zip(module.modules, params, state):
-            o, slot = _slot_step(m, p, s, x, caches, slot, pos)
-            outs.append(o)
-        return outs, slot
-    if not isinstance(module, Container):
-        y, _ = module.apply(params, state, x, training=False, rng=None)
-        return y, slot
-    raise NotImplementedError(
-        f"cached decoding: unsupported container {type(module).__name__}")
 
 
 def _prompt_bucket(t0: int) -> int:
@@ -305,6 +247,10 @@ class DecodeEngine:
         self.seqs_done = 0
         self.seqs_failed = 0
         self.cache_grows = 0
+        # tokens each held expert took (None for a model that counts none)
+        # and choices that went to experts held elsewhere, over all calls
+        self._expert_tokens: Optional[np.ndarray] = None
+        self.expert_tokens_elsewhere = 0
         self._busy_s = 0.0
         # request stamps, summed (always on: two clock reads a request)
         self.admitted = 0
@@ -426,14 +372,8 @@ class DecodeEngine:
         return fields
 
     def _cache_avals(self, cache_len: int):
-        out = []
-        for mha in kv._mha_modules(self.model):
-            shape = (self.slots, mha.num_heads, cache_len, mha.head_dim)
-            aval = jax.ShapeDtypeStruct(
-                shape, self.cache_dtype,
-                sharding=kv._cache_sharding(self._mesh, shape))
-            out.append({"k": aval, "v": aval})
-        return tuple(out)
+        return kv.cache_avals(self.model, self.slots, cache_len,
+                              self.cache_dtype, self._mesh)
 
     def _step_exe(self, cache_len: int):
         """The decode-step executable for the (slots, cache_len) bucket:
@@ -448,10 +388,7 @@ class DecodeEngine:
         # program as ``jit_decode_step``
         @partial(jax.jit, donate_argnums=(2,))
         def decode_step(params, state, caches, tok, pos):
-            x = tok[:, None]          # [S, 1] token ids
-            caches = list(caches)
-            y, _ = _slot_step(model, params, state, x, caches, 0, pos)
-            return y[:, -1], tuple(caches)
+            return kv._slot_step(model, params, state, tok, caches, pos)
 
         ivec = jax.ShapeDtypeStruct((S,), jnp.int32)
         exe = aot_mod.get_or_compile(
@@ -471,8 +408,8 @@ class DecodeEngine:
         cache_len) bucket: one new sequence enters ONE slot in one pass
         (models/decode._prefill).  The padded bucket, cut to the cache
         where it is longer, goes through the model as [1, P, E]: every
-        weight is read once a prompt, each attention layer's k and v land
-        in the slot by one write, and past the last attention layer only
+        weight is read once a prompt, each stateful layer's leaves land
+        in the slot by one write each, and past the last such layer only
         the prompt's last real position goes on to the head.  Every prompt
         length in the bucket shares this compile (t0 is traced)."""
         memo = ("prefill", prompt_bucket, self.slots, cache_len)
@@ -527,33 +464,24 @@ class DecodeEngine:
             self._cache_len = want
             return
         if want > self._cache_len:
-            # grow to the next page: pad the length axis with zeros —
-            # masked positions carry exact-zero softmax weight, so the
-            # in-flight slots decode on unchanged
-            grown = []
-            for c in self._caches:
-                pad = {}
-                for n, arr in c.items():
-                    z = jnp.zeros(arr.shape[:2]
-                                  + (want - self._cache_len,)
-                                  + arr.shape[3:], arr.dtype)
-                    pad[n] = jnp.concatenate([arr, z], axis=2)
-                grown.append(pad)
-            self._caches = tuple(grown)
-            if self._mesh is not None:
-                self._caches = tuple(
-                    {n: jax.device_put(arr, kv._cache_sharding(
-                        self._mesh, arr.shape))
-                     for n, arr in c.items()} for c in self._caches)
+            # grow to the next page: each leaf padded with zeros along its
+            # own length axis — masked positions carry exact-zero softmax
+            # weight, so the in-flight slots decode on unchanged
+            self._caches = kv.grow_cache(self.model, self._caches, want,
+                                         self._mesh)
             self._cache_len = want
             self.cache_grows += 1
 
     def cache_bytes_per_slot(self) -> int:
+        """Bytes of decode state one slot holds at the present cache
+        length, from the layers' declarations (nothing is read off the
+        device)."""
         if self._caches is None:
             return 0
-        total = sum(int(arr.nbytes) for c in self._caches
-                    for arr in c.values())
-        return total // self.slots
+        item = jnp.dtype(self.cache_dtype).itemsize
+        return sum(int(np.prod(a.shape)) * item
+                   for c in self._cache_avals(self._cache_len)
+                   for a in c.values()) // self.slots
 
     # -- the persistent step loop ---------------------------------------
 
@@ -619,6 +547,43 @@ class DecodeEngine:
                         help="time to first token (submit to the first "
                              "sampled token), seconds")
 
+    def _count_experts(self, counts) -> None:
+        """Fold one call's expert token counts (held experts, then the
+        choices that went elsewhere) into the running ones."""
+        if counts is None:
+            return
+        counts = np.asarray(counts).astype(np.int64)
+        if self._expert_tokens is None:
+            self._expert_tokens = np.zeros(len(counts) - 1, np.int64)
+        self._expert_tokens += counts[:-1]
+        self.expert_tokens_elsewhere += int(counts[-1])
+        reg = metrics_export._REGISTRY
+        if reg is not None:
+            help_ = "routed expert choices of live tokens, by where the " \
+                    "expert is held"
+            reg.counter_inc("bigdl_decode_expert_tokens_total",
+                            float(counts[:-1].sum()), help=help_,
+                            held="here")
+            reg.counter_inc("bigdl_decode_expert_tokens_total",
+                            float(counts[-1]), help=help_, held="elsewhere")
+            reg.gauge_set("bigdl_decode_expert_imbalance",
+                          self._expert_imbalance(),
+                          help="busiest held expert's tokens over the "
+                               "held experts' mean, since start")
+
+    def _expert_imbalance(self) -> float:
+        t = self._expert_tokens
+        return float(t.max() / max(t.mean(), 1e-9)) if t is not None else 0.0
+
+    def _expert_stats(self) -> dict:
+        """The expert counters since start ({} for a model that counts
+        none): for ``stats()`` and the ``serve.decode`` track alike."""
+        if self._expert_tokens is None:
+            return {}
+        return {"expert_tokens": int(self._expert_tokens.sum()),
+                "expert_tokens_elsewhere": self.expert_tokens_elsewhere,
+                "expert_tokens_max": int(self._expert_tokens.max())}
+
     def _sample(self, seq: _Seq, logits_row: np.ndarray) -> int:
         tok, seq.rng = sample_next(logits_row[None], seq.temperature,
                                    seq.top_k, seq.rng)
@@ -670,13 +635,14 @@ class DecodeEngine:
             toks[:t0] = prompt
             exe = self._prefill_exe(pb, self._cache_len)
             try:
-                logits, self._caches = exe(
+                logits, self._caches, counts = exe(
                     self._params, self._state, self._caches,
                     jnp.asarray(toks), jnp.int32(s), jnp.int32(t0))
             except Exception as e:  # noqa: BLE001
                 self._fail_slot(s, SlotFault(f"decode: prefill failed in "
                                              f"slot {s}: {e!r}"))
                 return
+            self._count_experts(counts)
             self.prefill_steps += 1
             self.prompt_tokens += t0
             self.prefill_positions += len(toks)
@@ -729,16 +695,17 @@ class DecodeEngine:
             with telemetry.span("decode.step", cat="serve",
                                 active=len(active)):
                 tok = np.zeros(self.slots, np.int32)
-                pos = np.zeros(self.slots, np.int32)
+                pos = np.full(self.slots, -1, np.int32)   # -1: an idle row
                 for s in active:
                     seq = self._slots[s]
                     tok[s] = seq.buf[seq.pos]
                     pos[s] = seq.pos
                 exe = self._step_exe(self._cache_len)
-                logits, self._caches = exe(self._params, self._state,
-                                           self._caches, jnp.asarray(tok),
-                                           jnp.asarray(pos))
+                logits, self._caches, counts = exe(
+                    self._params, self._state, self._caches,
+                    jnp.asarray(tok), jnp.asarray(pos))
                 logits = np.asarray(logits)
+                self._count_experts(counts)
             self.decode_steps += 1
             with telemetry.span("decode.sample", cat="serve",
                                 active=len(active)):
@@ -757,7 +724,7 @@ class DecodeEngine:
         n_active = sum(1 for s in self._slots if s is not None)
         steps = self.prefill_steps + self.decode_steps
         telemetry.counter(
-            "serve.decode",
+            "serve.decode", **self._expert_stats(),
             tokens_per_s=self.tokens_out / max(self._busy_s, 1e-9),
             fill=n_active / self.slots,
             prefill_frac=self.prefill_steps / max(steps, 1),
@@ -799,6 +766,7 @@ class DecodeEngine:
                                            "lowers", "compiles",
                                            "corrupt")},
         }
+        out.update(self._expert_stats())
         cards = hlostats.ledger()
         if cards:
             out["compile_cards"] = cards

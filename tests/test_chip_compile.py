@@ -174,3 +174,42 @@ def test_fused_conv_bn_forward_and_grad_compile(one_chip, mkn):
     assert "tpu_custom_call" in _compile(fwd, x, w, g, g)
     assert "tpu_custom_call" in _compile(
         jax.grad(loss, argnums=(0, 1, 2, 3)), x, w, g, g)
+
+
+# ------------------------------------------- the latent-cache decode step
+
+def test_latent_decode_step_compiles_at_the_cells_sizes(one_chip):
+    """`dsv2.decode`'s step at its real sizes (64 slots, a 4,608-long latent
+    cache, 40 held experts a layer): it fits one chip beside its 9.3 GB of
+    weights, the grouped expert product is the compiler's own ragged dot,
+    and each layer's cache is written in place (a scatter under the
+    donation), not copied."""
+    from bigdl_tpu.common import get_policy, set_policy
+    from bigdl_tpu.models import decode as kv
+    from benchmark import harness
+    cell = harness.Cell("dsv2.decode")
+    cm, cfg, tr = cell.cfg_mod, cell.cfg, cell.traffic
+    prior = get_policy()
+    try:
+        cm.set_policy(cfg)
+        model = cm.build_model(cfg)
+        on = lambda t: jax.tree.map(
+            lambda a: _aval(a.shape, a.dtype, one_chip), t)
+        params, state = on(jax.eval_shape(model.init, jax.random.key(0)))
+        slots, length = tr["slots"], tr["max_len"]
+        caches = on(kv.cache_avals(model, slots, length, jnp.bfloat16))
+        ivec = _aval((slots,), jnp.int32, one_chip)
+        compiled = jax.jit(
+            lambda p, s, c, tok, pos: kv._slot_step(model, p, s, tok, c, pos),
+            donate_argnums=(2,)).lower(params, state, caches, ivec,
+                                       ivec).compile()
+    finally:
+        set_policy(prior)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("ragged-dot") >= 4 * 3          # 4 layers x 3 products
+    cache_bytes = sum(int(np.prod(a.shape)) * 2 for c in caches
+                      for a in c.values())
+    assert mem.alias_size_in_bytes >= cache_bytes     # donated, in place
+    assert mem.temp_size_in_bytes < 1e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+    assert text.count(" scatter(") >= 2 * cfg["num_hidden_layers"]

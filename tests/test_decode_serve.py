@@ -224,7 +224,7 @@ def test_prefill_logits_and_cache_match_the_per_position_oracle(lm, t0):
     eng = DecodeEngine(lm, slots=3, page=16, cache_dtype=np.float32)
     toks = np.zeros(8, np.int32)
     toks[:t0] = prompt
-    logits, caches = eng._prefill_exe(8, 16)(
+    logits, caches, _counts = eng._prefill_exe(8, 16)(
         eng._params, eng._state, eng._fresh_caches(16), toks,
         np.int32(1), np.int32(t0))
     assert logits.shape == (64,)
