@@ -8,8 +8,14 @@ through VMEM, keeping running (max, sum, accumulator) statistics so the full
 [Tq, Tk] score matrix never materializes in HBM.
 
 On TPU the kernel tiles onto the MXU with (block_q x d) @ (d x block_k)
-matmuls in f32 accumulation; on CPU (tests / virtual meshes) we use the exact
-jnp reference instead — same math, XLA-fused.
+matmuls whose operands keep the dtype they arrive in: bfloat16 q, k, v go to
+the MXU as bfloat16 (one pass; a product of two bfloat16 numbers is exact in
+float32), float32 q, k, v as float32 at `Precision.HIGHEST`.  Both products
+accumulate in float32, the softmax statistics are float32, and the
+probabilities are rounded to v's dtype for `p @ v` — the arithmetic of
+`mha_reference`.  The blocks are chosen from the shape (`_choose_blocks`).
+On CPU (tests / virtual meshes) we use the exact jnp reference instead —
+same math, XLA-fused.
 """
 
 from __future__ import annotations
@@ -76,13 +82,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)            # [bq, d]
-        k = k_ref[0].astype(jnp.float32)            # [bk, d]
-        v = v_ref[0].astype(jnp.float32)            # [bk, d]
+        # the operands go to the MXU in the dtype they arrive in: bfloat16
+        # in one pass (the products are exact in float32, so q @ k^T is the
+        # sum the float32 cast would give), float32 at HIGHEST as before
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]       # [bq, d], [bk, d] x 2
+        precision = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+                     else jax.lax.Precision.DEFAULT)
+        # the scale multiplies the float32 scores: folded into q it would
+        # round q
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST) * sm_scale   # [bq, bk]
+            precision=precision) * sm_scale                    # [bq, bk]
         kj = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         if causal:
@@ -98,11 +109,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         # exp(-inf - -inf) would be NaN; fully-masked blocks give m_new=-inf
         alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_new))
         p = jnp.where(s == _NEG_INF, 0.0, jnp.exp(s - m_new))
+        # l sums the float32 p; only the MXU's operand is rounded to v's
+        # dtype, as mha_reference rounds its probabilities
         l_new = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST)
+            precision=precision)
         m_scr[:] = m_new
         l_scr[:] = l_new
 
@@ -113,15 +126,79 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
 
 
+# Mosaic's scoped VMEM is 16 MiB by default (v5e; later chips have more) and
+# the kernel asks for no more: a raised limit made the same blocks slower
+# (PERF.md section 6, PR 28).  `_vmem_bytes` is kept under seven eighths.
+_VMEM_BUDGET = 14 * 2 ** 20
+_LANES = 128
+_BLOCK_CAP = 1024          # both blocks: where the sweep's optimum lies
+_BWD_BLOCK_Q = 128
+
+
+def _round_up(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
+
+
+def _vmem_bytes(block_q: int, block_k: int, D: int, dtype) -> int:
+    """VMEM one grid step of `_flash_kernel` is reckoned to hold: the q, o,
+    k, v tiles (double-buffered by the pipeline, the last dim padded to the
+    128 lanes), the three float32 scratch buffers, and the score tile twice
+    in float32 (s, p) and once in the operands' dtype (p as the MXU takes
+    it).  An upper bound: the smallest limit Mosaic compiled eleven probes
+    under (blocks 256-2,048, D 64-256, both dtypes) was 0.17-0.90 of it."""
+    size = jnp.dtype(dtype).itemsize
+    d = _round_up(D, _LANES)
+    tiles = 2 * 2 * (block_q + block_k) * d * size
+    scratch = block_q * (2 * _LANES + d) * 4
+    scores = block_q * _round_up(block_k, _LANES) * (2 * 4 + size)
+    return tiles + scratch + scores
+
+
+def _choose_blocks(Tq: int, Tk: int, D: int, dtype) -> tuple[int, int]:
+    """(block_q, block_k) for a `[.., Tq, D] x [.., Tk, D]` call.
+
+    Large blocks keep the MXU fed (few, full grid steps), small ones skip
+    more of a causal triangle: on a v5e the larger block won at every
+    length and head size swept in bfloat16, up to the 1,024 x 1,024 the
+    default scoped VMEM takes, and a wide key block beat a tall query block
+    of the same area (PERF.md section 6, PR 28).  A length splits into the
+    fewest blocks under the cap, of even size, rounded up to the tiling
+    (query rows to the operands' sublane packing, keys to the 128 lanes of
+    the score tile), so padding is under one tile a block and a short
+    sequence is one block.  Whatever the shape, the blocks are halved,
+    the query block first, until `_vmem_bytes` fits the budget, so an
+    unseen head size or dtype still compiles."""
+    rows = 32 // jnp.dtype(dtype).itemsize     # 8 float32, 16 bfloat16
+
+    def fit(T, cap, align):
+        n_blocks = -(-T // cap)
+        return _round_up(-(-T // n_blocks), align)
+
+    block_q = fit(Tq, _BLOCK_CAP, rows)
+    block_k = fit(Tk, _BLOCK_CAP, _LANES)
+    while _vmem_bytes(block_q, block_k, D, dtype) > _VMEM_BUDGET:
+        if block_q > rows and (block_q >= block_k or block_k == _LANES):
+            block_q = _round_up(block_q // 2, rows)
+        elif block_k > _LANES:
+            block_k = _round_up(block_k // 2, _LANES)
+        else:
+            break       # one tile of each: nothing smaller exists
+    return block_q, block_k
+
+
 def _flash_pallas(q, k, v, *, causal: bool, sm_scale: float,
-                  block_q: int, block_k: int, interpret: bool):
+                  block_q: Optional[int], block_k: Optional[int],
+                  interpret: bool):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    block_q = min(block_q, Tq)
-    block_k = min(block_k, Tk)
+    # an explicit block wins (cut to the sequence); one left to the rule is
+    # a whole tile and may be longer than the sequence, which is padded
+    chosen = _choose_blocks(Tq, Tk, D, q.dtype)
+    block_q = chosen[0] if block_q is None else min(block_q, Tq)
+    block_k = chosen[1] if block_k is None else min(block_k, Tk)
 
     # pad sequence lengths up to block multiples; padded keys are masked
     # inside the kernel, padded query rows are sliced off the output
@@ -210,8 +287,10 @@ def _flash_bwd_chunked(q, k, v, g, *, causal: bool, sm_scale: float,
     vf = v.astype(jnp.float32)
     col = jnp.arange(Tk)
 
-    hi = jax.lax.Precision.HIGHEST  # match the forward: MXU default
-    # precision would silently degrade f32 gradients to ~bf16 accuracy
+    hi = jax.lax.Precision.HIGHEST  # float32 whatever the inputs' dtype:
+    # for float32 inputs this is the forward's arithmetic; for bfloat16
+    # ones the forward rounds p to bfloat16 for p @ v and this does not, so
+    # it is the gradient of the same function to within that rounding
 
     def body(carry, idx_qc_gc):
         dk, dv = carry
@@ -249,8 +328,12 @@ def _flash_bwd_chunked(q, k, v, g, *, causal: bool, sm_scale: float,
 
 def _flash_diff_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
     q, k, v = res
+    # the scan's chunk is the caller's block_q, or the 128 rows it always
+    # was: it holds float32 [B, H, chunk, Tk] score blocks, which the
+    # forward's chosen block (up to 1,024 rows) would make eight times
+    # the size
     return _flash_bwd_chunked(q, k, v, g, causal=causal, sm_scale=sm_scale,
-                              block_q=block_q)
+                              block_q=block_q or _BWD_BLOCK_Q)
 
 
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
@@ -258,15 +341,23 @@ _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
 
 def flash_attention(q, k, v, *, causal: bool = False,
                     sm_scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     use_pallas: Optional[bool] = None,
                     interpret: bool = False):
     """Blockwise (flash) attention.  q,k,v: [B, H, T, D] -> [B, H, Tq, D].
 
+    block_q / block_k: None = chosen from (Tq, Tk, D, dtype) by
+    `_choose_blocks`; an explicit value wins.  The operands' dtype decides
+    the kernel's arithmetic (module docstring): nothing else selects it.
+
     use_pallas: None = auto (Pallas on TPU, jnp reference elsewhere;
-    BIGDL_TPU_ATTN_IMPL=jnp|pallas overrides — the kernel has executed on
-    a v5e inside the LM train step (chip_smoke.py), but has not been raced
-    against XLA's fusion there, so the default stays overridable).
+    BIGDL_TPU_ATTN_IMPL=jnp|pallas overrides — forward alone at
+    [8, 16, 1024, 64] bfloat16 on a v5e the kernel takes 0.60 ms and XLA's
+    fusion of the jnp path 4.04 (PERF.md section 6, PR 28), but the two
+    have not been raced inside a train step: there the jnp path keeps a
+    float32 [B, H, T, T] score tensor a layer for the backward, which
+    `gpt2m.train` has no room for (ROADMAP Design 1)).
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])

@@ -63,6 +63,10 @@ _ATTN_SHAPES = [
     pytest.param((8, 8, 2048, 64), jnp.bfloat16, id="8x8x2048x64-bf16"),
     pytest.param((4, 8, 512, 64), jnp.float32, id="4x8x512x64-f32"),
     pytest.param((2, 16, 4096, 128), jnp.bfloat16, id="2x16x4096x128-bf16"),
+    # the shape `gpt2m.train` runs (benchmark/configs/gpt2_medium.json)
+    pytest.param((8, 16, 1024, 64), jnp.bfloat16, id="8x16x1024x64-bf16"),
+    # a length the block rule pads to whole tiles
+    pytest.param((2, 4, 17, 64), jnp.bfloat16, id="2x4x17x64-bf16"),
 ]
 
 

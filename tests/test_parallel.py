@@ -89,6 +89,140 @@ class TestFlashAttention:
         ref = mha_reference(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
 
+    # -- bfloat16 operands: the MXU's own path -------------------------
+    #
+    # With bfloat16 q, k, v the kernel multiplies bfloat16 operands into
+    # float32 and rounds the probabilities to bfloat16 for p @ v, as
+    # mha_reference does.  Both compute the same float32 scores (bfloat16
+    # products are exact in float32).  They differ in where p is rounded
+    # (the kernel rounds exp(s - running max), the reference the normalised
+    # p): each rounding is at most 2^-8 relative (bfloat16 keeps 8 bits,
+    # so its unit roundoff is 2^-8), so each side's sum over keys is off by
+    # at most 2^-8 max|v|, and each side's result is rounded once more to
+    # bfloat16, at most 2^-8 max|v| again: four roundings, 2^-6 max|v|,
+    # bound the difference.
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("T,blocks", [(32, (16, 16)), (40, (16, 16)),
+                                          (40, (None, None))])
+    def test_bf16_matches_reference(self, causal, T, blocks):
+        q, k, v = (x.astype(jnp.bfloat16)
+                   for x in _qkv(B=2, H=2, T=T, D=16, seed=4))
+        out = flash_attention(q, k, v, causal=causal, use_pallas=True,
+                              interpret=True, block_q=blocks[0],
+                              block_k=blocks[1])
+        assert out.dtype == jnp.bfloat16
+        ref = mha_reference(q, k, v, causal=causal)
+        tol = 2.0 ** -6 * float(jnp.max(jnp.abs(v.astype(jnp.float32))))
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   atol=tol, rtol=0)
+
+    def test_bf16_scores_are_the_float32_cast_scores(self):
+        """With one key block and v = identity columns the output IS p:
+        bfloat16 operands must give the probabilities that the float32
+        cast gave, to float32's own rounding (the first cast bought
+        nothing)."""
+        T = D = 16
+        q, k, _ = (x.astype(jnp.bfloat16)
+                   for x in _qkv(B=1, H=2, T=T, D=D, seed=5))
+        eye = jnp.broadcast_to(jnp.eye(T, D, dtype=jnp.float32),
+                               (1, 2, T, D))
+        kw = dict(use_pallas=True, interpret=True, block_q=16, block_k=16)
+        lo = flash_attention(q.astype(jnp.float32), k.astype(jnp.float32),
+                             eye, **kw)
+        hi = flash_attention(q, k, eye.astype(jnp.bfloat16), **kw)
+        # hi's p and result are rounded to bfloat16: 2^-8 each, of p <= 1
+        np.testing.assert_allclose(np.asarray(hi, np.float32),
+                                   np.asarray(lo), atol=2.0 ** -7, rtol=0)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_bf16_grad_through_pallas_path(self, causal):
+        """The custom VJP with bfloat16 inputs.  The backward recomputes p
+        in float32 from the bfloat16 q, k, v, so for a loss linear in the
+        output it is the float32 gradient of exact attention on those
+        inputs, rounded once to bfloat16 (2^-8 relative, elementwise).
+        `jax.grad(mha_reference)` on bfloat16 inputs rounds p, dP and each
+        result to bfloat16 on the way (2^-8 each, and `dP - rowsum(dP p)`
+        cancels: taken as at most four times), so against it the gap is
+        held norm-wise at 4 x 4 x 2^-8 = 2^-4; a missing term or a wrong
+        mask reads of order 1."""
+        q, k, v = (x.astype(jnp.bfloat16)
+                   for x in _qkv(B=2, H=2, T=21, D=8, seed=6))
+        # weights that bfloat16 holds exactly: the output's cotangent is
+        # cast to the output's dtype on its way into the backward
+        w = jax.random.normal(jax.random.key(7), q.shape, jnp.float32)
+        w = w.astype(jnp.bfloat16).astype(jnp.float32)
+
+        def f_pallas(q, k, v):
+            out = flash_attention(q, k, v, causal=causal, use_pallas=True,
+                                  interpret=True, block_q=8, block_k=8)
+            return jnp.sum(out.astype(jnp.float32) * w)
+
+        def f_ref(q, k, v):
+            out = mha_reference(q, k, v, causal=causal)
+            return jnp.sum(out.astype(jnp.float32) * w)
+
+        gp = jax.grad(f_pallas, argnums=(0, 1, 2))(q, k, v)
+        g16 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+        g32 = jax.grad(f_ref, argnums=(0, 1, 2))(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+        for a, b16, b32 in zip(gp, g16, g32):
+            assert a.dtype == jnp.bfloat16
+            a = np.asarray(a, np.float32)
+            np.testing.assert_allclose(a, np.asarray(b32), rtol=2.0 ** -8,
+                                       atol=1e-6)
+            b16 = np.asarray(b16, np.float32)
+            assert (np.linalg.norm(a - b16)
+                    <= 2.0 ** -4 * np.linalg.norm(b16))
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    @pytest.mark.parametrize("Tk", [1, 17, 1024, 4096, 131072])
+    @pytest.mark.parametrize("Tq", [1, 17, 1024, 4096, 131072])
+    def test_block_rule(self, Tq, Tk, dtype):
+        """Blocks chosen from the shape are whole tiles (so Mosaic takes
+        them), pad a length by under one tile a block, never exceed the
+        sweep's cap, and are reckoned to fit VMEM."""
+        from bigdl_tpu.ops import attention as att
+
+        rows = 32 // jnp.dtype(dtype).itemsize
+        for D in (64, 128, 256):
+            block_q, block_k = att._choose_blocks(Tq, Tk, D, dtype)
+            assert block_q % rows == 0 and block_k % 128 == 0
+            assert 0 < block_q <= att._BLOCK_CAP
+            assert 0 < block_k <= att._BLOCK_CAP
+            for T, block, tile in ((Tq, block_q, rows), (Tk, block_k, 128)):
+                n_blocks = -(-T // block)         # as _flash_pallas pads
+                assert n_blocks * block - T < n_blocks * tile
+            assert att._vmem_bytes(block_q, block_k, D, dtype) \
+                <= att._VMEM_BUDGET
+
+    def test_block_rule_shrinks_to_the_budget(self):
+        """A head size nobody swept still gets blocks that fit."""
+        from bigdl_tpu.ops import attention as att
+
+        for D, dtype in ((2048, jnp.float32), (8192, jnp.bfloat16)):
+            bq, bk = att._choose_blocks(4096, 4096, D, dtype)
+            assert bq * bk < att._BLOCK_CAP ** 2
+            assert att._vmem_bytes(bq, bk, D, dtype) <= att._VMEM_BUDGET
+
+    def test_grad_with_default_blocks(self):
+        """With the blocks left to the rule the backward scan keeps its
+        own chunk: the gradients match those of explicit blocks."""
+        q, k, v = _qkv(B=1, H=2, T=40, D=8, seed=8)
+
+        def f(q, k, v, **kw):
+            out = flash_attention(q, k, v, causal=True, use_pallas=True,
+                                  interpret=True, **kw)
+            return jnp.sum(jnp.sin(out))
+
+        g0 = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+        g1 = jax.grad(lambda *a: f(*a, block_q=16, block_k=16),
+                      argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g0, g1):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=3e-5, rtol=3e-5)
+
 
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [False, True])
