@@ -351,31 +351,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
     `_choose_blocks`; an explicit value wins.  The operands' dtype decides
     the kernel's arithmetic (module docstring): nothing else selects it.
 
-    use_pallas: None = auto (Pallas on TPU, jnp reference elsewhere;
-    BIGDL_TPU_ATTN_IMPL=jnp|pallas overrides — forward alone at
-    [8, 16, 1024, 64] bfloat16 on a v5e the kernel takes 0.60 ms and XLA's
-    fusion of the jnp path 4.04 (PERF.md section 6, PR 28), but the two
-    have not been raced inside a train step: there the jnp path keeps a
-    float32 [B, H, T, T] score tensor a layer for the backward, which
-    `gpt2m.train` has no room for (ROADMAP Design 1)).
+    use_pallas: None = the backend decides: the Pallas kernel on a TPU
+    (the only path `gpt2m.train` can hold there: the jnp path keeps a
+    float32 [B, H, T, T] score tensor a layer for the backward) and
+    `mha_reference` elsewhere (the only one a CPU can take).  An explicit
+    value wins: tests and `ring_attention` pass one.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if use_pallas is None:
-        from ..utils import config
-        impl = config.get_str("ATTN_IMPL", "")
-        if impl and impl not in ("jnp", "pallas"):
-            # a typo must not silently measure the wrong path under a
-            # forced label (same rule as bn_experiment's unknown variants)
-            raise ValueError(
-                f"BIGDL_TPU_ATTN_IMPL={impl!r}: expected 'jnp' or 'pallas'")
-        if impl:
-            use_pallas = impl == "pallas"
-        else:
-            # which side ran is proven from the compiled program, never
-            # from this line: chip_smoke.py looks for `tpu_custom_call` in
-            # the LM step's text
-            use_pallas = jax.default_backend() == "tpu"
+        # which side ran is proven from the compiled program, never from
+        # this line: chip_smoke.py looks for `tpu_custom_call` in the LM
+        # step's text
+        use_pallas = jax.default_backend() == "tpu"
     if not use_pallas:
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
     return _flash_diff(q, k, v, causal, sm_scale, block_q, block_k,

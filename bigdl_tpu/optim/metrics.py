@@ -45,7 +45,7 @@ class Metrics:
 
     def snapshot(self) -> dict:
         """All counters as ``{name: {mean, count, total}}`` — ONE exportable
-        source for the epoch log, bench records, and telemetry consumers
+        source for the epoch log, tool records, and telemetry consumers
         (replaces the ad-hoc per-caller counter paths)."""
         return {k: {"mean": self.mean(k), "count": self._counts[k],
                     "total": self._sums[k]} for k in sorted(self._sums)}

@@ -325,25 +325,14 @@ class Optimizer:
         self.metrics = Metrics()
         self._compiled = None
         self._mesh = None
-        # per-step MFU counter (armed lazily at the first step, only when
-        # telemetry is tracing): flops/step from the analytic jaxpr count,
-        # denominator = device peak * mesh size (utils/flops.py)
-        self._step_flops = None
-        self._mfu_denom = None
-        # per-step collective-cost counter (armed with mfu): the measured
-        # standalone wall time of the gradient wire's all-reduce
-        # (parallel/wire.measure_collective_seconds) — traces show it next
-        # to step_s so overlap (or its absence) is visible
-        self._collective_s = None
-        # knobs the compiled step was built with (_build_step fills it;
-        # bench embeds it in the per-config record)
+        # knobs the compiled step was built with (_build_step fills it)
         self._step_knobs = {}
         # the step's compile-card self-description (knobs + wire-bucket +
         # fused-buffer counts; _build_step fills it, utils/hlostats reads)
         self._card_extra = {}
         # (pipe_axis_size, GPipeSequential) when the model pipelines over
         # a pipe>1 mesh (_build_step fills it) — arms the per-step
-        # train.pipe_bubble_fraction counter beside mfu; _aot_extra adds
+        # train.pipe_bubble_fraction counter; _aot_extra adds
         # the schedule knobs to the AOT cache fingerprint
         self._pipe_info = None
         self._aot_extra = None
@@ -944,19 +933,14 @@ class Optimizer:
             with mesh:
                 return jitted.lower(*args, **kw)
 
-        step_in_mesh.lower = lower_in_mesh  # bench/dryrun introspection
-        # the UNJITTED step for analytic-FLOPs tracing: make_jaxpr on the
-        # jitted wrapper would reuse pjit's cached trace, freezing whatever
-        # env-dependent lowering (e.g. the tiny-channel conv pad) was active
-        # at compile time
-        step_in_mesh.raw = step
+        step_in_mesh.lower = lower_in_mesh  # tools and dry runs compile it
         return step_in_mesh, param_sh, data_sh
 
     def _refresh_pipe_effective(self) -> None:
         """Fold the pipeline's EFFECTIVE microbatch count (the knob
         clamped to divide the local batch — set by the traced apply)
-        into step_knobs / the compile card, so bench records and cards
-        agree with what the schedule actually baked in (the
+        into step_knobs / the compile card, so the card
+        agrees with what the schedule actually baked in (the
         silent-clamp satellite, ISSUE 13)."""
         if self._pipe_info is None:
             return
@@ -991,57 +975,6 @@ class Optimizer:
 
         return fwd_in_mesh
 
-    def _arm_mfu(self, step_fn, example_args, mesh) -> None:
-        """One-shot arming of the per-step ``mfu`` counter (called only
-        when telemetry is tracing, so the extra trace costs nothing on
-        untraced runs): analytic FLOPs of one step from the UNJITTED
-        function (`.raw`, same source bench._step_flops uses) over the
-        device peak * mesh size.  Any failure disarms (denominator 0) —
-        the counter is diagnostics, never a crash."""
-        from ..utils import flops as flops_mod
-        self._mfu_denom = 0.0
-        # outside the guard below: a TPU whose kind has no row in the peaks
-        # table is an error, not a counter quietly disarmed
-        peak, src = flops_mod.device_peak_flops(jax.devices()[0])
-        try:
-            fn = getattr(step_fn, "raw", None)
-            if fn is None:
-                return
-            # fresh lambda: make_jaxpr caches by function identity
-            self._step_flops = flops_mod.jaxpr_flops(
-                jax.make_jaxpr(lambda *a: fn(*a))(*example_args))
-            if self._step_flops and peak > 0:
-                self._mfu_denom = peak * mesh.size
-                logger.info(
-                    "mfu counter armed: %.3e flops/step, peak %.3e x %d "
-                    "devices (%s)", self._step_flops, peak, mesh.size, src)
-        except Exception as e:  # noqa: BLE001 — diagnostics only
-            logger.info("mfu counter disarmed: %s: %s",
-                        type(e).__name__, e)
-
-    def _arm_collective(self, mesh) -> None:
-        """One-shot arming of the ``train.collective_s`` counter (with
-        the mfu arm, only when telemetry is tracing): the measured
-        standalone wall cost of the gradient wire's all-reduce over the
-        data axis, at the current wire dtype and bucket layout.  0.0 on a
-        1-device axis; any failure disarms — diagnostics, never a
-        crash."""
-        from ..parallel import wire as wire_mod
-        self._collective_s = 0.0
-        try:
-            self._collective_s = wire_mod.measure_collective_seconds(
-                mesh, self.model.params, get_policy().wire_dtype,
-                axis=self.strategy.batch_axes(mesh))
-            if self._collective_s:
-                logger.info("collective counter armed: %.6fs standalone "
-                            "gradient all-reduce (wire=%s, bucket_mb=%s)",
-                            self._collective_s,
-                            get_policy().wire_dtype,
-                            self._step_knobs.get("wire_bucket_mb"))
-        except Exception as e:  # noqa: BLE001 — diagnostics only
-            logger.info("collective counter disarmed: %s: %s",
-                        type(e).__name__, e)
-
     # ------------------------------------------------------------------
     # the driver loop (reference: DistriOptimizer.scala:141-381)
     # ------------------------------------------------------------------
@@ -1057,11 +990,6 @@ class Optimizer:
         # _optimize_impl keeps it stable across retry re-entries only)
         self._initial_blob = None
         self._preempted = False
-        # re-arm the mfu counter per run: batch shapes / mesh / tracing
-        # state may all have changed since the last optimize()
-        self._step_flops = None
-        self._mfu_denom = None
-        self._collective_s = None
         old_handlers = {}
         # armed from rank-consistent inputs ONLY (checkpoint_path and the
         # env knob must agree across ranks) — NOT from whether the signal
@@ -1103,7 +1031,7 @@ class Optimizer:
             supervision.set_active(self._sup)
         # run telemetry (BIGDL_TPU_TRACE): env-gated span tracer, one
         # trace.<rank>.json per process.  Only the call that CREATED the
-        # tracer closes it — a bench/tool that armed tracing around this
+        # tracer closes it — a tool that armed tracing around this
         # optimize() keeps ownership.  close() flushes, so the finally
         # below is also the flush-on-crash path for any raising exit.
         # per-LOGICAL-rank trace file: under the simulated-multi-host
@@ -1772,14 +1700,6 @@ class Optimizer:
                             _put_batch((batch.get_input(),
                                         batch.get_target()), data_sh)
                         rng = next_rng_key()
-                        if self._mfu_denom is None and telemetry.enabled():
-                            # arm the per-step mfu counter BEFORE the first
-                            # step consumes (donates) these params
-                            self._arm_mfu(
-                                step_fn, (params, net_state, opt_state, inp,
-                                          tgt, jnp.float32(lr), rng), mesh)
-                        if self._collective_s is None and telemetry.enabled():
-                            self._arm_collective(mesh)
                     neval = state["neval"]
                     with telemetry.span("dispatch", neval=neval):
                         params, net_state, opt_state, loss = step_fn(
@@ -1833,25 +1753,6 @@ class Optimizer:
                             "data_wait_s": data_wait, "step_s": step_dur,
                             "records_per_sec": n / max(step_dur, 1e-9),
                             "prefetch_queue_depth": float(qdepth or 0)}
-                        if self._mfu_denom:
-                            # steady-state host step wall ~= device step time
-                            # (the next dispatch blocks on this step's donated
-                            # buffers), so flops/wall/peak tracks true MFU
-                            # except on the compile step, which shows as an
-                            # honest dip
-                            counters["mfu"] = (
-                                self._step_flops / max(step_dur, 1e-9)
-                                / self._mfu_denom)
-                            counters["model_flops_per_step"] = self._step_flops
-                        if self._collective_s is not None:
-                            # standalone (unoverlapped) wire cost beside the
-                            # step wall: when the scheduler hides the
-                            # collective, step_s stays ~compute while
-                            # collective_fraction shows what WOULD have been
-                            # added serialized
-                            counters["collective_s"] = self._collective_s
-                            counters["collective_fraction"] = min(
-                                1.0, self._collective_s / max(step_dur, 1e-9))
                         if self._pipe_info is not None:
                             # the idle fraction of the schedule the step
                             # actually baked in: (n-1)/(m+n-1) under gpipe, the

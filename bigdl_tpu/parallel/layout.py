@@ -201,9 +201,8 @@ class MeshLayout:
     @classmethod
     def parse(cls, text: str) -> "MeshLayout":
         """'2,2,1' (data,fsdp,tp) or '1,1,1,2,1' (data,fsdp,tp,pipe,
-        expert) -> MeshLayout — the env/CLI spelling (bench.py
-        BIGDL_TPU_BENCH_LAYOUT, tools/shard_smoke.py,
-        tools/pipeline_smoke.py).  3-tuples stay valid: absent axes
+        expert) -> MeshLayout — the string spelling
+        (`TopologyRouter(layout=...)`).  3-tuples stay valid: absent axes
         default to 1."""
         parts = [int(p) for p in str(text).replace("x", ",").split(",")]
         if len(parts) not in (3, 5):

@@ -1,4 +1,4 @@
-"""Bucketed bf16 gradient wire + the collective-cost probe.
+"""Bucketed bf16 gradient wire.
 
 The reference ships gradients between nodes as `FP16CompressedTensor`
 **blocks** (parameters/AllReduceParameter.scala: the flat gradient is cut
@@ -15,36 +15,22 @@ to the wire dtype, concatenated into 1-D buffers of at most
 round-trip to f32.  The cast is elementwise and concatenate/slice move
 values verbatim, so the result is **bit-identical** to the per-leaf path —
 only the program XLA schedules changes: a handful of bucket-sized converts
-whose reductions the latency-hiding scheduler
-(`utils/platform.enable_overlap_flags`) can issue while the backward tail
-is still computing.  ``bucket_mb <= 0`` (the default) keeps the per-leaf
+whose reductions a latency-hiding scheduler can issue while the backward
+tail is still computing.  ``bucket_mb <= 0`` (the default) keeps the per-leaf
 path byte-for-byte.
-
-`measure_collective_seconds` is the telemetry side: a standalone timed
-all-reduce of the same wire bytes over the mesh's data axis.  The train
-loop arms it once per run (like the `mfu` counter) and emits it per step
-as ``train.collective_s`` — overlap working shows as
-``collective_s / step_s`` (the `collective_fraction`) being "free" (step
-time ~= compute time despite a visible collective cost); overlap broken
-shows step time carrying the full collective on top.
 """
 
 from __future__ import annotations
 
-import logging
-import time
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..utils import config as _config
 
-logger = logging.getLogger("bigdl_tpu")
-
 __all__ = ["bucket_assignment", "bucket_count", "wire_cast",
-           "measure_collective_seconds", "wire_bucket_mb"]
+           "wire_bucket_mb"]
 
 
 def wire_bucket_mb() -> float:
@@ -127,51 +113,3 @@ def wire_cast(grads, wire, bucket_mb: Optional[float] = None,
                 leaves[i].shape)
             off += n
     return jax.tree.unflatten(treedef, out)
-
-
-def measure_collective_seconds(mesh: Mesh, params, wire,
-                               bucket_mb: Optional[float] = None,
-                               axis="data", iters: int = 3) -> float:
-    """Measured wall seconds of the gradient wire's collective, standalone.
-
-    Builds wire-dtype buffers matching the grad tree's bucket layout, each
-    holding one partial-sum per device along the data axis, and times the
-    jitted cross-device reduction to a replicated result — exactly the
-    reduce the backward's implicit gradient all-reduce performs, without
-    the surrounding compute.  Returns 0.0 on a 1-device axis (no
-    collective exists).  This is the UNOVERLAPPED cost: compare it against
-    the measured step time (`collective_fraction`) to see whether the
-    scheduler hid it."""
-    # `axis` may be one name or a tuple (a MeshLayout mesh reduces
-    # gradients over data x fsdp — the strategy's batch axes)
-    axes = (axis,) if isinstance(axis, str) else tuple(axis)
-    axes = tuple(a for a in axes if a in mesh.axis_names)
-    dp = 1
-    for a in axes:
-        dp *= int(mesh.shape[a])
-    if dp <= 1:
-        return 0.0
-    axis = axes if len(axes) > 1 else axes[0]
-    wire = wire or jnp.float32
-    sizes = [int(leaf.size) for leaf in jax.tree.leaves(params)]
-    if not sizes:
-        return 0.0
-    if bucket_mb is None:
-        bucket_mb = wire_bucket_mb()
-    itemsize = jnp.dtype(wire).itemsize
-    if bucket_mb > 0:
-        buckets = bucket_assignment(sizes, itemsize, bucket_mb)
-        bucket_elems = [sum(sizes[i] for i in b) for b in buckets]
-    else:
-        bucket_elems = sizes  # per-leaf wire: one reduce per leaf
-    sharded = NamedSharding(mesh, P(axis, None))
-    rep = NamedSharding(mesh, P())
-    bufs = [jax.device_put(jnp.zeros((dp, n), wire), sharded)
-            for n in bucket_elems]
-    fn = jax.jit(lambda bs: [jnp.sum(b, axis=0) for b in bs],
-                 out_shardings=rep)
-    jax.block_until_ready(fn(bufs))  # compile outside the timing
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        jax.block_until_ready(fn(bufs))
-    return (time.perf_counter() - t0) / iters

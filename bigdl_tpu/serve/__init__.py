@@ -18,8 +18,8 @@ a queue-wait-driven autoscaler (serve/autoscale.py) grows/shrinks the
 pool between bounds with AOT-warm spawn, a `TopologyRouter`
 (serve/router.py) places mesh-sharded replicas on disjoint device
 subsets and routes by (bucket, per-replica queue depth), and recorded
-request traces (serve/tracefile.py) replay at 10-100x in `bench.py
---serve --replay` reporting per-tenant SLO attainment.  The continuous
+request traces (serve/tracefile.py) replay at 10-100x
+(`tools/scale_smoke.py`) reporting per-tenant SLO attainment.  The continuous
 deployment layer (serve/continuous.py) closes the optimizer->canary
 loop: the trainer's checkpoint path publishes CRC-framed release
 entries and a `DeployController` watches the lineage, verifies each

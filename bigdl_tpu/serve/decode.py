@@ -81,7 +81,7 @@ BIGDL_TPU_DECODE_ADMISSION     continuous 'continuous' (join per tick) or
                                           'batch' (run-to-completion —
                                           the baseline decode_smoke
                                           measures against)
-BIGDL_TPU_DECODE_MIN_STEP_MS   0          per-tick pacing floor (bench /
+BIGDL_TPU_DECODE_MIN_STEP_MS   0          per-tick pacing floor (drill /
                                           smoke determinism lever)
 =============================  =========  ================================
 """

@@ -1,8 +1,7 @@
 """Serving traffic traces: record real request streams, replay them 10-100x.
 
-Every throughput number the serving stack has published so far came from
-synthetic storms (``bench.py --serve``'s fixed-rate open loop and
-scripted bursts).  Real traffic is nothing like that: arrivals cluster,
+Synthetic storms (a fixed-rate open loop, scripted bursts) are nothing
+like real traffic: arrivals cluster,
 tenants interleave, priorities mix, deadlines vary.  This module makes
 recorded traffic a first-class artifact — the BigDL papers' "production
 workloads" pitch as a measurable file instead of a sentence:
@@ -32,8 +31,7 @@ workloads" pitch as a measurable file instead of a sentence:
   (``overload`` / ``timeout`` / ``errors``; real failures are never
   lumped into intentional shedding).
 
-``bench.py --serve --replay <trace> --speed K`` wraps the whole loop
-into one JSON record; ``tools/scale_smoke.py`` replays a recorded
+``tools/scale_smoke.py`` replays a recorded
 mini-trace at 10x against a fixed pool and an autoscaled one and
 asserts the autoscaled pool's attainment is strictly higher.
 """
@@ -309,8 +307,7 @@ def _percentiles_ms(latencies: List[float]) -> dict:
 
 def _classify(error) -> str:
     """Shed-by-cause bucket: intentional load shedding (overload
-    eviction/refusal, deadline timeout) vs real failures — the split the
-    bench's open loop historically lumped together."""
+    eviction/refusal, deadline timeout) apart from real failures."""
     if isinstance(error, ServerOverloaded):
         return "overload"          # includes QuotaExceeded (subclass)
     if isinstance(error, RequestTimeout):

@@ -44,10 +44,9 @@ from jax import lax
 
 from ..utils.config import get_int
 
-PEAK = 197e12  # v5e table peak; see utils/timing.measure_roofline
 # BIGDL_TPU_BN_BATCH overrides (the round-3 "MFU falls as batch grows"
 # anomaly — 256:0.333, 512:0.317, 1024:0.273 — needs per-variant batch
-# sweeps to localize; bench.py's step is identical, only stats vary)
+# sweeps to localize; the step is identical, only stats vary)
 BATCH = get_int("BN_BATCH", 256)
 
 
@@ -109,7 +108,7 @@ def _variant_apply(kind):
         return _PRISTINE_APPLY
     if kind not in ("baseline", "dtype_arg"):
         # unknown names must not silently benchmark the baseline under a
-        # wrong label — mislabeled numbers would enter the bench provenance
+        # wrong label — mislabeled numbers would enter the record
         raise ValueError(f"unknown BN variant: {kind!r}")
 
     def apply(self, params, state, x, *, training=False, rng=None):
@@ -194,8 +193,10 @@ def bench_variant(kind: str) -> None:
     compiled = jax.jit(g).lower(model.params).compile()
     compiled(model.params)
     dt, _ = measure_step_seconds(lambda: compiled(model.params))
-    print(f"bn[{kind:9s}] dt={dt * 1e3:8.2f}ms "
-          f"mfu={flops / dt / PEAK:.4f}", flush=True)
+    # seconds a step and the step's counted operations: the reader divides
+    # by the chip's peak (benchmark/peaks.json), this tool states no share
+    print(f"bn[{kind:9s}] dt={dt * 1e3:8.2f}ms flops={flops:.4e}",
+          flush=True)
 
 
 def main(argv=None):

@@ -17,9 +17,7 @@ Three compile choke points route through here:
 - Evaluator/Predictor/serve forward (optim.optimizer._ShardedForward) —
   keyed by a **structural module fingerprint** (no tracing needed), so a
   warm `InferenceServer.warmup()` performs zero fresh lowers: the serve
-  bucket ladder's N compiles become N cache reads;
-- bench.py's timed configs — the measured `compile_seconds` collapses on a
-  warm run and the per-config record carries the hit/miss delta.
+  bucket ladder's N compiles become N cache reads.
 
 Entries are CRC-framed pickles written through :mod:`.file_io` (the PR-1
 checkpoint framing — local, ``memory://`` and fsspec schemes all work, so a
@@ -39,7 +37,7 @@ its first call.
 Keying / invalidation: every key fingerprints (jax, jaxlib, bigdl_tpu
 versions; backend + device kind + device/process count; mesh shape+axes;
 arg avals incl. shardings; an optional ``BIGDL_TPU_AOT_CACHE_TAG``), plus
-the HLO hash (train/bench) or the module fingerprint (forward).  Change any
+the HLO hash (train) or the module fingerprint (forward).  Change any
 of them and the entry simply misses; stale entries are never served.
 
 Knobs:
@@ -77,7 +75,7 @@ _FORMAT = "bigdl_tpu-aot-v2"
 _SUFFIX = ".aotx"
 
 # process-wide counters: the "did this run compile anything?" ledger that
-# tests, bench records, and the telemetry counter track all read
+# tests, tool records, and the telemetry counter track all read
 _lock = threading.Lock()
 _STATS_KEYS = ("hits", "misses", "stores", "lowers", "compiles",
                "corrupt", "errors", "compile_s", "load_s")
@@ -460,7 +458,7 @@ def cached_compile(lowered, *, label: str, mesh=None,
                    example_args=None, extra: Optional[dict] = None,
                    card_extra: Optional[dict] = None):
     """HLO-hash-keyed compile of an already-lowered computation (the train
-    step / bench path: tracing+lowering is cheap, the XLA compile is not).  Cache disabled -> plain ``lowered.compile()``.
+    step path: tracing+lowering is cheap, the XLA compile is not).  Cache disabled -> plain ``lowered.compile()``.
 
     Every executable leaving here — freshly compiled OR deserialized from
     the cache — emits a compile card (utils/hlostats.py) when cards are

@@ -5,8 +5,8 @@ Reference: the reference's only fault-injection device is the
 (test/.../utils/TestUtils.scala:103, DistriOptimizerSpec.scala:89-97).
 This module generalizes that count-scheduled determinism into a first-class
 chaos layer the whole runtime shares: production code declares *fault
-points* (one `fire`/`transform` call per operation), tests and `bench.py
---chaos` attach *schedules* to them.  Everything is counter-driven — no
+points* (one `fire`/`transform` call per operation), tests and the
+`BIGDL_TPU_CHAOS` spec attach *schedules* to them.  Everything is counter-driven — no
 wall clock, no RNG — so every chaos run is exactly reproducible.
 
 Fault points wired into the runtime:

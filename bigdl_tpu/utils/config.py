@@ -7,6 +7,11 @@ properties become `BIGDL_TPU_*` environment variables (the process-level
 knob JAX programs use); the spark conf tier has no equivalent (no Spark);
 CLIs live in models/run.py and tools/.
 
+Every name the program reads has a row here and every row has a reader
+(tests/test_config_table.py holds both, and pins the count of names).  A
+row may hold several names: each after the first is written without the
+`BIGDL_TPU` prefix, as in ` / _IO_BACKOFF_MAX`.
+
 | env var                   | reference property               | default |
 |---------------------------|----------------------------------|---------|
 | BIGDL_TPU_SEED            | (RandomGenerator default seed)   | 0       |
@@ -15,17 +20,18 @@ CLIs live in models/run.py and tools/.
 | BIGDL_TPU_NUM_THREADS     | bigdl.coreNumber / MKL threads   | ncpu    |
 | BIGDL_TPU_LOG_FILE        | bigdl.utils.LoggerFilter.logFile | bigdl_tpu.log |
 | BIGDL_TPU_DISABLE_LOGGER_FILTER | bigdl.utils.LoggerFilter.disable | 0 |
-| BIGDL_TPU_CHECK_SINGLETON | bigdl.check.singleton            | 0       |
 | BIGDL_TPU_PREEMPTION_CHECKPOINT | (net-new: SIGTERM -> final snapshot) | 1 |
 | BIGDL_TPU_DEVICE_TIMEOUT  | (net-new: Engine.init device-discovery watchdog, seconds) | 0 (off) |
 | BIGDL_TPU_RNN_HOIST_MAX_ELEMENTS | (net-new: ConvLSTM hoist cap) | 2^28 |
 | BIGDL_TPU_XLA_CACHE | (net-new: persistent compile cache on/off; it lives at JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache — utils/platform.py) | 1 |
 | BIGDL_TPU_CONV_PAD_MIN_CIN | (net-new: tiny-channel conv pad, nn/conv.py) | 8 |
-| BIGDL_TPU_BN_IMPL / _FUSED_VJP / _STAT_ROWS | (net-new: BN variants, nn/normalization.py) | off |
+| BIGDL_TPU_BN_IMPL / _BN_FUSED_VJP / _BN_STAT_ROWS | (net-new: BN variants, nn/normalization.py) | off |
 | BIGDL_TPU_BN_BATCH | (net-new: bn_experiment batch) | 256 |
-| BIGDL_TPU_BENCH_REMAT / _FLASH_SHAPE | (net-new: bench knobs) | off |
-| BIGDL_TPU_BENCH_BN_AUTOTUNE | (net-new: resnet50_bf16 BN-variant race; 0=off, 1=force on CPU, default=TPU only) | tpu |
-| BIGDL_TPU_ATTN_IMPL | (net-new: flash-attention dispatch, jnp/pallas; ops/attention.py) | auto |
+| BIGDL_TPU_RING_ATTN | (net-new: MultiHeadAttention(seq_parallel=) takes the ring-attention path, nn/attention.py) | 0 (off) |
+| BIGDL_TPU_NO_DONATE | (net-new: keep the train step's inputs alive instead of donating params/state/slots, optim/optimizer._build_step) | 0 (donate) |
+| BIGDL_TPU_FSDP_MIN_SIZE | (net-new: leaves under this many elements stay replicated under fsdp, parallel/layout.py) | 4096 |
+| BIGDL_TPU_PIPE_MICROBATCHES / _PIPE_SCHEDULE / _PIPE_VIRTUAL_STAGES | (net-new: pipeline microbatches, schedule gpipe or 1f1b, interleaved slices a device; parallel/pipeline.py) | 4 / gpipe / 1 |
+| BIGDL_TPU_COORDINATOR / _NUM_PROCESSES / _PROCESS_ID | (net-new: jax.distributed address, world size and rank for Engine.init; a coordinator set means distributed) | off |
 | BIGDL_TPU_TEST_INSTALLED | (net-new: suite resolves installed wheel) | off |
 | BIGDL_TPU_IO_RETRIES | (net-new: remote-IO retry attempts per op, utils/file_io.py) | 3 |
 | BIGDL_TPU_IO_BACKOFF_BASE / _IO_BACKOFF_MAX | (net-new: remote-IO backoff seconds, exponential + deterministic jitter) | 0.05 / 2.0 |
@@ -33,7 +39,7 @@ CLIs live in models/run.py and tools/.
 | BIGDL_TPU_CKPT_KEEP_LAST | (net-new: checkpoint retention keep-last-K; 0 = unlimited) | 0 |
 | BIGDL_TPU_CKPT_KEEP_EVERY_EPOCHS | (net-new: mark a keeper snapshot every N epochs) | 0 |
 | BIGDL_TPU_CHAOS | (net-new: fault-injection spec, utils/chaos.py; see docs/robustness.md) | off |
-| BIGDL_TPU_SUPERVISE_DATA / _STEP / _COMPILE / _CHECKPOINT / _VALIDATION | (net-new: per-phase stall deadlines, seconds; utils/supervisor.py — COMPILE covers each attempt's first step, which holds the XLA compile) | 0 (off) |
+| BIGDL_TPU_SUPERVISE_DATA / _SUPERVISE_STEP / _SUPERVISE_COMPILE / _SUPERVISE_CHECKPOINT / _SUPERVISE_VALIDATION / _SUPERVISE_SERVE | (net-new: per-phase stall deadlines, seconds; utils/supervisor.py — COMPILE covers each attempt's first step, which holds the XLA compile) | 0 (off) |
 | BIGDL_TPU_SUPERVISE_DEADLINE | (net-new: default stall deadline for unlisted phases) | 0 (off) |
 | BIGDL_TPU_SUPERVISE_POLICY | (net-new: stall response — raise StallError or hard-exit) | raise |
 | BIGDL_TPU_SUPERVISE_PEER_STALE | (net-new: multi-host heartbeat staleness threshold, seconds) | 60 |
@@ -43,6 +49,8 @@ CLIs live in models/run.py and tools/.
 | BIGDL_TPU_TRACE | (net-new: run-telemetry trace output dir, utils/telemetry.py; empty = tracing off) | off |
 | BIGDL_TPU_TRACE_RING | (net-new: max buffered trace events; oldest dropped beyond this) | 65536 |
 | BIGDL_TPU_TRACE_FLUSH_EVERY | (net-new: trace events between automatic file flushes) | 4096 |
+| BIGDL_TPU_COMPILE_CARDS | (net-new: compile cards, utils/hlostats.py: 1 keeps them in memory, a path also writes them there; cards also arm beside a trace dir) | off |
+| BIGDL_TPU_METRICS / _METRICS_SLO_MS / _METRICS_WINDOW | (net-new: the /metrics plane on or off, the latency SLO it counts against, the rolling window of requests; utils/metrics_export.py) | 1 / 100 / 512 |
 | BIGDL_TPU_SERVE_MAX_BATCH | (net-new: online serving — max requests coalesced per device batch, serve/) | 8 |
 | BIGDL_TPU_SERVE_MAX_WAIT_MS | (net-new: flush deadline — max ms the oldest queued request waits for batch fill) | 5 |
 | BIGDL_TPU_SERVE_QUEUE_LIMIT | (net-new: bounded request queue; admission past it raises ServerOverloaded) | 64 |
@@ -58,15 +66,20 @@ CLIs live in models/run.py and tools/.
 | BIGDL_TPU_SERVE_CANARY_ERROR_MARGIN | (net-new: auto-rollback when canary batch error rate exceeds the incumbent's + margin) | 0.05 |
 | BIGDL_TPU_SERVE_TENANT_QPS | (net-new: per-tenant token-bucket admission quota, requests/s; over-quota -> typed QuotaExceeded with retry_after_s; 0 = quotas off) | 0 (off) |
 | BIGDL_TPU_SERVE_TENANT_BURST | (net-new: per-tenant token-bucket depth; 0 = 2x qps, min 1) | 0 (auto) |
+| BIGDL_TPU_SERVE_TRACE_LIMIT | (net-new: offered requests a TraceRecorder keeps, serve/tracefile.py) | 100000 |
+| BIGDL_TPU_SERVE_AUTOSCALE_MIN / _SERVE_AUTOSCALE_MAX / _SERVE_AUTOSCALE_STEP | (net-new: replica bounds and replicas added a decision, serve/autoscale.py; max 0 = autoscaling off) | initial / 0 / 1 |
+| BIGDL_TPU_SERVE_AUTOSCALE_TARGET_WAIT_MS / _SERVE_AUTOSCALE_UP_POLLS / _SERVE_AUTOSCALE_IDLE_S / _SERVE_AUTOSCALE_COOLDOWN_S / _SERVE_AUTOSCALE_POLL_S | (net-new: grow when the estimated queue wait passes the target for that many polls, shrink after idle seconds, cooldown between decisions, poll cadence) | 50 / 2 / 2.0 / 0.5 / 0.05 |
+| BIGDL_TPU_DECODE_SLOTS / _DECODE_PAGE / _DECODE_MAX_LEN / _DECODE_QUEUE_LIMIT | (net-new: DecodeEngine in-flight slots, cache-length ladder step, cache cap (0 = the model's), bounded queue; serve/decode.py) | 4 / 128 / 0 / 64 |
+| BIGDL_TPU_DECODE_DEADLINE_MS / _DECODE_MIN_STEP_MS / _DECODE_ADMISSION | (net-new: default request deadline (0 = none), per-tick pacing floor for drills, continuous or batch admission (batch is decode_smoke's baseline)) | 0 / 0 / continuous |
 | BIGDL_TPU_AOT_CACHE | (net-new: AOT executable-cache dir, utils/aot.py — serialized compiled executables; warm start = cache read, zero XLA compiles; empty/0 = off) | off |
 | BIGDL_TPU_AOT_CACHE_TAG | (net-new: free-form AOT fingerprint salt; bump to invalidate every entry at once) | "" |
-| BIGDL_TPU_PEAK_FLOPS | (net-new: per-device MFU denominator override, FLOP/s — utils/flops.device_peak_flops; default TPU table / 1e12 CPU-nominal; a TPU kind missing from the table is an error) | 0 (auto) |
 | BIGDL_TPU_FUSED_UPDATE | (net-new: multi-tensor fused optimizer update, optim/fused.py — flatten grad/param/slot trees into dtype-homogeneous 1-D buffers; bit-identical to the per-leaf path) | 0 (off) |
 | BIGDL_TPU_WIRE_BUCKET_MB | (net-new: max wire-dtype MB per gradient bucket, parallel/wire.py; 0 = per-leaf wire cast) | 0 (per-leaf) |
-| BIGDL_TPU_OVERLAP_FLAGS | (net-new: latency-hiding-scheduler / async-collective LIBTPU flags, utils/platform.enable_overlap_flags; 0 disables) | 1 |
 | BIGDL_TPU_CONV_ROUTE | (net-new: tiny-C_in conv lowering — pad (zero-pad), matmul (im2col reshaped-matmul, ops/convmm.py), lax (untouched); nn/conv._conv_route) | pad |
 | BIGDL_TPU_ELASTIC_PEER_LOST | (net-new: elastic host-loss threshold, seconds of heartbeat-PUBLICATION silence promoting a peer to PeerLostError; parallel/elastic — 0 disarms elasticity) | 0 (off) |
 | BIGDL_TPU_ELASTIC_WORLD / _ELASTIC_RANK | (net-new: simulated-multi-host logical topology for the elastic drill harness; utils/engine.Engine.world/rank) | off |
+| BIGDL_TPU_ELASTIC_JOIN / _ELASTIC_JOIN_TIMEOUT / _ELASTIC_JOIN_POLL | (net-new: a starting rank joins a running job through Optimizer._elastic_join; seconds it waits for an offer / poll cadence; parallel/elastic.py) | 0 / 120 / 0.25 |
+| BIGDL_TPU_ELASTIC_REFORM_GRACE | (net-new: seconds the supervisor lets a re-formed mesh settle before peer silence counts again) | 2.0 |
 | BIGDL_TPU_ELASTIC_NEGOTIATE_TIMEOUT / _ELASTIC_NEGOTIATE_POLL | (net-new: seconds to wait for every survivor's lineage view / poll cadence during elastic negotiation) | 60 / 0.25 |
 | BIGDL_TPU_DEPLOY_CANARY_FRACTION | (net-new: continuous deployment, serve/continuous.py — canary batch fraction the DeployController routes to each new release; 0 = plain full swaps) | 0.25 |
 | BIGDL_TPU_DEPLOY_ROLLBACK_BUDGET | (net-new: consecutive canary rollbacks before the deploy controller freezes unhealthy instead of flapping) | 2 |

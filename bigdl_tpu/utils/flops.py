@@ -1,13 +1,13 @@
 """Analytic FLOP counting by walking a jaxpr.
 
 Role in the reference: the perf harness `DistriOptimizerPerf.scala:91-95`
-reports only records/s; MFU accounting is net-new for the TPU rebuild
-(BASELINE.md: ResNet-50 >= 45% MFU on v5e).  XLA's `compiled.cost_analysis()`
-is the primary FLOPs source, but it can fail on experimental backends — this
-module is the deterministic fallback: trace the function with
-`jax.make_jaxpr` (no compile, no device) and count matmul/conv FLOPs
-directly from the equations, recursing into scan/cond/while/pjit/custom-vjp
-sub-jaxprs.
+reports only records/s; counting a model's operations is net-new for the TPU
+rebuild.  Trace the function with `jax.make_jaxpr` (no compile, no device)
+and count matmul/conv FLOPs directly from the equations, recursing into
+scan/cond/while/pjit/custom-vjp sub-jaxprs.  The count states no speed: the
+benchmark divides its configurations' own counts (checked against this one
+in tests/benchmark) by a device time and by `benchmark/peaks.json`, the one
+table of peaks.
 
 Conventions: a dot_general counts 2*M*N*K (multiply+add); a conv counts
 2 * prod(out_shape) * (in_features / feature_group_count) * prod(kernel_spatial).
@@ -23,49 +23,7 @@ import math
 
 import jax
 
-__all__ = ["jaxpr_flops", "fn_flops", "device_peak_flops",
-           "CPU_NOMINAL_PEAK"]
-
-# bf16 peak FLOP/s per *jax device* (v2/v3 devices are single cores) —
-# the MFU denominator bench.py and the Optimizer's per-step mfu counter
-# share.  Ordering matters: "v5p" must match before "v5" (lite/e).
-_TPU_PEAK_BF16 = (
-    ("v6", 918e12), ("v5p", 459e12), ("v5", 197e12),  # v5 lite / v5e
-    ("v4", 275e12), ("v3", 61.5e12), ("v2", 22.5e12),
-)
-
-# Nominal CPU denominator: there is no honest single peak for a shared
-# host CPU, but a FIXED nominal one still makes the per-step mfu counter
-# a usable *regression* signal in CPU traces (the absolute value is
-# meaningless; the trend is not).  Override with BIGDL_TPU_PEAK_FLOPS.
-CPU_NOMINAL_PEAK = 1e12
-
-
-def device_peak_flops(device=None):
-    """(peak_flops, source) for the MFU denominator.
-
-    source is ``"env"`` (BIGDL_TPU_PEAK_FLOPS override), ``"table"`` (TPU
-    device-kind match), or ``"nominal"`` (a non-TPU device,
-    :data:`CPU_NOMINAL_PEAK`).  Callers that refuse to report MFU against
-    a made-up denominator (bench.py) gate on ``source != "nominal"``.  A
-    device on platform ``tpu`` whose kind matches no row of the table is
-    an error: a utilisation against the CPU's nominal peak would look like
-    a measurement."""
-    from . import config
-    env = config.get_float("PEAK_FLOPS", 0.0)
-    if env > 0:
-        return env, "env"
-    if device is None:
-        device = jax.devices()[0]
-    if device.platform == "tpu":
-        kind = device.device_kind.lower()
-        for key, val in _TPU_PEAK_BF16:
-            if key in kind:
-                return val, "table"
-        raise ValueError(
-            f"no bf16 peak known for TPU device_kind "
-            f"{device.device_kind!r}: add it to flops._TPU_PEAK_BF16")
-    return CPU_NOMINAL_PEAK, "nominal"
+__all__ = ["jaxpr_flops", "fn_flops"]
 
 
 def _prod(xs):

@@ -22,8 +22,7 @@ One card per (label, program), captured at the three compile choke points
   the fused-buffer count, so structural claims about the step are in the
   card even before reading the HLO;
 - Evaluator/Predictor/serve forward (``optim.optimizer._ShardedForward``)
-  — the serve bucket ladder emits one card per bucket shape;
-- ``bench.py``'s timed configs — each bench record embeds its card.
+  — the serve bucket ladder emits one card per bucket shape.
 
 What a card holds (see :func:`compile_card`): the op histogram of the
 **optimized HLO** text (``convolution`` / ``dot`` / ``convert`` /

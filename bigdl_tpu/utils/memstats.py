@@ -1,15 +1,12 @@
-"""Device-memory accounting for the bench/smoke trajectory.
+"""Device-memory accounting for the smoke drills and the gate.
 
 FSDP's whole value proposition is a MEMORY number — per-device
 parameter+slot bytes dropping to ~1/N — and donation's is a PEAK number
 (no second params+slots copy alive during the update).  Neither shows
-up in images/sec, so bench.py records them explicitly in every
-per-config record (satellite of ISSUE 9):
+up in images/sec, so the drills (tools/shard_smoke.py,
+tools/pipeline_smoke.py, tools/perf_gate.py) read them explicitly
+(satellite of ISSUE 9):
 
-- :func:`device_memory_stats` — the accelerator runtime's own ledger
-  (``device.memory_stats()``: ``bytes_in_use`` / ``peak_bytes_in_use``
-  on TPU/GPU plugins).  Returns None where the backend has no ledger
-  (CPU), in which case callers fall back to
 - :func:`live_device_bytes` — the live-buffer sum: every
   ``jax.live_arrays()`` leaf's addressable shards on one device.  No
   peak semantics, but deltas across a step still show donation working
@@ -25,22 +22,8 @@ from typing import Optional
 
 import jax
 
-__all__ = ["device_memory_stats", "live_device_bytes", "tree_device_bytes",
-           "tree_total_bytes", "memory_record", "pipeline_stage_bytes",
+__all__ = ["live_device_bytes", "tree_device_bytes", "tree_total_bytes",
            "embedding_table_bytes", "compiled_memory_analysis"]
-
-
-def device_memory_stats(device=None) -> Optional[dict]:
-    """``device.memory_stats()`` where the backend implements it, else
-    None (CPU devices raise/return nothing useful)."""
-    dev = device or jax.devices()[0]
-    try:
-        stats = dev.memory_stats()
-    except Exception:  # noqa: BLE001 — unimplemented on this backend
-        return None
-    if not stats or "bytes_in_use" not in stats:
-        return None
-    return dict(stats)
 
 
 def _shard_bytes_on(leaf, device) -> int:
@@ -88,47 +71,15 @@ def tree_total_bytes(tree) -> int:
     return total
 
 
-def pipeline_stage_bytes(model, params, device=None):
-    """Per-stage parameter accounting for every GPipeSequential in the
-    model (parallel/pipeline): the stacked stage params' logical bytes,
-    bytes per stage, and the bytes actually resident on one device —
-    1/n_stages of the stack under a pipe=n layout, the whole stack when
-    replicated.  Walks the module tree parallel to the params pytree
-    (the Container/Graph list-alignment, like layout.role_tree).
-    Returns a list of one dict per pipeline, or None when the model has
-    no pipelined region."""
-    from ..parallel.pipeline import GPipeSequential
-    dev = device or jax.devices()[0]
-    out = []
-
-    def walk(mod, p):
-        if isinstance(mod, GPipeSequential):
-            total = tree_total_bytes(p)
-            n = len(mod.stages)
-            out.append({"stages": n,
-                        "stage_param_bytes": total // max(n, 1),
-                        "stacked_param_bytes": total,
-                        "param_bytes_per_device": tree_device_bytes(p, dev)})
-            return
-        kids = getattr(mod, "modules", None)
-        if kids is not None and isinstance(p, list) and len(kids) == len(p):
-            for m, cp in zip(kids, p):
-                walk(m, cp)
-
-    walk(model, params)
-    return out or None
-
-
 def embedding_table_bytes(model, params, device=None):
     """Per-table accounting for every module whose param_roles() place a
     parameter under ``embedding_row`` (LookupTable and friends): logical
     table bytes, bytes resident on one device, and the resident fraction
     — exactly 1/N under an fsdp×tp=N row-sharded layout, 1.0 when
     replicated.  Embedding tables dominate recommender memory (the
-    wide-and-deep workload's whole FSDP story), so bench.py reports this
-    block per config.  Walks the module tree parallel to the params
-    pytree (the Container/Graph list-alignment, like
-    pipeline_stage_bytes).  Returns a list of one dict per table, or
+    wide-and-deep workload's whole FSDP story); tools/perf_gate.py pins
+    the fraction.  Walks the module tree parallel to the params
+    pytree (the Container/Graph list-alignment).  Returns a list of one dict per table, or
     None when the model has no embedding-role parameters."""
     dev = device or jax.devices()[0]
     out = []
@@ -189,26 +140,3 @@ def compiled_memory_analysis(compiled) -> Optional[dict]:
     return out or None
 
 
-def memory_record(params=None, opt_state=None, device=None) -> dict:
-    """The bench-record memory block: runtime ledger when available
-    (``source: memory_stats``), live-buffer sum fallback
-    (``source: live_buffer_sum``), plus per-device and total bytes for
-    the given params/opt_state trees."""
-    dev = device or jax.devices()[0]
-    rec: dict = {}
-    stats = device_memory_stats(dev)
-    if stats is not None:
-        rec["source"] = "memory_stats"
-        rec["bytes_in_use"] = int(stats.get("bytes_in_use", 0))
-        if "peak_bytes_in_use" in stats:
-            rec["peak_bytes_in_use"] = int(stats["peak_bytes_in_use"])
-    else:
-        rec["source"] = "live_buffer_sum"
-        rec["bytes_in_use"] = live_device_bytes(dev)
-    if params is not None:
-        rec["param_bytes_per_device"] = tree_device_bytes(params, dev)
-        rec["param_bytes_total"] = tree_total_bytes(params)
-    if opt_state is not None:
-        rec["slot_bytes_per_device"] = tree_device_bytes(opt_state, dev)
-        rec["slot_bytes_total"] = tree_total_bytes(opt_state)
-    return rec

@@ -3,9 +3,9 @@
 One installation is supported (jax / jaxlib 0.9.0, libtpu 0.0.34) and JAX
 selects its platform the ordinary way: `JAX_PLATFORMS=cpu` in the
 environment, or `jax.config.update("jax_platforms", ...)` before the backend
-is first used.  This module is the single home for the three start-up
+is first used.  This module is the single home for the two start-up
 idioms the launchers share: forcing N virtual CPU devices (tests, CPU
-drills), placing the persistent compile cache, and the TPU overlap flags.
+drills) and placing the persistent compile cache.
 Importing it initialises no backend.
 """
 
@@ -14,50 +14,7 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-__all__ = ["force_cpu", "enable_compilation_cache", "enable_overlap_flags"]
-
-
-#: latency-hiding-scheduler / async-collective flags for the TPU compiler.
-#: The bucketed gradient wire (parallel/wire.py) gives XLA a handful of
-#: bucket-sized bf16 all-reduces; these flags let it ISSUE them while the
-#: backward tail is still computing instead of serializing them after it —
-#: the MLPerf TPU-pods overlap move (PAPERS.md).  Flag-by-flag: the
-#: latency-hiding scheduler reorders ops to hide collective latency behind
-#: compute; async-collective fusion converts blocking collectives to
-#: start/done pairs (multiple_steps lets one fusion span several of them);
-#: overlap_compute_collective_tc runs collectives on the transfer core
-#: concurrently with TensorCore compute.
-_OVERLAP_FLAGS = (
-    "--xla_tpu_enable_latency_hiding_scheduler=true",
-    "--xla_tpu_enable_async_collective_fusion=true",
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-    "--xla_tpu_enable_async_collective_fusion_multiple_steps=true",
-    "--xla_tpu_overlap_compute_collective_tc=true",
-    "--xla_enable_async_all_gather=true",
-)
-
-
-def enable_overlap_flags() -> Optional[str]:
-    """Arm the XLA collective-overlap flags via LIBTPU_INIT_ARGS.
-
-    Must run BEFORE the TPU backend initializes (libtpu reads the env at
-    load); call it next to `force_cpu`/`enable_compilation_cache` at
-    process start (bench.py does).  Flags go into LIBTPU_INIT_ARGS — read
-    only by libtpu, so the call is inert on CPU/GPU processes — and any
-    flag the operator already set there wins (only missing keys are
-    appended).  ``BIGDL_TPU_OVERLAP_FLAGS=0`` disables.  Returns the
-    LIBTPU_INIT_ARGS value in effect, or None when disabled.
-    """
-    from . import config as _config
-
-    if not _config.get_bool("OVERLAP_FLAGS", True):
-        return None
-    cur = os.environ.get("LIBTPU_INIT_ARGS", "")
-    add = [f for f in _OVERLAP_FLAGS if f.split("=", 1)[0] not in cur]
-    if add:
-        os.environ["LIBTPU_INIT_ARGS"] = " ".join(
-            ([cur] if cur else []) + add)
-    return os.environ.get("LIBTPU_INIT_ARGS")
+__all__ = ["force_cpu", "enable_compilation_cache"]
 
 
 #: where the persistent compile cache goes when JAX_COMPILATION_CACHE_DIR is
@@ -80,7 +37,7 @@ def enable_compilation_cache() -> Optional[str]:
     there and this function sets no directory at all.  Where it is not, the
     cache goes to :data:`CHECKOUT_CACHE_DIR`.  Either way every compile is
     cached, however short.  `Engine.init()` calls this, so every entry point
-    (`bigdl-tpu-run`, bench.py, tools/perf.py, chip_smoke.py) shares this
+    (`bigdl-tpu-run`, benchmark/run.py, tools/perf.py, chip_smoke.py) shares this
     one site; the call is idempotent and takes effect mid-process.
 
     Layering note: this warms the XLA *compiler* per jit function; the AOT

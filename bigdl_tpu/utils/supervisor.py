@@ -34,9 +34,9 @@ Core pieces
   * ``exit``: ``os._exit(86)`` after the report — for wedged backends
     where Python can't unwind (utils/timing.py documents exactly such a
     backend: ``block_until_ready`` returns while the RPC never does).
-  * ``on_stall`` callback: the embedder owns the response (bench.py's
-    emit-partial-results-and-exit watchdog is this supervisor with a
-    callback — one liveness mechanism, not two).
+  * ``on_stall`` callback: the embedder owns the response (a tool that
+    emits partial results and exits is this supervisor with a callback:
+    one liveness mechanism, not two).
 
 - Auxiliary **channels** (:meth:`Supervisor.channel`): background workers
   of the supervised loop — the input-pipeline prefetch thread
@@ -225,7 +225,7 @@ class Supervisor:
         sup.stop()
 
     Deadline lookup: exact phase name, else the prefix before ``:``
-    (bench stages like ``compile:resnet50``), else `default_deadline`;
+    (stages like ``compile:resnet50``), else `default_deadline`;
     None/0 means the phase is unwatched."""
 
     def __init__(self, deadlines: Optional[Dict[str, float]] = None,
@@ -382,7 +382,7 @@ class Supervisor:
 
     def set_deadlines(self, default: Optional[float] = None,
                       phases: Optional[Dict[str, float]] = None) -> None:
-        """Reconfigure deadlines (bench installs its stage limits here)."""
+        """Reconfigure deadlines (a tool installs its stage limits here)."""
         if default is not None:
             self.default_deadline = default
         if phases:

@@ -35,8 +35,8 @@ Core pieces
 - :func:`merge_traces` + :func:`phase_breakdown` + :func:`format_report`
   are the analysis core behind ``tools/trace_report.py``: merge
   ``trace.*.json`` of all ranks, compute per-phase p50/p95/max, the
-  ``data_wait_fraction`` (input-bound vs compute-bound diagnosis, same
-  definition as bench.py's e2e stage) and straggler ranks.
+  ``data_wait_fraction`` (input-bound vs compute-bound diagnosis) and
+  straggler ranks.
 
 Who emits what (all through the module-level helpers, so everything is
 inert until a tracer is active):
@@ -540,9 +540,8 @@ def phase_breakdown(merged: dict) -> dict:
       (the optimizer's ``data``/``step``/``checkpoint``/``validation``
       first, then every other span name seen);
     - ``ranks``: per rank — wall seconds (first span start to last span
-      end), ``data_wait_fraction`` (sum of ``data`` span time / wall: the
-      same numerator/denominator bench.py's e2e stage reports), mean step
-      seconds;
+      end), ``data_wait_fraction`` (sum of ``data`` span time / wall),
+      mean step seconds;
     - ``data_wait_fraction`` overall + ``diagnosis``;
     - ``straggler_ranks``: ranks whose mean ``step`` span runs > 1.5x the
       median rank's (the one-slow-host signal);
@@ -612,7 +611,7 @@ def phase_breakdown(merged: dict) -> dict:
         if e.get("ph") == "i":
             instants[e["name"]] = instants.get(e["name"], 0) + 1
     # counter tracks ("C" events): per track.series — count/mean/max/last.
-    # This is where the optimizer's per-step mfu and the aot hit/miss
+    # This is where the optimizer's per-step track and the aot hit/miss
     # ledger become part of the printed report (regressions show up in
     # `trace_report` output, not just inside Perfetto).
     counter_vals: Dict[str, List[float]] = {}

@@ -1,4 +1,4 @@
-"""Regression tests for the round-3 advisor findings (ADVICE.md)."""
+"""Regression tests for the round-3 advisor findings."""
 
 import numpy as np
 import jax
@@ -9,7 +9,7 @@ from bigdl_tpu.nn.module import scale_epoch
 
 
 def test_direct_scale_assignment_bumps_epoch():
-    """ADVICE #1: m.scale_w = x (no setter) must invalidate cached trees."""
+    """finding 1: m.scale_w = x (no setter) must invalidate cached trees."""
     lin = nn.Linear(4, 3)
     lin.build(jax.random.PRNGKey(0))
     assert lin._grad_scale_tree() is None  # all-ones fast path, cached
@@ -22,7 +22,7 @@ def test_direct_scale_assignment_bumps_epoch():
 
 
 def test_dense_hoist_cap(monkeypatch):
-    """ADVICE #2: the HBM hoist cap applies to dense cells, and the fallback
+    """finding 2: the HBM hoist cap applies to dense cells, and the fallback
     scan path computes the same values."""
     cell = nn.LSTM(8, 16)
     params, _ = cell.init(jax.random.PRNGKey(0))
@@ -48,7 +48,7 @@ def test_dense_hoist_cap(monkeypatch):
 
 
 def test_preemption_armed_without_main_thread(tmp_path):
-    """ADVICE #3: arming is derived from rank-consistent inputs, so a
+    """finding 3: arming is derived from rank-consistent inputs, so a
     non-main thread (where signal.signal raises) still arms."""
     import threading
 
@@ -79,7 +79,7 @@ def test_preemption_armed_without_main_thread(tmp_path):
 
 
 def test_evaluator_peek_does_not_drop_generator_sample():
-    """ADVICE #4: one-shot generator-backed datasets keep their first sample
+    """finding 4: one-shot generator-backed datasets keep their first sample
     through Evaluator's batch-size autodetect peek."""
     from bigdl_tpu.dataset import Sample
     from bigdl_tpu.optim import Evaluator, Top1Accuracy

@@ -84,8 +84,21 @@ def test_data_parallel_tiny_on_virtual_devices(fresh_policy, capsys):
 
 
 def test_sync_phase_tiny(capsys):
+    """What the phase returns and prints.  Which of the three times is the
+    smallest is a statement about a chip (the enqueue returns before the
+    device ends): on a loaded CPU a 128^3 matmul orders them any way."""
     out = chip_smoke.sync_phase(TINY)
-    assert out["enqueue"] <= out["block"] and out["fetch"] > 0
+    assert set(out) == {"block", "fetch", "enqueue"}
+    assert all(v > 0 for v in out.values())
+    (line,) = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+               if l.startswith('{"obs": "sync"')]
+    assert set(line) == {"obs", "t", "matmul_n", "block_until_ready_s",
+                         "host_fetch_s", "enqueue_only_s",
+                         "tflops_block_until_ready", "tflops_host_fetch"}
+    assert line["matmul_n"] == TINY.matmul_n
+    assert (line["block_until_ready_s"], line["host_fetch_s"],
+            line["enqueue_only_s"]) == (out["block"], out["fetch"],
+                                        out["enqueue"])
 
 
 @pytest.mark.parametrize("text,ok", [

@@ -129,51 +129,6 @@ def test_other_conv_families_inherit_pad(monkeypatch):
                                    err_msg=type(conv).__name__)
 
 
-def test_bench_flops_count_nominal_model(monkeypatch):
-    """bench._step_flops must count NOMINAL FLOPs (pad disabled) even though
-    the compiled step contains the padded convs — and must trace the raw
-    (unjitted) step so pjit's cached padded trace can't leak through."""
-    import sys, os
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    import jax
-    import bigdl_tpu.nn as nn
-    from bigdl_tpu import Engine
-    from bigdl_tpu.optim import Optimizer, SGD, Trigger
-    from bigdl_tpu.utils.flops import jaxpr_flops
-
-    Engine.reset()
-    Engine.init(devices=[jax.devices()[0]])
-    model = nn.Sequential().add(nn.SpatialConvolution(1, 6, 5, 5)) \
-        .add(nn.Reshape([24 * 24 * 6])).add(nn.Linear(24 * 24 * 6, 4)) \
-        .add(nn.LogSoftMax())
-    model.build(jax.random.PRNGKey(0))
-    opt = Optimizer(model, dataset=None, criterion=nn.ClassNLLCriterion(),
-                    end_trigger=Trigger.max_iteration(1))
-    opt.set_optim_method(SGD(0.1))
-    step, param_sh, _ = opt._build_step(Engine.mesh())
-    inp = jnp.zeros((8, 28, 28, 1))
-    tgt = jnp.zeros((8,), jnp.int32)
-    args = (jax.device_put(model.params, param_sh), model.state,
-            opt.optim_method.init_state(model.params), inp, tgt,
-            jnp.float32(0.1), jax.random.key(1))
-    # compile FIRST (pad active) so pjit's cache holds the padded trace —
-    # the exact leak scenario
-    compiled = step.lower(*args).compile()
-    flops, detail = bench._step_flops(step, compiled, args)
-    # nominal vs padded reference counts: fresh lambda wrappers per trace —
-    # make_jaxpr caches by function identity, so re-tracing step.raw itself
-    # would return the first call's jaxpr regardless of the env toggle
-    monkeypatch.setenv("BIGDL_TPU_CONV_PAD_MIN_CIN", "0")
-    nominal = jaxpr_flops(jax.make_jaxpr(lambda *a: step.raw(*a))(*args))
-    monkeypatch.setenv("BIGDL_TPU_CONV_PAD_MIN_CIN", "8")
-    padded = jaxpr_flops(jax.make_jaxpr(lambda *a: step.raw(*a))(*args))
-    assert padded > 1.5 * nominal          # the pad is visible in FLOPs
-    assert flops == pytest.approx(nominal)  # but the bench reports nominal
-    Engine.reset()
-
-
 # ----------------------------------------------------------------------
 # reshaped-matmul (im2col) route — ops/convmm.py via BIGDL_TPU_CONV_ROUTE
 # ----------------------------------------------------------------------

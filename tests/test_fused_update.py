@@ -162,22 +162,6 @@ def test_wire_cast_none_passthrough():
     assert wire_mod.wire_cast(g, None, 8.0) is g
 
 
-def test_measure_collective_seconds():
-    Engine.reset()
-    Engine.init()
-    mesh = Engine.mesh()
-    t = wire_mod.measure_collective_seconds(mesh, _tree(5), jnp.bfloat16,
-                                            bucket_mb=0.01)
-    if mesh.shape.get("data", 1) > 1:
-        assert t > 0.0
-    # single-device axis: no collective exists
-    Engine.reset()
-    Engine.init(devices=[jax.devices()[0]])
-    assert wire_mod.measure_collective_seconds(
-        Engine.mesh(), _tree(5), jnp.bfloat16) == 0.0
-    Engine.reset()
-
-
 # ----------------------------------------------------------------------
 # end-to-end parity (the acceptance criterion)
 # ----------------------------------------------------------------------
@@ -376,59 +360,9 @@ def test_bucketed_wire_with_fused_update_and_zero(monkeypatch):
             rtol=1e-4, atol=1e-5)
 
 
-def test_collective_counter_in_trace_and_report(tmp_path, monkeypatch):
-    """Acceptance: `train.collective_s` (and collective_fraction) appear
-    in the Optimizer's counter track and in tools/trace_report.py output
-    when tracing is armed, beside the existing mfu track."""
-    import os
-    import subprocess
-    import sys
-
-    from bigdl_tpu.utils import telemetry
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    trace_dir = tmp_path / "trace"
-    monkeypatch.setenv("BIGDL_TPU_TRACE", str(trace_dir))
-    monkeypatch.setenv("BIGDL_TPU_WIRE_BUCKET_MB", "0.25")
-    Engine.init()
-    _train(_lenet, steps=4)
-
-    merged = telemetry.merge_traces(str(trace_dir))
-    counters = [e for e in merged["traceEvents"]
-                if e.get("ph") == "C" and e.get("name") == "train"]
-    with_coll = [e for e in counters if "collective_s" in e["args"]]
-    assert with_coll, "no collective_s samples on the train counter track"
-    # 8-device data axis: a real cross-device reduce was measured
-    assert all(e["args"]["collective_s"] > 0 for e in with_coll)
-    assert all(0 <= e["args"]["collective_fraction"] <= 1
-               for e in with_coll)
-
-    bd = telemetry.phase_breakdown(merged)
-    assert "train.collective_s" in bd["counters"]
-
-    r = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "trace_report.py"),
-         str(trace_dir)],
-        capture_output=True, text=True, timeout=120,
-        env={**os.environ, "PYTHONPATH": repo})
-    assert r.returncode == 0, r.stderr
-    assert "train.collective_s" in r.stdout
-
-
-def test_collective_not_armed_without_tracing():
-    Engine.init()
-    model = _lenet()
-    ds = DataSet.array(_samples()).transform(
-        SampleToMiniBatch(32, drop_last=True))
-    opt = (Optimizer(model, ds, nn.ClassNLLCriterion())
-           .set_optim_method(Adam(1e-3))
-           .set_end_when(Trigger.max_iteration(2)))
-    opt.optimize()
-    assert opt._collective_s is None
-
-
 def test_step_knobs_recorded(monkeypatch):
-    """_build_step records the knobs it was traced with — bench embeds
-    them in the per-config record for MFU attribution."""
+    """_build_step records the knobs it was traced with; the compile card
+    carries them."""
     monkeypatch.setenv("BIGDL_TPU_FUSED_UPDATE", "1")
     monkeypatch.setenv("BIGDL_TPU_WIRE_BUCKET_MB", "4")
     Engine.init(devices=[jax.devices()[0]])
@@ -440,63 +374,3 @@ def test_step_knobs_recorded(monkeypatch):
     opt._build_step(Engine.mesh())
     assert opt._step_knobs == {"fused_update": True, "wire_bucket_mb": 4.0,
                                "donate": True}
-
-
-def test_collective_counter_verified_against_probe(tmp_path, monkeypatch):
-    """Collective-overlap VERIFICATION (ISSUE 13 satellite of the PR 7
-    flags): the emitted train.collective_s / collective_fraction
-    counters on a multi-axis (2,2,1) layout mesh must be internally
-    consistent (fraction == min(1, collective_s/step_s) of the same
-    sample, modulo the trace's 1e-6 arg rounding) AND agree with an
-    independent wire.measure_collective_seconds probe over the same
-    data x fsdp axes — the counter is a checked claim, not a hope."""
-    import json as _json
-    import os
-
-    from bigdl_tpu.common import get_policy
-    from bigdl_tpu.parallel import LayoutSharding, MeshLayout
-
-    monkeypatch.setenv("BIGDL_TPU_TRACE", str(tmp_path))
-    monkeypatch.setenv("BIGDL_TPU_WIRE_BUCKET_MB", "0.25")
-    set_seed(11)
-    model = nn.Sequential(nn.Linear(64, 64, with_bias=False), nn.ReLU(),
-                          nn.Linear(64, 8, with_bias=False))
-    rng = np.random.default_rng(3)
-    xs = rng.normal(0.0, 1.0, size=(96, 64)).astype(np.float32)
-    ys = rng.integers(0, 8, size=96)
-    ds = DataSet.array(
-        [Sample(x, np.int32(y)) for x, y in zip(xs, ys)]).transform(
-        SampleToMiniBatch(32, drop_last=True))
-    Engine.reset()
-    mesh = MeshLayout(2, 2, 1).install(jax.devices()[:4])
-    opt = (Optimizer(model, ds, nn.CrossEntropyCriterion(),
-                     strategy=LayoutSharding(model, min_size=0))
-           .set_optim_method(SGD(learning_rate=0.05))
-           .set_end_when(Trigger.max_iteration(3))
-           .set_log_interval(1))
-    opt.optimize()
-
-    samples = []
-    for name in os.listdir(tmp_path):
-        if not name.startswith("trace."):
-            continue
-        blob = _json.loads((tmp_path / name).read_text())
-        for ev in blob.get("traceEvents", []):
-            if ev.get("ph") == "C" and ev.get("name") == "train":
-                a = ev.get("args", {})
-                if "collective_s" in a and "step_s" in a:
-                    samples.append((a["collective_s"],
-                                    a["collective_fraction"], a["step_s"]))
-    assert samples, "no collective samples on the train counter track"
-    for cs, frac, ss in samples:
-        assert cs > 0  # 4-device data x fsdp axes: a real reduce
-        expect = min(1.0, cs / max(ss, 1e-9))
-        assert abs(frac - expect) <= 0.02 * expect + 1e-5
-    # the armed value vs an independent probe of the SAME reduce
-    probe = wire_mod.measure_collective_seconds(
-        mesh, model.params, get_policy().wire_dtype, bucket_mb=0.25,
-        axis=("data", "fsdp"))
-    assert probe > 0
-    ratio = samples[0][0] / probe
-    assert 0.02 <= ratio <= 50.0, \
-        f"armed collective_s {samples[0][0]} vs probe {probe}"

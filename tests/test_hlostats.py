@@ -14,6 +14,7 @@ the committed PERF_BASELINE.json.
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -40,8 +41,8 @@ def _reset_hlostats():
 
 
 def _build_lenet_step(batch_size=16):
-    """The real compiled train step on device 0 (tools/lenet_cold.py
-    pattern); fresh Optimizer so env knobs re-bake."""
+    """The real compiled train step on device 0; fresh Optimizer so env
+    knobs re-bake."""
     from bigdl_tpu.models.lenet import LeNet5
     from bigdl_tpu.optim import Optimizer, SGD, Trigger
 
@@ -346,17 +347,18 @@ def test_trace_report_empty_dir_names_path(tmp_path):
 def test_trace_report_diff_cli(tmp_path):
     a, b = str(tmp_path / "a"), str(tmp_path / "b")
     _fake_trace(a, step_ms=(5.0, 5.0),
-                counters=[("train", {"mfu": 0.30})])
+                counters=[("train", {"records_per_sec": 0.30})])
     _fake_trace(b, step_ms=(10.0, 10.0),
-                counters=[("train", {"mfu": 0.15})])
+                counters=[("train", {"records_per_sec": 0.15})])
     proc = _run_cli([a, "--diff", b])
     assert proc.returncode == 0, proc.stderr
-    assert "B/A" in proc.stdout and "train.mfu" in proc.stdout
+    assert "B/A" in proc.stdout and "train.records_per_sec" in proc.stdout
     blob = json.loads(_run_cli([a, "--diff", b, "--json"]).stdout)
     assert blob["phases"]["step"]["total_ratio"] == pytest.approx(2.0,
                                                                   rel=0.05)
-    assert blob["counters"]["train.mfu"]["last"] == [0.3, 0.15]
-    assert blob["counters"]["train.mfu"]["delta"] == pytest.approx(-0.15)
+    assert blob["counters"]["train.records_per_sec"]["last"] == [0.3, 0.15]
+    assert blob["counters"]["train.records_per_sec"]["delta"] == pytest.approx(
+        -0.15)
 
 
 def test_diff_breakdowns_only_in_one_run():
@@ -408,35 +410,55 @@ def test_perf_gate_check_logic():
     baseline = {"metrics": {
         "conv_ops": {"value": 0, "match": "exact"},
         "ratio": {"value": 1.25, "match": "max"},
+        "over": {"value": 0.5, "match": "max"},
         "floor": {"value": 2, "match": "min"},
         "unmeasured": {"value": 1, "match": "exact"}}}
-    measured = {"conv_ops": 5, "ratio": 1.0, "floor": 3, "extra_new": 7}
+    measured = {"conv_ops": 5, "ratio": 1.0, "over": 0.51, "floor": 3,
+                "extra_new": 7}
     rows, regressions = gate.check(measured, baseline)
-    assert regressions == ["conv_ops", "unmeasured"]
+    # a count drifts, a ratio passes its bound, a kind the gate does not
+    # know (a floor is a rate's kind: there is none) and a missing reading
+    assert regressions == ["conv_ops", "floor", "over", "unmeasured"]
     by_name = {r[0]: r[3] for r in rows}
     assert by_name["conv_ops"].startswith("REGRESSED")
     assert by_name["ratio"] == "OK"
-    assert by_name["floor"] == "OK"
+    assert by_name["over"] == "REGRESSED (<= 0.5)"
+    assert "unknown match kind" in by_name["floor"]
     assert by_name["extra_new"].startswith("NEW")
     assert by_name["unmeasured"].startswith("MISSING")
-    # time slack widens max bounds only
-    _, regressions = gate.check({"conv_ops": 0, "ratio": 2.0, "floor": 2,
-                                 "unmeasured": 1}, baseline, time_slack=2.0)
-    assert regressions == []
 
 
-def test_perf_gate_baseline_committed_and_wellformed():
+def _baseline_rows():
     path = os.path.join(_REPO_ROOT, "PERF_BASELINE.json")
     assert os.path.exists(path), "PERF_BASELINE.json must be committed"
     blob = json.load(open(path))
     assert blob["format"] == "bigdl_tpu-perf-baseline-v1"
-    m = blob["metrics"]
+    return blob["metrics"]
+
+
+def test_perf_gate_baseline_committed_and_wellformed():
+    m = _baseline_rows()
     assert m["lenet_matmul.conv_ops"] == {"value": 0, "match": "exact"}
     assert m["wire.upcasts"]["value"] == m["wire.buckets"]["value"]
     assert m["wire.buckets"]["value"] < m["wire.leaves"]["value"]
     assert m["fused.buffers"]["value"] == 1
-    for name in ("conv_route.step_ratio", "aot.warm_over_cold"):
-        assert m[name]["match"] == "max"
+    assert set(_gate_mod().DEFAULT_RATIO_BOUNDS) == {
+        n for n, row in m.items() if row["match"] == "max"}
+
+
+def test_perf_gate_rows_are_counts():
+    """The gate runs on the CPU, so no row is a time, a rate or a ratio of
+    times: a row is an exact count, or a ratio of bytes or of schedule
+    slots bounded from above, and none carries a slack of its own."""
+    time_like = re.compile(r"(_s|_ms|_us|_seconds|per_s|_over_cold|"
+                           r"_over_full|step_ratio|traced_ratio)$")
+    for name, row in _baseline_rows().items():
+        assert set(row) <= {"value", "match", "note"}, name
+        assert not time_like.search(name), name
+        if row["match"] == "max":
+            assert name.endswith(("bubble_fraction", "bytes_ratio")), name
+        else:
+            assert row["match"] == "exact", name
 
 
 def test_perf_gate_cli_passes_on_clean_head():
@@ -454,37 +476,3 @@ def test_perf_gate_cli_passes_on_clean_head():
     blob = json.loads(proc.stdout.splitlines()[-1])
     assert blob["ok"] is True and blob["regressions"] == []
     assert blob["measured"]["lenet_matmul.conv_ops"] == 0
-
-
-# ----------------------------------------------------------------------
-# bench artifact-proofing
-# ----------------------------------------------------------------------
-
-def test_bench_partial_and_error_records(tmp_path, monkeypatch):
-    """_fail leaves BOTH artifacts: the final error record at --out and
-    the partial record with env + traceback (the flaky-backend evidence
-    contract) — exercised in-process, no subprocess bench run."""
-    sys.path.insert(0, _REPO_ROOT)
-    import bench
-    out = str(tmp_path / "round.json")
-    monkeypatch.setitem(bench._OUT_STATE, "path", out)
-    monkeypatch.setenv("BIGDL_TPU_TEST_MARKER_KNOB", "1")
-    bench._STALL_STATE["results"].clear()
-    bench._flush_partial("init")
-    p = json.load(open(out + ".partial.json"))
-    assert p["metric"] == "bench_partial" and p["stage"] == "init"
-    assert p["env"]["BIGDL_TPU_TEST_MARKER_KNOB"] == "1"
-    # an exception with a traceback lands in both records
-    monkeypatch.setattr(bench, "_claim_emit", lambda: True)
-    monkeypatch.setattr(bench.os, "_exit", lambda code: None)
-    try:
-        raise TimeoutError("jax.devices() did not return within 5s")
-    except TimeoutError as e:
-        bench._fail(e, "init")
-    f = json.load(open(out))
-    assert f["metric"] == "bench_error" and f["stage"] == "init"
-    assert "TimeoutError" in f["traceback"]
-    assert "jax.devices" in f["error"]
-    p = json.load(open(out + ".partial.json"))
-    assert p["error_type"] == "TimeoutError"
-    bench._EMIT_DONE.clear()  # module-global: leave it how we found it

@@ -364,7 +364,7 @@ class TestDonation:
         """BIGDL_TPU_NO_DONATE=1 keeps the inputs alive — and therefore
         holds TWO params+slots copies after the step, which is the peak
         memory donation removes (measured via the live-buffer sum, the
-        CPU fallback bench.py records)."""
+        CPU fallback of utils/memstats)."""
         step, args, opt = self._built_step(monkeypatch, no_donate=True)
         assert opt._step_knobs["donate"] is False
         before = memstats.live_device_bytes()

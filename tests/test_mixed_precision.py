@@ -1,8 +1,8 @@
 """bf16 mixed-precision coverage (DTypePolicy compute_dtype=bfloat16).
 
-The MFU-target bench config trains ResNet-50 under this policy
-(bench.py::_cfg_resnet50_bf16) but no test exercised it — a dtype bug in
-any layer's compute path would only surface on the real chip.  Contract
+The benchmark's `resnet50.train` cell trains ResNet-50 under this policy
+— a dtype bug in any layer's compute path would otherwise only surface
+on the real chip.  Contract
 under test: params stay f32, forward/backward run, values agree with the
 f32 path within bf16 tolerance, and end-to-end training converges.
 """
@@ -88,14 +88,19 @@ def test_bf16_forward_backward_matches_f32(name, build, shape, kind):
 
 
 def test_bf16_training_converges():
-    """End-to-end: the bench's mixed-precision configuration (f32 params,
-    bf16 compute, bf16 wire) trains to high accuracy."""
+    """End-to-end: the mixed-precision configuration of `resnet50.train`
+    (f32 params, bf16 compute, bf16 wire) trains to high accuracy.  Seeded
+    here: sixteen Adam steps reach 0.95 from most initialisations, not all
+    (1 of 48 seeds read 0.81), and the global stream's state is whatever
+    the worker's earlier files left."""
     from test_e2e_lenet import synthetic_mnist
+    from bigdl_tpu.common import set_seed
     from bigdl_tpu.models.lenet import LeNet5
     from bigdl_tpu.optim import Adam, Evaluator, Optimizer, Top1Accuracy, \
         Trigger
     from bigdl_tpu.utils.engine import Engine
 
+    set_seed(0)
     set_policy(DTypePolicy(compute_dtype=jnp.bfloat16))
     Engine.reset()
     Engine.init()

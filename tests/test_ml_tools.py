@@ -300,22 +300,3 @@ def test_cli_transformer_synthetic_smoke():
         assert opt.optim_method.hyper["neval"] > 3
     finally:
         sys.argv = argv_save
-
-
-def test_serving_bench_tool_smoke(capsys):
-    """tools/serving_bench runs all three decode paths and emits one JSON
-    line (bench.py conventions); ratios are hardware-dependent so only the
-    contract is asserted here."""
-    import json
-
-    from bigdl_tpu.tools.serving_bench import main
-
-    out = main(["--d-model", "32", "--num-heads", "4", "--num-layers", "1",
-                "--vocab", "64", "--max-len", "16", "--batch", "1",
-                "--num-tokens", "4"])
-    assert {r["path"] for r in out["results"]} == \
-        {"full_fwd", "kv_cache", "kv_int8"}
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    parsed = json.loads(line)
-    assert parsed["metric"] == "serving_decode_tokens_per_sec"
-    assert all(r["tokens_per_sec"] > 0 for r in parsed["results"])

@@ -608,24 +608,35 @@ class TestExpertParallel:
         assert np.isfinite(loss) and loss < 2.4  # descending from ln(12)
 
 
-def test_attn_impl_env_override(monkeypatch):
-    """BIGDL_TPU_ATTN_IMPL forces the dispatch; both paths agree (the
-    flash-vs-XLA race is measured on hardware, so the default must stay
-    overridable — and plugin platform names must not silently reroute)."""
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_attention_path_follows_the_backend(monkeypatch, backend):
+    """use_pallas=None lets the backend decide: the Pallas kernel on a
+    TPU, `mha_reference` elsewhere; an explicit use_pallas= wins, and both
+    paths agree.  The backend is faked here; on a chip chip_smoke.py looks
+    for `tpu_custom_call` in the compiled LM step."""
     import numpy as np
     import jax
 
-    from bigdl_tpu.ops.attention import flash_attention
+    from bigdl_tpu.ops import attention
 
     q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 16, 8))
     k = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 16, 8))
     v = jax.random.normal(jax.random.PRNGKey(2), (1, 2, 16, 8))
-    monkeypatch.setenv("BIGDL_TPU_ATTN_IMPL", "jnp")
-    o_jnp = flash_attention(q, k, v, causal=True)
-    monkeypatch.setenv("BIGDL_TPU_ATTN_IMPL", "pallas")
-    o_pl = flash_attention(q, k, v, causal=True, interpret=True)
+    o_jnp = attention.flash_attention(q, k, v, causal=True, use_pallas=False)
+    o_pl = attention.flash_attention(q, k, v, causal=True, use_pallas=True,
+                                     interpret=True)
     np.testing.assert_allclose(np.asarray(o_jnp), np.asarray(o_pl),
                                rtol=1e-4, atol=1e-4)
-    monkeypatch.setenv("BIGDL_TPU_ATTN_IMPL", "xla")
-    with pytest.raises(ValueError, match="ATTN_IMPL"):
-        flash_attention(q, k, v, causal=True)
+
+    taken = []
+    for name in ("mha_reference", "_flash_diff"):
+        def spy(*a, _name=name, _real=getattr(attention, name), **kw):
+            taken.append(_name)
+            return _real(*a, **kw)
+        monkeypatch.setattr(attention, name, spy)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    out = attention.flash_attention(q, k, v, causal=True, interpret=True)
+    want, same = {"cpu": ("mha_reference", o_jnp),
+                  "tpu": ("_flash_diff", o_pl)}[backend]
+    assert taken == [want]
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(same))

@@ -267,7 +267,7 @@ class TestPipelineTraining:
         compile does not — utils/aot.cached_compile).
 
         The XLA persistent cache is un-latched for the duration (the
-        test_serve/lenet_cold attribution discipline): an executable
+        test_serve attribution discipline): an executable
         XLA read back from its disk cache is not stored by the AOT layer,
         so with that cache warm this ledger would show a miss."""
         from jax._src import compilation_cache as _cc

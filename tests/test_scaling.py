@@ -1,6 +1,6 @@
 """Scaling-evidence tooling (bigdl_tpu/tools/scaling.py): the compiled
 distributed train step must contain real XLA collectives, and the HLO
-introspection that bench.py / dryrun_multichip rely on must find them.
+introspection that dryrun_multichip relies on must find them.
 """
 
 import numpy as np

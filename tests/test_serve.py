@@ -516,49 +516,6 @@ def test_http_front_end_roundtrip():
         server.stop()
 
 
-# ----------------------------------------------------------- bench mode
-
-
-def test_bench_serve_mode_record():
-    """bench.py --serve produces the serving record (closed+open loop +
-    bursty traffic storm, percentiles, shed accounting by priority
-    class) — tiny config on the test mesh."""
-    import bench
-
-    Engine.init()
-
-    def builder():
-        return _linear_model(), np.zeros((4,), np.float32)
-
-    rec = bench._serve_bench(clients=3, requests=18, model_builder=builder)
-    assert rec["metric"] == "serve_requests_per_sec"
-    assert rec["value"] > 0
-    closed, open_loop = rec["closed_loop"], rec["open_loop"]
-    assert closed["requests"] == 18 and not closed["errors"]
-    assert closed["batches"] < closed["requests"]  # coalescing in bench
-    for k in ("p50_ms", "p95_ms", "p99_ms"):
-        assert closed[k] is not None
-    assert 0.0 <= open_loop["shed_rate"] <= 1.0
-    # real failures get their OWN bucket (never lumped into shed) and
-    # the four buckets partition the offered load exactly
-    assert open_loop["errors"] == 0
-    assert open_loop["served"] + open_loop["shed_overload"] + \
-        open_loop["shed_timeout"] + open_loop["errors"] == \
-        open_loop["offered"]
-    # traffic storm: bursty load over three priority classes, shed rate
-    # reported per class (the priority-aware-admission measurement)
-    storm = rec["storm"]
-    assert set(storm["by_priority"]) == {"0", "1", "2"}
-    assert storm["offered"] == sum(v["offered"] for v in
-                                   storm["by_priority"].values())
-    assert 0.0 <= storm["shed_rate"] <= 1.0
-    assert storm["errors"] == 0
-    for v in storm["by_priority"].values():
-        assert v["offered"] == (v["served"] + v["shed_overload"] +
-                                v["shed_timeout"] + v["errors"])
-        assert 0.0 <= v["shed_rate"] <= 1.0
-
-
 # ------------------------------------------- restart x AOT warm start
 
 
@@ -569,8 +526,7 @@ def test_replica_restart_rewarms_ladder_from_aot_cache(tmp_path,
     zero misses, zero XLA compiles (pure cache reads), asserted via the
     stats()["aot"] ledger — restart is seconds, not a cold compile.
 
-    The XLA persistent cache is un-latched for the duration (same
-    attribution discipline as tools/lenet_cold.py --aot-cache): an
+    The XLA persistent cache is un-latched for the duration: an
     executable that XLA read back from its disk cache is not stored by the
     AOT layer (utils/aot._compile_timed), so with that cache warm the
     zero-fresh-lowers ledger would show misses."""

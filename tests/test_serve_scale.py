@@ -730,41 +730,12 @@ def test_http_autoscale_stats_retry_after_503_and_trace_header(tmp_path):
         server.stop()
 
 
-# ------------------------------------------------------- bench replay
+# ------------------------------------------- the autoscale report section
 
 
-def test_bench_replay_mode_record(tmp_path):
-    """bench.py --serve --replay: per-tenant SLO attainment beside
-    percentiles and shed-by-cause, from a trace file, with the
-    fixed-pool comparison record."""
-    import bench
-
-    Engine.init()
-    path = str(tmp_path / "bench.rec")
-    xs = _rows(10)
-    events = [TraceEvent(0.02 if i else 0.0, xs[i % len(xs)],
-                         tenant=f"t{i % 2}", priority=i % 2,
-                         deadline_ms=500.0) for i in range(30)]
-    write_trace(path, events)
-
-    def builder():
-        return _linear_model(), np.zeros((4,), np.float32)
-
-    rec = bench._serve_replay_bench(trace_path=path, speed=10.0,
-                                    compare=True, autoscale_max=2,
-                                    model_builder=builder)
-    assert rec["metric"] == "serve_replay_slo_attainment"
-    assert rec["events"] == 30 and rec["speed"] == 10.0
-    rep = rec["replay"]
-    assert set(rep["per_tenant"]) == {"t0", "t1"}
-    assert set(rep["per_priority"]) == {"0", "1"}
-    assert rep["shed"].keys() == {"overload", "timeout", "errors"}
-    assert rep["offered"] == 30
-    assert rep["p50_ms"] is not None
-    assert rep["pool"]["autoscale_max"] == 2
-    assert "fixed" in rec and "attainment_gain" in rec
-    # telemetry promotion: the autoscale counter track becomes a report
-    # section like the aot ledger
+def test_autoscale_track_is_a_report_section():
+    """The autoscale counter track becomes a section of the trace report,
+    like the aot ledger."""
     from bigdl_tpu.utils import telemetry
     bd = telemetry.phase_breakdown({"traceEvents": [
         {"ph": "C", "name": "serve.autoscale", "ts": 1.0,

@@ -203,19 +203,15 @@ def test_traced_lenet_run_has_phase_spans_and_counters(tmp_path,
         assert bd["phases"][phase]["count"] >= 1, bd["phases"]
     assert bd["phases"]["step"]["count"] == 5
     assert 0.0 <= bd["data_wait_fraction"] <= 1.0
-    # per-step counter track: the four loop series plus the per-step MFU
-    # pair (armed because tracing is on — utils/flops.device_peak_flops
-    # always yields a denominator, nominal on CPU) and the gradient-wire
-    # collective pair (armed with it; the 8-device data axis has a real
-    # cross-device reduce to measure)
+    # per-step counter track: what the host observed, under names that
+    # claim nothing about the device (a pipelined model adds its
+    # schedule's pipe_bubble_fraction)
     ctr = [e for e in merged["traceEvents"]
            if e["ph"] == "C" and e["name"] == "train"]
     assert len(ctr) == 5
     assert set(ctr[0]["args"]) == {"data_wait_s", "step_s",
                                    "records_per_sec",
-                                   "prefetch_queue_depth",
-                                   "mfu", "model_flops_per_step",
-                                   "collective_s", "collective_fraction"}
+                                   "prefetch_queue_depth"}
     # the prefetch worker produced on its own named thread track
     spans = [e for e in merged["traceEvents"]
              if e["ph"] == "X" and e["name"] == "prefetch.item"]

@@ -1,12 +1,12 @@
 #!/usr/bin/env python
 """Merge multi-rank run traces and print the phase breakdown.
 
-Every traced process (``BIGDL_TPU_TRACE=<dir>`` or ``bench.py --trace``)
+Every traced process (``BIGDL_TPU_TRACE=<dir>``)
 writes ``trace.<rank>.json`` (Chrome trace-event JSON,
 ``bigdl_tpu.utils.telemetry``).  This tool merges all ranks onto one
 wall-clock timeline and prints the diagnosis a TensorBoard-less operator
 needs: per-phase p50/p95/max, the ``data_wait_fraction`` (input-bound vs
-compute-bound — same definition as bench.py's e2e stage), straggler
+compute-bound), straggler
 ranks (one slow host's ``step`` spans stand out against the median),
 counter-track series in deterministic (sorted) order — including the
 ``compile`` track compile cards emit (utils/hlostats.py) — and, when the
