@@ -20,8 +20,10 @@ types raise rather than silently mis-decode.
 
 What is kept between steps is each layer's own declaration
 (``Module.decode_state``: leaves, each leaf's length axis, its layout
-role): ``MultiHeadAttention`` keeps ``{k, v}`` of ``[rows, H, L, D]``,
-``LatentAttention`` ``{c_kv, k_rope}`` of ``[rows, L, width]``,
+role): ``MultiHeadAttention`` keeps ``{k, v}`` of ``[rows, L, H * D]``,
+``LatentAttention`` ``{c_kv, k_rope}`` of ``[rows, L, width]`` (in both a
+position of a row is one whole minor row, and a step writes its S new rows
+in place under the donation, one scatter a leaf),
 ``PositionalEmbedding`` nothing but needs the position.  ``init_kv_cache``
 asks the layers, and ``decode_walk`` is the one walk the serving engine's
 two programs (prefill, step) share: it hands every declaring layer its
@@ -116,12 +118,12 @@ def init_kv_cache(model, batch: int, max_len: int, dtype=jnp.float32,
                   mesh=None):
     """Zeroed decode state for ``batch`` rows of ``max_len`` positions: one
     dict of buffers for each layer that declares some
-    (``Module.decode_state``), e.g. ``{k, v}`` of [B, H, max_len, D] for
+    (``Module.decode_state``), e.g. ``{k, v}`` of [B, max_len, H * D] for
     every ``MultiHeadAttention``.
 
     ``mesh``: optional canonical layout mesh (parallel/layout
     ``build_mesh``) — each leaf is then placed through its declared role
-    (``kv_cache``: rows over data x fsdp, heads over tp), so a
+    (``kv_cache``: rows over data x fsdp, the heads' axis over tp), so a
     tp-sharded model decodes against caches that already match its
     column-parallel q/k/v kernels: each device holds exactly the 1/tp
     of the cache its heads produce, no per-step resharding."""
@@ -225,19 +227,24 @@ def _cached_attention(mha, params, x, cache, pos):
             "(MultiHeadAttention(causal=False) found)")
     B, _, E = x.shape
     H, D = mha.num_heads, mha.head_dim
-    split = lambda y: y.reshape(B, 1, H, D).transpose(0, 2, 1, 3)
-    q, k, v = (split(mha._proj(params, x, n)) for n in "qkv")
+    q = mha._proj(params, x, "q").reshape(B, 1, H, D).transpose(0, 2, 1, 3)
+    # the leaves are [B, L, E] (MultiHeadAttention.decode_state): every row
+    # writes the one position
     ck = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache["k"].dtype), (0, 0, pos, 0))
+        cache["k"], mha._proj(params, x, "k").astype(cache["k"].dtype),
+        (0, pos, 0))
     cv = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache["v"].dtype), (0, 0, pos, 0))
-    L = ck.shape[2]
-    scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
-                        ck.astype(jnp.float32)) / (D ** 0.5)
+        cache["v"], mha._proj(params, x, "v").astype(cache["v"].dtype),
+        (0, pos, 0))
+    L = ck.shape[1]
+    scores = jnp.einsum("bhqd,blhd->bhql", q.astype(jnp.float32),
+                        ck.reshape(B, L, H, D).astype(jnp.float32)) \
+        / (D ** 0.5)
     mask = jnp.arange(L)[None, None, None, :] <= pos
     scores = jnp.where(mask, scores, -jnp.inf)
     w = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bhql,bhld->bhqd", w, cv.astype(jnp.float32))
+    o = jnp.einsum("bhql,blhd->bhqd", w,
+                   cv.reshape(B, L, H, D).astype(jnp.float32))
     o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(B, 1, E)
     return mha._proj(params, o, "o"), {"k": ck, "v": cv}
 
@@ -423,7 +430,7 @@ def beam_generate(model, prompt, num_tokens: int, max_len: int,
         gather = (np.arange(B)[:, None] * beam_size + src).reshape(-1)
         if not np.array_equal(gather, np.arange(rows)):
             buf = buf[gather].copy()
-            # cache reorder is a full [rows, H, max_len, D] copy per layer —
+            # cache reorder is a full [rows, max_len, H * D] copy per layer —
             # skip when the permutation is the identity (always true for
             # beam_size=1) and on the final step, whose caches are unused
             if pos + 2 < t0 + num_tokens:

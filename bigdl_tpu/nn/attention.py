@@ -36,6 +36,16 @@ from .rotary import apply_rope, rope_angles, rope_inv_freq, yarn_mscale
 __all__ = ["MultiHeadAttention", "LatentAttention"]
 
 
+def _write_rows(cache, pos, new):
+    """How a decode step puts what it has computed into its donated state:
+    ``cache [S, L, width]`` with ``new [S, width]`` at position ``pos[s]``
+    of row ``s``, one scatter of S whole minor rows (XLA writes them in
+    place; a window with an axis *before* the position, as ``[H, 1, D]``
+    into ``[S, H, L, D]``, it expands into a loop of S passes instead)."""
+    return cache.at[jnp.arange(cache.shape[0]), pos].set(
+        new.astype(cache.dtype))
+
+
 class MultiHeadAttention(Module):
     """Self-attention over [B, T, E] inputs."""
 
@@ -134,11 +144,13 @@ class MultiHeadAttention(Module):
                 "(MultiHeadAttention(causal=False) found)")
 
     def decode_state(self, rows: int, length: int):
-        """A key and a value for every head and position:
-        ``[rows, H, length, D]`` each."""
-        shape = (rows, self.num_heads, length, self.head_dim)
-        return {"k": StateLeaf(shape, 2, "kv_cache"),
-                "v": StateLeaf(shape, 2, "kv_cache")}
+        """A key and a value for every position, the heads side by side as
+        the projections give them: ``[rows, length, H * D]`` each.  A
+        position of a row is then one whole minor row of the leaf, which a
+        step can write in place."""
+        shape = (rows, length, self.embed_dim)
+        return {"k": StateLeaf(shape, 1, "kv_cache"),
+                "v": StateLeaf(shape, 1, "kv_cache")}
 
     def decode_prefill(self, params, x, cache, slot, length):
         """x: [1, P, E], a whole prompt from position 0 entering the fresh
@@ -148,16 +160,18 @@ class MultiHeadAttention(Module):
 
         The prompt attends causally over itself with `decode_step`'s
         float32 score path and exact-zero masked weights; k and v of all P
-        positions go into the cache by one write each."""
+        positions go into the cache, ``[1, P, E]`` at ``(slot, 0, 0)``, by
+        one write each."""
         self._require_causal()
         _, P, E = x.shape
         H, D = self.num_heads, self.head_dim
-        split = lambda y: y.reshape(1, P, H, D).transpose(0, 2, 1, 3)
-        q, k, v = (split(self._proj(params, x, n)) for n in "qkv")
+        q, k, v = (self._proj(params, x, n) for n in "qkv")     # [1, P, E]
         # attend over what the cache will hold: k and v in the cache's dtype
         k, v = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
-        ck = jax.lax.dynamic_update_slice(cache["k"], k, (slot, 0, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], v, (slot, 0, 0, 0))
+        ck = jax.lax.dynamic_update_slice(cache["k"], k, (slot, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cache["v"], v, (slot, 0, 0))
+        split = lambda y: y.reshape(1, P, H, D).transpose(0, 2, 1, 3)
+        q, k, v = split(q), split(k), split(v)
         scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
                             k.astype(jnp.float32)) / (D ** 0.5)
         mask = jnp.arange(P)[None, :] <= jnp.arange(P)[:, None]
@@ -169,29 +183,30 @@ class MultiHeadAttention(Module):
 
     def decode_step(self, params, x, cache, pos):
         """x: [S, 1, E], pos: [S] int32, every row at its own position;
-        returns ([S, 1, E], new_cache)."""
+        returns ([S, 1, E], new_cache).  Each row's key and value land at
+        its position by one scatter of S whole minor rows a leaf
+        (`_write_rows`: in place under the step's donation), not by a pass
+        over the cache or a loop over the rows."""
         self._require_causal()
         pos = jnp.maximum(pos, 0)                 # an idle row: position 0
         S, _, E = x.shape
         H, D = self.num_heads, self.head_dim
-        split = lambda y: y.reshape(S, 1, H, D).transpose(0, 2, 1, 3)
-        q, k, v = (split(self._proj(params, x, n)) for n in "qkv")
-
-        def upd(c, u, p):  # c: [H, L, D], u: [H, 1, D], p: scalar
-            return jax.lax.dynamic_update_slice(c, u, (0, p, 0))
-
-        ck = jax.vmap(upd)(cache["k"], k.astype(cache["k"].dtype), pos)
-        cv = jax.vmap(upd)(cache["v"], v.astype(cache["v"].dtype), pos)
-        L = ck.shape[2]
-        scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
-                            ck.astype(jnp.float32)) / (D ** 0.5)
+        q = self._proj(params, x, "q").reshape(S, 1, H, D) \
+            .transpose(0, 2, 1, 3)
+        ck, cv = (_write_rows(cache[n], pos, self._proj(params, x, n)[:, 0])
+                  for n in "kv")
+        L = ck.shape[1]
+        scores = jnp.einsum("bhqd,blhd->bhql", q.astype(jnp.float32),
+                            ck.reshape(S, L, H, D).astype(jnp.float32)) \
+            / (D ** 0.5)
         # per-row causal horizon; positions past a row's pos get EXACT
         # zero softmax weight (exp(-inf)), so stale cache rows from a
         # previous occupant of the slot contribute exactly nothing
         mask = jnp.arange(L)[None, None, None, :] <= pos[:, None, None, None]
         scores = jnp.where(mask, scores, -jnp.inf)
         w = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhql,bhld->bhqd", w, cv.astype(jnp.float32))
+        o = jnp.einsum("bhql,blhd->bhqd", w,
+                       cv.reshape(S, L, H, D).astype(jnp.float32))
         o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(S, 1, E)
         return self._proj(params, o, "o"), {"k": ck, "v": cv}
 
@@ -389,18 +404,15 @@ class LatentAttention(Module):
     def decode_step(self, params, x, cache, pos):
         """x: [S, 1, hidden], pos: [S]: the absorbed form.  Each row's
         ``c_kv`` and ``k_rope`` land at its own position by one scatter of S
-        rows (in place under the step's donation), not by a pass over the
-        cache; scores and the weighted sum take compute-dtype operands from
-        the cache as it is, with float32 accumulation."""
+        rows (`_write_rows`, in place under the step's donation), not by a
+        pass over the cache; scores and the weighted sum take compute-dtype
+        operands from the cache as it is, with float32 accumulation."""
         c = get_policy().compute_dtype
         S = x.shape[0]
         pos = jnp.maximum(pos, 0)                 # an idle row: position 0
         q_nope, q_rope, c_kv, k_rope = self._project(params, x[:, 0], pos)
-        rows = jnp.arange(S)
-        cc = cache["c_kv"].at[rows, pos].set(
-            c_kv.astype(cache["c_kv"].dtype))
-        ck = cache["k_rope"].at[rows, pos].set(
-            k_rope.astype(cache["k_rope"].dtype))
+        cc = _write_rows(cache["c_kv"], pos, c_kv)
+        ck = _write_rows(cache["k_rope"], pos, k_rope)
         up = self._up(params).astype(c)
         q_lat = jnp.einsum("shn,chn->shc", q_nope.astype(c),
                            up[..., :self.nope],

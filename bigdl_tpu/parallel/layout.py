@@ -115,8 +115,9 @@ ROLES: Dict[str, Tuple[Optional[int], Optional[int]]] = {
     # way embedding_row shards LookupTable rows, with an fsdp fallback on
     # the remaining axes (parallel/expert.MoEFFN; _spec_for special case)
     "expert_table": (None, None),
-    # decode KV caches [slots, heads, cache_len, head_dim]: slots shard
-    # over data x fsdp like batch rows, heads over tp to match the
+    # decode KV caches [slots, cache_len, heads x head_dim]: slots shard
+    # over data x fsdp like batch rows, the last axis (the heads side by
+    # side, as the k/v projections give them) over tp to match the
     # column-parallel q/k/v kernels (models/decode.py, serve/decode.py;
     # _spec_for special case — never min_size-gated: a cache that stops
     # matching its attention kernels' sharding forces a resharding
@@ -294,11 +295,12 @@ class MeshLayout:
                         break
             return P(*parts)
         if role in ("kv_cache", "latent_cache") and ndim >= 2:
-            # [slots, heads, cache_len, head_dim]: slots ride the batch
-            # axes (data x fsdp, degrading like embedding_row when the
-            # slot count does not divide the product), heads ride tp so
-            # each device holds exactly the cache rows its column-
-            # parallel attention heads produce.  No min_size gate.
+            # [slots, cache_len, width]: slots ride the batch axes (data
+            # x fsdp, degrading like embedding_row when the slot count
+            # does not divide the product); a kv_cache's width is its
+            # heads side by side and rides tp, so each device holds
+            # exactly the columns its column-parallel k/v kernels
+            # produce.  No min_size gate.
             if self.data * self.fsdp > 1:
                 if shape[0] % (self.data * self.fsdp) == 0:
                     parts[0] = (DATA_AXIS, FSDP_AXIS)
@@ -307,8 +309,8 @@ class MeshLayout:
                 elif self.fsdp > 1 and shape[0] % self.fsdp == 0:
                     parts[0] = FSDP_AXIS
             if role == "kv_cache" and self.tp > 1 \
-                    and shape[1] % self.tp == 0:
-                parts[1] = TP_AXIS
+                    and shape[-1] % self.tp == 0:
+                parts[-1] = TP_AXIS
             return P(*parts)
         if role == "embedding_row" and ndim >= 1:
             # rows over fsdp x tp together — folding 'expert' in too when

@@ -180,18 +180,15 @@ def test_fused_conv_bn_forward_and_grad_compile(one_chip, mkn):
         jax.grad(loss, argnums=(0, 1, 2, 3)), x, w, g, g)
 
 
-# ------------------------------------------- the latent-cache decode step
+# ------------------------------------------------ the cells' decode steps
 
-def test_latent_decode_step_compiles_at_the_cells_sizes(one_chip):
-    """`dsv2.decode`'s step at its real sizes (64 slots, a 4,608-long latent
-    cache, 40 held experts a layer): it fits one chip beside its 9.3 GB of
-    weights, the grouped expert product is the compiler's own ragged dot,
-    and each layer's cache is written in place (a scatter under the
-    donation), not copied."""
+def _compile_decode_step(workload, one_chip):
+    """A decode cell's step at its real sizes, donated state and all, as
+    `DecodeEngine` builds it; returns (compiled, cfg, bytes of the state)."""
     from bigdl_tpu.common import get_policy, set_policy
     from bigdl_tpu.models import decode as kv
     from benchmark import harness
-    cell = harness.Cell("dsv2.decode")
+    cell = harness.Cell(workload)
     cm, cfg, tr = cell.cfg_mod, cell.cfg, cell.traffic
     prior = get_policy()
     try:
@@ -209,11 +206,38 @@ def test_latent_decode_step_compiles_at_the_cells_sizes(one_chip):
                                        ivec).compile()
     finally:
         set_policy(prior)
-    text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert text.count("ragged-dot") >= 4 * 3          # 4 layers x 3 products
     cache_bytes = sum(int(np.prod(a.shape)) * 2 for c in caches
                       for a in c.values())
+    return compiled, cfg, cache_bytes
+
+
+def test_latent_decode_step_compiles_at_the_cells_sizes(one_chip):
+    """`dsv2.decode`'s step at its real sizes (64 slots, a 4,608-long latent
+    cache, 40 held experts a layer): it fits one chip beside its 9.3 GB of
+    weights, the grouped expert product is the compiler's own ragged dot,
+    and each layer's cache is written in place (a scatter under the
+    donation), not copied."""
+    compiled, cfg, cache_bytes = _compile_decode_step("dsv2.decode", one_chip)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("ragged-dot") >= 4 * 3          # 4 layers x 3 products
     assert mem.alias_size_in_bytes >= cache_bytes     # donated, in place
     assert mem.temp_size_in_bytes < 1e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
     assert text.count(" scatter(") >= 2 * cfg["num_hidden_layers"]
+
+
+def test_kv_decode_step_compiles_at_the_cells_sizes(one_chip):
+    """`gpt2m.decode`'s step at its real sizes (32 slots, 24 layers of
+    `[32, 512, 1024]` bfloat16 keys and values): each leaf is written by one
+    scatter in place under the donation, and nothing of the write is a loop
+    over slots or a pass over a leaf (ISSUE 30: the vmapped
+    `dynamic_update_slice` compiled to 48 `while` loops of 32 trips)."""
+    compiled, cfg, cache_bytes = _compile_decode_step("gpt2m.decode",
+                                                      one_chip)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert cache_bytes == 2 * cfg["n_layer"] * 32 * 512 * cfg["n_embd"] * 2
+    assert mem.alias_size_in_bytes >= cache_bytes     # donated, in place
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert text.count(" scatter(") == 2 * cfg["n_layer"]
+    assert " while(" not in text
+    assert "dynamic-update-slice(" not in text

@@ -232,13 +232,13 @@ def test_prefill_logits_and_cache_match_the_per_position_oracle(lm, t0):
     for got, want in zip(caches, ref_caches):
         for n in "kv":
             arr = np.asarray(got[n])
-            np.testing.assert_allclose(arr[1, :, :t0],
-                                       np.asarray(want[n])[0, :, :t0],
+            np.testing.assert_allclose(arr[1, :t0],
+                                       np.asarray(want[n])[0, :t0],
                                        rtol=1e-5, atol=1e-5)
             # the pads' rows are written and finite, nothing beyond the
             # bucket is, and the other slots are untouched
             assert np.isfinite(arr).all()
-            assert not arr[1, :, 8:].any() and not arr[[0, 2]].any()
+            assert not arr[1, 8:].any() and not arr[[0, 2]].any()
 
 
 def test_prefill_program_has_no_loop_and_one_row_at_the_head(lm_odd,

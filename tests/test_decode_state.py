@@ -35,8 +35,8 @@ def _ds():
 def test_layers_declare_their_decode_state():
     mha = nn.MultiHeadAttention(32, 4, causal=True)
     assert mha.decode_state(3, 16) == {
-        "k": StateLeaf((3, 4, 16, 8), 2, "kv_cache"),
-        "v": StateLeaf((3, 4, 16, 8), 2, "kv_cache")}
+        "k": StateLeaf((3, 16, 32), 1, "kv_cache"),
+        "v": StateLeaf((3, 16, 32), 1, "kv_cache")}
     mla = nn.LatentAttention(32, 2, 16, 8, 8, 4, 8)
     assert mla.decode_state(3, 16) == {
         "c_kv": StateLeaf((3, 16, 8), 1, "latent_cache"),
@@ -102,7 +102,7 @@ def test_an_expert_layer_reads_which_tokens_are_real_off_the_interface():
     assert not hasattr(ep, "live_tokens") and not hasattr(kv, "live_tokens")
 
 
-@pytest.mark.parametrize("make,axes", [(_lm, {"k": 2, "v": 2}),
+@pytest.mark.parametrize("make,axes", [(_lm, {"k": 1, "v": 1}),
                                        (_ds, {"c_kv": 1, "k_rope": 1})])
 def test_cache_grows_along_each_leafs_own_length_axis(make, axes):
     m = make()
@@ -136,15 +136,187 @@ def test_both_state_kinds_are_placed_on_a_mesh():
             for n, a in c.items():
                 spec = a.sharding.spec
                 assert spec[0] == specs[n]
-                # heads over tp for {k, v}; a latent has no head axis and
-                # every tp share holds it whole
-                assert (spec[1] if len(spec) > 1 else None) == \
-                    ("tp" if n in "kv" else None)
+                # the heads' axis (the last) over tp for {k, v}; a latent
+                # has no head axis and every tp share holds it whole
+                assert [i for i, ax in enumerate(spec) if ax == "tp"] == \
+                    ([2] if n in "kv" else [])
     m = _ds()
     prompt = np.arange(1, 6, dtype=np.int32)
     want = cached_generate(m, prompt, 4, 16)
     with DecodeEngine(m, slots=2, page=16, max_len=16, mesh=mesh) as eng:
         np.testing.assert_array_equal(eng.generate(prompt, 4), want)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 30: the step writes each slot's new key and value rows in place
+# ---------------------------------------------------------------------------
+
+def _mha_step_inputs(length, dtype, seed=0):
+    """A tiny layer, a cache full of a previous occupant's values, and five
+    rows at their own positions: one in the middle, an idle one, one at
+    position 0, one at the last position, one more."""
+    mha = nn.MultiHeadAttention(32, 4, causal=True)
+    params, _ = mha.init(jax.random.key(seed))
+    ks = jax.random.split(jax.random.key(seed + 1), 3)
+    x = jax.random.normal(ks[0], (5, 1, 32))
+    shape = mha.decode_state(5, length)["k"].shape
+    cache = {"k": jax.random.normal(ks[1], shape).astype(dtype),
+             "v": jax.random.normal(ks[2], shape).astype(dtype)}
+    pos = np.array([3, -1, 0, length - 1, length // 2], np.int32)
+    return mha, params, x, cache, pos
+
+
+@pytest.mark.parametrize("length,dtype", [
+    (16, jnp.float32), (16, jnp.bfloat16), (200, jnp.float32),
+    (256, jnp.bfloat16)])
+def test_decode_step_writes_each_rows_own_position(length, dtype):
+    """Against a reference that writes row by row: the state differs from
+    the one handed in at position pos[s] of row s, all heads, and nowhere
+    else, bit for bit; the output is the oracle's (`_cached_attention`, one
+    row at its scalar position), bit for bit."""
+    mha, params, x, cache, pos = _mha_step_inputs(length, dtype)
+    y, new = mha.decode_step(params, x, cache, jnp.asarray(pos))
+    H, D = mha.num_heads, mha.head_dim
+    at = np.maximum(pos, 0)                      # an idle row: position 0
+    as32 = lambda a: np.array(a.astype(jnp.float32))
+    for n in "kv":
+        proj = as32(mha._proj(params, x, n).astype(dtype))     # [S, 1, E]
+        want = as32(cache[n])
+        for s in range(5):
+            want[s, at[s]] = proj[s, 0]
+        got = as32(new[n])
+        np.testing.assert_array_equal(got, want)
+        # what changed: S x H rows of D, each at its row's own position
+        # (a rounded value may happen to equal the one it replaces)
+        changed = (got != as32(cache[n])).reshape(5, length, H, D)
+        assert 0.9 * 5 * H * D < changed.sum() <= 5 * H * D
+        assert (changed.any(axis=-1).sum(axis=1) == 1).all()   # [S, H]
+    for s in range(5):
+        row = {n: cache[n][s:s + 1] for n in "kv"}
+        want_y, want_row = kv._cached_attention(mha, params, x[s:s + 1], row,
+                                                int(at[s]))
+        np.testing.assert_array_equal(np.asarray(y[s:s + 1]),
+                                      np.asarray(want_y))
+        for n in "kv":
+            np.testing.assert_array_equal(as32(new[n][s:s + 1]),
+                                          as32(want_row[n]))
+
+
+@pytest.mark.parametrize("length,dtype", [(16, jnp.float32),
+                                          (256, jnp.bfloat16)])
+def test_stale_rows_past_a_slots_position_weigh_exactly_nothing(length,
+                                                                dtype):
+    """What a previous occupant left beyond `pos` changes no bit of the
+    output, however large, and the step leaves it where it was."""
+    mha, params, x, cache, pos = _mha_step_inputs(length, dtype, seed=3)
+    beyond = (np.arange(length)[None, :]
+              > np.maximum(pos, 0)[:, None])[:, :, None]       # [S, L, 1]
+    clean = {n: jnp.where(beyond, 0, c) for n, c in cache.items()}
+    loud = {n: jnp.where(beyond, 2.0 ** 15, c).astype(dtype)
+            for n, c in cache.items()}
+    y_clean, _ = mha.decode_step(params, x, clean, jnp.asarray(pos))
+    y_loud, new = mha.decode_step(params, x, loud, jnp.asarray(pos))
+    np.testing.assert_array_equal(np.asarray(y_loud), np.asarray(y_clean))
+    stale = np.broadcast_to(beyond, new["k"].shape)
+    assert (np.asarray(new["k"].astype(jnp.float32))[stale] == 2.0 ** 15).all()
+
+
+def _leaf_writers(model, slots=3, length=16):
+    """For each leaf of the decode state the step returns: the equation of
+    the step's jaxpr that produces it, and whether that equation takes the
+    leaf handed in as its operand."""
+    caches = kv.cache_avals(model, slots, length, jnp.float32)
+    ivec = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda c, tok, pos: kv._slot_step(model, model.params, model.state,
+                                          tok, c, pos)[1])(caches, ivec,
+                                                           ivec).jaxpr
+    given = jaxpr.invars[:len(jax.tree.leaves(caches))]
+    by_out = {v: e for e in jaxpr.eqns for v in e.outvars}
+    return [(by_out[v], any(by_out[v].invars[0] is g for g in given))
+            for v in jaxpr.outvars]
+
+
+@pytest.mark.parametrize("make", [_lm, _ds])
+def test_each_leaf_is_written_once_and_by_whole_minor_rows(make):
+    """The structural guard: a leaf of the state comes straight out of one
+    scatter whose operand is the donated leaf, and the axes the update
+    spans all lie after the axes that place it (whole minor rows).  A window
+    with the heads *before* the position, ``[1, H, 1, D]`` into ``[S, H, L,
+    D]``, is what XLA expanded into a loop of S passes a leaf."""
+    writers = _leaf_writers(make())
+    assert len(writers) == 4
+    for eqn, takes_leaf in writers:
+        assert eqn.primitive.name == "scatter" and takes_leaf, eqn
+        dn = eqn.params["dimension_numbers"]
+        operand, updates = eqn.invars[0].aval, eqn.invars[2].aval
+        window_axes = [a for a in range(operand.ndim)
+                       if a not in dn.inserted_window_dims
+                       and a not in dn.operand_batching_dims]
+        spans = [a for a, d in zip(window_axes, dn.update_window_dims)
+                 if updates.shape[d] > 1]
+        places = set(range(operand.ndim)) - set(spans)
+        assert spans and min(spans) > max(places), dn
+
+
+@pytest.mark.parametrize("make", [_lm, _ds])
+def test_donated_state_comes_back_in_its_own_buffers(make, recwarn):
+    m = make()
+    step = jax.jit(lambda p, s, c, tok, pos: kv._slot_step(m, p, s, tok, c,
+                                                           pos),
+                   donate_argnums=(2,))
+    caches = tuple(init_kv_cache(m, 3, 16, jnp.float32))
+    before = [a.unsafe_buffer_pointer() for a in jax.tree.leaves(caches)]
+    tok = jnp.asarray([5, 6, 7], jnp.int32)
+    _, new, _ = step(m.params, m.state, caches, tok,
+                     jnp.asarray([2, -1, 15], jnp.int32))
+    jax.block_until_ready(new)
+    assert not [w for w in recwarn if "donated" in str(w.message)]
+    assert all(a.is_deleted() for a in jax.tree.leaves(caches))
+    assert [a.unsafe_buffer_pointer() for a in jax.tree.leaves(new)] == before
+
+
+@pytest.mark.parametrize("shape3", [(2, 1, 2), (2, 2, 2), (1, 2, 4)])
+def test_kv_cache_rides_the_mesh_heads_over_tp_and_grows_there(shape3):
+    """The declaration's last axis is the heads side by side: `kv_cache`
+    puts it over tp (whole heads a share) and the rows over data x fsdp,
+    and a cache grown along its length stays where it was."""
+    from jax.sharding import PartitionSpec as P
+    lay = MeshLayout(*shape3)
+    mesh = lay.build_mesh(jax.devices()[:int(np.prod(shape3))])
+    leaf = nn.MultiHeadAttention(32, 4, causal=True).decode_state(4, 8)["k"]
+    assert lay.spec_for(leaf.role, leaf.shape, min_size=0) == \
+        P(("data", "fsdp"), None, "tp")
+    m = _lm()
+    caches = tuple(init_kv_cache(m, 4, 8, jnp.float32, mesh=mesh))
+    marked = tuple({n: a + 1 for n, a in c.items()} for c in caches)
+    grown = kv.grow_cache(m, marked, 32, mesh)
+    for c in grown:
+        for a in c.values():
+            assert a.shape == (4, 32, 32)
+            assert tuple(a.sharding.spec) == (("data", "fsdp"), None, "tp")
+            assert {s.data.shape for s in a.addressable_shards} == {
+                (4 // (shape3[0] * shape3[1]), 32, 32 // shape3[2])}
+            old, new = np.split(np.asarray(a), [8], axis=1)
+            assert (old == 1).all() and (new == 0).all()
+
+
+def test_cached_generate_tokens_are_the_parents_on_a_fixed_seed():
+    """Tokens the tree before ISSUE 30 gave for this seed (its
+    `[rows, H, L, D]` leaves), greedy and beam, float32 and bfloat16 cache:
+    the layout of the state is no part of the result."""
+    from bigdl_tpu.models import beam_generate
+    lm = TransformerLM(vocab_size=64, max_len=64, d_model=32, num_heads=4,
+                       num_layers=2).build(jax.random.key(30))
+    prompt = np.array([[5, 9, 33, 2, 17], [40, 1, 1, 62, 8]], np.int32)
+    first = [5, 9, 33, 2, 17, 8, 63, 35, 53, 1, 22, 27, 19, 51, 14, 58, 56]
+    assert cached_generate(lm, prompt, 12, 24).tolist() == [
+        first, [40, 1, 1, 62, 8, 24, 62, 19, 56, 59, 10, 25, 32, 23, 42, 0,
+                0]]
+    assert cached_generate(lm, prompt[0], 12, 24,
+                           cache_dtype=jnp.bfloat16).tolist() == first
+    assert beam_generate(lm, prompt[0], 8, 24, beam_size=3).tolist() == [
+        5, 9, 33, 2, 17, 22, 27, 19, 51, 14, 58, 56, 59]
 
 
 # (h) ---------------------------------------------------------------------
