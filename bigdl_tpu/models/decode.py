@@ -20,15 +20,31 @@ types raise rather than silently mis-decode.
 
 What is kept between steps is each layer's own declaration
 (``Module.decode_state``: leaves, each leaf's length axis, its layout
-role): ``MultiHeadAttention`` keeps ``{k, v}`` of ``[rows, L, H * D]``,
+role): ``MultiHeadAttention`` keeps ``{k, v}`` of ``[rows, L, H_kv * D]``,
 ``LatentAttention`` ``{c_kv, k_rope}`` of ``[rows, L, width]`` (in both a
 position of a row is one whole minor row, and a step writes its S new rows
 in place under the donation, one scatter a leaf),
-``PositionalEmbedding`` nothing but needs the position.  ``init_kv_cache``
-asks the layers, and ``decode_walk`` is the one walk the serving engine's
-two programs (prefill, step) share: it hands every declaring layer its
-leaves through ``decode_prefill`` or ``decode_step`` and applies every
-other leaf to the positions in hand.  ``cached_generate`` keeps a walk of
+``PositionalEmbedding`` nothing but needs the position.
+
+There are two kinds of leaf, and one cache holds both (``nn.StateLeaf``).
+A leaf *with a length axis* holds something of every position: it grows by
+a page along that axis, a step reads it under the mask ``<= pos``, and so a
+prefill may leave its pads' rows, and a slot's last occupant its stale
+ones, where they fell.  A leaf *without one* (``length_axis`` None:
+``Mamba2Mixer``'s recurrent state ``ssm`` of ``[rows, H, P, N]`` float32
+and its convolution's last inputs ``conv`` of ``[rows, K - 1, channels]``)
+has a fixed size a row, and nothing masks it.  What a prefill owes such a
+leaf: it starts from zero whatever the slot held, no pad moves it, and the
+row is written whole, as it stands after the prompt's last real position.
+What a step owes it: one update a row in place (an idle row may write
+anything, since the next prefill of that slot overwrites all of it).
+``grow_cache`` carries it over bit for bit.  No function here or in
+serve/decode.py tests a layer's type for any of this.
+
+``init_kv_cache`` asks the layers, and ``decode_walk`` is the one walk the
+serving engine's two programs (prefill, step) share: it hands every
+declaring layer its leaves through ``decode_prefill`` or ``decode_step``
+and applies every other leaf to the positions in hand.  ``cached_generate`` keeps a walk of
 its own (``_step``, one position shared by all rows, written out for
 ``MultiHeadAttention``): it is the oracle the engine's tokens are held to.
 """
@@ -108,10 +124,24 @@ def cache_avals(model, rows: int, length: int, dtype, mesh=None):
     declare them: one dict a stateful layer."""
     return tuple(
         {n: jax.ShapeDtypeStruct(
-            leaf.shape, dtype,
+            leaf.shape, leaf.dtype or dtype,
             sharding=_cache_sharding(mesh, leaf.shape, leaf.role))
          for n, leaf in spec.items()}
         for _m, spec in _stateful_modules(model, rows, length))
+
+
+def state_bytes_per_row(model, length: int, dtype) -> tuple:
+    """Bytes of decode state one row holds at ``length`` positions, from
+    the layers' declarations: (all leaves, the leaves of fixed size)."""
+    total = fixed = 0
+    for _m, spec in _stateful_modules(model, 1, length):
+        for leaf in spec.values():
+            n = int(np.prod(leaf.shape)) \
+                * jnp.dtype(leaf.dtype or dtype).itemsize
+            total += n
+            if leaf.length_axis is None:
+                fixed += n
+    return total, fixed
 
 
 def init_kv_cache(model, batch: int, max_len: int, dtype=jnp.float32,
@@ -140,16 +170,19 @@ def init_kv_cache(model, batch: int, max_len: int, dtype=jnp.float32,
 def grow_cache(model, caches, length: int, mesh=None):
     """``caches`` padded with zeros to ``length`` positions along each
     leaf's own length axis (masked positions carry exact-zero weight, so
-    rows in flight decode on unchanged)."""
+    rows in flight decode on unchanged); a leaf of fixed size is carried
+    over as it is (rows in flight keep their recurrence)."""
     grown = []
     rows = jax.tree.leaves(caches)[0].shape[0]
     for c, (_m, spec) in zip(caches, _stateful_modules(model, rows, length)):
         out = {}
         for n, arr in c.items():
             ax, shape = spec[n].length_axis, spec[n].shape
-            pad = jnp.zeros(arr.shape[:ax] + (shape[ax] - arr.shape[ax],)
-                            + arr.shape[ax + 1:], arr.dtype)
-            out[n] = jnp.concatenate([arr, pad], axis=ax)
+            out[n] = arr
+            if ax is not None:
+                pad = jnp.zeros(arr.shape[:ax] + (shape[ax] - arr.shape[ax],)
+                                + arr.shape[ax + 1:], arr.dtype)
+                out[n] = jnp.concatenate([arr, pad], axis=ax)
             sh = _cache_sharding(mesh, shape, spec[n].role)
             if sh is not None:
                 out[n] = jax.device_put(out[n], sh)
@@ -162,7 +195,8 @@ class _Walk:
     (module docstring).  ``visit(module, params, x, cache) -> (y, cache)``
     serves the layers that declare state; ``caches`` (a list) is updated in
     place, and ``tally`` gathers what the layers without leaves report of
-    the call (an expert layer: the tokens each held expert took).  With
+    the call (an expert layer: the tokens each held expert took, and the
+    experts each position's router chose).  With
     ``last`` set (a prefill: all positions of one prompt at once),
     everything past the last layer that keeps leaves is position-wise, so a
     Sequential outside any ConcatTable keeps only position ``last`` from
@@ -174,10 +208,15 @@ class _Walk:
         self.last = last
         self.tally = []
 
-    def counts(self):
-        """The reports of the call summed over the layers that made one
-        (None where none did)."""
-        return sum(self.tally[1:], self.tally[0]) if self.tally else None
+    def report(self):
+        """What the layers without leaves reported of the call, None where
+        none did: (the token counts summed over those layers, a tuple of
+        each layer's chosen experts, ``[..., k]`` int32, in traversal
+        order)."""
+        if not self.tally:
+            return None
+        counts = [c for c, _idx in self.tally]
+        return sum(counts[1:], counts[0]), tuple(i for _c, i in self.tally)
 
     def walk(self, module, params, state, x, layer=0, in_table=False):
         """Returns (y, next_layer)."""
@@ -225,27 +264,17 @@ def _cached_attention(mha, params, x, cache, pos):
         raise NotImplementedError(
             "cached decoding requires causal attention "
             "(MultiHeadAttention(causal=False) found)")
-    B, _, E = x.shape
-    H, D = mha.num_heads, mha.head_dim
-    q = mha._proj(params, x, "q").reshape(B, 1, H, D).transpose(0, 2, 1, 3)
-    # the leaves are [B, L, E] (MultiHeadAttention.decode_state): every row
-    # writes the one position
+    q = mha._proj(params, x, "q")
+    # the leaves are [B, L, H_kv * D] (MultiHeadAttention.decode_state):
+    # every row writes the one position
     ck = jax.lax.dynamic_update_slice(
         cache["k"], mha._proj(params, x, "k").astype(cache["k"].dtype),
         (0, pos, 0))
     cv = jax.lax.dynamic_update_slice(
         cache["v"], mha._proj(params, x, "v").astype(cache["v"].dtype),
         (0, pos, 0))
-    L = ck.shape[1]
-    scores = jnp.einsum("bhqd,blhd->bhql", q.astype(jnp.float32),
-                        ck.reshape(B, L, H, D).astype(jnp.float32)) \
-        / (D ** 0.5)
-    mask = jnp.arange(L)[None, None, None, :] <= pos
-    scores = jnp.where(mask, scores, -jnp.inf)
-    w = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bhql,blhd->bhqd", w,
-                   cv.reshape(B, L, H, D).astype(jnp.float32))
-    o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(B, 1, E)
+    mask = jnp.arange(ck.shape[1]) <= pos
+    o = mha._attend(q, ck, cv, mask, x.dtype)
     return mha._proj(params, o, "o"), {"k": ck, "v": cv}
 
 
@@ -292,30 +321,44 @@ def _prefill(model, params, state, toks, caches, slot, t0):
     """One-pass prefill of one sequence into cache row `slot`: `toks` is
     the prompt, [P] with pads past its `t0` real tokens (P no longer than
     the cache).  Returns the [V] logits of position t0 - 1, the caches,
-    and the expert token counts of the t0 real tokens (None for a model
-    that counts none).
+    and what the expert layers report (None for a model that has none):
+    the token counts of the t0 real tokens, and a tuple of the experts each
+    layer's router chose, `[positions, k]` int32 a layer: the P positions
+    of the bucket, pads included, or, past the last layer that keeps
+    leaves, the one position t0 - 1.
 
-    Rows t0..P-1 of the slot take the pads' state: finite, and masked by
-    `<= pos` in every later step until the sequence overwrites them, like
-    a previous occupant's stale rows."""
+    In a leaf with a length axis, rows t0..P-1 of the slot take the pads'
+    state: finite, and masked by `<= pos` in every later step until the
+    sequence overwrites them, like a previous occupant's stale rows.  A
+    leaf of fixed size is written whole, as it is after position t0 - 1
+    (each layer's `decode_prefill` sees `t0` and keeps its pads out)."""
     w = _Walk(list(caches),
               lambda m, p, x, c: m.decode_prefill(p, x, c, slot, t0),
               last=t0 - 1)
     y, _ = w.walk(model, params, state, toks[None])
     if y.shape[1] != 1:  # nothing follows the last stateful layer
         y = jax.lax.dynamic_slice_in_dim(y, t0 - 1, 1, axis=1)
-    return y[0, 0], tuple(w.caches), w.counts()
+    report = w.report()
+    if report is not None:
+        report = report[0], tuple(i[0] for i in report[1])
+    return y[0, 0], tuple(w.caches), report
 
 
 def _slot_step(model, params, state, tok, caches, pos):
     """Every row one position forward, each at its own: `tok` and `pos`
     are [S]; a row with `pos` < 0 is idle (it computes position 0 of
     token `tok`, is counted nowhere, and what it writes a prefill
-    overwrites).  Returns ([S, V] logits, caches, expert token counts)."""
+    overwrites).  Returns ([S, V] logits, caches, and what the expert
+    layers report, None for a model that has none: the live rows' token
+    counts and the experts every row's routers chose, `[layers, S, k]`
+    int32, idle rows included as routed)."""
     w = _Walk(list(caches),
               lambda m, p, x, c: m.decode_step(p, x, c, pos))
     y, _ = w.walk(model, params, state, tok[:, None])
-    return y[:, -1], tuple(w.caches), w.counts()
+    report = w.report()
+    if report is not None:
+        report = report[0], jnp.stack([i[:, 0] for i in report[1]])
+    return y[:, -1], tuple(w.caches), report
 
 
 def _get_step(model, rows: int, max_len: int, dtype):

@@ -55,4 +55,5 @@ from .criterion import (
     SmoothL1CriterionWithWeights, SoftMarginCriterion, SoftmaxWithCriterion,
     TimeDistributedCriterion)
 from .attention import LatentAttention, MultiHeadAttention
+from .mamba import Mamba2Mixer
 from .fused import ConvBN, ConvBNAddReLU, fuse_conv_bn

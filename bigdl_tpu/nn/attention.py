@@ -1,8 +1,12 @@
 """Attention layers (net-new vs the 2017 reference; required for the rebuild's
 long-context capability, SURVEY.md §5.7/§7).
 
-MultiHeadAttention: fused qkv projection -> flash attention (Pallas kernel on
-TPU, ops/attention.py) -> output projection.  With `seq_parallel=True` the
+MultiHeadAttention: q, k, v projections -> flash attention (Pallas kernel on
+TPU, ops/attention.py) -> output projection.  ``num_kv_heads`` fewer key and
+value heads than query heads (grouped-query attention: query head ``i``
+reads key head ``i // (num_heads / num_kv_heads)``) and a ``head_dim`` that
+is not ``embed_dim / num_heads`` are arguments; the defaults are the plain
+form.  With `seq_parallel=True` the
 attention core runs as a ring over the mesh 'seq' axis (parallel/ring_attention)
 so sequences sharded across devices never gather.  `BIGDL_TPU_RING_ATTN=1`
 instead reuses a MeshLayout's 'tp' axis as the sequence axis: on a tp>1
@@ -47,21 +51,33 @@ def _write_rows(cache, pos, new):
 
 
 class MultiHeadAttention(Module):
-    """Self-attention over [B, T, E] inputs."""
+    """Self-attention over [B, T, E] inputs: ``num_heads`` query heads of
+    width ``head_dim`` (default ``embed_dim / num_heads``) over
+    ``num_kv_heads`` key and value heads (default: as many), each shared
+    by a run of ``num_heads / num_kv_heads`` consecutive query heads; scores
+    are scaled by ``head_dim^-0.5``; no positions are applied here."""
 
-    #: (E, E) projections are applied x @ w (in-major): kernel_in
+    #: projections are applied x @ w (in-major): kernel_in
     PARAM_ROLES = {"wq": "kernel_in", "wk": "kernel_in", "wv": "kernel_in",
                    "wo": "kernel_in", "*": "bias"}
 
     def __init__(self, embed_dim: int, num_heads: int, causal: bool = False,
                  seq_parallel: bool = False, seq_axis: str = "seq",
-                 with_bias: bool = True):
+                 with_bias: bool = True, num_kv_heads: Optional[int] = None,
+                 head_dim: Optional[int] = None):
         super().__init__()
-        if embed_dim % num_heads:
-            raise ValueError(f"embed_dim {embed_dim} % num_heads {num_heads}")
+        if head_dim is None:
+            if embed_dim % num_heads:
+                raise ValueError(
+                    f"embed_dim {embed_dim} % num_heads {num_heads}")
+            head_dim = embed_dim // num_heads
+        num_kv_heads = num_kv_heads or num_heads
+        if num_heads % num_kv_heads:
+            raise ValueError(f"num_heads {num_heads} % num_kv_heads "
+                             f"{num_kv_heads}")
         self.embed_dim = embed_dim
-        self.num_heads = num_heads
-        self.head_dim = embed_dim // num_heads
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim = head_dim
         self.causal = causal
         self.seq_parallel = seq_parallel
         self.seq_axis = seq_axis
@@ -70,6 +86,8 @@ class MultiHeadAttention(Module):
     def _init(self, rng):
         ks = jax.random.split(rng, 4)
         e = self.embed_dim
+        qw = self.num_heads * self.head_dim
+        kvw = self.num_kv_heads * self.head_dim
         winit = self.weight_initializer or default_weight_init
         dt = get_policy().param_dtype
 
@@ -77,13 +95,13 @@ class MultiHeadAttention(Module):
             fi, fo = compute_fans(shape)
             return winit(k, shape, fi, fo, dt)
 
-        p = {"wq": w(ks[0], (e, e)), "wk": w(ks[1], (e, e)),
-             "wv": w(ks[2], (e, e)), "wo": w(ks[3], (e, e))}
+        p = {"wq": w(ks[0], (e, qw)), "wk": w(ks[1], (e, kvw)),
+             "wv": w(ks[2], (e, kvw)), "wo": w(ks[3], (qw, e))}
         if self.with_bias:
             # distinct arrays per bias: aliased leaves crash buffer donation
             # in the compiled train step ("donate the same buffer twice")
-            p.update({k: jnp.zeros((e,), dt)
-                      for k in ("bq", "bk", "bv", "bo")})
+            p.update({"bq": jnp.zeros((qw,), dt), "bk": jnp.zeros((kvw,), dt),
+                      "bv": jnp.zeros((kvw,), dt), "bo": jnp.zeros((e,), dt)})
         return p
 
     def _proj(self, params, x, name):
@@ -113,10 +131,14 @@ class MultiHeadAttention(Module):
         return mesh
 
     def _apply(self, params, x):
-        B, T, E = x.shape
-        H, D = self.num_heads, self.head_dim
-        split = lambda y: y.reshape(B, T, H, D).transpose(0, 2, 1, 3)
-        q, k, v = (split(self._proj(params, x, n)) for n in "qkv")
+        B, T, _ = x.shape
+        H, G, D = self.num_heads, self.num_kv_heads, self.head_dim
+        split = lambda y, n: y.reshape(B, T, n, D).transpose(0, 2, 1, 3)
+        q = split(self._proj(params, x, "q"), H)
+        k, v = (split(self._proj(params, x, n), G) for n in "kv")
+        if G != H:
+            # the full-sequence cores take a key head for every query head
+            k, v = (jnp.repeat(a, H // G, axis=1) for a in (k, v))
         ring_mesh = None if self.seq_parallel else self._ring_over_tp(T)
         if self.seq_parallel:
             from ..parallel.ring_attention import ring_attention
@@ -130,7 +152,7 @@ class MultiHeadAttention(Module):
         else:
             from ..ops.attention import flash_attention
             o = flash_attention(q, k, v, causal=self.causal)
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, E)
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
         return self._proj(params, o, "o")
 
     # -- incremental decoding ------------------------------------------
@@ -144,13 +166,32 @@ class MultiHeadAttention(Module):
                 "(MultiHeadAttention(causal=False) found)")
 
     def decode_state(self, rows: int, length: int):
-        """A key and a value for every position, the heads side by side as
-        the projections give them: ``[rows, length, H * D]`` each.  A
-        position of a row is then one whole minor row of the leaf, which a
-        step can write in place."""
-        shape = (rows, length, self.embed_dim)
+        """A key and a value for every position, the key-value heads side
+        by side as the projections give them: ``[rows, length, H_kv * D]``
+        each.  A position of a row is then one whole minor row of the leaf,
+        which a step can write in place."""
+        shape = (rows, length, self.num_kv_heads * self.head_dim)
         return {"k": StateLeaf(shape, 1, "kv_cache"),
                 "v": StateLeaf(shape, 1, "kv_cache")}
+
+    def _attend(self, q, k, v, mask, dtype):
+        """q ``[B, Q, H * D]`` over keys and values ``[B, L, H_kv * D]``:
+        float32 scores of each group's query heads over its one key head,
+        exact-zero weight where ``mask`` (broadcast to ``[B, G, R, Q, L]``)
+        is false; returns ``[B, Q, H * D]`` in ``dtype``."""
+        B, Q, _ = q.shape
+        L = k.shape[1]
+        G, D = self.num_kv_heads, self.head_dim
+        R = self.num_heads // G
+        q = q.reshape(B, Q, G, R, D).transpose(0, 2, 3, 1, 4)
+        scores = jnp.einsum("bgrqd,blgd->bgrql", q.astype(jnp.float32),
+                            k.reshape(B, L, G, D).astype(jnp.float32)) \
+            / (D ** 0.5)
+        w = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+        o = jnp.einsum("bgrql,blgd->bgrqd", w,
+                       v.reshape(B, L, G, D).astype(jnp.float32))
+        return o.astype(dtype).transpose(0, 3, 1, 2, 4) \
+            .reshape(B, Q, G * R * D)
 
     def decode_prefill(self, params, x, cache, slot, length):
         """x: [1, P, E], a whole prompt from position 0 entering the fresh
@@ -160,25 +201,17 @@ class MultiHeadAttention(Module):
 
         The prompt attends causally over itself with `decode_step`'s
         float32 score path and exact-zero masked weights; k and v of all P
-        positions go into the cache, ``[1, P, E]`` at ``(slot, 0, 0)``, by
-        one write each."""
+        positions go into the cache, ``[1, P, H_kv * D]`` at ``(slot, 0,
+        0)``, by one write each."""
         self._require_causal()
-        _, P, E = x.shape
-        H, D = self.num_heads, self.head_dim
-        q, k, v = (self._proj(params, x, n) for n in "qkv")     # [1, P, E]
+        P = x.shape[1]
+        q, k, v = (self._proj(params, x, n) for n in "qkv")
         # attend over what the cache will hold: k and v in the cache's dtype
         k, v = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
         ck = jax.lax.dynamic_update_slice(cache["k"], k, (slot, 0, 0))
         cv = jax.lax.dynamic_update_slice(cache["v"], v, (slot, 0, 0))
-        split = lambda y: y.reshape(1, P, H, D).transpose(0, 2, 1, 3)
-        q, k, v = split(q), split(k), split(v)
-        scores = jnp.einsum("bhqd,bhld->bhql", q.astype(jnp.float32),
-                            k.astype(jnp.float32)) / (D ** 0.5)
         mask = jnp.arange(P)[None, :] <= jnp.arange(P)[:, None]
-        scores = jnp.where(mask, scores, -jnp.inf)
-        w = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhql,bhld->bhqd", w, v.astype(jnp.float32))
-        o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(1, P, E)
+        o = self._attend(q, k, v, mask, x.dtype)
         return self._proj(params, o, "o"), {"k": ck, "v": cv}
 
     def decode_step(self, params, x, cache, pos):
@@ -189,25 +222,15 @@ class MultiHeadAttention(Module):
         over the cache or a loop over the rows."""
         self._require_causal()
         pos = jnp.maximum(pos, 0)                 # an idle row: position 0
-        S, _, E = x.shape
-        H, D = self.num_heads, self.head_dim
-        q = self._proj(params, x, "q").reshape(S, 1, H, D) \
-            .transpose(0, 2, 1, 3)
+        q = self._proj(params, x, "q")
         ck, cv = (_write_rows(cache[n], pos, self._proj(params, x, n)[:, 0])
                   for n in "kv")
-        L = ck.shape[1]
-        scores = jnp.einsum("bhqd,blhd->bhql", q.astype(jnp.float32),
-                            ck.reshape(S, L, H, D).astype(jnp.float32)) \
-            / (D ** 0.5)
         # per-row causal horizon; positions past a row's pos get EXACT
         # zero softmax weight (exp(-inf)), so stale cache rows from a
         # previous occupant of the slot contribute exactly nothing
-        mask = jnp.arange(L)[None, None, None, :] <= pos[:, None, None, None]
-        scores = jnp.where(mask, scores, -jnp.inf)
-        w = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhql,blhd->bhqd", w,
-                       cv.reshape(S, L, H, D).astype(jnp.float32))
-        o = o.astype(x.dtype).transpose(0, 2, 1, 3).reshape(S, 1, E)
+        mask = jnp.arange(ck.shape[1])[None, None, None, None, :] \
+            <= pos[:, None, None, None, None]
+        o = self._attend(q, ck, cv, mask, x.dtype)
         return self._proj(params, o, "o"), {"k": ck, "v": cv}
 
 

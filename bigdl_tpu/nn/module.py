@@ -45,12 +45,21 @@ __all__ = ["Module", "Container", "Criterion", "StateLeaf"]
 
 class StateLeaf(NamedTuple):
     """One array a layer keeps between decode steps (``Module.decode_state``):
-    its shape for ``rows`` sequences of ``length`` positions, which axis is
-    the length (the one a cache grows along), and the layout role that
-    places it on a mesh (parallel/layout.ROLES)."""
+    its shape for ``rows`` sequences of ``length`` positions, and the layout
+    role that places it on a mesh (parallel/layout.ROLES).  There are two
+    kinds.  A leaf with a ``length_axis`` holds something of every position
+    (keys and values, a latent): it grows by a page along that axis, and
+    what lies past a row's position is masked, so a prefill may leave its
+    pads' rows there.  A leaf whose ``length_axis`` is ``None`` is of fixed
+    size a row whatever the length (a recurrent state, a convolution's last
+    inputs): nothing masks it, so a prefill writes the row whole, as it is
+    after the prompt's last real position, and a growing cache carries it
+    over bit for bit.  ``dtype``: the leaf's own where it must not follow
+    the cache's (a recurrence summed in float32); ``None`` is the cache's."""
     shape: tuple
-    length_axis: int
+    length_axis: Optional[int]
     role: str
+    dtype: Any = None
 
 _uid_counter = itertools.count()
 

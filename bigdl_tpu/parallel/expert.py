@@ -23,11 +23,13 @@ This module adds the real thing, TPU-first, in the GShard/Switch style:
     schedule must be pinned (and as the parity oracle for the GSPMD path).
 
 A third layer, `GatedMoE`, is the dropless one that serving needs: gated
-(SwiGLU) experts beside shared experts, softmax scores with group-limited
-top-k, and a share `held=(first, count)` of the experts: it routes over all
-of them and adds the terms of those it holds.  Tokens are sorted by expert
-and each held expert multiplies its own run of rows (`lax.ragged_dot`), so
-there is no capacity and no token is dropped at any skew.
+(SwiGLU) or plain two-matrix experts beside shared experts, softmax or
+sigmoid scores with group-limited top-k (a selection bias that chooses but
+does not weigh, renormalised weights), and a share `held=(first, count)` of
+the experts: it routes over all of them and adds the terms of those it
+holds.  Tokens are sorted by expert and each held expert multiplies its own
+run of rows (ops/grouped.py: `lax.ragged_dot`, or a Pallas grouped matmul
+where that one tiles badly), so there is no capacity and no token is dropped at any skew.
 
 The Switch load-balancing auxiliary loss (num_experts * sum(fraction_e *
 mean_prob_e)) is exposed via `load_balancing_loss`.
@@ -46,6 +48,7 @@ from ..utils.compat import shard_map
 
 from ..common import get_policy
 from ..nn.module import Module
+from ..ops.grouped import grouped_matmul
 
 __all__ = ["MoEFFN", "expert_parallel_ffn", "top_k_routing",
            "load_balancing_loss", "GatedMoE", "group_limited_top_k"]
@@ -331,10 +334,21 @@ class GatedMoE(Module):
     """``y = Shared(x) + scale * sum_i s_i Expert_i(x)`` over the chosen
     experts this layer holds (module docstring).
 
-    Every expert and the shared block are gated MLPs, ``W_down(SiLU(W_gate
-    x) * W_up x)``, without biases.  ``s = softmax(x W_g)`` over all
-    ``num_experts`` in float32; the choice is ``group_limited_top_k``; the
-    weights are the chosen ``s`` (not renormalised) times ``scale``.
+    Every expert and the shared block are gated MLPs, ``W_down(act(W_gate
+    x) * W_up x)``, or with ``gated=False`` plain ones, ``W_down act(W_up
+    x)``, without biases (a plain expert's ``w_up`` is kept as rows, ``[E,
+    d_expert, d_model]``, so that both its tables end in ``d_model``: an
+    expert width need not fill whole lanes, as 1,856 does not, and a table
+    that ends in one is copied before every product; ops/grouped.py);
+    ``act`` is ``"silu"`` or ``"relu2"`` (``relu(x)^2``).
+    ``s = softmax(x W_g)`` (``score="softmax"``) or ``sigmoid(x W_g)``
+    (``"sigmoid"``) over all ``num_experts`` in float32; the choice is
+    ``group_limited_top_k`` of ``s``, or with ``select_bias`` of ``s + b``
+    for a per-expert ``b`` that chooses and does not weigh; the weights are
+    the chosen ``s``, with ``renormalise`` divided by their sum + 1e-20,
+    times ``scale``.  The shared block is ``d_shared`` wide (default
+    ``n_shared * d_expert``).  These are a model's architecture, not
+    tuning.
 
     ``held = (first, count)``: the stacked tables hold experts ``first ..
     first + count - 1`` (default: all).  The router keeps every output, and
@@ -349,19 +363,32 @@ class GatedMoE(Module):
     expert and count nowhere; their output is the shared experts' alone.
     """
 
-    PARAM_ROLES = {"gate": "kernel_whole", "w_gate": "expert_table",
+    PARAM_ROLES = {"gate": "kernel_whole", "select_bias": "bias",
+                   "w_gate": "expert_table",
                    "w_up": "expert_table", "w_down": "expert_table",
                    "shared_gate": "kernel_in", "shared_up": "kernel_in",
                    "shared_down": "kernel_in"}
 
+    _ACTS = {"silu": jax.nn.silu,
+             "relu2": lambda x: jnp.square(jax.nn.relu(x))}
+
     def __init__(self, d_model: int, d_expert: int, num_experts: int,
                  k: int, n_group: int = 1, topk_group: int = 1,
-                 n_shared: int = 0, scale: float = 1.0, held=None):
+                 n_shared: int = 0, scale: float = 1.0, held=None,
+                 score: str = "softmax", select_bias: bool = False,
+                 renormalise: bool = False, gated: bool = True,
+                 act: str = "silu", d_shared: Optional[int] = None):
         super().__init__()
+        if score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score {score!r}")
         self.d_model, self.d_expert = d_model, d_expert
         self.num_experts, self.k = num_experts, k
         self.n_group, self.topk_group = n_group, topk_group
         self.n_shared, self.scale = n_shared, float(scale)
+        self.score, self.select_bias = score, select_bias
+        self.renormalise, self.gated = renormalise, gated
+        self.act = self._ACTS[act]
+        self.d_shared = n_shared * d_expert if d_shared is None else d_shared
         self.first, self.count = held if held is not None \
             else (0, num_experts)
         if not (0 <= self.first and self.count >= 1
@@ -376,13 +403,18 @@ class GatedMoE(Module):
             * (1.0 / fan) ** 0.5
         p = {"gate": jax.random.normal(ks[0], (D, self.num_experts), dt)
              * 0.02,
-             "w_gate": n(ks[1], (E, D, H), D), "w_up": n(ks[2], (E, D, H), D),
+             "w_up": n(ks[2], (E, D, H) if self.gated else (E, H, D), D),
              "w_down": n(ks[3], (E, H, D), H)}
-        if self.n_shared:
-            S = self.n_shared * H
-            p.update(shared_gate=n(ks[4], (D, S), D),
-                     shared_up=n(ks[5], (D, S), D),
+        S = self.d_shared
+        if S:
+            p.update(shared_up=n(ks[5], (D, S), D),
                      shared_down=n(ks[6], (S, D), S))
+        if self.gated:
+            p["w_gate"] = n(ks[1], (E, D, H), D)
+            if S:
+                p["shared_gate"] = n(ks[4], (D, S), D)
+        if self.select_bias:
+            p["select_bias"] = jnp.zeros((self.num_experts,), dt)
         return p
 
     def _init_state(self):
@@ -394,16 +426,25 @@ class GatedMoE(Module):
         logits = jnp.matmul(xt.astype(jnp.float32),
                             params["gate"].astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
-        w, idx = group_limited_top_k(jax.nn.softmax(logits, axis=-1),
-                                     self.n_group, self.topk_group, self.k)
+        s = jax.nn.softmax(logits, axis=-1) if self.score == "softmax" \
+            else jax.nn.sigmoid(logits)
+        pick = s + params["select_bias"].astype(jnp.float32) \
+            if self.select_bias else s
+        w, idx = group_limited_top_k(pick, self.n_group, self.topk_group,
+                                     self.k)
+        if self.select_bias:          # the bias chooses and does not weigh
+            w = jnp.take_along_axis(s, idx, axis=-1)
+        if self.renormalise:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return w * self.scale, idx
 
     def apply(self, params, state, x, *, training=False, rng=None):
-        y, counts = self._forward(params, x)
+        y, (counts, _chosen) = self._forward(params, x)
         return y, {"expert_tokens": counts}
 
     # incremental decoding: nothing is kept, but which tokens are real
-    # matters (Module.decode_state)
+    # matters (Module.decode_state); the report of a call is (the live
+    # tokens' counts, the experts every position's router chose)
 
     def decode_state(self, rows: int, length: int):
         return {}
@@ -418,8 +459,10 @@ class GatedMoE(Module):
         return self._forward(params, x, (pos >= 0)[:, None])
 
     def _forward(self, params, x, live=None):
-        """x [..., D] -> (y, counts [count + 1]); ``live`` (boolean, of
-        ``x.shape[:-1]``) marks the real tokens, all of them without it."""
+        """x [..., D] -> (y, (counts [count + 1], chosen [..., k] int32));
+        ``live`` (boolean, of ``x.shape[:-1]``) marks the real tokens, all
+        of them without it.  ``chosen`` are the router's choices of all
+        ``num_experts`` as made, pads and idle rows included."""
         c = get_policy().compute_dtype
         f32 = jnp.float32
         D, E, k = self.d_model, self.count, self.k
@@ -442,9 +485,10 @@ class GatedMoE(Module):
         # rows 0..sum(held)-1 of the sorted tokens are the held experts'
         # runs, one after another; what follows belongs to no group
         xs = jnp.take(xt, order // k, axis=0)
-        dot = lambda a, b: lax.ragged_dot(a, b.astype(c), held,
-                                          preferred_element_type=f32)
-        h = jax.nn.silu(dot(xs, params["w_gate"])) * dot(xs, params["w_up"])
+        dot = lambda a, b, rows=False: grouped_matmul(
+            a, b.astype(c), held, transposed=rows)
+        h = self.act(dot(xs, params["w_gate"])) * dot(xs, params["w_up"]) \
+            if self.gated else self.act(dot(xs, params["w_up"], True))
         out = dot(h.astype(c), params["w_down"])             # [T k, D] f32
         # back to token order, weighted; rows past the runs hold nothing
         # that may be read, so they are selected away, not multiplied
@@ -452,10 +496,12 @@ class GatedMoE(Module):
         own = (jnp.arange(T * k) < jnp.sum(held))[inv]
         picked = jnp.where(own[:, None], jnp.take(out, inv, axis=0), 0.0)
         y = jnp.sum(picked.reshape(T, k, D) * w[:, :, None], axis=1)
-        if self.n_shared:
+        if self.d_shared:
             mm = lambda a, b: jnp.matmul(a, b.astype(c),
                                          preferred_element_type=f32)
-            hs = jax.nn.silu(mm(xt, params["shared_gate"])) \
-                * mm(xt, params["shared_up"])
+            hs = self.act(mm(xt, params["shared_gate"])) \
+                * mm(xt, params["shared_up"]) \
+                if self.gated else self.act(mm(xt, params["shared_up"]))
             y = y + mm(hs.astype(c), params["shared_down"])
-        return y.astype(c).reshape(x.shape), sizes[:E + 1]
+        return y.astype(c).reshape(x.shape), \
+            (sizes[:E + 1], idx.reshape(x.shape[:-1] + (k,)).astype(jnp.int32))

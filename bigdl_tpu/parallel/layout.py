@@ -128,6 +128,10 @@ ROLES: Dict[str, Tuple[Optional[int], Optional[int]]] = {
     # Slots over data x fsdp as for kv_cache; every tp share holds the whole
     # latent, as it applies the latent projections whole
     "latent_cache": (None, None),
+    # a recurrent layer's state of fixed size a row, [slots, heads, ...]
+    # (nn/mamba.Mamba2Mixer ``ssm``): slots as for kv_cache, the heads'
+    # axis, the second, over tp
+    "ssm_state": (None, None),
 }
 
 
@@ -294,7 +298,7 @@ class MeshLayout:
                         parts[ax] = FSDP_AXIS
                         break
             return P(*parts)
-        if role in ("kv_cache", "latent_cache") and ndim >= 2:
+        if role in ("kv_cache", "latent_cache", "ssm_state") and ndim >= 2:
             # [slots, cache_len, width]: slots ride the batch axes (data
             # x fsdp, degrading like embedding_row when the slot count
             # does not divide the product); a kv_cache's width is its
@@ -308,9 +312,12 @@ class MeshLayout:
                     parts[0] = DATA_AXIS
                 elif self.fsdp > 1 and shape[0] % self.fsdp == 0:
                     parts[0] = FSDP_AXIS
-            if role == "kv_cache" and self.tp > 1 \
-                    and shape[-1] % self.tp == 0:
-                parts[-1] = TP_AXIS
+            # (a kv_cache of fewer key-value heads than tp shares is split
+            # inside a head: still each device's columns of k and v)
+            tp_ax = {"kv_cache": ndim - 1, "ssm_state": 1}.get(role)
+            if tp_ax is not None and self.tp > 1 \
+                    and shape[tp_ax] % self.tp == 0:
+                parts[tp_ax] = TP_AXIS
             return P(*parts)
         if role == "embedding_row" and ndim >= 1:
             # rows over fsdp x tp together — folding 'expert' in too when

@@ -95,7 +95,7 @@ class PendingRequest:
     ChaosFault / StallError...)."""
 
     __slots__ = ("payload", "enqueued", "admitted", "first_token",
-                 "deadline", "tenant", "priority",
+                 "routing", "deadline", "tenant", "priority",
                  "version", "latency_s", "rid", "rid_owner",
                  "_event", "_result", "_error")
 
@@ -110,6 +110,10 @@ class PendingRequest:
         # one-shot request, which has neither)
         self.admitted = None
         self.first_token = None
+        # the experts a generated sequence's routers chose, set with the
+        # result (serve/decode.py; None: a model without routed experts,
+        # or a one-shot request)
+        self.routing = None
         self.deadline = deadline
         self.tenant = tenant     # quota/accounting tag (control plane)
         self.priority = int(priority)  # higher = shed later

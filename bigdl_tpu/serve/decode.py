@@ -28,10 +28,13 @@ took; PAPERS.md):
   compile per (prompt-bucket, slots, cache-page), the prompt's length
   traced).  Both programs are one walk (models/decode ``_Walk``) over
   what each layer declares (``Module.decode_state``, ``decode_prefill``,
-  ``decode_step``): ``MultiHeadAttention`` keeps ``{k, v}`` a head,
-  ``LatentAttention`` a latent and one rotary key for all heads, and the
-  engine knows neither.  ``cached_generate`` keeps its
-  position-by-position walk and shares no prefill code with the engine:
+  ``decode_step``): ``MultiHeadAttention`` keeps ``{k, v}`` a key-value
+  head, ``LatentAttention`` a latent and one rotary key for all heads,
+  ``Mamba2Mixer`` a recurrent state and a convolution's last inputs, of
+  fixed size whatever the length (a prefill writes such a row whole, a
+  growing cache carries it over; models/decode.py), and the engine knows
+  none of them.  ``cached_generate`` keeps its position-by-position walk
+  and shares no prefill code with the engine:
   it is the oracle the engine's greedy tokens are held to, token for
   token, by test.
 - The bucket ladder extends to **(batch-slots, cache-page)** pages:
@@ -51,14 +54,25 @@ took; PAPERS.md):
   token budget.
 - Telemetry: the ``serve.decode`` counter track emits tokens/s,
   active-slot fill, prefill-vs-decode step fractions, the share of the
-  prefills' positions that were padding (``prefill_pad_frac``) and
-  cache bytes/slot — promoted to a ``decode:`` trace_report section like
-  ``aot``/``autoscale`` (utils/telemetry.phase_breakdown).  A model with
-  routed experts that count their tokens (parallel/expert.GatedMoE)
+  prefills' positions that were padding (``prefill_pad_frac``), cache
+  bytes/slot and the part of them that is of fixed size
+  (``state_bytes_per_slot``) — promoted to a ``decode:`` trace_report
+  section like ``aot``/``autoscale`` (utils/telemetry.phase_breakdown).  A
+  model with routed experts that count their tokens (parallel/expert.GatedMoE)
   returns one small count vector beside the logits of every call:
   ``expert_tokens`` (choices that went to experts held here),
   ``expert_tokens_elsewhere`` and ``expert_tokens_max`` (the busiest held
   expert's), so load balance reads as max over mean.
+- Routing: a model with routed experts also returns, beside the counts,
+  the experts every position's router chose (a few integers a token), and
+  a finished request carries its own as ``PendingRequest.routing``: int32
+  ``[expert layers, positions, k]`` for positions ``0 .. len(result) - 2``
+  (each position whose output chose the next token or fed the state), -1
+  where a prefill computed nothing (past the last layer that keeps state
+  only a prompt's last position is computed).  Routing is discrete: who
+  holds served tokens against another computation of the model has to
+  give it the choices that were made.  None for a model without routed
+  experts.
 - Chaos: ``serve.decode@<slot>`` fires once per tick for every slot
   that participates (prefill or decode).  A faulted slot fails ITS
   sequence typed (:class:`SlotFault`/ChaosFault), frees the slot, and
@@ -143,7 +157,7 @@ class _Seq:
     """Host-side state of one in-flight sequence (one slot)."""
 
     __slots__ = ("req", "buf", "t0", "pos", "emitted", "max_tokens",
-                 "eos", "temperature", "top_k", "rng")
+                 "eos", "temperature", "top_k", "rng", "routed")
 
     def __init__(self, req: PendingRequest, prompt: np.ndarray,
                  max_tokens: int, eos, temperature: float, top_k: int,
@@ -159,6 +173,8 @@ class _Seq:
         self.temperature = temperature
         self.top_k = top_k
         self.rng = rng
+        # the experts each position's routers chose (PendingRequest.routing)
+        self.routed: Optional[np.ndarray] = None
 
 
 class DecodeEngine:
@@ -252,6 +268,8 @@ class DecodeEngine:
         self._expert_tokens: Optional[np.ndarray] = None
         self.expert_tokens_elsewhere = 0
         self._busy_s = 0.0
+        # bytes of fixed size a slot's prefill writes whole (a constant)
+        self._state_bytes = self.state_bytes_per_slot()
         # request stamps, summed (always on: two clock reads a request)
         self.admitted = 0
         self.queue_wait_s = 0.0      # enqueued -> admitted to a slot
@@ -474,14 +492,18 @@ class DecodeEngine:
 
     def cache_bytes_per_slot(self) -> int:
         """Bytes of decode state one slot holds at the present cache
-        length, from the layers' declarations (nothing is read off the
-        device)."""
+        length, leaves of both kinds, from the layers' declarations
+        (nothing is read off the device)."""
         if self._caches is None:
             return 0
-        item = jnp.dtype(self.cache_dtype).itemsize
-        return sum(int(np.prod(a.shape)) * item
-                   for c in self._cache_avals(self._cache_len)
-                   for a in c.values()) // self.slots
+        return kv.state_bytes_per_row(self.model, self._cache_len,
+                                      self.cache_dtype)[0]
+
+    def state_bytes_per_slot(self) -> int:
+        """The part of ``cache_bytes_per_slot`` that is of fixed size
+        whatever the length (recurrent state; 0 for a model of keys and
+        values alone)."""
+        return kv.state_bytes_per_row(self.model, 1, self.cache_dtype)[1]
 
     # -- the persistent step loop ---------------------------------------
 
@@ -517,6 +539,8 @@ class DecodeEngine:
     def _finish_slot(self, s: int) -> None:
         seq = self._slots[s]
         out = seq.buf[: seq.t0 + seq.emitted].copy()
+        if seq.routed is not None:
+            seq.req.routing = seq.routed[:, : len(out) - 1]
         seq.req._resolve(result=out, version="decode", now=self.clock())
         reg = metrics_export._REGISTRY
         if reg is not None and seq.req.latency_s is not None:
@@ -629,24 +653,39 @@ class DecodeEngine:
         pb = _prompt_bucket(t0)
         # the prefill call, the fetch of its logits and the first sample
         with telemetry.span("decode.admit", cat="serve", prompt_len=t0,
-                            bucket=pb, slot=s):
+                            bucket=pb, slot=s,
+                            state_bytes=self._state_bytes):
             # the bucket, cut to the cache where it is longer (t0 fits)
             toks = np.zeros(min(pb, self._cache_len), np.int32)
             toks[:t0] = prompt
             exe = self._prefill_exe(pb, self._cache_len)
             try:
-                logits, self._caches, counts = exe(
+                logits, self._caches, report = exe(
                     self._params, self._state, self._caches,
                     jnp.asarray(toks), jnp.int32(s), jnp.int32(t0))
             except Exception as e:  # noqa: BLE001
                 self._fail_slot(s, SlotFault(f"decode: prefill failed in "
                                              f"slot {s}: {e!r}"))
                 return
+            # one fetch for the logits and what the expert layers report
+            logits, (counts, chosen) = jax.device_get(
+                (logits, report or (None, None)))
             self._count_experts(counts)
+            if chosen is not None:
+                # [layers, positions, k]; a layer saw the whole bucket (its
+                # pads go) or the prompt's last position alone
+                seq.routed = np.full(
+                    (len(chosen), t0 + seq.max_tokens, chosen[0].shape[-1]),
+                    -1, np.int32)
+                for layer, a in enumerate(chosen):
+                    if len(a) == 1:
+                        seq.routed[layer, t0 - 1] = a[0]
+                    else:
+                        seq.routed[layer, :t0] = a[:t0]
             self.prefill_steps += 1
             self.prompt_tokens += t0
             self.prefill_positions += len(toks)
-            self._advance(s, self._sample(seq, np.asarray(logits)))
+            self._advance(s, self._sample(seq, logits))
 
     def _tick(self) -> bool:
         """One loop iteration: admit into free slots, decode all active
@@ -701,11 +740,16 @@ class DecodeEngine:
                     tok[s] = seq.buf[seq.pos]
                     pos[s] = seq.pos
                 exe = self._step_exe(self._cache_len)
-                logits, self._caches, counts = exe(
+                logits, self._caches, report = exe(
                     self._params, self._state, self._caches,
                     jnp.asarray(tok), jnp.asarray(pos))
-                logits = np.asarray(logits)
+                # one fetch for the logits and what the expert layers report
+                logits, (counts, chosen) = jax.device_get(
+                    (logits, report or (None, None)))
                 self._count_experts(counts)
+                if chosen is not None:
+                    for s in active:
+                        self._slots[s].routed[:, pos[s]] = chosen[:, s]
             self.decode_steps += 1
             with telemetry.span("decode.sample", cat="serve",
                                 active=len(active)):
@@ -732,7 +776,14 @@ class DecodeEngine:
             / max(self.prefill_positions, 1),
             decode_frac=self.decode_steps / max(steps, 1),
             cache_bytes_per_slot=self.cache_bytes_per_slot(),
+            state_bytes_per_slot=self._state_bytes,
             cache_len=self._cache_len)
+        reg = metrics_export._REGISTRY
+        if reg is not None:
+            reg.gauge_set("bigdl_decode_state_bytes_per_slot",
+                          float(self._state_bytes),
+                          help="decode state of fixed size a slot holds "
+                               "whatever the length (recurrent state), bytes")
 
     # -- introspection --------------------------------------------------
 
@@ -747,6 +798,7 @@ class DecodeEngine:
             "admission": self.admission,
             "cache_len": self._cache_len,
             "cache_bytes_per_slot": self.cache_bytes_per_slot(),
+            "state_bytes_per_slot": self._state_bytes,
             "cache_grows": self.cache_grows,
             "prefill_steps": self.prefill_steps,
             "prompt_tokens": self.prompt_tokens,

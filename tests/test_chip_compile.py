@@ -12,7 +12,8 @@ imports this file but only one runs it.  JAX's persistent compile cache is
 off around the compiles (an entry written for a described device cannot be
 read back without one).  `ops/attention.flash_attention` asks
 `jax.default_backend()` and would take the jnp reference on this CPU host,
-so the tests steer it (`use_pallas=True`) themselves.
+so the tests steer it (`use_pallas=True`) themselves; of `ops/grouped.py`
+they take the kernel's own entry (`_pallas`).
 """
 
 import os
@@ -206,8 +207,8 @@ def _compile_decode_step(workload, one_chip):
                                        ivec).compile()
     finally:
         set_policy(prior)
-    cache_bytes = sum(int(np.prod(a.shape)) * 2 for c in caches
-                      for a in c.values())
+    cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for c in caches for a in c.values())
     return compiled, cfg, cache_bytes
 
 
@@ -241,3 +242,71 @@ def test_kv_decode_step_compiles_at_the_cells_sizes(one_chip):
     assert text.count(" scatter(") == 2 * cfg["n_layer"]
     assert " while(" not in text
     assert "dynamic-update-slice(" not in text
+
+
+# ------------------------------- the hybrid cell: grouped matmul, state update
+
+def _entry(text):
+    """The entry computation's instructions, metadata cut off."""
+    import re
+    return [re.sub(r", (metadata|backend_config)=\{.*", "", ln).strip()
+            for ln in text[text.index("ENTRY"):].splitlines()]
+
+
+@pytest.mark.parametrize("rows", [768, 6144, 6])
+@pytest.mark.parametrize("transposed,k,n", [(True, 2688, 1856),
+                                            (False, 1856, 2688)])
+def test_grouped_matmul_compiles_at_the_cells_shapes(one_chip, rows,
+                                                     transposed, k, n):
+    """`nemo3.decode`'s expert products (64 held experts, hidden 2,688,
+    expert width 1,856: neither a multiple of 256) for a step's 768 rows, a
+    1,024-token prompt's 6,144 and the 6 of a prompt's last position: the
+    Pallas kernel fits its fast memory, and reads the table
+    `bf16[64,1856,2688]` as the device keeps it, with no copy of it."""
+    from bigdl_tpu.ops.grouped import _pallas
+    text = _compile(
+        lambda x, w, g: _pallas(x, w, g, transposed),
+        _aval((rows, k), jnp.bfloat16, one_chip),
+        _aval((64, 1856, 2688), jnp.bfloat16, one_chip),
+        _aval((64,), jnp.int32, one_chip))
+    entry = _entry(text)
+    assert sum("tpu_custom_call" in ln for ln in entry) == 1
+    assert not any(" copy(" in ln and "bf16[64," in ln for ln in entry)
+    assert any("bf16[64,1856,2688]{2,1,0" in ln and "parameter(" in ln
+               for ln in entry)
+
+
+def test_hybrid_decode_step_compiles_at_the_cells_sizes(one_chip,
+                                                        monkeypatch):
+    """`nemo3.decode`'s step at its real sizes (128 slots, 6 Mamba layers of
+    `f32[128,32,64,128]` recurrent state, 2 attention layers of
+    `[128, 1536, 128]` keys and values, 64 held experts a layer): it fits
+    one chip beside its 8.9 GB of weights; every leaf is updated in place
+    under the donation; each recurrent state crosses HBM once in and once
+    out (one fusion a layer whose result holds the leaf: no Pallas call is
+    needed for it, PERF.md PR 32); the expert products are the grouped
+    matmul kernel, two a layer, and no expert table is copied."""
+    import bigdl_tpu.parallel.expert as ep
+    from bigdl_tpu.ops.grouped import _pallas
+    # on a TPU these shapes take the kernel; here the test says so
+    monkeypatch.setattr(ep, "grouped_matmul",
+                        lambda x, w, sizes, transposed=False:
+                        _pallas(x, w, sizes, transposed))
+    compiled, cfg, cache_bytes = _compile_decode_step("nemo3.decode",
+                                                      one_chip)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    fixed = 6 * 128 * (32 * 64 * 128 * 4 + 3 * 3072 * 2)
+    assert cache_bytes == fixed + 2 * 2 * 128 * 1536 * 128 * 2
+    assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+    entry = _entry(text)
+    leaf = "f32[128,32,64,128]"
+    made = [ln for ln in entry if " = " in ln and not any(
+        op in ln for op in ("parameter(", "get-tuple-element(", " tuple("))
+        and leaf in ln.split(" = ")[1].split(" fusion(")[0]]
+    assert len(made) == 6 and all(" fusion(" in ln for ln in made)
+    assert not any(" copy(" in ln and leaf in ln for ln in entry)
+    assert sum("tpu_custom_call" in ln for ln in entry) == 2 * 6
+    assert not any(" copy(" in ln and "bf16[64," in ln for ln in entry)
+    assert text.count(" scatter(") >= 2 * 2             # keys and values

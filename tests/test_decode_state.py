@@ -1,6 +1,7 @@
 """Decode state as the layer's own declaration (ISSUE 27): what is kept, which
-axis is the length, where it lives on a mesh; one walk for the engine's two
-programs; and `Module.attach()` that makes no gradient until one is read."""
+axis is the length (ISSUE 32: or that there is none), where it lives on a
+mesh; one walk for the engine's two programs; and `Module.attach()` that
+makes no gradient until one is read."""
 
 import numpy as np
 import pytest
@@ -32,6 +33,169 @@ def _ds():
         experts_held=(0, 4)).build(jax.random.key(1))
 
 
+def _nemo():
+    """Two Mamba layers, one attention layer (4 query heads on 1 key head),
+    one expert layer: leaves of both kinds in one cache."""
+    from bigdl_tpu.models.nemotron import NemotronHLM
+    return NemotronHLM(
+        vocab_size=64, hidden=32, pattern="ME*M", mamba_heads=4,
+        mamba_head_dim=8, mamba_groups=2, ssm_state=8, conv_kernel=4,
+        chunk=8, num_heads=4, num_kv_heads=1, head_dim=8, expert_width=16,
+        shared_width=32, num_experts=8, experts_per_token=2,
+        routed_scaling_factor=2.5).build(jax.random.key(2))
+
+
+def test_a_leaf_without_a_length_is_declared_so():
+    m = nn.Mamba2Mixer(32, 4, 8, 2, 8)
+    spec = m.decode_state(3, 16)
+    assert spec == {
+        "ssm": StateLeaf((3, 4, 8, 8), None, "ssm_state", jnp.float32),
+        "conv": StateLeaf((3, 3, 4 * 8 + 2 * 2 * 8), None, "latent_cache")}
+    # whatever the length
+    assert m.decode_state(3, 999) == spec
+    gqa = nn.MultiHeadAttention(32, 4, causal=True, num_kv_heads=1,
+                                head_dim=8)
+    assert gqa.decode_state(3, 16)["k"] == StateLeaf((3, 16, 8), 1,
+                                                     "kv_cache")
+    assert [sorted(c) for c in init_kv_cache(_nemo(), 2, 8)] == \
+        [["conv", "ssm"], ["k", "v"], ["conv", "ssm"]]
+
+
+def test_grow_cache_carries_a_fixed_leaf_over_bit_for_bit():
+    m = _nemo()
+    caches = init_kv_cache(m, 2, 8, jnp.bfloat16)
+    marked = tuple({n: (a + jnp.arange(a.size, dtype=jnp.float32)
+                        .reshape(a.shape).astype(a.dtype) % 7 + 1)
+                    for n, a in c.items()} for c in caches)
+    grown = kv.grow_cache(m, marked, 32)
+    for old, new in zip(marked, grown):
+        for n in old:
+            if n in ("ssm", "conv"):
+                assert new[n].shape == old[n].shape
+                assert new[n].dtype == old[n].dtype
+                np.testing.assert_array_equal(np.asarray(new[n], np.float32),
+                                              np.asarray(old[n], np.float32))
+            else:
+                assert new[n].shape == (2, 32, 8)
+                np.testing.assert_array_equal(
+                    np.asarray(new[n][:, :8], np.float32),
+                    np.asarray(old[n], np.float32))
+                assert not np.asarray(new[n][:, 8:], np.float32).any()
+    assert grown[0]["ssm"].dtype == jnp.float32
+
+
+@pytest.mark.parametrize("first,second", [((5, 3), (9, 14)),
+                                          ((11, 4), (3, 20))])
+def test_a_cache_that_grows_mid_flight_leaves_the_tokens_unchanged(first,
+                                                                    second):
+    """A sequence is in flight in an 8-position page when one that needs 32
+    is admitted: the cache grows under the first (its keys and values along
+    their length, its recurrent state carried over as it is), and both get
+    the tokens they get alone in a cache that never grew."""
+    m = _nemo()
+    rows = [(np.random.default_rng(200 + n).integers(1, 64, n)
+             .astype(np.int32), k) for n, k in (first, second)]
+    (p1, k1), (p2, k2) = rows
+    eng = DecodeEngine(m, slots=2, page=8, max_len=32)
+    # driven by hand, so that the second arrives while the first decodes
+    h1 = eng.submit(p1, k1)
+    assert eng._tick()
+    assert eng._cache_len == (8 if len(p1) + k1 <= 8 else 16)
+    before = jax.tree.map(np.asarray, eng._caches)
+    h2 = eng.submit(p2, k2)
+    fixed = {(i, n) for i, c in enumerate(before) for n in c
+             if n in ("ssm", "conv")}
+    grown_at = eng.cache_grows
+    eng._ensure_cache(len(p2) + k2, idle=False)
+    assert eng.cache_grows == grown_at + 1 and eng._cache_len == 32
+    for i, n in fixed:              # bit for bit through the growth
+        np.testing.assert_array_equal(
+            np.asarray(eng._caches[i][n], np.float32),
+            np.asarray(before[i][n], np.float32))
+    eng.start()
+    out1, out2 = h1.result(120.0), h2.result(120.0)
+    eng.stop()
+    np.testing.assert_array_equal(out1, cached_generate(m, p1, k1, 32))
+    np.testing.assert_array_equal(out2, cached_generate(m, p2, k2, 32))
+
+
+def test_engine_counts_both_kinds_of_state():
+    m = _nemo()
+    with DecodeEngine(m, slots=2, page=8, max_len=32,
+                      cache_dtype=jnp.bfloat16) as eng:
+        eng.generate(np.arange(1, 6, dtype=np.int32), 2)
+        st = eng.stats()
+        held = sum(a.nbytes for c in eng._caches for a in c.values()) // 2
+    fixed = 2 * (4 * 8 * 8 * 4 + 3 * 64 * 2)
+    assert st["state_bytes_per_slot"] == fixed
+    assert st["cache_bytes_per_slot"] == fixed + 2 * st["cache_len"] * 8 * 2
+    assert st["cache_bytes_per_slot"] == held
+    # a model of keys and values alone has none of fixed size
+    with DecodeEngine(_lm(), slots=2, page=8, max_len=32) as eng:
+        eng.generate(np.arange(1, 6, dtype=np.int32), 2)
+        assert eng.stats()["state_bytes_per_slot"] == 0
+
+
+@pytest.mark.parametrize("make", ["ds", "nemo", "lm"])
+def test_a_finished_request_carries_the_experts_its_tokens_chose(make):
+    """An expert layer reports, beside its counts, the experts each
+    position's router chose; the engine's two programs return them for
+    every caller, and a finished request carries its own as
+    `PendingRequest.routing`, `[expert layers, positions, k]` for positions
+    0 .. len(result) - 2.  A model without routed experts reports nothing
+    and its requests carry None."""
+    from bigdl_tpu.parallel.expert import GatedMoE
+    moe = GatedMoE(32, 16, 8, 2, n_group=2, topk_group=1, held=(0, 4))
+    p, _ = moe.init(jax.random.key(0))
+    x = jax.random.normal(jax.random.key(1), (3, 1, 32))
+    _y, (_counts, seen) = moe.decode_step(p, x, None, jnp.zeros(3, jnp.int32))
+    assert seen.shape == (3, 1, 2) and seen.dtype == jnp.int32
+    np.testing.assert_array_equal(
+        seen.reshape(3, 2), moe.route(p, x.reshape(3, 32))[1])
+    m = {"ds": _ds, "nemo": _nemo, "lm": _lm}[make]()
+    prompt = np.arange(3, 14, dtype=np.int32)
+    with DecodeEngine(m, slots=2, page=16, max_len=32) as eng:
+        req = eng.submit(prompt, 5)
+        row = req.result(120.0)
+        report = eng._step_exe(eng._cache_len)(
+            eng._params, eng._state, eng._fresh_caches(eng._cache_len),
+            jnp.zeros(2, jnp.int32), jnp.full(2, -1, jnp.int32))[2]
+    if make == "lm":
+        assert report is None and req.routing is None
+        return
+    experts, k = 1, 2               # each of the two has one expert layer
+    assert report[1].shape == (experts, 2, k)
+    assert req.routing.shape == (experts, len(row) - 1, k) \
+        and req.routing.dtype == np.int32
+    # ds: the expert layer follows the last attention layer, so the prefill
+    # ran it on the prompt's last position alone; nemo's lies before its
+    # last layer that keeps state, and saw the whole prompt
+    given = (req.routing >= 0).all(-1)[0]
+    assert given[10:].all()
+    assert given[:10].all() == (make == "nemo") and not (
+        make == "ds" and given[:10].any())
+    # the choices are the router's on the full forward's input to the layer
+    from bigdl_tpu.nn.containers import Sequential
+    seen = []
+
+    def walk(mod, pp, ss, h):
+        if isinstance(mod, Sequential):
+            for mm, p2, s2 in zip(mod.modules, pp, ss):
+                h = walk(mm, p2, s2, h)
+            return h
+        if isinstance(mod, nn.ConcatTable):
+            return [walk(mm, p2, s2, h)
+                    for mm, p2, s2 in zip(mod.modules, pp, ss)]
+        if isinstance(mod, GatedMoE):
+            seen.append(mod.route(pp, h.reshape(-1, h.shape[-1]))[1])
+        return mod.apply(pp, ss, h)[0]
+
+    walk(m, m.params, m.state, jnp.asarray(row[None, :-1]))
+    want = np.asarray(seen[0])
+    np.testing.assert_array_equal(
+        np.sort(req.routing[0][given], -1), np.sort(want[given], -1))
+
+
 def test_layers_declare_their_decode_state():
     mha = nn.MultiHeadAttention(32, 4, causal=True)
     assert mha.decode_state(3, 16) == {
@@ -53,13 +217,14 @@ def test_layers_declare_their_decode_state():
 
 
 @pytest.mark.parametrize("model,prompts", [
-    ("lm", [(5, 9), (3, 12), (17, 6)]), ("ds", [(4, 7), (9, 5)])])
+    ("lm", [(5, 9), (3, 12), (17, 6)]), ("ds", [(4, 7), (9, 5)]),
+    ("nemo", [(5, 9), (13, 6), (3, 11)])])
 def test_engine_tokens_are_bit_equal_to_cached_generate(model, prompts):
     """The merged walk against the oracle's own, for both state kinds
     (`cached_generate` steps a latent layer through its `decode_step`, all
     rows at one position; the engine prefills it in one pass and steps every
     slot at its own)."""
-    m = _lm() if model == "lm" else _ds()
+    m = {"lm": _lm, "ds": _ds, "nemo": _nemo}[model]()
     rows = [np.random.default_rng(100 + n).integers(1, 64, n).astype(np.int32)
             for n, _ in prompts]
     with DecodeEngine(m, slots=2, page=8, max_len=32) as eng:
@@ -68,7 +233,7 @@ def test_engine_tokens_are_bit_equal_to_cached_generate(model, prompts):
         st = eng.stats()
     for p, (_, k), out in zip(rows, prompts, outs):
         np.testing.assert_array_equal(out, cached_generate(m, p, k, 32))
-    assert ("expert_tokens" in st) == (model == "ds")
+    assert ("expert_tokens" in st) == (model in ("ds", "nemo"))
 
 
 def test_an_expert_layer_reads_which_tokens_are_real_off_the_interface():
@@ -83,17 +248,17 @@ def test_an_expert_layer_reads_which_tokens_are_real_off_the_interface():
     x = jax.random.normal(jax.random.key(1), (1, 12, 32))
     want, ns = moe.apply(p, s, x)
     assert int(ns["expert_tokens"].sum()) == 12 * 2
-    y, counts = moe.decode_prefill(p, x, None, 0, 7)
+    y, (counts, _chosen) = moe.decode_prefill(p, x, None, 0, 7)
     assert int(counts.sum()) == 7 * 2
     np.testing.assert_allclose(y[:, :7], want[:, :7], atol=1e-6)
     # the last real position alone, as the walk hands it on after the last
     # layer that keeps leaves
-    y1, c1 = moe.decode_prefill(p, x[:, 6:7], None, 0, 7)
+    y1, (c1, _chosen) = moe.decode_prefill(p, x[:, 6:7], None, 0, 7)
     assert int(c1.sum()) == 2
     np.testing.assert_allclose(y1, want[:, 6:7], atol=1e-6)
     rows = x[0][:, None]                                   # [12, 1, 32]
     pos = jnp.asarray([3, -1, 0, 5, -1, -1, 2, 9, 1, -1, 4, 7])
-    y, counts = moe.decode_step(p, rows, None, pos)
+    y, (counts, _chosen) = moe.decode_step(p, rows, None, pos)
     assert int(counts.sum()) == 8 * 2
     live = np.asarray(pos) >= 0
     np.testing.assert_allclose(y[live, 0], want[0][live], atol=1e-6)

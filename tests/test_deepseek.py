@@ -142,7 +142,7 @@ def test_prefill_then_decode_through_the_engines_programs():
         nonlocal caches
         toks = np.zeros(bucket, np.int32)
         toks[:t0] = r.integers(0, 211, t0)
-        lp, caches, counts = eng._prefill_exe(bucket, L)(
+        lp, caches, (counts, _chosen) = eng._prefill_exe(bucket, L)(
             eng._params, eng._state, caches, jnp.asarray(toks),
             np.int32(slot), np.int32(t0))
         seqs[slot] = {"toks": list(toks[:t0]), "lp": [np.asarray(lp)]}
@@ -156,7 +156,7 @@ def test_prefill_then_decode_through_the_engines_programs():
             nxt = int(r.integers(0, 211))       # any token: teacher-forced
             seqs[s]["toks"].append(nxt)
             tok[s], pos[s] = nxt, len(seqs[s]["toks"]) - 1
-        lp, caches, counts = eng._step_exe(L)(
+        lp, caches, (counts, _chosen) = eng._step_exe(L)(
             eng._params, eng._state, caches, jnp.asarray(tok),
             jnp.asarray(pos))
         for s in active:
@@ -446,8 +446,9 @@ def test_expert_counters_reach_stats_metrics_and_the_report(monkeypatch):
     from bigdl_tpu.utils.telemetry import Tracer
     cfg, p0, model = seeded(small_cfg(heads=4, held=(4, 8)))
     prompt = np.arange(1, 8, dtype=np.int32)
-    # nobody reads: the step fetches the logits and the one count vector,
-    # and the track is not computed
+    # nobody reads: the step fetches the logits and what the expert layers
+    # report (one count vector, the chosen experts), and the track is not
+    # computed
     monkeypatch.setattr(metrics_export, "_REGISTRY", None)
     telemetry.set_active(None)
     fetched = []

@@ -26,10 +26,18 @@ bfloat16 operands, and prints one JSON line with
 * ``decided_by``: for each width in ``--margins``, the share of positions
   whose choice among the held experts the reference decides by that width in
   every layer (its ``held_choice_decided``, what the configuration's
-  ``logits_fn`` compares at), and over them each side's held flips and gaps.
+  ``logits_fn`` compares at), and over them each side's held flips and gaps;
+* with ``--forced`` (a configuration with ``routed_logits_fn``) ``forced``:
+  each side put where a served run is: its own choices forced into the
+  float32 reference, its greedy tokens' gap read there, and the largest
+  share of a layer's positions whose held choice is not the reference's own
+  given the choices before (the cell's ``logit_gap`` and
+  ``routing_disagree``); and, on the first seed, the same share for the
+  program's router with a fault planted in it (``planted``: the selection
+  bias left out; the fifth and the seventh best for the fifth and sixth).
 
     python3 tools/routing_check.py --workload dsv2.decode --seed 7 \\
-        --rows 2 --length 1024
+        --rows 2 --length 1024      (or --workload nemo3.decode --forced)
 """
 
 import argparse
@@ -46,32 +54,37 @@ if _REPO_ROOT not in sys.path:
 
 def program_choices(model, params, state, toks):
     """The program's log-probabilities and, for each ``GatedMoE`` layer, the
-    experts it chose: the model's own modules applied one after another,
-    with the router asked again on the input its layer saw."""
+    experts it chose: the model's own modules applied one after another
+    (containers walked, every other module through its own ``apply``), with
+    the router asked again on the input its layer saw.  Knows no model."""
     import jax
     import jax.numpy as jnp
+    from bigdl_tpu.nn.containers import ConcatTable, Sequential
     from bigdl_tpu.parallel.expert import GatedMoE
-    x, chosen, router = toks, [], []
-    for m, p, s in zip(model.modules, params, state):
-        ffn = m.modules[1].modules[0].modules[0].modules[1] \
-            if hasattr(m, "modules") and len(m.modules) == 2 else None
-        if isinstance(ffn, GatedMoE):
-            attn_res, mlp_res = m.modules
-            mid, _ = attn_res.apply(p[0], s[0], x)
-            norm = mlp_res.modules[0].modules[0].modules[0]
-            pn = p[1][0][0]
-            xn, _ = norm.apply(pn[0], {}, mid)
-            flat = xn.reshape(-1, xn.shape[-1])
-            _w, idx = ffn.route(pn[1], flat)
-            hot = jnp.zeros((idx.shape[0], ffn.num_experts), bool)
+    chosen, router = [], []
+
+    def walk(m, p, s, x):
+        if isinstance(m, Sequential):
+            for mm, pp, ss in zip(m.modules, p, s):
+                x = walk(mm, pp, ss, x)
+            return x
+        if isinstance(m, ConcatTable):
+            return [walk(mm, pp, ss, x)
+                    for mm, pp, ss in zip(m.modules, p, s)]
+        if isinstance(m, GatedMoE):
+            flat = x.reshape(-1, x.shape[-1])
+            _w, idx = m.route(p, flat)
+            hot = jnp.zeros((idx.shape[0], m.num_experts), bool)
             chosen.append(hot.at[jnp.arange(idx.shape[0])[:, None],
                                  idx].set(True).reshape(
-                xn.shape[:-1] + (ffn.num_experts,)))
+                x.shape[:-1] + (m.num_experts,)))
             router.append(jnp.matmul(
-                flat.astype(jnp.float32), pn[1]["gate"].astype(jnp.float32),
+                flat.astype(jnp.float32), p["gate"].astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST).reshape(
                     chosen[-1].shape))
-        x, _ = m.apply(p, s, x)
+        return m.apply(p, s, x)[0]
+
+    x = walk(model, params, state, toks)
     # [B, layers, T, routed] each
     return x, jnp.stack(chosen, axis=1), jnp.stack(router, axis=1)
 
@@ -92,14 +105,15 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seeds", default=None,
+                    help="several seeds, one line each, in one process")
+    ap.add_argument("--forced", action="store_true")
     ap.add_argument("--rows", type=int, default=2)
     ap.add_argument("--length", type=int, default=1024)
     ap.add_argument("--margins", default="0.02,0.05,0.07,0.1,0.2")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
 
-    import jax
-    import jax.numpy as jnp
     from benchmark import harness
     cell = harness.Cell(args.workload)
     cm, cfg = cell.cfg_mod, dict(cell.cfg)
@@ -107,6 +121,18 @@ def main(argv=None) -> int:
         cfg.update(cell.cfg.get("rehearse", {}))
     cm.set_policy(cfg)
     model = cm.build_model(cfg)
+    seeds = [int(x) for x in args.seeds.split(",")] if args.seeds \
+        else [args.seed]
+    for n, seed in enumerate(seeds):
+        args.seed = seed
+        one_seed(args, cm, cfg, model, planted=args.forced and n == 0)
+    return 0
+
+
+def one_seed(args, cm, cfg, model, planted: bool) -> None:
+    import jax
+    import jax.numpy as jnp
+    from benchmark import harness
     key = jax.random.key(args.seed)
     params, state = harness.program_weights(cm, cfg, model, key)
     toks = jnp.asarray(np.random.default_rng(args.seed).integers(
@@ -116,7 +142,7 @@ def main(argv=None) -> int:
     logp = logp.astype(np.float32)
     del params
     p0 = jax.jit(lambda k: cm.init_params(cfg, k))(key)
-    from benchmark.reference import deepseek_v2_share4 as ref
+    ref = cm.ref            # the configuration's own plain reference
     widths = tuple(float(e) for e in args.margins.split(","))
 
     def run(prec):
@@ -135,7 +161,7 @@ def main(argv=None) -> int:
            "logp_diff_max": float(np.abs(
                logp - np.asarray(jax.nn.log_softmax(want))).max())}
     sides = {"program": (logp, mine, mine_router)}
-    for prec in cfg["control_precisions"] + ["bf16"]:
+    for prec in cfg["control_precisions"] + ([] if args.forced else ["bf16"]):
         scores, seen = run(prec)
         sides[prec] = (scores, seen["chosen"], seen["router"])
     for name, (scores, c, router) in sides.items():
@@ -166,8 +192,48 @@ def main(argv=None) -> int:
             row[name] = {"held_flipped_positions":
                          int((fh.any(1) & clear).sum()),
                          "gap": gap_stats(g, clear)}
+    if args.forced:
+        k = ref.sizes(cfg)["k"]
+        forced_fn = jax.jit(cm.routed_logits_fn(cfg, "f32"))
+
+        def forced(hot):
+            idx = np.argsort(~hot, axis=-1, kind="stable")[..., :k]
+            got, _made, dis = forced_fn(p0, toks, jnp.asarray(
+                idx.astype(np.int32)))
+            return np.asarray(got), np.asarray(dis)
+
+        out["forced"] = {}
+        for name, (scores, c, _router) in sides.items():
+            got, dis = forced(c)
+            g = got.max(-1) - np.take_along_axis(
+                got, scores.argmax(-1)[..., None], -1)[..., 0]
+            out["forced"][name] = {
+                "gap": gap_stats(g), "disagree_max": float(dis.max()),
+                "disagree_by_layer": [round(float(x), 5)
+                                      for x in dis.max(axis=0)]}
+        if planted:
+            # the program's own router logits, a fault planted in the rule
+            bias = np.stack([np.asarray(p["select_bias"], np.float32)
+                             for _norm, p in p0[1:-2] if "gate" in p])
+            score = 1 / (1 + np.exp(-mine_router))
+            rank = lambda key: np.argsort(-key, axis=-1, kind="stable")
+
+            def hot(idx):
+                chosen = np.zeros(score.shape, bool)
+                np.put_along_axis(chosen, idx, True, -1)
+                return chosen
+
+            fair = rank(score + bias[None, :, None, :])
+            faults = {
+                "bias_left_out": hot(rank(score)[..., :k]),
+                "seventh_for_sixth": hot(np.concatenate(
+                    [fair[..., :k - 1], fair[..., k:k + 1]], -1))}
+            out["planted"] = {
+                name: {"disagree_max": float(dis.max()),
+                       "disagree_smallest_by_layer": [
+                           round(float(x), 5) for x in dis.min(axis=0)]}
+                for name, c in faults.items() for dis in [forced(c)[1]]}
     print(json.dumps(out), flush=True)
-    return 0
 
 
 if __name__ == "__main__":
