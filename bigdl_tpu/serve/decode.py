@@ -37,6 +37,16 @@ took; PAPERS.md):
   and shares no prefill code with the engine:
   it is the oracle the engine's greedy tokens are held to, token for
   token, by test.
+- **The greedy token is chosen where the log-probabilities lie.**  Both
+  programs return, beside the logits, the index of each row's largest
+  entry (the first among equals, ``np.argmax``'s rule on the same
+  bfloat16 values): ``int32[slots]`` from the step, a scalar from the
+  prefill.  The host fetches those and what the expert layers report, and
+  the ``[slots, vocabulary]`` array stays on the device.  What a request
+  says decides its row's way, nothing else: ``temperature`` 0 takes the
+  device's token; ``temperature`` above 0 has its row fetched
+  (``_LogitRow``, which fetches itself when turned into an array) and
+  goes through ``sample_next`` on the host with the request's own seed.
 - The bucket ladder extends to **(batch-slots, cache-page)** pages:
   cache length is allocated in power-of-2 multiples of
   ``BIGDL_TPU_DECODE_PAGE`` (models/decode.init_kv_cache buffers), so a
@@ -56,10 +66,13 @@ took; PAPERS.md):
   active-slot fill, prefill-vs-decode step fractions, the share of the
   prefills' positions that were padding (``prefill_pad_frac``), cache
   bytes/slot and the part of them that is of fixed size
-  (``state_bytes_per_slot``) — promoted to a ``decode:`` trace_report
+  (``state_bytes_per_slot``), and how often the device's token was taken
+  (``tokens_device_sampled``) against rows of log-probabilities brought
+  to the host for requests that sample (``logit_rows_fetched``: 0 under
+  greedy traffic) — promoted to a ``decode:`` trace_report
   section like ``aot``/``autoscale`` (utils/telemetry.phase_breakdown).  A
   model with routed experts that count their tokens (parallel/expert.GatedMoE)
-  returns one small count vector beside the logits of every call:
+  returns one small count vector beside the tokens of every call:
   ``expert_tokens`` (choices that went to experts held here),
   ``expert_tokens_elsewhere`` and ``expert_tokens_max`` (the busiest held
   expert's), so load balance reads as max over mean.
@@ -177,6 +190,41 @@ class _Seq:
         self.routed: Optional[np.ndarray] = None
 
 
+def _with_tokens(logits, caches, report):
+    """What ``models/decode`` ``_slot_step`` or ``_prefill`` returns, with
+    the index of each row's largest entry put beside the logits, the first
+    among equals: ``np.argmax``'s rule on the same values, which is part of
+    the result (bfloat16 log-probabilities tie often).  The barrier holds
+    the compiler to the values that leave the program: fused into the
+    log-softmax it would compare them as they are before they are rounded
+    to bfloat16, where ties are none."""
+    logits = jax.lax.optimization_barrier(logits)
+    tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return logits, tokens, caches, report
+
+
+class _LogitRow:
+    """One row of log-probabilities as the program left it on the device,
+    and ``token``, the index of its largest entry as the same program chose
+    it.  ``len()`` is the vocabulary; turned into an array the row is
+    fetched, which only a request that samples asks for."""
+
+    __slots__ = ("token", "_logits", "_index")
+
+    def __init__(self, token: int, logits, index: Optional[int] = None):
+        self.token = int(token)
+        self._logits = logits        # [vocabulary], or [slots, vocabulary]
+        self._index = index          # the slot's row of the latter
+
+    def __len__(self) -> int:
+        return self._logits.shape[-1]
+
+    def __array__(self, dtype=None, copy=None):
+        row = self._logits if self._index is None \
+            else self._logits[self._index]
+        return np.asarray(row, dtype=dtype)
+
+
 class DecodeEngine:
     """Persistent continuous-batching decode loop (module docstring)."""
 
@@ -260,6 +308,10 @@ class DecodeEngine:
         self.prefill_positions = 0   # positions computed for them (pads too)
         self.decode_steps = 0
         self.tokens_out = 0
+        # greedy tokens taken as the device chose them, and rows of
+        # log-probabilities fetched for the requests that sample
+        self.tokens_device_sampled = 0
+        self.logit_rows_fetched = 0
         self.seqs_done = 0
         self.seqs_failed = 0
         self.cache_grows = 0
@@ -386,6 +438,9 @@ class DecodeEngine:
             (self._params, self._state))
         fields["kind"] = kind
         fields["program"] = "jit_" + jitted.__name__
+        # the key holds the program's name, not its text: both programs
+        # return each row's greedy token beside the logits
+        fields["tokens"] = "argmax"
         fields.update(dims)
         return fields
 
@@ -406,7 +461,8 @@ class DecodeEngine:
         # program as ``jit_decode_step``
         @partial(jax.jit, donate_argnums=(2,))
         def decode_step(params, state, caches, tok, pos):
-            return kv._slot_step(model, params, state, tok, caches, pos)
+            return _with_tokens(*kv._slot_step(model, params, state, tok,
+                                               caches, pos))
 
         ivec = jax.ShapeDtypeStruct((S,), jnp.int32)
         exe = aot_mod.get_or_compile(
@@ -439,7 +495,8 @@ class DecodeEngine:
         # ``jit_decode_prefill`` on the device trace's ``XLA Modules`` line
         @partial(jax.jit, donate_argnums=(2,))
         def decode_prefill(params, state, caches, toks, slot, t0):
-            return kv._prefill(model, params, state, toks, caches, slot, t0)
+            return _with_tokens(*kv._prefill(model, params, state, toks,
+                                             caches, slot, t0))
 
         # ``body``: the key holds the program's name, not its text, and the
         # per-position prefill before this one had the same name
@@ -608,9 +665,16 @@ class DecodeEngine:
                 "expert_tokens_elsewhere": self.expert_tokens_elsewhere,
                 "expert_tokens_max": int(self._expert_tokens.max())}
 
-    def _sample(self, seq: _Seq, logits_row: np.ndarray) -> int:
-        tok, seq.rng = sample_next(logits_row[None], seq.temperature,
-                                   seq.top_k, seq.rng)
+    def _sample(self, seq: _Seq, logits_row: _LogitRow) -> int:
+        """The one place every served token passes through.  A greedy
+        request takes the token the device chose; one that samples has its
+        row fetched and goes through ``sample_next``."""
+        if seq.temperature <= 0:
+            self.tokens_device_sampled += 1
+            return logits_row.token
+        self.logit_rows_fetched += 1
+        tok, seq.rng = sample_next(np.asarray(logits_row)[None],
+                                   seq.temperature, seq.top_k, seq.rng)
         return int(tok[0])
 
     def _advance(self, s: int, tok: int) -> None:
@@ -651,7 +715,7 @@ class DecodeEngine:
             self._fail_slot(s, e)
             return
         pb = _prompt_bucket(t0)
-        # the prefill call, the fetch of its logits and the first sample
+        # the prefill call, the fetch of its token and the first sample
         with telemetry.span("decode.admit", cat="serve", prompt_len=t0,
                             bucket=pb, slot=s,
                             state_bytes=self._state_bytes):
@@ -660,16 +724,17 @@ class DecodeEngine:
             toks[:t0] = prompt
             exe = self._prefill_exe(pb, self._cache_len)
             try:
-                logits, self._caches, report = exe(
+                logits, token, self._caches, report = exe(
                     self._params, self._state, self._caches,
                     jnp.asarray(toks), jnp.int32(s), jnp.int32(t0))
             except Exception as e:  # noqa: BLE001
                 self._fail_slot(s, SlotFault(f"decode: prefill failed in "
                                              f"slot {s}: {e!r}"))
                 return
-            # one fetch for the logits and what the expert layers report
-            logits, (counts, chosen) = jax.device_get(
-                (logits, report or (None, None)))
+            # one fetch for the token and what the expert layers report; the
+            # logits stay on the device
+            token, (counts, chosen) = jax.device_get(
+                (token, report or (None, None)))
             self._count_experts(counts)
             if chosen is not None:
                 # [layers, positions, k]; a layer saw the whole bucket (its
@@ -685,7 +750,7 @@ class DecodeEngine:
             self.prefill_steps += 1
             self.prompt_tokens += t0
             self.prefill_positions += len(toks)
-            self._advance(s, self._sample(seq, logits))
+            self._advance(s, self._sample(seq, _LogitRow(token, logits)))
 
     def _tick(self) -> bool:
         """One loop iteration: admit into free slots, decode all active
@@ -729,7 +794,7 @@ class DecodeEngine:
                 self._fail_slot(s, e)
                 active.remove(s)
         if active:
-            # the step's call and the fetch of its logits: the host
+            # the step's call and the fetch of its tokens: the host
             # blocked on the device
             with telemetry.span("decode.step", cat="serve",
                                 active=len(active)):
@@ -740,12 +805,13 @@ class DecodeEngine:
                     tok[s] = seq.buf[seq.pos]
                     pos[s] = seq.pos
                 exe = self._step_exe(self._cache_len)
-                logits, self._caches, report = exe(
+                logits, tokens, self._caches, report = exe(
                     self._params, self._state, self._caches,
                     jnp.asarray(tok), jnp.asarray(pos))
-                # one fetch for the logits and what the expert layers report
-                logits, (counts, chosen) = jax.device_get(
-                    (logits, report or (None, None)))
+                # one fetch for the tokens and what the expert layers
+                # report; the [slots, vocabulary] logits stay on the device
+                tokens, (counts, chosen) = jax.device_get(
+                    (tokens, report or (None, None)))
                 self._count_experts(counts)
                 if chosen is not None:
                     for s in active:
@@ -754,8 +820,8 @@ class DecodeEngine:
             with telemetry.span("decode.sample", cat="serve",
                                 active=len(active)):
                 for s in active:
-                    self._advance(s, self._sample(self._slots[s],
-                                                  logits[s]))
+                    self._advance(s, self._sample(
+                        self._slots[s], _LogitRow(tokens[s], logits, s)))
         dt = self.clock() - t_start
         if self.min_step_s > 0 and dt < self.min_step_s:
             time.sleep(self.min_step_s - dt)
@@ -770,6 +836,8 @@ class DecodeEngine:
         telemetry.counter(
             "serve.decode", **self._expert_stats(),
             tokens_per_s=self.tokens_out / max(self._busy_s, 1e-9),
+            tokens_device_sampled=self.tokens_device_sampled,
+            logit_rows_fetched=self.logit_rows_fetched,
             fill=n_active / self.slots,
             prefill_frac=self.prefill_steps / max(steps, 1),
             prefill_pad_frac=1.0 - self.prompt_tokens
@@ -805,6 +873,8 @@ class DecodeEngine:
             "prefill_positions": self.prefill_positions,
             "decode_steps": self.decode_steps,
             "tokens_out": self.tokens_out,
+            "tokens_device_sampled": self.tokens_device_sampled,
+            "logit_rows_fetched": self.logit_rows_fetched,
             "tokens_per_s": round(self.tokens_per_s(), 3),
             "seqs_done": self.seqs_done,
             "seqs_failed": self.seqs_failed,

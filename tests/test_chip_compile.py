@@ -188,6 +188,7 @@ def _compile_decode_step(workload, one_chip):
     `DecodeEngine` builds it; returns (compiled, cfg, bytes of the state)."""
     from bigdl_tpu.common import get_policy, set_policy
     from bigdl_tpu.models import decode as kv
+    from bigdl_tpu.serve.decode import _with_tokens
     from benchmark import harness
     cell = harness.Cell(workload)
     cm, cfg, tr = cell.cfg_mod, cell.cfg, cell.traffic
@@ -202,7 +203,8 @@ def _compile_decode_step(workload, one_chip):
         caches = on(kv.cache_avals(model, slots, length, jnp.bfloat16))
         ivec = _aval((slots,), jnp.int32, one_chip)
         compiled = jax.jit(
-            lambda p, s, c, tok, pos: kv._slot_step(model, p, s, tok, c, pos),
+            lambda p, s, c, tok, pos: _with_tokens(
+                *kv._slot_step(model, p, s, tok, c, pos)),
             donate_argnums=(2,)).lower(params, state, caches, ivec,
                                        ivec).compile()
     finally:
@@ -242,6 +244,18 @@ def test_kv_decode_step_compiles_at_the_cells_sizes(one_chip):
     assert text.count(" scatter(") == 2 * cfg["n_layer"]
     assert " while(" not in text
     assert "dynamic-update-slice(" not in text
+    # the greedy tokens leave the program beside the logits (ISSUE 33), and
+    # the argmax reads the bfloat16 values that leave it and nothing else:
+    # fused into the log-softmax it compares them before they are rounded
+    # and chose another of the tied entries in 31 rows of 32 on the chip
+    entry = _entry(text)
+    root = [ln for ln in entry if ln.startswith("ROOT ")][0]
+    assert "bf16[32,50257]" in root and "s32[32]" in root
+    argmax = [ln.split(" fusion(") for ln in entry if " fusion(" in ln
+              and ln.split(" = ")[1].startswith("(bf16[32]{")
+              and "s32[32]" in ln.split(" fusion(")[0]]
+    assert len(argmax) == 1, argmax
+    assert len(argmax[0][1].split(")")[0].split(", ")) == 1, argmax
 
 
 # ------------------------------- the hybrid cell: grouped matmul, state update

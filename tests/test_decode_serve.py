@@ -67,6 +67,21 @@ def _oracle(lm, prompt, max_tokens):
                            max_len=len(prompt) + max_tokens)
 
 
+def _spy_on_compiles(monkeypatch):
+    """label -> (AOT key fields, lowered text) of every executable built
+    from here on."""
+    seen = {}
+    real = aot_mod.get_or_compile
+
+    def spy(key_fields, lower_fn, *, label, card_extra=None):
+        seen[label] = (dict(key_fields), lower_fn().as_text())
+        return real(key_fields, lower_fn, label=label,
+                    card_extra=card_extra)
+
+    monkeypatch.setattr(aot_mod, "get_or_compile", spy)
+    return seen
+
+
 # ---------------------------------------------------------------------------
 # pad_rows trailing-axis padding (satellite: serve/batcher.py)
 # ---------------------------------------------------------------------------
@@ -163,6 +178,83 @@ def test_continuous_batching_bit_matches_oracle(lm):
 
 
 # ---------------------------------------------------------------------------
+# the greedy token chosen on the device (ISSUE 33)
+# ---------------------------------------------------------------------------
+
+def test_a_greedy_and_a_sampling_slot_side_by_side(lm):
+    # what the request says decides its row's way: the greedy slot takes
+    # the device's token and no row of it is fetched; the sampling slot's
+    # rows are fetched and go through sample_next with its own seed
+    greedy, sampled = _prompts(2, lo=5, hi=9, seed=21)
+    with DecodeEngine(lm, slots=2, page=32) as eng:
+        a = eng.submit(greedy, 9)
+        b = eng.submit(sampled, 7, temperature=0.8, top_k=5, seed=3)
+        outs = a.result(120.0), b.result(120.0)
+        st = eng.stats()
+    np.testing.assert_array_equal(outs[0], _oracle(lm, greedy, 9))
+    np.testing.assert_array_equal(outs[1], cached_generate(
+        lm, sampled, 7, max_len=len(sampled) + 7, temperature=0.8, top_k=5,
+        rng=jax.random.PRNGKey(3)))
+    assert st["tokens_device_sampled"] == 9 and st["logit_rows_fetched"] == 7
+    assert st["tokens_out"] == 16
+
+
+def test_greedy_traffic_fetches_no_row_and_the_track_says_so(lm, tmp_path):
+    from bigdl_tpu.utils import telemetry
+    tr = telemetry.Tracer(str(tmp_path))
+    telemetry.set_active(tr)
+    try:
+        with DecodeEngine(lm, slots=2, page=8) as eng:
+            for h in [eng.submit(p, 4) for p in _prompts(3, seed=22)]:
+                h.result(120.0)
+            st = eng.stats()
+    finally:
+        telemetry.set_active(None)
+    assert st["logit_rows_fetched"] == 0
+    assert st["tokens_device_sampled"] == st["tokens_out"] == 12
+    track = [e["args"] for e in tr.events_tail(4096)
+             if e.get("ph") == "C" and e["name"] == "serve.decode"]
+    assert track[-1]["tokens_device_sampled"] == 12
+    assert track[-1]["logit_rows_fetched"] == 0
+    tr.flush()
+    bd = telemetry.phase_breakdown(telemetry.merge_traces(str(tmp_path)))
+    assert bd["decode"]["tokens_device_sampled"] == 12
+    line = [ln for ln in telemetry.format_report(bd).splitlines()
+            if ln.startswith("decode:")][0]
+    assert "logit_rows_fetched=" in line and "tokens_device_sampled=" in line
+
+
+def test_the_row_handed_to_sample_fetches_itself_only_when_read():
+    from bigdl_tpu.serve.decode import _LogitRow
+    logits = jax.numpy.arange(3 * 64, dtype=jax.numpy.float32).reshape(3, 64)
+    row = _LogitRow(np.int32(63), logits, 1)
+    assert len(row) == 64 and row.token == 63
+    np.testing.assert_array_equal(np.asarray(row), np.arange(64, 128))
+    np.testing.assert_array_equal(np.asarray(_LogitRow(0, logits[2])),
+                                  np.arange(128, 192))
+
+
+def test_both_programs_return_tokens_and_their_keys_say_so(lm, monkeypatch):
+    seen = _spy_on_compiles(monkeypatch)
+    eng = DecodeEngine(lm, slots=2, page=16)
+    eng._step_exe(16)
+    eng._prefill_exe(8, 16)
+    for label, logits, tokens in (
+            ("decode.step", "tensor<2x64xf32>", "tensor<2xi32>"),
+            ("decode.prefill", "tensor<64xf32>", "tensor<i32>")):
+        fields, text = seen[label]
+        main = [ln for ln in text.splitlines() if "func.func public @main"
+                in ln][0]
+        results = main.split("->")[-1]
+        assert results.index(logits) < results.index(tokens), results
+        # a warm AOT directory written before the programs returned tokens
+        # holds them under keys without this field
+        assert fields["tokens"] == "argmax"
+        assert aot_mod.fingerprint(fields) != aot_mod.fingerprint(
+            {k: v for k, v in fields.items() if k != "tokens"})
+
+
+# ---------------------------------------------------------------------------
 # the one-pass admission prefill (ISSUE 26)
 # ---------------------------------------------------------------------------
 
@@ -224,7 +316,7 @@ def test_prefill_logits_and_cache_match_the_per_position_oracle(lm, t0):
     eng = DecodeEngine(lm, slots=3, page=16, cache_dtype=np.float32)
     toks = np.zeros(8, np.int32)
     toks[:t0] = prompt
-    logits, caches, _counts = eng._prefill_exe(8, 16)(
+    logits, _token, caches, _counts = eng._prefill_exe(8, 16)(
         eng._params, eng._state, eng._fresh_caches(16), toks,
         np.int32(1), np.int32(t0))
     assert logits.shape == (64,)
@@ -243,15 +335,7 @@ def test_prefill_logits_and_cache_match_the_per_position_oracle(lm, t0):
 
 def test_prefill_program_has_no_loop_and_one_row_at_the_head(lm_odd,
                                                              monkeypatch):
-    seen = {}
-    real = aot_mod.get_or_compile
-
-    def spy(key_fields, lower_fn, *, label, card_extra=None):
-        seen[label] = (dict(key_fields), lower_fn().as_text())
-        return real(key_fields, lower_fn, label=label,
-                    card_extra=card_extra)
-
-    monkeypatch.setattr(aot_mod, "get_or_compile", spy)
+    seen = _spy_on_compiles(monkeypatch)
     eng = DecodeEngine(lm_odd, slots=2, page=16)
     eng._prefill_exe(8, 16)
     fields, text = seen["decode.prefill"]

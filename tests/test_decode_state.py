@@ -159,7 +159,7 @@ def test_a_finished_request_carries_the_experts_its_tokens_chose(make):
         row = req.result(120.0)
         report = eng._step_exe(eng._cache_len)(
             eng._params, eng._state, eng._fresh_caches(eng._cache_len),
-            jnp.zeros(2, jnp.int32), jnp.full(2, -1, jnp.int32))[2]
+            jnp.zeros(2, jnp.int32), jnp.full(2, -1, jnp.int32))[3]
     if make == "lm":
         assert report is None and req.routing is None
         return
@@ -194,6 +194,65 @@ def test_a_finished_request_carries_the_experts_its_tokens_chose(make):
     want = np.asarray(seen[0])
     np.testing.assert_array_equal(
         np.sort(req.routing[0][given], -1), np.sort(want[given], -1))
+
+
+def _tie_head(m):
+    """The head's second half of the vocabulary made a copy of its first:
+    every row of the output then holds each value twice, 32 indices apart,
+    its largest too."""
+    at = max(i for i, p in enumerate(m.params)
+             if isinstance(p, dict) and "weight" in p
+             and p["weight"].shape[0] == 64)
+    head = {k: jnp.concatenate([a[:32], a[:32]])
+            for k, a in m.params[at].items()}
+    m.params = type(m.params)(
+        head if i == at else p for i, p in enumerate(m.params))
+    return m
+
+
+@pytest.mark.parametrize("make", ["lm", "ds", "nemo"])
+def test_both_programs_choose_the_first_of_the_largest_entries(make):
+    """The engine's two programs return, beside the logits, the index of
+    each row's largest entry as `np.argmax` gives it on the same values: the
+    first among equals.  The rule is part of the result (bfloat16
+    log-probabilities tie often), so the head here makes every row tie."""
+    m = _tie_head({"lm": _lm, "ds": _ds, "nemo": _nemo}[make]())
+    eng = DecodeEngine(m, slots=3, page=16, max_len=32)
+    toks = np.zeros(8, np.int32)
+    toks[:5] = [3, 9, 4, 7, 11]
+    logits, token, caches, _report = eng._prefill_exe(8, 16)(
+        eng._params, eng._state, eng._fresh_caches(16), jnp.asarray(toks),
+        np.int32(1), np.int32(5))
+    row = np.asarray(logits)
+    assert token.shape == () and token.dtype == jnp.int32
+    assert (row == row.max()).sum() >= 2
+    assert int(token) == np.argmax(row) < 32
+    logits, tokens, caches, _report = eng._step_exe(16)(
+        eng._params, eng._state, caches,
+        jnp.asarray([5, int(token), 17], jnp.int32),
+        jnp.asarray([-1, 5, 0], jnp.int32))
+    rows = np.asarray(logits)
+    assert tokens.shape == (3,) and tokens.dtype == jnp.int32
+    assert ((rows == rows.max(-1, keepdims=True)).sum(-1) >= 2).all()
+    np.testing.assert_array_equal(tokens, np.argmax(rows, -1))
+    assert (np.asarray(tokens) < 32).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_the_greedy_rule_is_numpys_on_the_same_values(dtype):
+    """Log-probabilities near -10.8 as bfloat16 holds them, 0.0625 apart:
+    many entries share the largest value, and the lowest index wins, as
+    `np.argmax` on the fetched row has it."""
+    from bigdl_tpu.serve.decode import _with_tokens
+    r = np.random.default_rng(4)
+    rows = jnp.asarray(-10.8 + 0.03 * r.standard_normal((16, 4096)), dtype)
+    out, got, _caches, _report = jax.jit(_with_tokens)(rows, (), None)
+    got, host = np.asarray(got), np.asarray(out)
+    np.testing.assert_array_equal(host, np.asarray(rows))
+    np.testing.assert_array_equal(got, np.argmax(host, -1))
+    assert got.dtype == np.int32
+    if dtype == jnp.bfloat16:
+        assert ((host == host.max(-1, keepdims=True)).sum(-1) > 1).all()
 
 
 def test_layers_declare_their_decode_state():

@@ -142,7 +142,7 @@ def test_prefill_then_decode_through_the_engines_programs():
         nonlocal caches
         toks = np.zeros(bucket, np.int32)
         toks[:t0] = r.integers(0, 211, t0)
-        lp, caches, (counts, _chosen) = eng._prefill_exe(bucket, L)(
+        lp, _tok, caches, (counts, _chosen) = eng._prefill_exe(bucket, L)(
             eng._params, eng._state, caches, jnp.asarray(toks),
             np.int32(slot), np.int32(t0))
         seqs[slot] = {"toks": list(toks[:t0]), "lp": [np.asarray(lp)]}
@@ -156,7 +156,7 @@ def test_prefill_then_decode_through_the_engines_programs():
             nxt = int(r.integers(0, 211))       # any token: teacher-forced
             seqs[s]["toks"].append(nxt)
             tok[s], pos[s] = nxt, len(seqs[s]["toks"]) - 1
-        lp, caches, (counts, _chosen) = eng._step_exe(L)(
+        lp, _toks, caches, (counts, _chosen) = eng._step_exe(L)(
             eng._params, eng._state, caches, jnp.asarray(tok),
             jnp.asarray(pos))
         for s in active:
