@@ -1629,7 +1629,53 @@ class Optimizer:
         # recomputed per attempt so a post-reform re-entry fires the
         # surviving rank's own address
         host_lost_point = f"host.lost@{Engine.rank()}"
-        pending_loss = None  # device array of the previous iteration's loss
+        # the step in flight whose loss the host has not read: (loss on the
+        # device, its iteration, its learning rate, its records, when it
+        # was called).  The host runs at most this one step ahead
+        pending = None
+        done_at = 0.0  # when the last loss reached the host
+
+        def fetch(**at) -> float:
+            with telemetry.span("loss_fetch", **at):
+                return float(pending[0])  # the host blocks on the device
+
+        def report(lossf: float) -> None:
+            """The pending step is complete: the sentinel, ``state["loss"]``
+            and, where its iteration logs, the line and the scalars, under
+            its own number."""
+            nonlocal pending, done_at
+            _, it, lr, n, called = pending
+            pending = None
+            # completion to completion: the device went from the last step
+            # to this one without waiting, unless nothing was in flight
+            # when this one was called
+            now = time.perf_counter()
+            dt = now - max(called, done_at)
+            done_at = now
+            state["loss"] = lossf = self._observe_loss(lossf, state)
+            self.metrics.add("computing time average", dt)
+            if it % self.log_interval:
+                return
+            rate = n / max(dt, 1e-9)
+            logger.info("Epoch %d [iteration %d] loss %.6f lr %.5g "
+                        "throughput %.1f records/s",
+                        state["epoch"], it, lossf, lr, rate)
+            if self.train_summary is not None:
+                # reference parity: Loss + LearningRate + Throughput every
+                # logged iteration (TrainSummary.scala tags, written at
+                # DistriOptimizer.scala:345-363)
+                ts = self.train_summary
+                ts.add_scalar("Loss", lossf, it)
+                ts.add_scalar("LearningRate", lr, it)
+                ts.add_scalar("Throughput", rate, it)
+
+        def drain(**at) -> None:
+            """Before anything brings device state to the host (histograms,
+            validation, a snapshot, an epoch's end): no weights leave the
+            device past a loss that was not seen finite."""
+            if pending is not None:
+                report(fetch(**at))
+
         while not self.end_trigger(state):
             self.dataset.shuffle()
             epoch_start = time.perf_counter()
@@ -1640,7 +1686,10 @@ class Optimizer:
                 # one span for the whole pass and, inside it, spans that
                 # together cover it (data, prepare, dispatch, loss_fetch,
                 # summary, triggers): what is left is the iteration's self
-                # time.  All are one call and one `is None` test when no
+                # time.  `dispatch` calls this iteration's step; `loss_fetch`
+                # then waits for the one before, and `summary` logs that one
+                # under its own number: the device never waits for the host
+                # pass.  All are one call and one `is None` test when no
                 # tracer is active
                 with telemetry.span("iteration",
                                     neval=state["neval"]) as it_span:
@@ -1705,54 +1754,33 @@ class Optimizer:
                         params, net_state, opt_state, loss = step_fn(
                             params, net_state, opt_state, inp, tgt,
                             jnp.float32(lr), rng)
-                    # Resolve the PREVIOUS step's loss (already computed on
-                    # device, so this never stalls the pipeline) — triggers
-                    # like min_loss therefore act on a 1-iteration-stale value
-                    # instead of forcing a device sync every step.
-                    if pending_loss is not None:
-                        with telemetry.span("loss_fetch", neval=neval):
-                            # the host blocked on the device
-                            lossf = float(pending_loss)
-                        state["loss"] = self._observe_loss(lossf, state)
-                    pending_loss = loss
+                    # Resolve the PREVIOUS step's loss (this iteration's
+                    # step is queued behind it, so the wait never leaves the
+                    # device idle) — triggers like min_loss therefore act on
+                    # a 1-iteration-stale value instead of forcing a device
+                    # sync every step.
+                    ran_ahead = pending is not None
+                    if ran_ahead:
+                        lossf = fetch(neval=neval)
                     n = batch.size()
                     epoch_records += n
-                    logged = neval % self.log_interval == 0
-                    if logged:
-                        with telemetry.span("loss_fetch", neval=neval):
-                            lossf = float(loss)
                     with telemetry.span("summary", neval=neval):
-                        if logged:
-                            lossf = self._observe_loss(lossf, state)
-                            state["loss"] = lossf
-                            pending_loss = None
-                            dt = time.perf_counter() - iter_start
-                            self.metrics.add("computing time average", dt)
-                            logger.info(
-                                "Epoch %d [iteration %d] loss %.6f lr %.5g "
-                                "throughput %.1f records/s",
-                                state["epoch"], neval, lossf, lr,
-                                n / max(dt, 1e-9))
-                            if self.train_summary is not None:
-                                # reference parity: Loss + LearningRate +
-                                # Throughput every logged iteration
-                                # (TrainSummary.scala tags, written at
-                                # DistriOptimizer.scala:345-363)
-                                ts = self.train_summary
-                                ts.add_scalar("Loss", lossf, neval)
-                                ts.add_scalar("LearningRate", lr, neval)
-                                ts.add_scalar("Throughput",
-                                              n / max(dt, 1e-9), neval)
+                        if ran_ahead:
+                            report(lossf)
+                        pending = (loss, neval, lr, n, iter_start)
                         # per-step telemetry: the host-side step span
-                        # (dispatch, plus the loss fetch on logged iterations)
-                        # and the counter track the trace_report phase
-                        # breakdown reads
+                        # (dispatch, plus the fetch of the loss before) and
+                        # the counter track the trace_report phase breakdown
+                        # reads
                         step_dur = time.perf_counter() - iter_start
                         telemetry.complete("step", step_dur, neval=neval)
                         counters = {
                             "data_wait_s": data_wait, "step_s": step_dur,
                             "records_per_sec": n / max(step_dur, 1e-9),
-                            "prefetch_queue_depth": float(qdepth or 0)}
+                            "prefetch_queue_depth": float(qdepth or 0),
+                            # 1: the step was called with the one before
+                            # still in flight; 0: a boundary drained it
+                            "ran_ahead": float(ran_ahead)}
                         if self._pipe_info is not None:
                             # the idle fraction of the schedule the step
                             # actually baked in: (n-1)/(m+n-1) under gpipe, the
@@ -1783,6 +1811,7 @@ class Optimizer:
                                 self.train_summary, "get_summary_trigger",
                                 lambda _n: None)("Parameters")
                             if ptrig is not None and ptrig(state):
+                                drain(neval=neval)
                                 leaves = jax.tree_util.tree_flatten_with_path(
                                     params)[0]
                                 for kp, leaf in leaves:
@@ -1807,10 +1836,13 @@ class Optimizer:
                         # — reference order
                         preempt = self._global_preempted()
                         if not preempt:
-                            self._maybe_validate(params, net_state, state)
+                            self._maybe_validate(
+                                params, net_state, state,
+                                drain=lambda: drain(neval=neval))
                         preempt, fire = self._checkpoint_decision(
                             state, force=preempt)
                         if fire:
+                            drain(neval=neval)
                             self._write_checkpoint(params, net_state, state,
                                                    opt_state, preempt=preempt)
                         if preempt:
@@ -1829,12 +1861,7 @@ class Optimizer:
                             # step 4)
                             self._check_join(state)
             self._close_data_pipeline()
-            if pending_loss is not None:
-                # outside any iteration: no `neval`
-                with telemetry.span("loss_fetch"):
-                    lossf = float(pending_loss)
-                state["loss"] = self._observe_loss(lossf, state)
-                pending_loss = None
+            drain()  # the epoch's last step; outside any iteration: no `neval`
 
             wall = time.perf_counter() - epoch_start
             if epoch_records == 0:
@@ -1901,10 +1928,12 @@ class Optimizer:
 
     # -- trigger hooks --------------------------------------------------
 
-    def _maybe_validate(self, params, net_state, state):
+    def _maybe_validate(self, params, net_state, state, drain=None):
         if (self.validation_trigger is None or
                 not self.validation_trigger(state)):
             return
+        if drain is not None:
+            drain()  # the loop's pending loss, before weights are read
         if self._sup is not None:
             self._sup.beat("validation")
         with telemetry.span("validation", neval=state["neval"]):

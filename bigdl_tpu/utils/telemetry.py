@@ -686,9 +686,19 @@ def phase_breakdown(merged: dict) -> dict:
     decode = {series[len("serve.decode."):]: st["last"]
               for series, st in counters.items()
               if series.startswith("serve.decode.")}
+    # the optimizer loop's track, promoted the same way: how many steps it
+    # counted and the share of them called while the step before was still
+    # in flight (optim/optimizer.py `ran_ahead`; the others wait for the
+    # device: an epoch's first step, and the one after a snapshot,
+    # validation or a histogram pull) — "does the host hide behind the
+    # device?" becomes a report line
+    ahead = counters.get("train.ran_ahead")
+    train = {"steps": ahead["count"], "ran_ahead": ahead["mean"]} \
+        if ahead is not None else {}
     return {"phases": phases, "ranks": ranks, "counters": counters,
             "aot": aot, "autoscale": autoscale, "deploy": deploy,
             "elastic": elastic, "fleet": fleet, "decode": decode,
+            "train": train,
             "data_wait_fraction": round(frac, 4),
             "diagnosis": ("input-bound (data_wait_fraction "
                           f"{frac:.2f} > 0.5: the host pipeline gates the "
@@ -736,6 +746,9 @@ def format_report(breakdown: dict, merged: Optional[dict] = None) -> str:
             st = breakdown["counters"][name]
             lines.append(f"{name:<28}{st['count']:>8}{st['mean']:>14.6g}"
                          f"{st['max']:>14.6g}{st['last']:>14.6g}")
+    if breakdown.get("train"):
+        lines.append("train: steps={steps}  ran_ahead={ran_ahead:g}".format(
+            **breakdown["train"]))
     if breakdown.get("aot"):
         lines.append("aot ledger: " + "  ".join(
             f"{k}={v}" for k, v in sorted(breakdown["aot"].items())))

@@ -545,9 +545,11 @@ def test_train_track_in_trace_and_report(tmp_path, monkeypatch):
     assert len(counters) == 2
     for e in counters:
         assert set(e["args"]) <= {"data_wait_s", "step_s", "records_per_sec",
-                                  "prefetch_queue_depth",
+                                  "prefetch_queue_depth", "ran_ahead",
                                   "pipe_bubble_fraction"}
         assert e["args"]["step_s"] > 0 and e["args"]["records_per_sec"] > 0
+    # the second step was called before the first one's loss was read
+    assert [e["args"]["ran_ahead"] for e in counters] == [0.0, 1.0]
 
     r = subprocess.run(
         [sys.executable, os.path.join(_REPO_ROOT, "tools",
@@ -556,3 +558,4 @@ def test_train_track_in_trace_and_report(tmp_path, monkeypatch):
         env={**os.environ, "PYTHONPATH": _REPO_ROOT})
     assert r.returncode == 0, r.stderr
     assert "train.step_s" in r.stdout and "mfu" not in r.stdout
+    assert "train: steps=2  ran_ahead=0.5" in r.stdout

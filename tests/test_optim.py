@@ -530,3 +530,172 @@ def test_graph_scale_propagates_and_regularizer_is_scaled():
     after = [np.asarray(x) for x in jax.tree.leaves(g.params)]
     for a, b in zip(before, after):   # fully frozen incl. weight decay
         np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# the loop logs every iteration from the pending loss (ISSUE 38): step n + 1
+# is called before loss n is read, and nothing a user sees changes but when
+# ---------------------------------------------------------------------------
+
+class _Recorder:
+    """A train summary that keeps what the loop hands it."""
+
+    def __init__(self, parameters_at=()):
+        self.scalars, self.histograms, self.logged_before = [], {}, {}
+        self.parameters_at = set(parameters_at)
+
+    def add_scalar(self, tag, value, step):
+        self.scalars.append((tag, step, value))
+        return self
+
+    def add_histogram(self, name, values, step):
+        self.histograms.setdefault(step, {})[name] = np.array(values)
+        self.logged_before[step] = [s for s, _ in self.series("Loss")]
+        return self
+
+    def get_summary_trigger(self, name):
+        if name == "Parameters" and self.parameters_at:
+            return lambda state: state["neval"] in self.parameters_at
+        return None
+
+    def series(self, tag):
+        return [(step, v) for t, step, v in self.scalars if t == tag]
+
+
+def _loop_run(log_interval=1, epochs=2, method=None, summary=None,
+              tap=None, **ckpt):
+    """A seeded run of 4 iterations an epoch; returns the optimizer, the
+    summary and every loss the loop observed, in order."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu.common import set_seed
+    from bigdl_tpu.dataset import DataSet, Sample, SampleToMiniBatch
+    from bigdl_tpu.optim import Optimizer
+    set_seed(11)
+    rng = np.random.default_rng(0)
+    samples = [Sample(rng.standard_normal(6).astype(np.float32),
+                      np.float32(i % 2)) for i in range(64)]
+    ds = DataSet.array(samples).transform(
+        SampleToMiniBatch(16, drop_last=True))
+    if tap is not None:
+        ds = ds.transform(tap)
+    summary = summary or _Recorder()
+    opt = (Optimizer(nn.Sequential().add(nn.Linear(6, 2)), ds,
+                     nn.CrossEntropyCriterion())
+           .set_optim_method(method or Adam(1e-2))
+           .set_end_when(Trigger.max_epoch(epochs))
+           .set_train_summary(summary)
+           .set_log_interval(log_interval))
+    if ckpt:
+        opt.set_checkpoint(ckpt["path"], ckpt["trigger"])
+    observed = []
+    orig = opt._observe_loss
+
+    def observe(lossf, state):
+        observed.append(orig(lossf, state))
+        return observed[-1]
+    opt._observe_loss = observe
+    return opt, summary, observed
+
+
+@pytest.mark.parametrize("log_interval", [1, 3, 10 ** 9])
+def test_every_iteration_logs_once_in_order_whatever_the_interval(
+        log_interval):
+    """`log_interval` decides which iterations print and write scalars,
+    nothing else: the losses are the same numbers, each observed once, in
+    order, and a logged iteration carries its own number."""
+    opt, every, base = _loop_run(1)
+    opt.optimize()
+    assert [s for s, _ in every.series("Loss")] == list(range(1, 9))
+    assert [v for _, v in every.series("Loss")] == base
+    assert opt.optim_method.hyper["loss"] == base[-1]
+
+    opt, some, observed = _loop_run(log_interval)
+    opt.optimize()
+    assert observed == base
+    logged = [n for n in range(1, 9) if n % log_interval == 0]
+    assert some.series("Loss") == [(n, base[n - 1]) for n in logged]
+    for tag in ("LearningRate", "Throughput"):
+        assert [s for s, _ in some.series(tag)] == logged
+    assert all(v > 0 for _, v in some.series("Throughput"))
+    assert opt.optim_method.hyper["loss"] == base[-1]
+
+
+def test_no_snapshot_holds_a_step_whose_loss_was_not_seen(tmp_path):
+    """A batch poisoned at the iteration a checkpoint fires on: the pending
+    loss is read before the snapshot is written, so when the sentinel
+    raises no snapshot of that iteration or a later one exists, and the
+    run recovers to finite weights."""
+    from bigdl_tpu.optim.optimizer import NonFiniteLossError
+    from bigdl_tpu.utils import chaos, file_io
+    k = 3
+    opt, _, observed = _loop_run(
+        path=str(tmp_path), trigger=Trigger.several_iteration(1))
+    seen_at_raise = []
+    inner = opt._observe_loss
+
+    def observe(lossf, state):
+        try:
+            return inner(lossf, state)
+        except NonFiniteLossError:
+            seen_at_raise.append([n for _, _, n in
+                                  file_io.checkpoint_lineage(str(tmp_path))])
+            raise
+    opt._observe_loss = observe
+    chaos.clear()
+    try:
+        with chaos.scoped(f"data.batch=nan@{k}"):
+            trained = opt.optimize()
+            assert chaos.counts()["data.batch"] > k     # training went on
+    finally:
+        chaos.clear()
+    assert seen_at_raise == [[2, 1]]
+    assert all(np.all(np.isfinite(np.asarray(leaf)))
+               for leaf in jax.tree.leaves(trained.params))
+    assert len(observed) >= 8 and all(np.isfinite(observed))
+
+
+@pytest.mark.parametrize("k", [1, 3, 4])
+def test_parameters_trigger_hands_over_exactly_k_updates(k):
+    """The histograms of iteration k are the parameters after k updates,
+    not k + 1: the loop is one call ahead of the losses it reads, never of
+    the weights it hands out (k = 4 is an epoch's last iteration)."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu.dataset.transformer import Transformer
+
+    class Tap(Transformer):
+        batches = []
+
+        def __call__(self, it):
+            for b in it:
+                self.batches.append((np.array(b.get_input()),
+                                     np.array(b.get_target())))
+                yield b
+
+    tap, rec = Tap(), _Recorder(parameters_at=(k,))
+    opt, _, observed = _loop_run(method=SGD(learning_rate=0.1),
+                                 summary=rec, tap=tap)
+    model, crit = opt.model, nn.CrossEntropyCriterion()
+    model.build()
+    params = jax.tree.map(np.array, model.params)   # the step donates its own
+    opt.optimize()
+    assert sorted(rec.histograms) == [k]
+    # the loss of iteration k was read, and logged, before its weights were
+    assert rec.logged_before == {k: list(range(1, k + 1))}
+    assert [s for s, _ in rec.series("Loss")] == list(range(1, 9))
+
+    def loss(p, x, y):
+        return crit.loss(model.apply(p, model.state, x, training=True)[0], y)
+    after = []
+    for x, y in tap.batches[:k + 1]:
+        g = jax.grad(loss)(params, x, y)
+        params = jax.tree.map(lambda p, d: p - 0.1 * d, params, g)
+        after.append([np.asarray(v) for v in jax.tree.leaves(params)])
+    got = list(rec.histograms[k].values())
+    assert len(got) == len(after[k - 1])
+    # within the step's own rounding of k updates, an update away from
+    # k - 1 and k + 1
+    for leaf, a in enumerate(got):
+        near = np.abs(a - after[k - 1][leaf]).max()
+        for other in (k - 2, k):
+            if other >= 0:
+                assert near < 0.05 * np.abs(a - after[other][leaf]).max()

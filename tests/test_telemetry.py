@@ -211,7 +211,7 @@ def test_traced_lenet_run_has_phase_spans_and_counters(tmp_path,
     assert len(ctr) == 5
     assert set(ctr[0]["args"]) == {"data_wait_s", "step_s",
                                    "records_per_sec",
-                                   "prefetch_queue_depth"}
+                                   "prefetch_queue_depth", "ran_ahead"}
     # the prefetch worker produced on its own named thread track
     spans = [e for e in merged["traceEvents"]
              if e["ph"] == "X" and e["name"] == "prefetch.item"]
@@ -349,6 +349,31 @@ def test_trace_report_cli(tmp_path):
         capture_output=True, text=True, timeout=120,
         env={**os.environ, "PYTHONPATH": _REPO_ROOT})
     assert res2.returncode != 0
+
+
+def test_train_track_promotes_ran_ahead_to_the_training_line(tmp_path):
+    """A synthetic `train` track of two epochs of four steps: the report's
+    `train:` line gives the steps and the share of them that were called
+    while the step before was in flight; a trace without the series (an
+    older program's) has no such line."""
+    tr = Tracer(str(tmp_path), rank=0, flush_every=0)
+    for i in range(8):
+        tr.complete("step", 0.004, neval=i + 1)
+        tr.counter("train", step_s=0.004, ran_ahead=float(i % 4 != 0))
+    tr.close()
+    bd = phase_breakdown(merge_traces(str(tmp_path)))
+    assert bd["train"] == {"steps": 8, "ran_ahead": 0.75}
+    res = subprocess.run(
+        [sys.executable, os.path.join(_REPO_ROOT, "tools",
+                                      "trace_report.py"), str(tmp_path)],
+        capture_output=True, text=True, timeout=120,
+        env={**os.environ, "PYTHONPATH": _REPO_ROOT})
+    assert res.returncode == 0, res.stderr
+    assert "train: steps=8  ran_ahead=0.75" in res.stdout.splitlines()
+    older = phase_breakdown({"traceEvents": [
+        {"ph": "C", "name": "train", "ts": 1.0, "args": {"step_s": 0.004}}]})
+    assert older["train"] == {}
+    assert "train:" not in telemetry.format_report(older)
 
 
 # ---------------------------------------------------------------------------

@@ -90,11 +90,14 @@ def test_loop_spans_cover_each_iteration_and_carry_neval(tracer):
     assert sorted(whole) == list(range(1, steps + 1))
     kids = {}
     for e in _spans(tracer):
-        if e["name"] in LOOP_CHILDREN:
+        if e["name"] in LOOP_CHILDREN and "args" in e:
             kids.setdefault(e["args"]["neval"], []).append(e)
     for n, it in whole.items():
         mine = sorted(kids[n], key=lambda e: e["ts"])
-        assert [e["name"] for e in mine] == list(LOOP_CHILDREN)
+        # `loss_fetch` waits for the step before: an epoch's first
+        # iteration (1 and 7) has none in flight
+        assert [e["name"] for e in mine] == [
+            c for c in LOOP_CHILDREN if c != "loss_fetch" or n % 6 != 1]
         # nested in the iteration, in order, without overlap
         edges = [it["ts"]] + [t for e in mine
                               for t in (e["ts"], e["ts"] + e["dur"])] \
@@ -105,12 +108,55 @@ def test_loop_spans_cover_each_iteration_and_carry_neval(tracer):
         assert 0 <= self_us < 1000, (n, self_us)
         # same thread
         assert {e["tid"] for e in mine} == {it["tid"]}
+    # an epoch's last loss is fetched after its loop, in no iteration: the
+    # span has no `neval`
+    flushed = [e for e in _spans(tracer, "loss_fetch") if "args" not in e]
+    assert len(flushed) == 2
+    assert all(e["ts"] >= whole[n]["ts"] + whole[n]["dur"] - 0.2
+               for e, n in zip(flushed, (6, 12)))
     # `step` is what it was: one an iteration, from inside `prepare` (the
     # learning rate is part of it) to the end of the log line
     step = {e["args"]["neval"]: e for e in _spans(tracer, "step")}
     assert sorted(step) == sorted(whole)
     # the epochs' ends (the `next()` that finds nothing) leave no event
     assert len(_spans(tracer, "data")) == steps
+
+
+class _LogMark:
+    """A train summary that marks on the trace when an iteration's loss is
+    handed over."""
+
+    def add_scalar(self, tag, value, step):
+        if tag == "Loss":
+            telemetry.instant("logged", neval=step)
+        return self
+
+
+def test_step_n_plus_1_is_called_before_loss_n_is_read(tracer):
+    """The order of events: iteration n + 1's `dispatch` has returned
+    before the `loss_fetch` that brings loss n to the host begins, and loss
+    n is logged after it, under its own number; an epoch's last loss is
+    read by the flush after the loop.  The `train` track's `ran_ahead`
+    says the same: every step but an epoch's first."""
+    _optimizer().set_train_summary(_LogMark()).optimize()
+    events = tracer.events_tail(1 << 16)
+    logged = {e["args"]["neval"]: e["ts"] for e in events
+              if e["ph"] == "i" and e["name"] == "logged"}
+    assert list(logged) == list(range(1, 13))           # once each, in order
+    whole, dispatch, fetch = (
+        {e["args"]["neval"]: e for e in _spans(tracer, name) if "args" in e}
+        for name in ("iteration", "dispatch", "loss_fetch"))
+    for n in range(1, 13):
+        if n % 6 == 0:
+            # an epoch's last: no later iteration; read after its own ends
+            assert logged[n] >= whole[n]["ts"] + whole[n]["dur"] - 0.2
+            continue
+        called = dispatch[n + 1]["ts"] + dispatch[n + 1]["dur"]
+        assert called <= fetch[n + 1]["ts"] + 0.2 and \
+            fetch[n + 1]["ts"] + fetch[n + 1]["dur"] <= logged[n] + 0.2, n
+    ahead = [e["args"]["ran_ahead"] for e in events
+             if e["ph"] == "C" and e["name"] == "train"]
+    assert ahead == [0.0, 1.0, 1.0, 1.0, 1.0, 1.0] * 2
 
 
 def test_data_span_is_the_blocking_next(tracer, monkeypatch):
