@@ -140,8 +140,13 @@ class PendingRequest:
                 if self.rid is not None:
                     args["req"] = self.rid
                 if self.admitted is not None:
+                    # a decode request: the engine's stamps, and the
+                    # lengths that turn them into time per token
                     args["queue_wait_ms"] = \
                         (self.admitted - self.enqueued) * 1e3
+                    args["prompt_len"] = len(self.payload["prompt"])
+                    if result is not None:
+                        args["tokens"] = len(result) - args["prompt_len"]
                 if self.first_token is not None:
                     args["ttft_ms"] = \
                         (self.first_token - self.enqueued) * 1e3
@@ -658,9 +663,13 @@ class DecodeQueue(DynamicBatcher):
 
     def wait_for_work(self, timeout: float) -> bool:
         """Park the step loop (briefly) until a sequence is queued or the
-        queue closes.  Returns True when there may be work."""
+        queue closes.  Returns True when there may be work.  Each sleep is
+        one ``decode.idle`` span (the engine had nothing to do: the
+        device's idle time there is the traffic's, not the host's); a call
+        that returns at once leaves none."""
         with self._cond:
             if self._q or self._closed:
                 return True
-            self._cond.wait(timeout)
+            with telemetry.span("decode.idle", cat="serve"):
+                self._cond.wait(timeout)
             return bool(self._q) or self._closed

@@ -62,7 +62,19 @@ took; PAPERS.md):
   eviction and tenant quotas all apply per-sequence; ``note_service``
   learns seconds/token so ``retry_after_s`` scales with the queued
   token budget.
-- Telemetry: the ``serve.decode`` counter track emits tokens/s,
+- Telemetry: a working pass of the loop is one ``decode.tick`` span
+  (``active``: slots carried over from the pass before, ``admitted``) with
+  ``decode.admit`` (one an admission), ``decode.step`` and
+  ``decode.sample`` inside it; in the first two, ``decode.call`` is the
+  host's part up to the executable's return and ``decode.fetch`` the one
+  ``jax.device_get``: the host blocked on the device and the transfer.  A
+  pass with nothing to do sleeps in ``decode.idle`` (serve/batcher.py).
+  Every active slot gets one token a tick, so the gaps a caller sees
+  between tokens are the periods of consecutive ticks; a request's flow
+  has four events (``queue.enqueue``, ``decode.admit``,
+  ``decode.first_token``, ``resolve``) whatever its length, and
+  ``serve.request`` carries ``queue_wait_ms``, ``ttft_ms``, ``prompt_len``
+  and ``tokens``.  The ``serve.decode`` counter track emits tokens/s,
   active-slot fill, prefill-vs-decode step fractions, the share of the
   prefills' positions that were padding (``prefill_pad_frac``), cache
   bytes/slot and the part of them that is of fixed size
@@ -299,6 +311,7 @@ class DecodeEngine:
         self._slots: List[Optional[_Seq]] = [None] * self.slots
         self._caches = None
         self._cache_len = 0
+        self._cache_bytes = 0        # bytes a slot holds at that length
         self._recorder = None
         self._thread: Optional[threading.Thread] = None
         self._lock = threading.Lock()
@@ -536,7 +549,7 @@ class DecodeEngine:
             # idle engine: re-page to exactly what the next admission
             # needs (a 17-token prompt must not pay for max_len)
             self._caches = self._fresh_caches(want)
-            self._cache_len = want
+            self._set_cache_len(want)
             return
         if want > self._cache_len:
             # grow to the next page: each leaf padded with zeros along its
@@ -544,17 +557,21 @@ class DecodeEngine:
             # weight, so the in-flight slots decode on unchanged
             self._caches = kv.grow_cache(self.model, self._caches, want,
                                          self._mesh)
-            self._cache_len = want
+            self._set_cache_len(want)
             self.cache_grows += 1
+
+    def _set_cache_len(self, cache_len: int) -> None:
+        """The cache's length and what follows from it: reckoned where it
+        changes (a walk over the model's modules), read every tick."""
+        self._cache_len = cache_len
+        self._cache_bytes = kv.state_bytes_per_row(
+            self.model, cache_len, self.cache_dtype)[0]
 
     def cache_bytes_per_slot(self) -> int:
         """Bytes of decode state one slot holds at the present cache
         length, leaves of both kinds, from the layers' declarations
-        (nothing is read off the device)."""
-        if self._caches is None:
-            return 0
-        return kv.state_bytes_per_row(self.model, self._cache_len,
-                                      self.cache_dtype)[0]
+        (nothing is read off the device; 0 before the first admission)."""
+        return self._cache_bytes
 
     def state_bytes_per_slot(self) -> int:
         """The part of ``cache_bytes_per_slot`` that is of fixed size
@@ -627,6 +644,10 @@ class DecodeEngine:
             reg.observe("bigdl_decode_ttft_seconds", ttft,
                         help="time to first token (submit to the first "
                              "sampled token), seconds")
+        if req.rid is not None:
+            # the one flow step of a request's tokens: the later ones are
+            # the ticks' spans, one token a tick for every active slot
+            telemetry.flow_step(req.rid, hop="decode.first_token")
 
     def _count_experts(self, counts) -> None:
         """Fold one call's expert token counts (held experts, then the
@@ -665,6 +686,17 @@ class DecodeEngine:
                 "expert_tokens_elsewhere": self.expert_tokens_elsewhere,
                 "expert_tokens_max": int(self._expert_tokens.max())}
 
+    def _fetch(self, program: str, tokens, report):
+        """The one ``jax.device_get`` of a call's tokens and of what the
+        expert layers report (the logits stay on the device): the host
+        blocked on the device and on the transfer, as ``decode.fetch``."""
+        out = (tokens, report or (None, None))
+        nbytes = sum(a.nbytes for a in jax.tree.leaves(out)) \
+            if telemetry.get_active() is not None else 0
+        with telemetry.span("decode.fetch", cat="serve", program=program,
+                            bytes=nbytes):
+            return jax.device_get(out)
+
     def _sample(self, seq: _Seq, logits_row: _LogitRow) -> int:
         """The one place every served token passes through.  A greedy
         request takes the token the device chose; one that samples has its
@@ -687,11 +719,6 @@ class DecodeEngine:
         self.tokens_out += 1
         if seq.emitted == 1:
             self._stamp_first_token(seq.req)
-        if seq.req.rid is not None:
-            # one flow step per emitted token: the per-token decode ticks
-            # become arrows on the request's chain in Perfetto
-            telemetry.flow_step(seq.req.rid, hop="decode.tick",
-                                slot=s, n=seq.emitted)
         if (seq.eos is not None and tok == seq.eos) or \
                 seq.emitted >= seq.max_tokens:
             self._finish_slot(s)
@@ -719,22 +746,23 @@ class DecodeEngine:
         with telemetry.span("decode.admit", cat="serve", prompt_len=t0,
                             bucket=pb, slot=s,
                             state_bytes=self._state_bytes):
-            # the bucket, cut to the cache where it is longer (t0 fits)
-            toks = np.zeros(min(pb, self._cache_len), np.int32)
-            toks[:t0] = prompt
-            exe = self._prefill_exe(pb, self._cache_len)
-            try:
-                logits, token, self._caches, report = exe(
-                    self._params, self._state, self._caches,
-                    jnp.asarray(toks), jnp.int32(s), jnp.int32(t0))
-            except Exception as e:  # noqa: BLE001
-                self._fail_slot(s, SlotFault(f"decode: prefill failed in "
-                                             f"slot {s}: {e!r}"))
-                return
-            # one fetch for the token and what the expert layers report; the
-            # logits stay on the device
-            token, (counts, chosen) = jax.device_get(
-                (token, report or (None, None)))
+            # the host's part, up to the executable's return
+            with telemetry.span("decode.call", cat="serve",
+                                program="decode_prefill"):
+                # the bucket, cut to the cache where it is longer (t0 fits)
+                toks = np.zeros(min(pb, self._cache_len), np.int32)
+                toks[:t0] = prompt
+                exe = self._prefill_exe(pb, self._cache_len)
+                try:
+                    logits, token, self._caches, report = exe(
+                        self._params, self._state, self._caches,
+                        jnp.asarray(toks), jnp.int32(s), jnp.int32(t0))
+                except Exception as e:  # noqa: BLE001
+                    self._fail_slot(s, SlotFault(f"decode: prefill failed "
+                                                 f"in slot {s}: {e!r}"))
+                    return
+            token, (counts, chosen) = self._fetch("decode_prefill", token,
+                                                  report)
             self._count_experts(counts)
             if chosen is not None:
                 # [layers, positions, k]; a layer saw the whole bucket (its
@@ -794,24 +822,24 @@ class DecodeEngine:
                 self._fail_slot(s, e)
                 active.remove(s)
         if active:
-            # the step's call and the fetch of its tokens: the host
-            # blocked on the device
+            # the step's call and the fetch of its tokens
             with telemetry.span("decode.step", cat="serve",
                                 active=len(active)):
-                tok = np.zeros(self.slots, np.int32)
-                pos = np.full(self.slots, -1, np.int32)   # -1: an idle row
-                for s in active:
-                    seq = self._slots[s]
-                    tok[s] = seq.buf[seq.pos]
-                    pos[s] = seq.pos
-                exe = self._step_exe(self._cache_len)
-                logits, tokens, self._caches, report = exe(
-                    self._params, self._state, self._caches,
-                    jnp.asarray(tok), jnp.asarray(pos))
-                # one fetch for the tokens and what the expert layers
-                # report; the [slots, vocabulary] logits stay on the device
-                tokens, (counts, chosen) = jax.device_get(
-                    (tokens, report or (None, None)))
+                # the host's part, up to the executable's return
+                with telemetry.span("decode.call", cat="serve",
+                                    program="decode_step"):
+                    tok = np.zeros(self.slots, np.int32)
+                    pos = np.full(self.slots, -1, np.int32)  # -1: idle row
+                    for s in active:
+                        seq = self._slots[s]
+                        tok[s] = seq.buf[seq.pos]
+                        pos[s] = seq.pos
+                    exe = self._step_exe(self._cache_len)
+                    logits, tokens, self._caches, report = exe(
+                        self._params, self._state, self._caches,
+                        jnp.asarray(tok), jnp.asarray(pos))
+                tokens, (counts, chosen) = self._fetch("decode_step", tokens,
+                                                       report)
                 self._count_experts(counts)
                 if chosen is not None:
                     for s in active:
@@ -843,7 +871,7 @@ class DecodeEngine:
             prefill_pad_frac=1.0 - self.prompt_tokens
             / max(self.prefill_positions, 1),
             decode_frac=self.decode_steps / max(steps, 1),
-            cache_bytes_per_slot=self.cache_bytes_per_slot(),
+            cache_bytes_per_slot=self._cache_bytes,
             state_bytes_per_slot=self._state_bytes,
             cache_len=self._cache_len)
         reg = metrics_export._REGISTRY
@@ -865,7 +893,7 @@ class DecodeEngine:
             "active": sum(1 for x in self._slots if x is not None),
             "admission": self.admission,
             "cache_len": self._cache_len,
-            "cache_bytes_per_slot": self.cache_bytes_per_slot(),
+            "cache_bytes_per_slot": self._cache_bytes,
             "state_bytes_per_slot": self._state_bytes,
             "cache_grows": self.cache_grows,
             "prefill_steps": self.prefill_steps,

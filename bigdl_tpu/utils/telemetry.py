@@ -49,8 +49,11 @@ inert until a tracer is active):
   with per-item ``prefetch.item`` spans, split into ``prefetch.produce``
   (the chain) and ``prefetch.stage`` (the copy to the device);
 - the decode engine (serve/decode.py): ``decode.tick`` with
-  ``decode.admit``/``decode.step``/``decode.sample`` inside it, and the
-  ``serve.decode`` counter track;
+  ``decode.admit``/``decode.step``/``decode.sample`` inside it, in the
+  first two ``decode.call`` (the host's part up to the executable's
+  return) and ``decode.fetch`` (the host blocked on the device and the
+  transfer), ``decode.idle`` (serve/batcher.py: the engine asleep with
+  nothing to do), and the ``serve.decode`` counter track;
 - file_io: ``ckpt.write``/``ckpt.read`` spans (write+verify),
   ``ckpt.retention`` spans, and an ``io.retry`` instant per remote-IO
   retry attempt;
@@ -71,6 +74,8 @@ Knobs (utils/config tier):
 
 from __future__ import annotations
 
+import collections
+import itertools
 import json
 import logging
 import threading
@@ -195,7 +200,9 @@ class Tracer:
         self._base_us = wall() * 1e6
         self._base_perf = self._clock()
         self._lock = threading.Lock()
-        self._events: List[dict] = []
+        # the ring: a full one drops its oldest event in O(1) per append
+        self._events: collections.deque = collections.deque(
+            maxlen=max(self.ring, 0))
         self._meta: List[dict] = []   # process/thread names: never evicted
         self._tids: Dict[int, int] = {}
         self.dropped = 0
@@ -245,10 +252,9 @@ class Tracer:
         with self._lock:
             if self._closed:
                 return
+            if len(self._events) >= self.ring:
+                self.dropped += 1       # the deque evicts its oldest
             self._events.append(ev)
-            if len(self._events) > self.ring:
-                del self._events[0]
-                self.dropped += 1
             self._since_flush += 1
             if self.flush_every > 0 and \
                     self._since_flush >= self.flush_every:
@@ -327,8 +333,9 @@ class Tracer:
 
     def flow_step(self, flow_id: str, **args) -> None:
         """A "t" flow phase: every later hop the request passes through
-        (front send, member enqueue, batch assembly, decode ticks,
-        retries, failovers) on whichever process observes it."""
+        (front send, member enqueue, batch assembly, a decode admission
+        and its first token, retries, failovers) on whichever process
+        observes it."""
         self._emit_flow("t", flow_id, args or None)
 
     def flow_finish(self, flow_id: str, **args) -> None:
@@ -344,7 +351,9 @@ class Tracer:
         reports so the timeline leading into a hang is preserved even if
         the trace file itself is lost)."""
         with self._lock:
-            return [dict(e) for e in self._events[-n:]]
+            skip = max(len(self._events) - n, 0)
+            return [dict(e) for e in
+                    itertools.islice(self._events, skip, None)]
 
     @property
     def path(self) -> str:
@@ -358,7 +367,7 @@ class Tracer:
         traced run (logged, not raised)."""
         from . import file_io
         with self._lock:
-            payload = {"traceEvents": self._meta + self._events,
+            payload = {"traceEvents": self._meta + list(self._events),
                        "displayTimeUnit": "ms",
                        "otherData": {"rank": self.rank, "host": self._host,
                                      "dropped_events": self.dropped}}
@@ -532,6 +541,39 @@ def _pct(sorted_vals: List[float], q: float) -> float:
                            len(sorted_vals) - 1)]
 
 
+def _decode_spans(spans: List[dict]) -> dict:
+    """What the decode engine's deepest spans say, for the ``decode:``
+    line: the median ``decode.call`` and ``decode.fetch`` of each program
+    (``call_ms.<program>``, ``fetch_ms.<program>``: the host's own part of
+    a device call, and its wait for the device and the transfer) with the
+    median bytes a fetch brought down (``fetch_bytes.<program>``), the
+    seconds the engine slept with nothing to do (``idle_s``), and from
+    ``serve.request``'s ``tokens`` / ``ttft_ms`` / ``prompt_len`` the median
+    time a request took for each token after its first
+    (``request_token_ms``) and its median prompt (``request_prompt_len``)."""
+    vals: Dict[str, List[float]] = {}
+    idle_s = None
+    for e in spans:
+        a = e.get("args") or {}
+        if e["name"] in ("decode.call", "decode.fetch"):
+            kind = e["name"][len("decode."):]
+            prog = str(a.get("program", "?")).replace("decode_", "")
+            vals.setdefault(f"{kind}_ms.{prog}", []).append(e["dur"] / 1e3)
+            if "bytes" in a:
+                vals.setdefault(f"fetch_bytes.{prog}", []).append(a["bytes"])
+        elif e["name"] == "decode.idle":
+            idle_s = (idle_s or 0.0) + e["dur"] / 1e6
+        elif e["name"] == "serve.request" and "prompt_len" in a:
+            vals.setdefault("request_prompt_len", []).append(a["prompt_len"])
+            if a.get("tokens", 0) > 1 and "ttft_ms" in a:
+                vals.setdefault("request_token_ms", []).append(
+                    (e["dur"] / 1e3 - a["ttft_ms"]) / (a["tokens"] - 1))
+    out = {k: round(_pct(sorted(v), 0.50), 3) for k, v in vals.items()}
+    if idle_s is not None:
+        out["idle_s"] = round(idle_s, 6)
+    return out
+
+
 def phase_breakdown(merged: dict) -> dict:
     """Per-phase stats + the input-bound-vs-compute-bound diagnosis from a
     merged trace.
@@ -682,10 +724,13 @@ def phase_breakdown(merged: dict) -> dict:
     # way: tokens/s, active-slot fill, prefill-vs-decode step fractions
     # and cache bytes/slot (serve/decode.py emits cumulative/derived
     # values per tick, so LAST is the steady-state answer) — "did the
-    # decode loop stay full and cheap?" becomes a report line
+    # decode loop stay full and cheap?" becomes a report line; beside
+    # them what its deepest spans say of the host's calls, its waits for
+    # the device and its sleep (_decode_spans)
     decode = {series[len("serve.decode."):]: st["last"]
               for series, st in counters.items()
               if series.startswith("serve.decode.")}
+    decode.update(_decode_spans(spans))
     # the optimizer loop's track, promoted the same way: how many steps it
     # counted and the share of them called while the step before was still
     # in flight (optim/optimizer.py `ran_ahead`; the others wait for the
@@ -866,7 +911,13 @@ def idle_by_cause(rows, trim: float = 0.1) -> List[list]:
     ``Tracer`` is active, :class:`_Span`) that covers it on the thread
     that drives the device, the one that holds ``dispatch`` or
     ``decode.step`` spans; what no span covers is ``unattributed`` and is
-    never spread over its neighbours.  Returns ``[[cause, seconds], ...]``,
+    never spread over its neighbours.  On the decode engine's thread the
+    deepest spans are ``decode.call`` (the device waits for the host to
+    make the call), ``decode.fetch`` (for the round trip: the result is
+    ready and the host has not seen it, or has and the next call is not
+    made), ``decode.sample``, ``decode.idle`` (for traffic: nothing was
+    asked) and what is left of ``decode.step``, ``decode.admit`` and
+    ``decode.tick`` themselves.  Returns ``[[cause, seconds], ...]``,
     largest first, averaged over the devices."""
     def dispatches(spans):
         return sum(1 for _s, _e, n in spans if n in _DISPATCH_SPANS)
@@ -924,7 +975,7 @@ _SEG_BY_DST = {
     "queue.enqueue": "transport",   # front send -> member admission
     "batch.assemble": "queue",      # enqueue -> pulled into a batch
     "decode.admit": "queue",        # enqueue -> admitted to a KV slot
-    "decode.tick": "device",        # admit/tick -> next decode step
+    "decode.first_token": "device",  # admit -> the prefill's own token
     "resolve": "device",            # batch assembly -> result resolved
     "front.done": "transport",      # member resolve -> front response
     "fleet.retry": "failover",      # send -> the attempt was abandoned

@@ -326,25 +326,234 @@ def test_request_stamps_are_ordered_and_summed(lm, monkeypatch):
         assert f"{name}_count 6" in text, text[-1500:]
 
 
+def _count(monkeypatch, owner, name, calls):
+    """Append ``name`` to ``calls`` at every call of ``owner.name``; for a
+    telemetry helper ``name:<span or track>``, and only where the engine or
+    its queue made it (compiling an executable has spans and tracks of its
+    own)."""
+    real = getattr(owner, name)
+
+    def spy(*a, **kw):
+        if owner is not telemetry:
+            calls.append(name)
+        elif str(a[0]).startswith(("decode.", "serve")):
+            calls.append(f"{name}:{a[0]}")
+        return real(*a, **kw)
+
+    monkeypatch.setattr(owner, name, spy)
+
+
+def _run_closed(lm, prompts, max_tokens, **kw):
+    """Every request queued and the queue closed before the loop starts: the
+    engine works from its first pass to its last and never waits."""
+    eng = DecodeEngine(lm, slots=2, page=16, **kw)
+    reqs = [eng.submit(p, max_tokens) for p in prompts]
+    eng.queue.close(drain=True)
+    eng.start()
+    eng.stop()
+    return eng, [r.result(120) for r in reqs]
+
+
 def test_decode_counter_arguments_wait_for_a_reader(lm, monkeypatch):
-    """The per-tick track's arguments (a sum over every cache array) are
-    computed only when a tracer or a registry will read them."""
+    """The per-tick track is emitted only when a tracer or a registry will
+    read it, and nothing a tick does walks the model: the bytes a slot holds
+    are reckoned where the cache's length changes."""
+    from bigdl_tpu.models import decode as kv
     # no registry, whatever ran before in this process (put back after)
     monkeypatch.setattr(metrics_export, "_REGISTRY", None)
     calls = []
-    real = DecodeEngine.cache_bytes_per_slot
-    monkeypatch.setattr(DecodeEngine, "cache_bytes_per_slot",
-                        lambda self: calls.append(1) or real(self))
-    with DecodeEngine(lm, slots=2, page=16) as eng:
-        eng.generate(_prompts(1)[0], 4)
-    assert not calls
+    _count(monkeypatch, kv, "state_bytes_per_row", calls)
+    _count(monkeypatch, telemetry, "counter", calls)
+    eng, _rows = _run_closed(lm, _prompts(1), 6)
+    # the constructor's state bytes and the one cache length; no track
+    assert sorted(calls) == ["counter:serve"] + ["state_bytes_per_row"] * 2
+    assert eng.stats()["cache_bytes_per_slot"] == eng.cache_bytes_per_slot() \
+        == kv.state_bytes_per_row(lm, 16, eng.cache_dtype)[0] > 0
+    del calls[:]
     tr = Tracer("memory://unused", flush_every=0)
     telemetry.set_active(tr)
+    eng, _rows = _run_closed(lm, _prompts(1), 6)
+    assert calls.count("state_bytes_per_row") == 2
+    assert calls.count("counter:serve.decode") == eng.decode_steps
+    track = [e for e in tr.events_tail(4096)
+             if e["ph"] == "C" and e["name"] == "serve.decode"]
+    assert len(track) == eng.decode_steps
+    assert track[-1]["args"]["cache_bytes_per_slot"] \
+        == eng.cache_bytes_per_slot()
+
+
+def test_no_tracer_no_telemetry_call_per_token(lm, monkeypatch):
+    """Tracing off, the telemetry calls of a run are those of its ticks and
+    its admissions: a second request decoded beside the first adds one
+    admission's three spans and nothing for its tokens."""
+    monkeypatch.setattr(metrics_export, "_REGISTRY", None)
+    calls = []
+    for name in ("span", "counter", "complete", "instant", "flow_start",
+                 "flow_step", "flow_finish"):
+        _count(monkeypatch, telemetry, name, calls)
+    n = 7
+    eng, rows = _run_closed(lm, _prompts(1, seed=3), n)
+    assert eng.tokens_out == n and eng.decode_steps == n - 1
+    one = list(calls)
+    del calls[:]
+    eng, rows = _run_closed(lm, _prompts(2, seed=3), n)
+    assert eng.tokens_out == 2 * n and eng.decode_steps == n - 1
+    admission = ["span:decode.admit", "span:decode.call",
+                 "span:decode.fetch", "counter:serve"]   # submit's depth
+    assert sorted(calls) == sorted(one + admission)
+    # a tick: itself, step > call and fetch, sample
+    tick = ["span:decode.tick", "span:decode.step", "span:decode.call",
+            "span:decode.fetch", "span:decode.sample"]
+    assert sorted(one) == sorted(tick * (n - 1) + admission)
+
+
+def _inside(child, parent, slack=0.2):
+    return parent["ts"] - slack <= child["ts"] and child["ts"] \
+        + child["dur"] <= parent["ts"] + parent["dur"] + slack
+
+
+def test_call_and_fetch_nest_in_step_and_admit_and_cover_them(tracer, lm):
+    """``decode.call`` is the host's part up to the executable's return,
+    ``decode.fetch`` the one ``device_get``; what is left of a step is the
+    experts' counts (none here), of an admission the first sample and the
+    counters: under half a millisecond by the median."""
+    eng, _rows = _run_closed(lm, _prompts(5, seed=1), 5)
+    calls, fetches = _spans(tracer, "decode.call"), \
+        _spans(tracer, "decode.fetch")
+    for parent_name, program, nbytes in (("decode.step", "decode_step", 8),
+                                         ("decode.admit", "decode_prefill",
+                                          4)):
+        parents = _spans(tracer, parent_name)
+        assert parents
+        left = []
+        for p in parents:
+            mine = [c for c in calls + fetches if _inside(c, p)
+                    and c["args"]["program"] == program]
+            assert [c["name"] for c in sorted(mine, key=lambda c: c["ts"])] \
+                == ["decode.call", "decode.fetch"], (p, mine)
+            assert all(c["tid"] == p["tid"] for c in mine)
+            fetch = [c for c in mine if c["name"] == "decode.fetch"][0]
+            # int32 tokens of two slots, or the prefill's one
+            assert fetch["args"]["bytes"] == nbytes
+            left.append(p["dur"] - sum(c["dur"] for c in mine))
+        assert min(left) >= -0.5
+        assert sorted(left)[len(left) // 2] < 500.0, left     # microseconds
+    assert len(calls) == len(fetches) == eng.decode_steps + eng.prefill_steps
+    # a working engine never slept
+    assert not _spans(tracer, "decode.idle")
+
+
+def test_an_idle_engine_leaves_idle_spans_outside_any_tick(tracer, lm):
+    import time
     with DecodeEngine(lm, slots=2, page=16) as eng:
-        eng.generate(_prompts(1)[0], 4)
-    assert calls
-    assert any(e["ph"] == "C" and e["name"] == "serve.decode"
-               for e in tr.events_tail(4096))
+        time.sleep(0.3)
+        eng.generate(_prompts(1)[0], 3)
+        time.sleep(0.15)
+    idle = _spans(tracer, "decode.idle")
+    ticks = _spans(tracer, "decode.tick")
+    assert len(idle) >= 3 and ticks
+    assert {e["tid"] for e in idle} == {e["tid"] for e in ticks}
+    # one span a sleep of at most the queue's slice; none inside a tick
+    assert all(e["dur"] <= 1e6 * (eng.queue._SLICE + 0.5) for e in idle)
+    assert sum(e["dur"] for e in idle) >= 0.2e6
+    assert not any(_inside(i, t, slack=0.0) for i in idle for t in ticks)
+
+
+def test_a_traced_request_leaves_four_flow_events_whatever_its_length(
+        tracer, lm):
+    n = 9
+    eng, rows = _run_closed(lm, _prompts(2, seed=4), n)
+    flows = [e for e in tracer.events_tail(1 << 16)
+             if e["ph"] in ("s", "t", "f")]
+    by_id = {}
+    for e in flows:
+        by_id.setdefault(e["id"], []).append(e)
+    assert len(by_id) == 2 and len(flows) == 8           # not 2 x (n + 3)
+    for evs in by_id.values():
+        assert [(e["ph"], e["args"]["hop"]) for e in evs] == [
+            ("s", "queue.enqueue"), ("t", "decode.admit"),
+            ("t", "decode.first_token"), ("f", "resolve")]
+    # the segments still sum to the request's time: queue up to the
+    # admission, device from there to the result
+    rb = telemetry.request_breakdown(
+        {"traceEvents": tracer.events_tail(1 << 16)})
+    assert rb["count"] == 2
+    for st in rb["requests"].values():
+        assert set(st["segments"]) <= {"queue", "device"}
+        assert sum(st["segments"].values()) == pytest.approx(
+            st["total_ms"], abs=0.01)
+    # the request's span says how long it was, so time per token needs no
+    # stamp per token
+    done = _spans(tracer, "serve.request")
+    assert sorted(e["args"]["prompt_len"] for e in done) \
+        == sorted(len(p) for p in _prompts(2, seed=4))
+    assert all(e["args"]["tokens"] == n for e in done)
+    assert all(len(r) == e["args"]["prompt_len"] + n
+               for r, e in zip(sorted(rows, key=len),
+                               sorted(done,
+                                      key=lambda e: e["args"]["prompt_len"])))
+
+
+def test_a_full_ring_keeps_the_newest_events(tmp_path):
+    tr = Tracer(str(tmp_path / "ring"), flush_every=0, ring=8)
+    for i in range(20):
+        tr.instant("e", n=i)
+    assert tr.dropped == 12
+    assert [e["args"]["n"] for e in tr.events_tail(64)] == list(range(12, 20))
+    assert [e["args"]["n"] for e in tr.events_tail(3)] == [17, 18, 19]
+    with open(tr.flush()) as f:
+        blob = json.load(f)
+    assert [e["args"]["n"] for e in blob["traceEvents"]
+            if e["ph"] == "i"] == list(range(12, 20))
+    assert blob["otherData"]["dropped_events"] == 12
+    # a ring that never filled dropped nothing
+    tr = Tracer(str(tmp_path / "ring2"), flush_every=0, ring=32)
+    for i in range(20):
+        tr.instant("e", n=i)
+    assert tr.dropped == 0 and len(tr.events_tail(64)) == 20
+
+
+def test_the_decode_line_names_the_deepest_spans(tracer):
+    """``trace_report``'s ``decode:`` line: the medians of ``decode.call``
+    and ``decode.fetch`` by program, the bytes a fetch brought, the seconds
+    asleep, and a request's time per token after its first."""
+    for ms, nbytes in ((0.4, 128), (0.6, 128), (0.5, 128)):
+        tracer.complete("decode.call", 2e-3, cat="serve",
+                        program="decode_step")
+        tracer.complete("decode.fetch", ms / 1e3, cat="serve",
+                        program="decode_step", bytes=nbytes)
+    tracer.complete("decode.call", 1e-3, cat="serve",
+                    program="decode_prefill")
+    tracer.complete("decode.fetch", 3e-3, cat="serve",
+                    program="decode_prefill", bytes=4)
+    tracer.complete("decode.idle", 0.05, cat="serve")
+    tracer.complete("decode.idle", 0.02, cat="serve")
+    tracer.complete("serve.request", 0.110, cat="serve", status="ok",
+                    queue_wait_ms=2.0, ttft_ms=10.0, prompt_len=40, tokens=11)
+    tracer.complete("serve.request", 0.5, cat="serve", status="ok")  # one-shot
+    tracer.counter("serve.decode", fill=0.75)
+    bd = telemetry.phase_breakdown(
+        {"traceEvents": tracer.events_tail(1 << 16)})
+    d = bd["decode"]
+    assert d["fill"] == 0.75
+    assert d["call_ms.step"] == pytest.approx(2.0)
+    assert d["fetch_ms.step"] == pytest.approx(0.5)
+    assert d["fetch_bytes.step"] == 128 and d["fetch_bytes.prefill"] == 4
+    assert d["call_ms.prefill"] == pytest.approx(1.0)
+    assert d["fetch_ms.prefill"] == pytest.approx(3.0)
+    assert d["idle_s"] == pytest.approx(0.07)
+    assert d["request_token_ms"] == pytest.approx(10.0)
+    assert d["request_prompt_len"] == 40
+    line = [ln for ln in telemetry.format_report(bd).splitlines()
+            if ln.startswith("decode:")][0]
+    for key in ("call_ms.step=2", "fetch_ms.step=0.5", "idle_s=0.07",
+                "request_token_ms=10"):
+        assert key in line, line
+    # a trace without the engine's spans keeps the track alone
+    tracer2 = Tracer("memory://unused2", flush_every=0)
+    tracer2.counter("serve.decode", fill=0.5)
+    assert telemetry.phase_breakdown(
+        {"traceEvents": tracer2.events_tail(64)})["decode"] == {"fill": 0.5}
 
 
 # ---------------------------------------------------------------------------
@@ -418,6 +627,33 @@ def test_idle_by_cause_names_spans_and_keeps_the_total():
     assert part["unattributed"] == pytest.approx(idle_s - part["dispatch"])
     # no thread that dispatches: nobody's spans explain the device
     assert [c for c, _s in idle_by_cause(rows + host[1:4])] == ["unattributed"]
+
+
+def test_idle_by_cause_names_the_decode_engines_deepest_spans():
+    """The same gap (110.38 to 140.07 ms) under the decode engine's spans:
+    each stretch goes to ``decode.call``, ``decode.fetch`` or ``decode.idle``
+    where one is open, and to their parents only where none is."""
+    rows = _recorded_rows()
+    ms = 1e6
+    host = [
+        _host("decode.tick", 100 * ms, 139 * ms),
+        _host("decode.step", 112 * ms, 138 * ms),
+        _host("decode.call", 112 * ms, 115 * ms),
+        _host("decode.fetch", 115 * ms, 137 * ms),
+        _host("decode.idle", 139 * ms, 139.8 * ms),
+    ]
+    causes = dict(idle_by_cause(rows + host))
+    alone = dict(idle_by_cause(rows))
+    assert sum(causes.values()) == pytest.approx(alone["unattributed"],
+                                                 rel=1e-9)
+    assert causes["decode.call"] == pytest.approx(3e-3, abs=2e-5)
+    assert causes["decode.fetch"] == pytest.approx(22e-3, abs=2e-5)
+    assert causes["decode.step"] == pytest.approx(1e-3, abs=2e-5)
+    assert causes["decode.idle"] == pytest.approx(0.8e-3, abs=2e-5)
+    # the tick's own: from the device's last operation to the step's span,
+    # and from the step's end to its own
+    assert 2e-3 < causes["decode.tick"] < 3e-3
+    assert 0 < causes["unattributed"] < 0.5e-3
 
 
 def test_trace_report_xplane_cli(tmp_path):

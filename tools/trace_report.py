@@ -20,7 +20,12 @@ steps, publishes, and promotions on one timeline.  Elastic episodes get
 the same treatment: the ``elastic:`` line counts the ``elastic.*``
 instants (detect/negotiate/agree/join/reform/resume) and reports
 ``joined`` — the last value of the ``peers`` counter track, the world
-size after the most recent shrink or grow (parallel/elastic.py).
+size after the most recent shrink or grow (parallel/elastic.py).  The
+``decode:`` line holds the decode engine's track (serve/decode.py) and what
+its deepest spans say: the medians of ``decode.call`` and ``decode.fetch``
+by program (the host's own part of a device call, and its wait for the
+device and the transfer), the bytes a fetch brought, the seconds asleep in
+``decode.idle``, and a request's time per token after its first.
 
 Usage::
 
