@@ -10,11 +10,36 @@ took; PAPERS.md):
 
 - :class:`DecodeEngine` runs a **persistent decode step loop** over a
   fixed-slot in-flight batch.  Every loop tick decodes ALL active slots
-  in ONE kernel call; a sequence that emits EOS or exhausts its token
-  budget leaves and frees its slot **that same tick** instead of holding
-  the batch hostage (run-to-completion static batching wastes device
-  steps on finished rows — the throughput gap tools/decode_smoke.py
-  gates, not asserts).
+  in ONE kernel call; a sequence that exhausts its token budget frees
+  its slot **with the call of its last step** instead of holding the
+  batch hostage (run-to-completion static batching wastes device steps
+  on finished rows — the throughput gap tools/decode_smoke.py gates, not
+  asserts).
+- **The loop runs one tick ahead of its reads.**  The slots' last tokens
+  live on the device as one ``int32[slots]`` vector: a prefill takes it
+  and returns it with its slot's row set to the prompt's first token, a
+  step takes it as its input tokens and leaves the next one.  A tick
+  calls, then reads: free slots are known from counts (``_Seq.called``),
+  requests are taken, their prefills called, the step called, and only
+  then one ``jax.device_get`` brings down what the calls of the tick
+  BEFORE left (the step's tokens, the admissions' first tokens, the
+  experts' counts and routing of both); first tokens are stamped and
+  finished requests answered there.  The device so holds its next
+  programs whenever it ends one, and a request is still answered as soon
+  as its last step ends (the host is by then blocked in that step's
+  fetch).  ``stats()["steps_ahead"]`` counts the steps called with a
+  call before them unread.  What cannot run ahead reads first, decided
+  by what a tick holds and by no setting: a slot whose request samples
+  has its next token on the host alone, so a tick that holds one reads
+  before it calls and the step takes that row's token from the host
+  (``feed``); an idle re-page, ``stop()`` and a tick with nothing to
+  call read everything that waits.  An EOS is seen a call late: the slot
+  computes one row too many (two after a prompt whose first token is its
+  EOS), counted nowhere a request can see
+  (``tokens_out``, ``tokens_device_sampled``, the result and its routing
+  are those of the tokens received; the experts' counts alone take it
+  in), and what it wrote lies past the next prompt's reach or under its
+  prefill, like an idle row's.
 - **Prefill and decode are separate jitted executables** with separate
   compile cards and AOT cache entries, keyed like the
   ``_ShardedForward`` buckets (module fingerprint + base fingerprint +
@@ -38,10 +63,11 @@ took; PAPERS.md):
   it is the oracle the engine's greedy tokens are held to, token for
   token, by test.
 - **The greedy token is chosen where the log-probabilities lie.**  Both
-  programs return, beside the logits, the index of each row's largest
+  programs compute, beside the logits, the index of each row's largest
   entry (the first among equals, ``np.argmax``'s rule on the same
-  bfloat16 values): ``int32[slots]`` from the step, a scalar from the
-  prefill.  The host fetches those and what the expert layers report, and
+  bfloat16 values) and return the slots' ``int32[slots]`` vector with
+  it: every row from the step, the admitted slot's from the prefill.
+  The host fetches those and what the expert layers report, and
   the ``[slots, vocabulary]`` array stays on the device.  What a request
   says decides its row's way, nothing else: ``temperature`` 0 takes the
   device's token; ``temperature`` above 0 has its row fetched
@@ -66,8 +92,11 @@ took; PAPERS.md):
   (``active``: slots carried over from the pass before, ``admitted``) with
   ``decode.admit`` (one an admission), ``decode.step`` and
   ``decode.sample`` inside it; in the first two, ``decode.call`` is the
-  host's part up to the executable's return and ``decode.fetch`` the one
-  ``jax.device_get``: the host blocked on the device and the transfer.  A
+  host's part up to the executable's return; ``decode.fetch``, in
+  ``decode.step`` after its call (in the tick itself where a pass calls
+  no step), is the one ``jax.device_get``: the host blocked on the device
+  and the transfer, for the calls of the pass before (``tick``: that
+  pass's number; ``calls``, ``bytes``).  A
   pass with nothing to do sleeps in ``decode.idle`` (serve/batcher.py).
   Every active slot gets one token a tick, so the gaps a caller sees
   between tokens are the periods of consecutive ticks; a request's flow
@@ -81,7 +110,9 @@ took; PAPERS.md):
   (``state_bytes_per_slot``), and how often the device's token was taken
   (``tokens_device_sampled``) against rows of log-probabilities brought
   to the host for requests that sample (``logit_rows_fetched``: 0 under
-  greedy traffic) — promoted to a ``decode:`` trace_report
+  greedy traffic), and ``ran_ahead`` (1 where the pass's step was called
+  with a call before it unread; its mean is the share the ``decode:``
+  line prints) — promoted to a ``decode:`` trace_report
   section like ``aot``/``autoscale`` (utils/telemetry.phase_breakdown).  A
   model with routed experts that count their tokens (parallel/expert.GatedMoE)
   returns one small count vector beside the tokens of every call:
@@ -100,8 +131,9 @@ took; PAPERS.md):
   experts.
 - Chaos: ``serve.decode@<slot>`` fires once per tick for every slot
   that participates (prefill or decode).  A faulted slot fails ITS
-  sequence typed (:class:`SlotFault`/ChaosFault), frees the slot, and
-  the other slots keep decoding with zero loss.
+  sequence typed (:class:`SlotFault`/ChaosFault), frees the slot (rows
+  of it that wait unread are dropped), and the other slots keep decoding
+  with zero loss.
 
 Config knobs (utils/config, all overridable per-engine):
 
@@ -179,19 +211,25 @@ def _prompt_bucket(t0: int) -> int:
 
 
 class _Seq:
-    """Host-side state of one in-flight sequence (one slot)."""
+    """Host-side state of one in-flight sequence (one slot).  ``called``
+    counts the tokens asked of the device (its prefill and every step that
+    carried its row), ``emitted`` those the host has read: the loop runs a
+    call ahead of its reads, so the first says when the slot is free and
+    the second when the request is answered."""
 
-    __slots__ = ("req", "buf", "t0", "pos", "emitted", "max_tokens",
-                 "eos", "temperature", "top_k", "rng", "routed")
+    __slots__ = ("req", "slot", "buf", "t0", "called", "emitted",
+                 "max_tokens", "eos", "temperature", "top_k", "rng",
+                 "routed", "over")
 
-    def __init__(self, req: PendingRequest, prompt: np.ndarray,
+    def __init__(self, req: PendingRequest, slot: int, prompt: np.ndarray,
                  max_tokens: int, eos, temperature: float, top_k: int,
                  rng):
         self.req = req
+        self.slot = slot
         self.t0 = len(prompt)
         self.buf = np.zeros(self.t0 + max_tokens, np.int32)
         self.buf[: self.t0] = prompt
-        self.pos = self.t0 - 1   # last position fed to the device
+        self.called = 0
         self.emitted = 0
         self.max_tokens = max_tokens
         self.eos = eos
@@ -200,6 +238,22 @@ class _Seq:
         self.rng = rng
         # the experts each position's routers chose (PendingRequest.routing)
         self.routed: Optional[np.ndarray] = None
+        self.over = False        # answered, with a row or an error
+
+
+class _Call:
+    """One device call whose results the host has not read: the pass that
+    made it, its program, the rows it computed as ``(sequence, position)``
+    (a prefill's one row has no position), and what it left on
+    the device: the log-probabilities, the ``int32[slots]`` tokens after
+    it and what the expert layers report."""
+
+    __slots__ = ("tick", "program", "rows", "logits", "tokens", "report")
+
+    def __init__(self, tick: int, program: str, rows, logits, tokens,
+                 report):
+        self.tick, self.program, self.rows = tick, program, rows
+        self.logits, self.tokens, self.report = logits, tokens, report
 
 
 def _with_tokens(logits, caches, report):
@@ -297,19 +351,28 @@ class DecodeEngine:
                                    clock=self.clock)
         self._mesh = mesh
         self._params, self._state = model.params, model.state
+        self._replicated = None      # where the token vector lives on a mesh
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             from ..parallel import layout as _layout
             self._params = jax.device_put(
                 self._params,
                 _layout.assign_shardings(model, self._params, mesh))
-            rep = NamedSharding(mesh, PartitionSpec())
+            rep = self._replicated = NamedSharding(mesh, PartitionSpec())
             self._state = jax.device_put(
                 self._state, jax.tree.map(lambda _: rep, self._state))
         self._module_fp = None       # lazy (fingerprinting traces shapes)
         self._exe: dict = {}         # (kind, *dims) -> compiled
         self._slots: List[Optional[_Seq]] = [None] * self.slots
         self._caches = None
+        # each slot's last token as the call before left it on the device,
+        # int32[slots]: a prefill sets its slot's row, a step takes the
+        # vector as its input and leaves the next one
+        self._tokens = jax.device_put(np.zeros(self.slots, np.int32),
+                                      self._replicated)
+        # the calls whose results the host has not read, oldest first
+        self._unread: List[_Call] = []
+        self._ticks = 0              # working passes of the loop
         self._cache_len = 0
         self._cache_bytes = 0        # bytes a slot holds at that length
         self._recorder = None
@@ -320,6 +383,9 @@ class DecodeEngine:
         self.prompt_tokens = 0       # real prompt tokens prefilled
         self.prefill_positions = 0   # positions computed for them (pads too)
         self.decode_steps = 0
+        # steps called with a call before them unread: the device held its
+        # next program when it ended the last one
+        self.steps_ahead = 0
         self.tokens_out = 0
         # greedy tokens taken as the device chose them, and rows of
         # log-probabilities fetched for the requests that sample
@@ -452,14 +518,26 @@ class DecodeEngine:
         fields["kind"] = kind
         fields["program"] = "jit_" + jitted.__name__
         # the key holds the program's name, not its text: both programs
-        # return each row's greedy token beside the logits
-        fields["tokens"] = "argmax"
+        # return each row's greedy token beside the logits, and take and
+        # leave the slots' token vector on the device
+        fields["tokens"] = "argmax_carried"
         fields.update(dims)
         return fields
 
     def _cache_avals(self, cache_len: int):
         return kv.cache_avals(self.model, self.slots, cache_len,
                               self.cache_dtype, self._mesh)
+
+    def _tokens_aval(self):
+        return jax.ShapeDtypeStruct((self.slots,), jnp.int32,
+                                    sharding=self._replicated)
+
+    def _pin_tokens(self, tokens):
+        """On a mesh the token vector leaves a program as the next one
+        takes it: whole on every device."""
+        if self._replicated is None:
+            return tokens
+        return jax.lax.with_sharding_constraint(tokens, self._replicated)
 
     def _step_exe(self, cache_len: int):
         """The decode-step executable for the (slots, cache_len) bucket:
@@ -468,23 +546,29 @@ class DecodeEngine:
         exe = self._exe.get(memo)
         if exe is not None:
             return exe
-        model, S = self.model, self.slots
+        model, S, pin = self.model, self.slots, self._pin_tokens
 
         # named for the device trace: its ``XLA Modules`` line shows this
         # program as ``jit_decode_step``
         @partial(jax.jit, donate_argnums=(2,))
-        def decode_step(params, state, caches, tok, pos):
-            return _with_tokens(*kv._slot_step(model, params, state, tok,
-                                               caches, pos))
+        def decode_step(params, state, caches, tokens, feed):
+            # ``tokens``: each row's last token as the call before left it
+            # here; ``feed``, from the host: each row's position (-1: an
+            # idle row) and, for a row whose token the host chose itself (a
+            # request that samples), that token, else -1
+            tok = jnp.where(feed[1] >= 0, feed[1], tokens)
+            logits, tokens, caches, report = _with_tokens(*kv._slot_step(
+                model, params, state, tok, caches, feed[0]))
+            return logits, pin(tokens), caches, report
 
-        ivec = jax.ShapeDtypeStruct((S,), jnp.int32)
         exe = aot_mod.get_or_compile(
             self._key_fields("decode.step", decode_step, slots=S,
                              cache_len=cache_len,
                              dtype=jnp.dtype(self.cache_dtype).name),
             lambda: decode_step.lower(
                 self._params, self._state, self._cache_avals(cache_len),
-                ivec, ivec),
+                self._tokens_aval(),
+                jax.ShapeDtypeStruct((2, S), jnp.int32)),
             label="decode.step",
             card_extra={"slots": S, "cache_len": cache_len})
         self._exe[memo] = exe
@@ -503,13 +587,16 @@ class DecodeEngine:
         exe = self._exe.get(memo)
         if exe is not None:
             return exe
-        model = self.model
+        model, pin = self.model, self._pin_tokens
 
         # ``jit_decode_prefill`` on the device trace's ``XLA Modules`` line
         @partial(jax.jit, donate_argnums=(2,))
-        def decode_prefill(params, state, caches, toks, slot, t0):
-            return _with_tokens(*kv._prefill(model, params, state, toks,
-                                             caches, slot, t0))
+        def decode_prefill(params, state, caches, tokens, toks, slot, t0):
+            # the slots' token vector comes back with row ``slot`` set to
+            # the prompt's first token: the same pass's step takes it there
+            logits, token, caches, report = _with_tokens(*kv._prefill(
+                model, params, state, toks, caches, slot, t0))
+            return logits, pin(tokens.at[slot].set(token)), caches, report
 
         # ``body``: the key holds the program's name, not its text, and the
         # per-position prefill before this one had the same name
@@ -520,6 +607,7 @@ class DecodeEngine:
                              dtype=jnp.dtype(self.cache_dtype).name),
             lambda: decode_prefill.lower(
                 self._params, self._state, self._cache_avals(cache_len),
+                self._tokens_aval(),
                 jax.ShapeDtypeStruct((min(prompt_bucket, cache_len),),
                                      jnp.int32),
                 jax.ShapeDtypeStruct((), jnp.int32),
@@ -547,7 +635,9 @@ class DecodeEngine:
         want = self._bucket_for(need)
         if self._caches is None or (idle and want != self._cache_len):
             # idle engine: re-page to exactly what the next admission
-            # needs (a 17-token prompt must not pay for max_len)
+            # needs (a 17-token prompt must not pay for max_len), once the
+            # last call on the old pages has been read
+            self._read()
             self._caches = self._fresh_caches(want)
             self._set_cache_len(want)
             return
@@ -590,28 +680,27 @@ class DecodeEngine:
             except Exception as e:  # noqa: BLE001 — engine must survive
                 # backstop: a fault not attributable to one slot fails
                 # every in-flight sequence typed rather than wedging the
-                # loop (the queue keeps serving future ticks)
-                now = self.clock()
-                for s in range(self.slots):
-                    seq = self._slots[s]
-                    if seq is not None:
-                        seq.req._resolve(error=e, now=now)
-                        self._slots[s] = None
-                        self.seqs_failed += 1
+                # loop (the queue keeps serving future ticks); what was
+                # called for them and not read is dropped
+                waiting = [seq for c in self._unread for seq, _pos in c.rows]
+                del self._unread[:]
+                for seq in waiting + [x for x in self._slots if x is not None]:
+                    self._fail(seq, e)
 
-    def _fail_slot(self, s: int, err: Exception) -> None:
-        seq = self._slots[s]
-        if seq is not None:
-            if seq.req.rid is not None:
-                # the fault lands on the request's flow (failover segment)
-                telemetry.flow_step(seq.req.rid, hop="decode.fault",
-                                    slot=s, error=type(err).__name__)
-            seq.req._resolve(error=err, now=self.clock())
-            self._slots[s] = None
-            self.seqs_failed += 1
+    def _fail(self, seq: _Seq, err: Exception) -> None:
+        """The sequence fails typed and its slot, if it still holds one, is
+        free; rows of it that wait unread are counted nowhere."""
+        if seq.over:
+            return
+        if seq.req.rid is not None:
+            # the fault lands on the request's flow (failover segment)
+            telemetry.flow_step(seq.req.rid, hop="decode.fault",
+                                slot=seq.slot, error=type(err).__name__)
+        seq.req._resolve(error=err, now=self.clock())
+        self._leave(seq)
+        self.seqs_failed += 1
 
-    def _finish_slot(self, s: int) -> None:
-        seq = self._slots[s]
+    def _finish(self, seq: _Seq) -> None:
         out = seq.buf[: seq.t0 + seq.emitted].copy()
         if seq.routed is not None:
             seq.req.routing = seq.routed[:, : len(out) - 1]
@@ -621,8 +710,15 @@ class DecodeEngine:
             reg.observe("bigdl_decode_ttlt_seconds", seq.req.latency_s,
                         help="time to last token (submit to full row), "
                              "seconds")
-        self._slots[s] = None
+        self._leave(seq)
         self.seqs_done += 1
+
+    def _leave(self, seq: _Seq) -> None:
+        """The sequence is answered.  Its slot is free, unless its last
+        call freed it already (and a next sequence may hold it by now)."""
+        seq.over = True
+        if self._slots[seq.slot] is seq:
+            self._slots[seq.slot] = None
 
     def _stamp_admitted(self, req: PendingRequest) -> None:
         req.admitted = self.clock()
@@ -686,16 +782,71 @@ class DecodeEngine:
                 "expert_tokens_elsewhere": self.expert_tokens_elsewhere,
                 "expert_tokens_max": int(self._expert_tokens.max())}
 
-    def _fetch(self, program: str, tokens, report):
-        """The one ``jax.device_get`` of a call's tokens and of what the
-        expert layers report (the logits stay on the device): the host
-        blocked on the device and on the transfer, as ``decode.fetch``."""
-        out = (tokens, report or (None, None))
+    def _fetch(self, n: int):
+        """The one ``jax.device_get`` of what the oldest ``n`` unread calls
+        left on the device: their tokens and what the expert layers report
+        (the logits stay there).  The host blocked on the device, on the
+        newest of those calls, and on the transfer, as ``decode.fetch``.
+        Returns the calls beside what came down, for :meth:`_take`; None
+        where nothing waits."""
+        if n <= 0:
+            return None
+        calls = self._unread[:n]
+        out = [(c.tokens, c.report or (None, None)) for c in calls]
         nbytes = sum(a.nbytes for a in jax.tree.leaves(out)) \
             if telemetry.get_active() is not None else 0
-        with telemetry.span("decode.fetch", cat="serve", program=program,
-                            bytes=nbytes):
-            return jax.device_get(out)
+        with telemetry.span("decode.fetch", cat="serve",
+                            program=calls[-1].program, tick=calls[-1].tick,
+                            calls=n, bytes=nbytes):
+            return calls, jax.device_get(out)
+
+    def _take(self, fetched) -> None:
+        """Give every row of the fetched calls its token, as
+        ``decode.sample``: first tokens are stamped and finished requests
+        answered here, a call after the one that computed them."""
+        if fetched is None:
+            return
+        calls, got = fetched
+        with telemetry.span("decode.sample", cat="serve",
+                            active=sum(len(c.rows) for c in calls)):
+            for c, (tokens, (counts, chosen)) in zip(calls, got):
+                self._count_experts(counts)
+                for seq, pos in c.rows:
+                    if seq.over:
+                        # it ended at an EOS the call before, or failed:
+                        # the row was computed for nobody
+                        continue
+                    if pos is None:
+                        self._prompt_routing(seq, chosen)
+                        row = _LogitRow(tokens[seq.slot], c.logits)
+                    else:
+                        if chosen is not None:
+                            seq.routed[:, pos] = chosen[:, seq.slot]
+                        row = _LogitRow(tokens[seq.slot], c.logits, seq.slot)
+                    self._advance(seq, self._sample(seq, row))
+        # only now: a fault above finds every sequence in the backstop
+        del self._unread[:len(calls)]
+
+    def _read(self) -> None:
+        """Nothing stays unread: before an idle re-page, and before a step
+        that needs a token the host alone can choose."""
+        self._take(self._fetch(len(self._unread)))
+
+    def _prompt_routing(self, seq: _Seq, chosen) -> None:
+        """A prefill's report of the experts its positions chose, into the
+        sequence's ``[layers, positions, k]``: a layer saw the whole bucket
+        (its pads go) or the prompt's last position alone."""
+        if chosen is None:
+            return
+        t0 = seq.t0
+        seq.routed = np.full(
+            (len(chosen), t0 + seq.max_tokens, chosen[0].shape[-1]),
+            -1, np.int32)
+        for layer, a in enumerate(chosen):
+            if len(a) == 1:
+                seq.routed[layer, t0 - 1] = a[0]
+            else:
+                seq.routed[layer, :t0] = a[:t0]
 
     def _sample(self, seq: _Seq, logits_row: _LogitRow) -> int:
         """The one place every served token passes through.  A greedy
@@ -709,27 +860,38 @@ class DecodeEngine:
                                    seq.temperature, seq.top_k, seq.rng)
         return int(tok[0])
 
-    def _advance(self, s: int, tok: int) -> None:
-        """Record one emitted token for slot ``s``; finish the sequence
-        the SAME step when it hits EOS or its budget."""
-        seq = self._slots[s]
-        seq.pos += 1
-        seq.buf[seq.pos] = tok
+    def _advance(self, seq: _Seq, tok: int) -> None:
+        """Record one token the host has read; the sequence is answered as
+        soon as that token is its EOS or its budget's last."""
+        seq.buf[seq.t0 + seq.emitted] = tok
         seq.emitted += 1
         self.tokens_out += 1
         if seq.emitted == 1:
             self._stamp_first_token(seq.req)
         if (seq.eos is not None and tok == seq.eos) or \
                 seq.emitted >= seq.max_tokens:
-            self._finish_slot(s)
+            self._finish(seq)
+
+    def _called(self, call: _Call) -> None:
+        """A call is on the device: its tokens are the next call's, its rows
+        wait to be read, and a row that was its sequence's last frees the
+        slot now, for the next pass's admission."""
+        self._tokens = call.tokens
+        self._unread.append(call)
+        for seq, _pos in call.rows:
+            seq.called += 1
+            if seq.called >= seq.max_tokens:
+                self._slots[seq.slot] = None
 
     def _admit(self, req: PendingRequest, s: int) -> None:
+        """Call the prefill of one request into slot ``s``; its first token
+        is read a call later."""
         p = req.payload
         prompt = p["prompt"]
         t0 = len(prompt)
         rng = jax.random.PRNGKey(p.get("seed", 0)) \
             if p.get("temperature", 0.0) > 0 else None
-        seq = _Seq(req, prompt, p["max_tokens"], p.get("eos"),
+        seq = _Seq(req, s, prompt, p["max_tokens"], p.get("eos"),
                    p.get("temperature", 0.0), p.get("top_k", 0), rng)
         self._slots[s] = seq
         self._stamp_admitted(req)
@@ -739,10 +901,9 @@ class DecodeEngine:
         try:
             chaos.fire(f"serve.decode@{s}", thread_exc=SlotFault)
         except Exception as e:  # noqa: BLE001 — typed per-sequence fail
-            self._fail_slot(s, e)
+            self._fail(seq, e)
             return
         pb = _prompt_bucket(t0)
-        # the prefill call, the fetch of its token and the first sample
         with telemetry.span("decode.admit", cat="serve", prompt_len=t0,
                             bucket=pb, slot=s,
                             state_bytes=self._state_bytes):
@@ -754,42 +915,60 @@ class DecodeEngine:
                 toks[:t0] = prompt
                 exe = self._prefill_exe(pb, self._cache_len)
                 try:
-                    logits, token, self._caches, report = exe(
+                    logits, tokens, self._caches, report = exe(
                         self._params, self._state, self._caches,
-                        jnp.asarray(toks), jnp.int32(s), jnp.int32(t0))
+                        self._tokens, jnp.asarray(toks), jnp.int32(s),
+                        jnp.int32(t0))
                 except Exception as e:  # noqa: BLE001
-                    self._fail_slot(s, SlotFault(f"decode: prefill failed "
-                                                 f"in slot {s}: {e!r}"))
+                    self._fail(seq, SlotFault(f"decode: prefill failed "
+                                              f"in slot {s}: {e!r}"))
                     return
-            token, (counts, chosen) = self._fetch("decode_prefill", token,
-                                                  report)
-            self._count_experts(counts)
-            if chosen is not None:
-                # [layers, positions, k]; a layer saw the whole bucket (its
-                # pads go) or the prompt's last position alone
-                seq.routed = np.full(
-                    (len(chosen), t0 + seq.max_tokens, chosen[0].shape[-1]),
-                    -1, np.int32)
-                for layer, a in enumerate(chosen):
-                    if len(a) == 1:
-                        seq.routed[layer, t0 - 1] = a[0]
-                    else:
-                        seq.routed[layer, :t0] = a[:t0]
+            self._called(_Call(self._ticks, "decode_prefill",
+                               [(seq, None)], logits, tokens, report))
             self.prefill_steps += 1
             self.prompt_tokens += t0
             self.prefill_positions += len(toks)
-            self._advance(s, self._sample(seq, _LogitRow(token, logits)))
+
+    def _step(self, rows) -> bool:
+        """Call the decode step for ``rows``, the sequence of every slot
+        taken: one position forward each, in ONE kernel call.  True
+        when a call before it was unread: the device holds this one by the
+        time it ends that one."""
+        # the host's part, up to the executable's return
+        with telemetry.span("decode.call", cat="serve",
+                            program="decode_step"):
+            # positions (-1: an idle row) over the tokens the host chose
+            # itself (-1: the device's own, which never came down)
+            feed = np.full((2, self.slots), -1, np.int32)
+            at = []
+            for seq in rows:
+                pos = seq.t0 + seq.called - 1
+                feed[0, seq.slot] = pos
+                if seq.temperature > 0:
+                    feed[1, seq.slot] = seq.buf[pos]
+                at.append((seq, pos))
+            exe = self._step_exe(self._cache_len)
+            logits, tokens, self._caches, report = exe(
+                self._params, self._state, self._caches, self._tokens,
+                jnp.asarray(feed))
+        ahead = bool(self._unread)
+        self._called(_Call(self._ticks, "decode_step", at, logits, tokens,
+                           report))
+        self.decode_steps += 1
+        self.steps_ahead += ahead
+        return ahead
 
     def _tick(self) -> bool:
         """One loop iteration: admit into free slots, decode all active
-        slots in one kernel call.  Returns False when closed + drained."""
+        slots in one kernel call, read what the pass before called.
+        Returns False when closed + drained."""
         q = self.queue
         free = [s for s in range(self.slots) if self._slots[s] is None]
         n_active = self.slots - len(free)
         incoming: List[PendingRequest] = []
         if free and (self.admission == "continuous" or n_active == 0):
             incoming = q.take(len(free))
-        if n_active == 0 and not incoming:
+        if n_active == 0 and not incoming and not self._unread:
             if q.closed and q.depth() == 0:
                 return False
             q.wait_for_work(DecodeQueue._SLICE)
@@ -800,56 +979,50 @@ class DecodeEngine:
         return True
 
     def _work(self, q, free, n_active, incoming) -> None:
-        """What one tick does once there is something to do (the
-        ``decode.tick`` span): admissions, one decode step, the counters."""
+        """What one pass does once there is something to do (the
+        ``decode.tick`` span).  It calls, then reads: the admissions'
+        prefills, one decode step for every slot taken, and only then the
+        results of the calls the pass before made, so the device holds its
+        next program whenever it ends one.  What cannot run ahead reads
+        first: a slot whose request samples has its next token on the host
+        alone."""
         t_start = self.clock()
         tokens_before = self.tokens_out
+        self._ticks += 1
         if incoming:
             need = max(len(r.payload["prompt"]) + r.payload["max_tokens"]
                        for r in incoming)
             self._ensure_cache(need, idle=(n_active == 0))
-            for r in incoming:
-                self._admit(r, free.pop(0))
-        # decode every still-active slot (including freshly prefilled
-        # ones — their first token is already in the buffer) one
-        # position forward, in ONE kernel call
-        active = [s for s in range(self.slots)
-                  if self._slots[s] is not None]
-        for s in list(active):
+        # the calls of the passes before: read once this pass has called
+        old = len(self._unread)
+        for r in incoming:
+            self._admit(r, free.pop(0))
+        # every slot still taken (a freshly prefilled one too: its first
+        # token lies in the token vector) goes one position forward
+        rows = [seq for seq in self._slots if seq is not None]
+        for seq in list(rows):
             try:
-                chaos.fire(f"serve.decode@{s}", thread_exc=SlotFault)
+                chaos.fire(f"serve.decode@{seq.slot}", thread_exc=SlotFault)
             except Exception as e:  # noqa: BLE001
-                self._fail_slot(s, e)
-                active.remove(s)
-        if active:
-            # the step's call and the fetch of its tokens
+                self._fail(seq, e)
+                rows.remove(seq)
+        if any(seq.temperature > 0 for seq in rows):
+            # a sampled token exists on the host alone, so this pass
+            # reads, then calls (a read can end a row: its EOS, seen now)
+            self._read()
+            old = 0
+            rows = [seq for seq in rows if not seq.over]
+        ahead = None
+        if rows:
+            # the step's call, and the host blocked on the calls before it
             with telemetry.span("decode.step", cat="serve",
-                                active=len(active)):
-                # the host's part, up to the executable's return
-                with telemetry.span("decode.call", cat="serve",
-                                    program="decode_step"):
-                    tok = np.zeros(self.slots, np.int32)
-                    pos = np.full(self.slots, -1, np.int32)  # -1: idle row
-                    for s in active:
-                        seq = self._slots[s]
-                        tok[s] = seq.buf[seq.pos]
-                        pos[s] = seq.pos
-                    exe = self._step_exe(self._cache_len)
-                    logits, tokens, self._caches, report = exe(
-                        self._params, self._state, self._caches,
-                        jnp.asarray(tok), jnp.asarray(pos))
-                tokens, (counts, chosen) = self._fetch("decode_step", tokens,
-                                                       report)
-                self._count_experts(counts)
-                if chosen is not None:
-                    for s in active:
-                        self._slots[s].routed[:, pos[s]] = chosen[:, s]
-            self.decode_steps += 1
-            with telemetry.span("decode.sample", cat="serve",
-                                active=len(active)):
-                for s in active:
-                    self._advance(s, self._sample(
-                        self._slots[s], _LogitRow(tokens[s], logits, s)))
+                                active=len(rows)):
+                ahead = self._step(rows)
+                fetched = self._fetch(old)
+        else:
+            # nothing to call: what waits is all there is to do
+            fetched = self._fetch(old)
+        self._take(fetched)
         dt = self.clock() - t_start
         if self.min_step_s > 0 and dt < self.min_step_s:
             time.sleep(self.min_step_s - dt)
@@ -861,8 +1034,11 @@ class DecodeEngine:
             return      # nothing reads the track: compute none of it
         n_active = sum(1 for s in self._slots if s is not None)
         steps = self.prefill_steps + self.decode_steps
+        # 1: this pass's step was called with a call before it unread; 0:
+        # it read first (a slot samples) or the device had nothing
+        ran = {} if ahead is None else {"ran_ahead": float(ahead)}
         telemetry.counter(
-            "serve.decode", **self._expert_stats(),
+            "serve.decode", **self._expert_stats(), **ran,
             tokens_per_s=self.tokens_out / max(self._busy_s, 1e-9),
             tokens_device_sampled=self.tokens_device_sampled,
             logit_rows_fetched=self.logit_rows_fetched,
@@ -900,6 +1076,7 @@ class DecodeEngine:
             "prompt_tokens": self.prompt_tokens,
             "prefill_positions": self.prefill_positions,
             "decode_steps": self.decode_steps,
+            "steps_ahead": self.steps_ahead,
             "tokens_out": self.tokens_out,
             "tokens_device_sampled": self.tokens_device_sampled,
             "logit_rows_fetched": self.logit_rows_fetched,

@@ -51,9 +51,11 @@ inert until a tracer is active):
 - the decode engine (serve/decode.py): ``decode.tick`` with
   ``decode.admit``/``decode.step``/``decode.sample`` inside it, in the
   first two ``decode.call`` (the host's part up to the executable's
-  return) and ``decode.fetch`` (the host blocked on the device and the
-  transfer), ``decode.idle`` (serve/batcher.py: the engine asleep with
-  nothing to do), and the ``serve.decode`` counter track;
+  return) and in ``decode.step`` (in the tick itself where it calls no
+  step) ``decode.fetch`` (the host blocked on the device and the
+  transfer, for the calls of the tick before: ``tick``), ``decode.idle``
+  (serve/batcher.py: the engine asleep with nothing to do), and the
+  ``serve.decode`` counter track (``ran_ahead`` a step);
 - file_io: ``ckpt.write``/``ckpt.read`` spans (write+verify),
   ``ckpt.retention`` spans, and an ``io.retry`` instant per remote-IO
   retry attempt;
@@ -731,6 +733,10 @@ def phase_breakdown(merged: dict) -> dict:
               for series, st in counters.items()
               if series.startswith("serve.decode.")}
     decode.update(_decode_spans(spans))
+    # ``ran_ahead`` is 0 or 1 a step (was it called with a call before it
+    # unread: serve/decode.py), so its mean is the share, as in ``train:``
+    if "ran_ahead" in decode:
+        decode["ran_ahead"] = counters["serve.decode.ran_ahead"]["mean"]
     # the optimizer loop's track, promoted the same way: how many steps it
     # counted and the share of them called while the step before was still
     # in flight (optim/optimizer.py `ran_ahead`; the others wait for the

@@ -201,12 +201,15 @@ def _compile_decode_step(workload, one_chip):
         params, state = on(jax.eval_shape(model.init, jax.random.key(0)))
         slots, length = tr["slots"], tr["max_len"]
         caches = on(kv.cache_avals(model, slots, length, jnp.bfloat16))
-        ivec = _aval((slots,), jnp.int32, one_chip)
+        # the tokens the call before left on the device, and the host's
+        # positions over the tokens it chose itself (ISSUE 40)
         compiled = jax.jit(
-            lambda p, s, c, tok, pos: _with_tokens(
-                *kv._slot_step(model, p, s, tok, c, pos)),
-            donate_argnums=(2,)).lower(params, state, caches, ivec,
-                                       ivec).compile()
+            lambda p, s, c, tokens, feed: _with_tokens(*kv._slot_step(
+                model, p, s, jnp.where(feed[1] >= 0, feed[1], tokens), c,
+                feed[0])),
+            donate_argnums=(2,)).lower(
+                params, state, caches, _aval((slots,), jnp.int32, one_chip),
+                _aval((2, slots), jnp.int32, one_chip)).compile()
     finally:
         set_policy(prior)
     cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize

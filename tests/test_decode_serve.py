@@ -239,19 +239,202 @@ def test_both_programs_return_tokens_and_their_keys_say_so(lm, monkeypatch):
     eng = DecodeEngine(lm, slots=2, page=16)
     eng._step_exe(16)
     eng._prefill_exe(8, 16)
-    for label, logits, tokens in (
-            ("decode.step", "tensor<2x64xf32>", "tensor<2xi32>"),
-            ("decode.prefill", "tensor<64xf32>", "tensor<i32>")):
+    for label, logits in (("decode.step", "tensor<2x64xf32>"),
+                          ("decode.prefill", "tensor<64xf32>")):
         fields, text = seen[label]
         main = [ln for ln in text.splitlines() if "func.func public @main"
                 in ln][0]
-        results = main.split("->")[-1]
-        assert results.index(logits) < results.index(tokens), results
+        args, results = main.split("->")
+        # both leave the slots' tokens beside the logits, and take the
+        # vector the call before left (ISSUE 40): one such argument each
+        assert results.index(logits) < results.index("tensor<2xi32>"), results
+        assert args.count("tensor<2xi32>") == 1, args
         # a warm AOT directory written before the programs returned tokens
-        # holds them under keys without this field
-        assert fields["tokens"] == "argmax"
+        # holds them under keys without this field, and one written while
+        # the host fed every token under another value of it
+        assert fields["tokens"] == "argmax_carried"
         assert aot_mod.fingerprint(fields) != aot_mod.fingerprint(
             {k: v for k, v in fields.items() if k != "tokens"})
+        assert aot_mod.fingerprint(fields) != aot_mod.fingerprint(
+            dict(fields, tokens="argmax"))
+
+
+# ---------------------------------------------------------------------------
+# the loop runs a call ahead of its reads (ISSUE 40)
+# ---------------------------------------------------------------------------
+
+class _Left:
+    """What a fake program leaves on the device: it says when it is read."""
+
+    def __init__(self, log, name, value):
+        self.log, self.name, self.value = log, name, value
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("read", self.name))
+        return np.asarray(self.value, dtype=dtype)
+
+
+def _run_queued(eng, requests, **kw):
+    """Every request queued and the queue closed before the loop starts: the
+    engine works from its first pass to its last and never waits."""
+    handles = [eng.submit(p, mt, **dict(kw, **own))
+               for p, mt, own in requests]
+    eng.queue.close(drain=True)
+    eng.start()
+    eng.stop()
+    return handles
+
+
+def test_the_next_step_is_called_before_the_last_ones_tokens_are_read(lm):
+    # programs that record when they are called and when what they left is
+    # read; each leaves its own number as every slot's token
+    log, n = [], {"step": 0, "prefill": 0}
+
+    def fake(kind):
+        def exe(params, state, caches, tokens, *rest):
+            n[kind] += 1
+            name = f"{kind}{n[kind]}"
+            log.append(("call", name))
+            return "logits", _Left(log, name, [n[kind]] * 2), caches, None
+        return exe
+
+    eng = DecodeEngine(lm, slots=2, page=16)
+    eng._step_exe = lambda cache_len: fake("step")
+    eng._prefill_exe = lambda bucket, cache_len: fake("prefill")
+    prompts = _prompts(2, seed=31)
+    handles = _run_queued(eng, [(p, 5, {}) for p in prompts])
+    st = eng.stats()
+    assert st["decode_steps"] == 4 and st["prefill_steps"] == 2
+    at = {e: i for i, e in enumerate(log)}
+    # both prefills and the first step are called before anything is read,
+    # and step k + 1 before step k's tokens come down
+    assert log[:3] == [("call", "prefill1"), ("call", "prefill2"),
+                       ("call", "step1")]
+    for k in range(1, 4):
+        assert at[("call", f"step{k + 1}")] < at[("read", f"step{k}")], log
+    assert [e for e in log if e[0] == "read"] == [
+        ("read", "prefill1"), ("read", "prefill2")] + [
+        ("read", f"step{k}") for k in range(1, 5)]
+    assert st["steps_ahead"] == st["decode_steps"]
+    # each row: its prompt, its prefill's token, then the steps' in order
+    for i, (p, h) in enumerate(zip(prompts, handles)):
+        np.testing.assert_array_equal(
+            h.result(1.0), np.concatenate([p, [i + 1, 1, 2, 3, 4]]))
+    assert st["tokens_out"] == 10 and st["active"] == 0
+
+
+def test_a_mixed_load_gets_the_oracles_rows_request_by_request(lm):
+    # greedy, greedy that stops at an EOS, sampling with a seed, budgets of
+    # one and two tokens, seven requests through two slots
+    prompts = _prompts(7, lo=3, hi=12, seed=32)
+    full = _oracle(lm, prompts[1], 9)
+    gen = [int(t) for t in full[len(prompts[1]):]]
+    k = next(i for i in range(1, len(gen)) if gen[i] not in gen[:i])
+    requests = [(prompts[0], 6, {}),
+                (prompts[1], 9, {"eos_token": gen[k]}),
+                (prompts[2], 5, {"temperature": 0.8, "top_k": 5, "seed": 3}),
+                (prompts[3], 1, {}),
+                (prompts[4], 2, {}),
+                (prompts[5], 1, {"temperature": 0.7, "seed": 9}),
+                (prompts[6], 7, {"temperature": 1.1, "top_k": 3, "seed": 4})]
+    eng = DecodeEngine(lm, slots=2, page=32)
+    rows = [h.result(1.0) for h in _run_queued(eng, requests)]
+    st = eng.stats()
+    for (p, mt, own), row in zip(requests, rows):
+        if "temperature" in own:
+            want = cached_generate(
+                lm, p, mt, max_len=len(p) + mt, temperature=own["temperature"],
+                top_k=own.get("top_k", 0), rng=jax.random.PRNGKey(own["seed"]))
+        else:
+            want = _oracle(lm, p, mt)
+            if "eos_token" in own:
+                want = want[: len(p) + k + 1]
+        np.testing.assert_array_equal(row, want)
+    # only tokens that were received count: the row a slot computed past its
+    # EOS, before the host had seen it, is nobody's
+    received = sum(len(r) - len(req[0]) for r, req in zip(rows, requests))
+    assert st["tokens_out"] == received == 6 + k + 1 + 5 + 1 + 2 + 1 + 7
+    assert st["tokens_device_sampled"] == 6 + k + 1 + 1 + 2
+    assert st["logit_rows_fetched"] == 5 + 1 + 7
+    assert st["seqs_done"] == 7 and st["active"] == 0
+    assert 0 < st["steps_ahead"] < st["decode_steps"]
+
+
+def test_a_pass_that_holds_a_sampling_slot_reads_before_it_calls(lm):
+    # a sampled token exists on the host alone: no step of such a load is
+    # called with a call before it unread, and the rows are the oracle's
+    prompts = _prompts(3, seed=33)
+    eng = DecodeEngine(lm, slots=2, page=16)
+    handles = _run_queued(
+        eng, [(p, 4, {"temperature": 0.9, "top_k": 4, "seed": 11 + i})
+              for i, p in enumerate(prompts)])
+    st = eng.stats()
+    assert st["decode_steps"] > 0 and st["steps_ahead"] == 0
+    for i, (p, h) in enumerate(zip(prompts, handles)):
+        np.testing.assert_array_equal(h.result(1.0), cached_generate(
+            lm, p, 4, max_len=len(p) + 4, temperature=0.9, top_k=4,
+            rng=jax.random.PRNGKey(11 + i)))
+    # a greedy load beside it runs ahead at every step
+    eng = DecodeEngine(lm, slots=2, page=16)
+    _run_queued(eng, [(p, 4, {}) for p in prompts])
+    assert eng.stats()["steps_ahead"] == eng.stats()["decode_steps"] > 0
+
+
+@pytest.mark.parametrize("fault", ["chaos", "step", "prefill"])
+def test_a_fault_with_a_read_pending_leaves_no_request_unanswered(lm, fault):
+    # a slot's chaos fault, a step that throws and a prefill that throws,
+    # each while calls wait unread: every request is answered, with its
+    # oracle's row or a typed error, and no slot stays taken
+    prompts = _prompts(5, seed=34)
+    eng = DecodeEngine(lm, slots=2, page=16)
+    real = {"step": eng._step_exe, "prefill": eng._prefill_exe}
+    count = {"step": 0, "prefill": 0}
+
+    def throwing(kind, at):
+        def build(*dims):
+            exe = real[kind](*dims)
+
+            def call(*args):
+                count[kind] += 1
+                if count[kind] == at:
+                    raise RuntimeError(f"the {kind} broke")
+                return exe(*args)
+            return call
+        return build
+
+    if fault == "step":
+        eng._step_exe = throwing("step", 3)
+    elif fault == "prefill":
+        eng._prefill_exe = throwing("prefill", 3)
+    with chaos.scoped("serve.decode@1=fail@3" if fault == "chaos" else ""):
+        handles = _run_queued(eng, [(p, 6, {}) for p in prompts])
+    st = eng.stats()
+    failed = 0
+    for p, h in zip(prompts, handles):
+        assert h.done()
+        try:
+            np.testing.assert_array_equal(h.result(1.0), _oracle(lm, p, 6))
+        except (chaos.ChaosFault, SlotFault, RuntimeError) as e:
+            failed += 1
+            assert fault in ("step", "prefill") or \
+                isinstance(e, chaos.ChaosFault)
+    # the step's fault is nobody's: both sequences in flight fail; the
+    # others take one sequence each
+    assert failed == (2 if fault == "step" else 1)
+    assert st["seqs_failed"] == failed and st["seqs_done"] == 5 - failed
+    assert st["active"] == 0 and not eng._unread
+    assert all(s is None for s in eng._slots)
+
+
+def test_stop_with_a_step_in_flight_returns_every_row_whole(lm):
+    prompts = _prompts(4, seed=35)
+    eng = DecodeEngine(lm, slots=2, page=16).start()
+    handles = [eng.submit(p, 6) for p in prompts]
+    eng.stop(drain=True)            # returns once the loop has read it all
+    for p, h in zip(prompts, handles):
+        assert h.done()
+        np.testing.assert_array_equal(h.result(0.0), _oracle(lm, p, 6))
+    assert not eng._unread and eng.stats()["tokens_out"] == 24
 
 
 # ---------------------------------------------------------------------------
@@ -316,10 +499,12 @@ def test_prefill_logits_and_cache_match_the_per_position_oracle(lm, t0):
     eng = DecodeEngine(lm, slots=3, page=16, cache_dtype=np.float32)
     toks = np.zeros(8, np.int32)
     toks[:t0] = prompt
-    logits, _token, caches, _counts = eng._prefill_exe(8, 16)(
-        eng._params, eng._state, eng._fresh_caches(16), toks,
-        np.int32(1), np.int32(t0))
+    logits, tokens, caches, _counts = eng._prefill_exe(8, 16)(
+        eng._params, eng._state, eng._fresh_caches(16),
+        np.array([5, 6, 7], np.int32), toks, np.int32(1), np.int32(t0))
     assert logits.shape == (64,)
+    # the slots' token vector comes back with this slot's row set
+    np.testing.assert_array_equal(tokens, [5, np.argmax(logits), 7])
     np.testing.assert_allclose(logits, ref[0], rtol=1e-5, atol=1e-5)
     for got, want in zip(caches, ref_caches):
         for n in "kv":

@@ -159,7 +159,7 @@ def test_a_finished_request_carries_the_experts_its_tokens_chose(make):
         row = req.result(120.0)
         report = eng._step_exe(eng._cache_len)(
             eng._params, eng._state, eng._fresh_caches(eng._cache_len),
-            jnp.zeros(2, jnp.int32), jnp.full(2, -1, jnp.int32))[3]
+            jnp.zeros(2, jnp.int32), jnp.full((2, 2), -1, jnp.int32))[3]
     if make == "lm":
         assert report is None and req.routing is None
         return
@@ -196,6 +196,42 @@ def test_a_finished_request_carries_the_experts_its_tokens_chose(make):
         np.sort(req.routing[0][given], -1), np.sort(want[given], -1))
 
 
+@pytest.mark.parametrize("make", ["ds", "nemo"])
+def test_a_request_that_stops_at_its_eos_carries_its_own_tokens_routing(make):
+    """The host sees an EOS a call late (ISSUE 40): by then the next step is
+    on the device with the slot's row in it.  That row is nobody's: the
+    result, `tokens_out` and the routing are those of the tokens the request
+    received.  The experts' counts alone take it in: the device counts every
+    row it is given a position for."""
+    m = {"ds": _ds, "nemo": _nemo}[make]()
+    prompt = np.arange(3, 14, dtype=np.int32)
+    with DecodeEngine(m, slots=2, page=16, max_len=32) as eng:
+        whole = eng.submit(prompt, 8)
+        full = whole.result(120.0)
+    gen = [int(t) for t in full[len(prompt):]]
+    k = next(i for i in range(1, len(gen)) if gen[i] not in gen[:i])
+    assert k + 1 < 8
+
+    def served(**kw):
+        with DecodeEngine(m, slots=2, page=16, max_len=32) as eng:
+            req = eng.submit(prompt, **kw)
+            row = req.result(120.0)
+        return req, row, eng.stats()    # stopped: nothing waits unread
+
+    req, row, st = served(max_tokens=8, eos_token=gen[k])
+    np.testing.assert_array_equal(row, full[: len(prompt) + k + 1])
+    assert st["tokens_out"] == st["tokens_device_sampled"] == k + 1
+    assert req.routing.shape[1] == len(row) - 1
+    np.testing.assert_array_equal(req.routing,
+                                  whole.routing[:, : len(row) - 1])
+    # the same tokens by a budget: one step fewer was called
+    _req, same, cut = served(max_tokens=k + 1)
+    np.testing.assert_array_equal(same, row)
+    assert st["decode_steps"] == cut["decode_steps"] + 1 == k + 1
+    chose = lambda t: t["expert_tokens"] + t["expert_tokens_elsewhere"]
+    assert chose(st) == chose(cut) + 2      # one expert layer, k = 2
+
+
 def _tie_head(m):
     """The head's second half of the vocabulary made a copy of its first:
     every row of the output then holds each value twice, 32 indices apart,
@@ -220,17 +256,21 @@ def test_both_programs_choose_the_first_of_the_largest_entries(make):
     eng = DecodeEngine(m, slots=3, page=16, max_len=32)
     toks = np.zeros(8, np.int32)
     toks[:5] = [3, 9, 4, 7, 11]
-    logits, token, caches, _report = eng._prefill_exe(8, 16)(
-        eng._params, eng._state, eng._fresh_caches(16), jnp.asarray(toks),
+    logits, tokens, caches, _report = eng._prefill_exe(8, 16)(
+        eng._params, eng._state, eng._fresh_caches(16),
+        jnp.asarray([5, 40, 17], jnp.int32), jnp.asarray(toks),
         np.int32(1), np.int32(5))
     row = np.asarray(logits)
-    assert token.shape == () and token.dtype == jnp.int32
+    assert tokens.shape == (3,) and tokens.dtype == jnp.int32
     assert (row == row.max()).sum() >= 2
-    assert int(token) == np.argmax(row) < 32
+    # the slots' tokens come back with this slot's row set, the others' kept
+    np.testing.assert_array_equal(tokens, [5, np.argmax(row), 17])
+    assert int(tokens[1]) < 32
+    # the step takes the vector as it is: rows 0 (idle) and 1 the device's
+    # own, row 2 the token the host chose (a request that samples)
     logits, tokens, caches, _report = eng._step_exe(16)(
-        eng._params, eng._state, caches,
-        jnp.asarray([5, int(token), 17], jnp.int32),
-        jnp.asarray([-1, 5, 0], jnp.int32))
+        eng._params, eng._state, caches, tokens,
+        jnp.asarray([[-1, 5, 0], [-1, -1, 17]], jnp.int32))
     rows = np.asarray(logits)
     assert tokens.shape == (3,) and tokens.dtype == jnp.int32
     assert ((rows == rows.max(-1, keepdims=True)).sum(-1) >= 2).all()

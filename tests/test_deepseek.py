@@ -142,9 +142,9 @@ def test_prefill_then_decode_through_the_engines_programs():
         nonlocal caches
         toks = np.zeros(bucket, np.int32)
         toks[:t0] = r.integers(0, 211, t0)
-        lp, _tok, caches, (counts, _chosen) = eng._prefill_exe(bucket, L)(
-            eng._params, eng._state, caches, jnp.asarray(toks),
-            np.int32(slot), np.int32(t0))
+        lp, _toks, caches, (counts, _chosen) = eng._prefill_exe(bucket, L)(
+            eng._params, eng._state, caches, jnp.zeros(3, jnp.int32),
+            jnp.asarray(toks), np.int32(slot), np.int32(t0))
         seqs[slot] = {"toks": list(toks[:t0]), "lp": [np.asarray(lp)]}
         return np.asarray(counts)
 
@@ -156,9 +156,10 @@ def test_prefill_then_decode_through_the_engines_programs():
             nxt = int(r.integers(0, 211))       # any token: teacher-forced
             seqs[s]["toks"].append(nxt)
             tok[s], pos[s] = nxt, len(seqs[s]["toks"]) - 1
+        # every token from the host, as for a request that samples
         lp, _toks, caches, (counts, _chosen) = eng._step_exe(L)(
-            eng._params, eng._state, caches, jnp.asarray(tok),
-            jnp.asarray(pos))
+            eng._params, eng._state, caches, jnp.zeros(3, jnp.int32),
+            jnp.asarray(np.stack([pos, np.where(pos >= 0, tok, -1)])))
         for s in active:
             seqs[s]["lp"].append(np.asarray(lp[s]))
         return np.asarray(counts)

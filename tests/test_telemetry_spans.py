@@ -374,12 +374,17 @@ def test_decode_counter_arguments_wait_for_a_reader(lm, monkeypatch):
     telemetry.set_active(tr)
     eng, _rows = _run_closed(lm, _prompts(1), 6)
     assert calls.count("state_bytes_per_row") == 2
-    assert calls.count("counter:serve.decode") == eng.decode_steps
+    # one a working pass: every step's, and the one that reads the last step
+    assert calls.count("counter:serve.decode") == eng.decode_steps + 1
     track = [e for e in tr.events_tail(4096)
              if e["ph"] == "C" and e["name"] == "serve.decode"]
-    assert len(track) == eng.decode_steps
+    assert len(track) == eng.decode_steps + 1
     assert track[-1]["args"]["cache_bytes_per_slot"] \
         == eng.cache_bytes_per_slot()
+    # `ran_ahead` a step: each was called with the call before it unread
+    assert [e["args"].get("ran_ahead") for e in track] \
+        == [1.0] * eng.decode_steps + [None]
+    assert eng.stats()["steps_ahead"] == eng.decode_steps
 
 
 def test_no_tracer_no_telemetry_call_per_token(lm, monkeypatch):
@@ -398,13 +403,17 @@ def test_no_tracer_no_telemetry_call_per_token(lm, monkeypatch):
     del calls[:]
     eng, rows = _run_closed(lm, _prompts(2, seed=3), n)
     assert eng.tokens_out == 2 * n and eng.decode_steps == n - 1
+    # an admission is called and not waited for: no fetch of its own
     admission = ["span:decode.admit", "span:decode.call",
-                 "span:decode.fetch", "counter:serve"]   # submit's depth
+                 "counter:serve"]                        # submit's depth
     assert sorted(calls) == sorted(one + admission)
-    # a tick: itself, step > call and fetch, sample
-    tick = ["span:decode.tick", "span:decode.step", "span:decode.call",
-            "span:decode.fetch", "span:decode.sample"]
-    assert sorted(one) == sorted(tick * (n - 1) + admission)
+    # a pass that calls a step: itself, step > call; each but the first
+    # then reads the pass before (step > fetch, sample), and a last pass
+    # reads the last step
+    called = ["span:decode.tick", "span:decode.step", "span:decode.call"]
+    read = ["span:decode.fetch", "span:decode.sample"]
+    assert sorted(one) == sorted(called * (n - 1) + read * (n - 1)
+                                 + ["span:decode.tick"] + admission)
 
 
 def _inside(child, parent, slack=0.2):
@@ -414,31 +423,40 @@ def _inside(child, parent, slack=0.2):
 
 def test_call_and_fetch_nest_in_step_and_admit_and_cover_them(tracer, lm):
     """``decode.call`` is the host's part up to the executable's return,
-    ``decode.fetch`` the one ``device_get``; what is left of a step is the
-    experts' counts (none here), of an admission the first sample and the
-    counters: under half a millisecond by the median."""
+    ``decode.fetch`` the one ``device_get``, of the calls of the pass before:
+    an admission holds its call alone, a step its call and then the fetch;
+    what is left of a step is under half a millisecond by the median."""
     eng, _rows = _run_closed(lm, _prompts(5, seed=1), 5)
     calls, fetches = _spans(tracer, "decode.call"), \
         _spans(tracer, "decode.fetch")
-    for parent_name, program, nbytes in (("decode.step", "decode_step", 8),
-                                         ("decode.admit", "decode_prefill",
-                                          4)):
-        parents = _spans(tracer, parent_name)
-        assert parents
-        left = []
-        for p in parents:
-            mine = [c for c in calls + fetches if _inside(c, p)
-                    and c["args"]["program"] == program]
-            assert [c["name"] for c in sorted(mine, key=lambda c: c["ts"])] \
-                == ["decode.call", "decode.fetch"], (p, mine)
-            assert all(c["tid"] == p["tid"] for c in mine)
-            fetch = [c for c in mine if c["name"] == "decode.fetch"][0]
-            # int32 tokens of two slots, or the prefill's one
-            assert fetch["args"]["bytes"] == nbytes
-            left.append(p["dur"] - sum(c["dur"] for c in mine))
-        assert min(left) >= -0.5
-        assert sorted(left)[len(left) // 2] < 500.0, left     # microseconds
-    assert len(calls) == len(fetches) == eng.decode_steps + eng.prefill_steps
+    ticks = sorted(_spans(tracer, "decode.tick"), key=lambda t: t["ts"])
+    for p in _spans(tracer, "decode.admit"):
+        mine = [c for c in calls + fetches if _inside(c, p)]
+        assert [(c["name"], c["args"]["program"]) for c in mine] \
+            == [("decode.call", "decode_prefill")], (p, mine)
+    left = []
+    steps = _spans(tracer, "decode.step")
+    for p in steps:
+        mine = sorted([c for c in calls + fetches if _inside(c, p)],
+                      key=lambda c: c["ts"])
+        assert [c["name"] for c in mine] in (
+            ["decode.call"], ["decode.call", "decode.fetch"]), (p, mine)
+        assert mine[0]["args"]["program"] == "decode_step"
+        assert all(c["tid"] == p["tid"] for c in mine)
+        left.append(p["dur"] - sum(c["dur"] for c in mine))
+    assert min(left) >= -0.5
+    assert sorted(left)[len(left) // 2] < 500.0, left     # microseconds
+    # every call is read once: a fetch brings the int32 tokens of two slots
+    # for each call of the pass before, whose number it carries
+    assert len(calls) == eng.decode_steps + eng.prefill_steps \
+        == sum(f["args"]["calls"] for f in fetches)
+    for f in fetches:
+        assert f["args"]["bytes"] == 8 * f["args"]["calls"]
+        mine = [i for i, t in enumerate(ticks, 1) if _inside(f, t)]
+        assert mine == [f["args"]["tick"] + 1], (f, mine)
+    # all but the first step's and the last pass's lie in a step
+    assert sum(1 for f in fetches if any(_inside(f, p) for p in steps)) \
+        == len(fetches) - 1 == len(steps) - 1
     # a working engine never slept
     assert not _spans(tracer, "decode.idle")
 
@@ -531,11 +549,15 @@ def test_the_decode_line_names_the_deepest_spans(tracer):
     tracer.complete("serve.request", 0.110, cat="serve", status="ok",
                     queue_wait_ms=2.0, ttft_ms=10.0, prompt_len=40, tokens=11)
     tracer.complete("serve.request", 0.5, cat="serve", status="ok")  # one-shot
+    # `ran_ahead` is 0 or 1 a step (a pass that calls none leaves it out):
+    # the line prints the share, as `train:` does
+    for ahead in (1.0, 1.0, 0.0, 1.0):
+        tracer.counter("serve.decode", fill=0.5, ran_ahead=ahead)
     tracer.counter("serve.decode", fill=0.75)
     bd = telemetry.phase_breakdown(
         {"traceEvents": tracer.events_tail(1 << 16)})
     d = bd["decode"]
-    assert d["fill"] == 0.75
+    assert d["fill"] == 0.75 and d["ran_ahead"] == pytest.approx(0.75)
     assert d["call_ms.step"] == pytest.approx(2.0)
     assert d["fetch_ms.step"] == pytest.approx(0.5)
     assert d["fetch_bytes.step"] == 128 and d["fetch_bytes.prefill"] == 4
@@ -547,7 +569,7 @@ def test_the_decode_line_names_the_deepest_spans(tracer):
     line = [ln for ln in telemetry.format_report(bd).splitlines()
             if ln.startswith("decode:")][0]
     for key in ("call_ms.step=2", "fetch_ms.step=0.5", "idle_s=0.07",
-                "request_token_ms=10"):
+                "request_token_ms=10", "ran_ahead=0.75"):
         assert key in line, line
     # a trace without the engine's spans keeps the track alone
     tracer2 = Tracer("memory://unused2", flush_every=0)
