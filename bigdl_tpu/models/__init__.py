@@ -9,6 +9,7 @@ from .decode import beam_generate, cached_generate, init_kv_cache
 from .deepseek import DeepSeekV2LM
 from .lenet import LeNet5
 from .nemotron import NemotronHLM
+from .qwen3_next import Qwen3NextLM
 from .resnet import ResNet, ShortcutType
 from .rnn import PTBModel, SimpleRNN
 from .textclassifier import TextClassifier
@@ -23,7 +24,7 @@ __all__ = [
     "AlexNet", "Autoencoder", "DeepSeekV2LM", "Inception_Layer_v1", "Inception_Layer_v2",
     "Inception_v1", "Inception_v1_NoAuxClassifier", "Inception_v2",
     "Inception_v2_NoAuxClassifier", "LeNet5", "NemotronHLM", "PTBModel",
-    "PositionalEmbedding", "ResNet", "ShortcutType", "SimpleRNN",
+    "PositionalEmbedding", "Qwen3NextLM", "ResNet", "ShortcutType", "SimpleRNN",
     "TextClassifier", "TransformerBlock", "TransformerLM",
     "TreeLSTMSentiment", "beam_generate", "cached_generate",
     "encode_tree", "init_kv_cache",

@@ -46,7 +46,9 @@ serving engine's two programs (prefill, step) share: it hands every
 declaring layer its leaves through ``decode_prefill`` or ``decode_step``
 and applies every other leaf to the positions in hand.  ``cached_generate`` keeps a walk of
 its own (``_step``, one position shared by all rows, written out for
-``MultiHeadAttention``): it is the oracle the engine's tokens are held to.
+the plain ``MultiHeadAttention``; every other layer with state, that one
+with its norms, gate or rotary positions among them, goes through its own
+``decode_step``): it is the oracle the engine's tokens are held to.
 """
 
 from __future__ import annotations
@@ -284,7 +286,7 @@ def _step(module, params, state, x, caches, slot, pos):
     `caches` is mutated in place (list of per-MHA dicts) — the caller
     rebuilds the functional output tuple.
     """
-    if isinstance(module, MultiHeadAttention):
+    if isinstance(module, MultiHeadAttention) and not module._shaped:
         y, caches[slot] = _cached_attention(module, params, x, caches[slot],
                                             pos)
         return y, slot + 1

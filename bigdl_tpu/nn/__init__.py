@@ -56,4 +56,5 @@ from .criterion import (
     TimeDistributedCriterion)
 from .attention import LatentAttention, MultiHeadAttention
 from .mamba import Mamba2Mixer
+from .deltanet import GatedDeltaNet
 from .fused import ConvBN, ConvBNAddReLU, fuse_conv_bn

@@ -35,7 +35,8 @@ from ..common import get_policy
 from .initialization import compute_fans, default_weight_init
 from .module import Module, StateLeaf
 from .normalization import rms_norm
-from .rotary import apply_rope, rope_angles, rope_inv_freq, yarn_mscale
+from .rotary import (apply_rope, apply_rope_half, rope_angles, rope_inv_freq,
+                     yarn_mscale)
 
 __all__ = ["MultiHeadAttention", "LatentAttention"]
 
@@ -55,16 +56,33 @@ class MultiHeadAttention(Module):
     width ``head_dim`` (default ``embed_dim / num_heads``) over
     ``num_kv_heads`` key and value heads (default: as many), each shared
     by a run of ``num_heads / num_kv_heads`` consecutive query heads; scores
-    are scaled by ``head_dim^-0.5``; no positions are applied here."""
+    are scaled by ``head_dim^-0.5``; no positions are applied here by
+    default.
+
+    Three options, each off by default (the default's parameters and
+    program are then what they were), as the Qwen3-Next family's full
+    attention has them: ``qk_norm`` norms every query and key head over its
+    ``head_dim`` (``x / sqrt(mean x^2 + eps) * (1 + w)``, one ``w`` for the
+    queries' heads and one for the keys', zero at the start); ``gated``
+    makes ``W_q`` twice as wide, a head's columns ``[q | gate]``, and the
+    head's output is multiplied by ``sigmoid(gate)`` before ``W_o``;
+    ``rope = (theta, rotary_dim)`` turns the first ``rotary_dim`` values of
+    every query and key head by its position (half-split pairs,
+    ``nn/rotary.apply_rope_half``) and leaves the rest.  The order is the
+    source's: norm, then rotate; the cache keeps keys normed and
+    rotated."""
 
     #: projections are applied x @ w (in-major): kernel_in
     PARAM_ROLES = {"wq": "kernel_in", "wk": "kernel_in", "wv": "kernel_in",
-                   "wo": "kernel_in", "*": "bias"}
+                   "wo": "kernel_in", "q_norm": "norm_scale",
+                   "k_norm": "norm_scale", "*": "bias"}
 
     def __init__(self, embed_dim: int, num_heads: int, causal: bool = False,
                  seq_parallel: bool = False, seq_axis: str = "seq",
                  with_bias: bool = True, num_kv_heads: Optional[int] = None,
-                 head_dim: Optional[int] = None):
+                 head_dim: Optional[int] = None, qk_norm: bool = False,
+                 gated: bool = False, rope: Optional[tuple] = None,
+                 eps: float = 1e-6):
         super().__init__()
         if head_dim is None:
             if embed_dim % num_heads:
@@ -82,6 +100,16 @@ class MultiHeadAttention(Module):
         self.seq_parallel = seq_parallel
         self.seq_axis = seq_axis
         self.with_bias = with_bias
+        self.qk_norm, self.gated, self.eps = qk_norm, gated, eps
+        self.rotary_dim, self.inv_freq = 0, None
+        if rope is not None:
+            theta, self.rotary_dim = rope
+            if self.rotary_dim % 2 or self.rotary_dim > head_dim:
+                raise ValueError(f"rotary_dim {self.rotary_dim} of a head of "
+                                 f"{head_dim}")
+            self.inv_freq = rope_inv_freq(self.rotary_dim, theta)
+        #: whether anything stands between the projections and the scores
+        self._shaped = qk_norm or gated or rope is not None
 
     def _init(self, rng):
         ks = jax.random.split(rng, 4)
@@ -95,13 +123,19 @@ class MultiHeadAttention(Module):
             fi, fo = compute_fans(shape)
             return winit(k, shape, fi, fo, dt)
 
-        p = {"wq": w(ks[0], (e, qw)), "wk": w(ks[1], (e, kvw)),
+        # gated: a head's columns of W_q are [q | gate]
+        qcols = 2 * qw if self.gated else qw
+        p = {"wq": w(ks[0], (e, qcols)), "wk": w(ks[1], (e, kvw)),
              "wv": w(ks[2], (e, kvw)), "wo": w(ks[3], (qw, e))}
         if self.with_bias:
             # distinct arrays per bias: aliased leaves crash buffer donation
             # in the compiled train step ("donate the same buffer twice")
-            p.update({"bq": jnp.zeros((qw,), dt), "bk": jnp.zeros((kvw,), dt),
+            p.update({"bq": jnp.zeros((qcols,), dt),
+                      "bk": jnp.zeros((kvw,), dt),
                       "bv": jnp.zeros((kvw,), dt), "bo": jnp.zeros((e,), dt)})
+        if self.qk_norm:
+            p.update({"q_norm": jnp.zeros((self.head_dim,), dt),
+                      "k_norm": jnp.zeros((self.head_dim,), dt)})
         return p
 
     def _proj(self, params, x, name):
@@ -113,6 +147,36 @@ class MultiHeadAttention(Module):
         if self.with_bias:
             y = y + params["b" + name].astype(c)
         return y
+
+    def _shape(self, params, q, k, pos):
+        """The options, between the projections and the scores: ``q [...,
+        H * D]`` (twice as wide when gated) and ``k [..., H_kv * D]`` at
+        positions ``pos [...]`` -> (q, k, the heads' gates ``[..., H * D]``
+        or None)."""
+        H, G, D, r = (self.num_heads, self.num_kv_heads, self.head_dim,
+                      self.rotary_dim)
+        lead = q.shape[:-1]
+        gate = None
+        if self.gated:
+            q = q.reshape(lead + (H, 2 * D))
+            q, gate = q[..., :D], q[..., D:].reshape(lead + (H * D,))
+        q, k = q.reshape(lead + (H, D)), k.reshape(lead + (G, D))
+        if self.qk_norm:
+            q = rms_norm(q, params["q_norm"], self.eps, plus_one=True)
+            k = rms_norm(k, params["k_norm"], self.eps, plus_one=True)
+        if r:
+            cos, sin = (a[..., None, :] for a in
+                        rope_angles(pos, self.inv_freq))
+            q, k = (jnp.concatenate(
+                [apply_rope_half(a[..., :r], cos, sin), a[..., r:]], axis=-1)
+                for a in (q, k))
+        return q.reshape(lead + (H * D,)), k.reshape(lead + (G * D,)), gate
+
+    @staticmethod
+    def _gate(o, gate):
+        """A head's output times ``sigmoid`` of its gate (float32 inside)."""
+        return (o.astype(jnp.float32)
+                * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(o.dtype)
 
     def _ring_over_tp(self, T):
         """The env-gated ring-attention seam: a MeshLayout 'tp' axis
@@ -134,8 +198,16 @@ class MultiHeadAttention(Module):
         B, T, _ = x.shape
         H, G, D = self.num_heads, self.num_kv_heads, self.head_dim
         split = lambda y, n: y.reshape(B, T, n, D).transpose(0, 2, 1, 3)
-        q = split(self._proj(params, x, "q"), H)
-        k, v = (split(self._proj(params, x, n), G) for n in "kv")
+        gate = None
+        # two branches here and in ``decode_step``: the plain form keeps the
+        # order of its operations, so its program is what it was
+        if self._shaped:
+            q, k, v = (self._proj(params, x, n) for n in "qkv")
+            q, k, gate = self._shape(params, q, k, jnp.arange(T)[None])
+            q, k, v = split(q, H), split(k, G), split(v, G)
+        else:
+            q = split(self._proj(params, x, "q"), H)
+            k, v = (split(self._proj(params, x, n), G) for n in "kv")
         if G != H:
             # the full-sequence cores take a key head for every query head
             k, v = (jnp.repeat(a, H // G, axis=1) for a in (k, v))
@@ -153,6 +225,8 @@ class MultiHeadAttention(Module):
             from ..ops.attention import flash_attention
             o = flash_attention(q, k, v, causal=self.causal)
         o = o.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+        if gate is not None:
+            o = self._gate(o, gate)
         return self._proj(params, o, "o")
 
     # -- incremental decoding ------------------------------------------
@@ -206,12 +280,17 @@ class MultiHeadAttention(Module):
         self._require_causal()
         P = x.shape[1]
         q, k, v = (self._proj(params, x, n) for n in "qkv")
+        gate = None
+        if self._shaped:
+            q, k, gate = self._shape(params, q, k, jnp.arange(P)[None])
         # attend over what the cache will hold: k and v in the cache's dtype
         k, v = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
         ck = jax.lax.dynamic_update_slice(cache["k"], k, (slot, 0, 0))
         cv = jax.lax.dynamic_update_slice(cache["v"], v, (slot, 0, 0))
         mask = jnp.arange(P)[None, :] <= jnp.arange(P)[:, None]
         o = self._attend(q, k, v, mask, x.dtype)
+        if gate is not None:
+            o = self._gate(o, gate)
         return self._proj(params, o, "o"), {"k": ck, "v": cv}
 
     def decode_step(self, params, x, cache, pos):
@@ -223,14 +302,24 @@ class MultiHeadAttention(Module):
         self._require_causal()
         pos = jnp.maximum(pos, 0)                 # an idle row: position 0
         q = self._proj(params, x, "q")
-        ck, cv = (_write_rows(cache[n], pos, self._proj(params, x, n)[:, 0])
-                  for n in "kv")
+        gate = None
+        if self._shaped:
+            k, v = (self._proj(params, x, n) for n in "kv")
+            q, k, gate = self._shape(params, q, k, pos[:, None])
+            ck, cv = (_write_rows(cache[n], pos, a[:, 0])
+                      for n, a in (("k", k), ("v", v)))
+        else:
+            ck, cv = (_write_rows(cache[n], pos,
+                                  self._proj(params, x, n)[:, 0])
+                      for n in "kv")
         # per-row causal horizon; positions past a row's pos get EXACT
         # zero softmax weight (exp(-inf)), so stale cache rows from a
         # previous occupant of the slot contribute exactly nothing
         mask = jnp.arange(ck.shape[1])[None, None, None, None, :] \
             <= pos[:, None, None, None, None]
         o = self._attend(q, ck, cv, mask, x.dtype)
+        if gate is not None:
+            o = self._gate(o, gate)
         return self._proj(params, o, "o"), {"k": ck, "v": cv}
 
 

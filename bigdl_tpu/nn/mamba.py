@@ -44,9 +44,48 @@ from ..common import get_policy
 from .initialization import compute_fans, default_weight_init
 from .module import Module, StateLeaf
 
-__all__ = ["Mamba2Mixer"]
+__all__ = ["Mamba2Mixer", "causal_conv", "causal_windows", "conv_tail",
+           "matmul_f32"]
 
 F32 = jnp.float32
+
+
+def matmul_f32(x, w):
+    """``x @ w`` over the last axis: compute-dtype operands, float32
+    accumulation and result (both recurrent layers' projections)."""
+    c = get_policy().compute_dtype
+    return jax.lax.dot_general(
+        x.astype(c), w.astype(c), (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=F32)
+
+
+def causal_conv(window, weight, bias=None):
+    """The causal depthwise convolution of the recurrent layers (this one
+    and ``nn/deltanet.GatedDeltaNet``) at one position: ``window [..., K,
+    channels]``, the last K inputs oldest first, times ``weight [K,
+    channels]`` summed over the taps, plus ``bias`` where the layer has
+    one, through SiLU (float32)."""
+    y = jnp.sum(window.astype(F32) * weight.astype(F32), axis=-2)
+    if bias is not None:
+        y = y + bias.astype(F32)
+    return jax.nn.silu(y)
+
+
+def causal_windows(x, taps: int):
+    """x [B, T, channels] -> [B, T, K, channels]: position t's window is
+    inputs t-K+1..t, zeros before the start."""
+    T = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return jnp.stack([padded[:, k:k + T] for k in range(taps)], axis=2)
+
+
+def conv_tail(x, length, taps: int):
+    """What a convolution keeps of a prompt ``x [B, T, channels]`` whose
+    first ``length`` (traced) positions are real: inputs ``length - K + 1
+    .. length - 1``, zeros before the start, ``[B, K - 1, channels]``."""
+    return jax.lax.dynamic_slice_in_dim(
+        jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))), length, taps - 1,
+        axis=1)
 
 
 class Mamba2Mixer(Module):
@@ -103,12 +142,7 @@ class Mamba2Mixer(Module):
 
     # -- the pieces ------------------------------------------------------
 
-    @staticmethod
-    def _mm(x, w):
-        c = get_policy().compute_dtype
-        return jax.lax.dot_general(
-            x.astype(c), w.astype(c), (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=F32)
+    _mm = staticmethod(matmul_f32)
 
     def _project(self, params, u):
         """u [..., d_model] -> z [..., H P], xBC [..., channels] (before the
@@ -148,9 +182,8 @@ class Mamba2Mixer(Module):
     def _conv(self, params, window):
         """window [..., K, channels], the last K inputs oldest first -> the
         convolution's output at the newest, through SiLU (float32)."""
-        y = jnp.sum(window.astype(F32) * params["conv_weight"].astype(F32),
-                    axis=-2) + params["conv_bias"].astype(F32)
-        return jax.nn.silu(y)
+        return causal_conv(window, params["conv_weight"],
+                           params["conv_bias"])
 
     def _scan(self, params, u, length=None):
         """u [B, T, d_model] from a zero state; positions ``>= length``
@@ -162,11 +195,7 @@ class Mamba2Mixer(Module):
         H, P, G, N, K, Q = (self.heads, self.head_dim, self.groups,
                             self.state, self.conv_kernel, self.chunk)
         z, xbc, dt = self._project(params, u)
-        # the causal convolution: position t sees inputs t-K+1..t, zeros
-        # before the start
-        padded = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
-        window = jnp.stack([padded[:, k:k + T] for k in range(K)], axis=2)
-        x, Bm, Cm = self._split(self._conv(params, window))
+        x, Bm, Cm = self._split(self._conv(params, causal_windows(xbc, K)))
         dt, A = self._steps(params, dt)
         if length is not None:
             # a pad has dt = 0: its decay is exp(0) = 1 and its input term
@@ -246,8 +275,7 @@ class Mamba2Mixer(Module):
         both leaves of row ``slot`` are written whole."""
         K = self.conv_kernel
         y, ssm, xbc = self._scan(params, x, length)
-        tail = jax.lax.dynamic_slice_in_dim(
-            jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0))), length, K - 1, axis=1)
+        tail = conv_tail(xbc, length, K)
         return y, {"ssm": jax.lax.dynamic_update_slice(
                        cache["ssm"], ssm.astype(cache["ssm"].dtype),
                        (slot, 0, 0, 0)),

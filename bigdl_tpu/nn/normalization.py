@@ -347,29 +347,35 @@ class LayerNorm(Module):
 class RMSNorm(Module):
     """x / sqrt(mean(x^2) + eps) * weight over the last axis: LayerNorm
     without the mean and the shift.  Statistics in float32 whatever the
-    compute dtype, like LayerNorm."""
+    compute dtype, like LayerNorm.  ``plus_one``: the zero-centred form,
+    ``... * (1 + weight)`` with the weight zero at the start (the Qwen3-Next
+    and Gemma families'); the parameter keeps its name and shape."""
 
     PARAM_ROLES = {"weight": "norm_scale"}
 
-    def __init__(self, n_output: int, eps: float = 1e-6):
+    def __init__(self, n_output: int, eps: float = 1e-6,
+                 plus_one: bool = False):
         super().__init__()
         self.n_output = n_output
         self.eps = eps
+        self.plus_one = plus_one
 
     def _init(self, rng):
-        return {"weight": jnp.ones((self.n_output,),
-                                   get_policy().param_dtype)}
+        make = jnp.zeros if self.plus_one else jnp.ones
+        return {"weight": make((self.n_output,), get_policy().param_dtype)}
 
     def _apply(self, params, x):
-        return rms_norm(x, params["weight"], self.eps)
+        return rms_norm(x, params["weight"], self.eps, self.plus_one)
 
 
-def rms_norm(x, weight, eps: float):
+def rms_norm(x, weight, eps: float, plus_one: bool = False):
     """RMSNorm's arithmetic, for layers that norm a projection inside
-    themselves (nn/attention.LatentAttention)."""
+    themselves (nn/attention.LatentAttention, and MultiHeadAttention's
+    ``qk_norm``)."""
     xf = x.astype(jnp.float32)
     y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+    w = weight.astype(jnp.float32)
+    return (y * (1.0 + w if plus_one else w)).astype(x.dtype)
 
 
 class Normalize(Module):

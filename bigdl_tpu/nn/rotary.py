@@ -22,7 +22,8 @@ import math
 import numpy as np
 import jax.numpy as jnp
 
-__all__ = ["rope_inv_freq", "yarn_mscale", "rope_angles", "apply_rope"]
+__all__ = ["rope_inv_freq", "yarn_mscale", "rope_angles", "apply_rope",
+           "apply_rope_half"]
 
 
 def _correction_dim(turns: float, dim: int, base: float, original: int):
@@ -77,5 +78,17 @@ def apply_rope(x, cos, sin):
     xf = x.astype(jnp.float32)
     pairs = xf.reshape(xf.shape[:-1] + (xf.shape[-1] // 2, 2))
     a, b = pairs[..., 0], pairs[..., 1]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def apply_rope_half(x, cos, sin):
+    """``apply_rope`` for vectors whose pairs are ``(x[i], x[i + dim / 2])``
+    going in as well as coming out (the ``rotate_half`` form of the
+    Qwen and Llama families): ``out[i] = x[i] cos_i - x[i + dim / 2] sin_i``,
+    ``out[i + dim / 2] = x[i + dim / 2] cos_i + x[i] sin_i``."""
+    xf = x.astype(jnp.float32)
+    half = xf.shape[-1] // 2
+    a, b = xf[..., :half], xf[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)

@@ -10,7 +10,12 @@ tiles, a grid step moves 32 KB, and the steps' own cost is the product's time
 (`nemo3.decode`, PR 32: 8.8 ms for 638 MB of weights that stream in 0.8).  And
 it takes a group's matrix only as ``[k, n]``: a table whose ``n`` is no
 multiple of the 128 lanes is kept by the device with ``k`` in the lanes, and
-is copied whole before every product.  For those shapes the product is the
+is copied whole before every product.  And where the groups are many and
+small (`qwen3n.decode`, PR 41: 128 matrices of 2 MB, ``[2048, 512]`` and
+``[512, 2048]``) it streams them at 91 % of the bandwidth for a decode step's
+1,920 rows (0.33-0.36 ms a product in the step) but takes 0.83-0.96 ms for a
+prompt's 2,560-10,240 rows, where the kernel below takes 0.46-0.64.  For
+those shapes the product is the
 Pallas grouped matmul that ships with jax (``megablox.gmm``) with tiles chosen
 here from the shape: the whole contraction at once, up to 512 output columns,
 128 rows (256 for a prompt's thousands), so a grid step moves megabytes; and
@@ -24,6 +29,14 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["grouped_matmul"]
+
+
+#: a group's matrix at or under this many bytes is "small", and more rows
+#: than this are a prompt's, not a decode step's (read on the chip, PR 41:
+#: 1,920 rows of 192 slots x 10 choices stay on the ragged dot, a 256-token
+#: prompt's 2,560 do not; `dsv2.decode`'s 15.7 MB matrices are not small)
+_SMALL_GROUP_BYTES = 4 << 20
+_FEW_ROWS = 2048
 
 
 def _tiles(m: int, k: int, n: int) -> tuple:
@@ -44,9 +57,12 @@ def grouped_matmul(x, w, sizes, *, transposed: bool = False):
     nothing that may be read.  The shape and the backend decide which
     product runs (module docstring): ``lax.ragged_dot`` where it tiles well
     or no TPU is there, the Pallas kernel on a TPU otherwise."""
-    k = x.shape[1]
+    m, k = x.shape
     n = w.shape[1] if transposed else w.shape[2]
     tiles_well = not transposed and k % 256 == 0 and n % 256 == 0
+    # many small matrices under a prompt's thousands of rows (module text)
+    if k * n * w.dtype.itemsize <= _SMALL_GROUP_BYTES and m > _FEW_ROWS:
+        tiles_well = False
     if tiles_well or min(k, n) < 128 or jax.default_backend() != "tpu":
         return lax.ragged_dot(x, jnp.swapaxes(w, 1, 2) if transposed else w,
                               sizes, preferred_element_type=jnp.float32)

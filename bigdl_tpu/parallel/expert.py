@@ -347,8 +347,10 @@ class GatedMoE(Module):
     for a per-expert ``b`` that chooses and does not weigh; the weights are
     the chosen ``s``, with ``renormalise`` divided by their sum + 1e-20,
     times ``scale``.  The shared block is ``d_shared`` wide (default
-    ``n_shared * d_expert``).  These are a model's architecture, not
-    tuning.
+    ``n_shared * d_expert``); with ``shared_gate`` its output is weighed by
+    a gate of its own, ``sigmoid(x w_sg)`` for one vector ``w_sg``
+    (``shared_score``; the Qwen MoE families').  These are a model's
+    architecture, not tuning.
 
     ``held = (first, count)``: the stacked tables hold experts ``first ..
     first + count - 1`` (default: all).  The router keeps every output, and
@@ -367,7 +369,7 @@ class GatedMoE(Module):
                    "w_gate": "expert_table",
                    "w_up": "expert_table", "w_down": "expert_table",
                    "shared_gate": "kernel_in", "shared_up": "kernel_in",
-                   "shared_down": "kernel_in"}
+                   "shared_down": "kernel_in", "shared_score": "kernel_whole"}
 
     _ACTS = {"silu": jax.nn.silu,
              "relu2": lambda x: jnp.square(jax.nn.relu(x))}
@@ -377,7 +379,8 @@ class GatedMoE(Module):
                  n_shared: int = 0, scale: float = 1.0, held=None,
                  score: str = "softmax", select_bias: bool = False,
                  renormalise: bool = False, gated: bool = True,
-                 act: str = "silu", d_shared: Optional[int] = None):
+                 act: str = "silu", d_shared: Optional[int] = None,
+                 shared_gate: bool = False):
         super().__init__()
         if score not in ("softmax", "sigmoid"):
             raise ValueError(f"score {score!r}")
@@ -389,6 +392,7 @@ class GatedMoE(Module):
         self.renormalise, self.gated = renormalise, gated
         self.act = self._ACTS[act]
         self.d_shared = n_shared * d_expert if d_shared is None else d_shared
+        self.shared_gate = bool(shared_gate and self.d_shared)
         self.first, self.count = held if held is not None \
             else (0, num_experts)
         if not (0 <= self.first and self.count >= 1
@@ -415,6 +419,8 @@ class GatedMoE(Module):
                 p["shared_gate"] = n(ks[4], (D, S), D)
         if self.select_bias:
             p["select_bias"] = jnp.zeros((self.num_experts,), dt)
+        if self.shared_gate:
+            p["shared_score"] = n(jax.random.fold_in(rng, 7), (D, 1), D)
         return p
 
     def _init_state(self):
@@ -502,6 +508,9 @@ class GatedMoE(Module):
             hs = self.act(mm(xt, params["shared_gate"])) \
                 * mm(xt, params["shared_up"]) \
                 if self.gated else self.act(mm(xt, params["shared_up"]))
-            y = y + mm(hs.astype(c), params["shared_down"])
+            ys = mm(hs.astype(c), params["shared_down"])
+            if self.shared_gate:
+                ys = ys * jax.nn.sigmoid(mm(xt, params["shared_score"]))
+            y = y + ys
         return y.astype(c).reshape(x.shape), \
             (sizes[:E + 1], idx.reshape(x.shape[:-1] + (k,)).astype(jnp.int32))

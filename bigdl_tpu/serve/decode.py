@@ -107,7 +107,9 @@ took; PAPERS.md):
   active-slot fill, prefill-vs-decode step fractions, the share of the
   prefills' positions that were padding (``prefill_pad_frac``), cache
   bytes/slot and the part of them that is of fixed size
-  (``state_bytes_per_slot``), and how often the device's token was taken
+  (``state_bytes_per_slot``; ``state_bytes_fixed`` is that part over all
+  slots, and ``state_bytes_per_position`` what a slot holds of each
+  position, so the two kinds of cache read side by side), and how often the device's token was taken
   (``tokens_device_sampled``) against rows of log-probabilities brought
   to the host for requests that sample (``logit_rows_fetched``: 0 under
   greedy traffic), and ``ran_ahead`` (1 where the pass's step was called
@@ -399,8 +401,12 @@ class DecodeEngine:
         self._expert_tokens: Optional[np.ndarray] = None
         self.expert_tokens_elsewhere = 0
         self._busy_s = 0.0
-        # bytes of fixed size a slot's prefill writes whole (a constant)
-        self._state_bytes = self.state_bytes_per_slot()
+        # the two kinds of decode state, from the layers' declarations
+        # (constants): bytes of fixed size a slot's prefill writes whole,
+        # and bytes a slot holds of every position
+        one, self._state_bytes = kv.state_bytes_per_row(
+            self.model, 1, self.cache_dtype)
+        self._position_bytes = one - self._state_bytes
         # request stamps, summed (always on: two clock reads a request)
         self.admitted = 0
         self.queue_wait_s = 0.0      # enqueued -> admitted to a slot
@@ -667,7 +673,7 @@ class DecodeEngine:
         """The part of ``cache_bytes_per_slot`` that is of fixed size
         whatever the length (recurrent state; 0 for a model of keys and
         values alone)."""
-        return kv.state_bytes_per_row(self.model, 1, self.cache_dtype)[1]
+        return self._state_bytes
 
     # -- the persistent step loop ---------------------------------------
 
@@ -1049,6 +1055,8 @@ class DecodeEngine:
             decode_frac=self.decode_steps / max(steps, 1),
             cache_bytes_per_slot=self._cache_bytes,
             state_bytes_per_slot=self._state_bytes,
+            state_bytes_fixed=self.slots * self._state_bytes,
+            state_bytes_per_position=self._position_bytes,
             cache_len=self._cache_len)
         reg = metrics_export._REGISTRY
         if reg is not None:
@@ -1071,6 +1079,8 @@ class DecodeEngine:
             "cache_len": self._cache_len,
             "cache_bytes_per_slot": self._cache_bytes,
             "state_bytes_per_slot": self._state_bytes,
+            "state_bytes_fixed": self.slots * self._state_bytes,
+            "state_bytes_per_position": self._position_bytes,
             "cache_grows": self.cache_grows,
             "prefill_steps": self.prefill_steps,
             "prompt_tokens": self.prompt_tokens,

@@ -327,3 +327,51 @@ def test_hybrid_decode_step_compiles_at_the_cells_sizes(one_chip,
     assert sum("tpu_custom_call" in ln for ln in entry) == 2 * 6
     assert not any(" copy(" in ln and "bf16[64," in ln for ln in entry)
     assert text.count(" scatter(") >= 2 * 2             # keys and values
+
+
+def test_delta_rule_decode_step_compiles_at_the_cells_sizes(one_chip):
+    """`qwen3n.decode`'s step at its real sizes (192 slots, 9 linear layers
+    of `f32[192,8,128,128]` matrix state, 3 full layers of `[192, 1536,
+    256]` keys and values, 128 held experts a layer of `[2048, 512]` and
+    `[512, 2048]` matrices): it fits one chip beside its 10.27 GB of
+    weights; every leaf is updated in place under the donation; each matrix
+    state is written by one fusion a layer (what it holds of `k` and `q` is
+    read in a pass before it: PERF.md PR 41); the expert products stay the
+    compiler's own ragged dot (both widths are multiples of 256), which the
+    TPU compiler turns into its own kernel calls, three a layer, and no
+    expert table is copied."""
+    compiled, cfg, cache_bytes = _compile_decode_step("qwen3n.decode",
+                                                      one_chip)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    fixed = 9 * 192 * (8 * 128 * 128 * 4 + 3 * 2048 * 2)
+    assert cache_bytes == fixed + 3 * 2 * 192 * 1536 * 256 * 2
+    assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+    entry = _entry(text)
+    leaf = "f32[192,8,128,128]"
+    made = [ln for ln in entry if " = " in ln and " fusion(" in ln
+            and leaf in ln.split(" = ")[1].split(" fusion(")[0]]
+    assert len(made) == 9, made
+    assert not any(" copy(" in ln and "bf16[128," in ln for ln in entry)
+    assert sum("ragged-dot" in ln and "tpu_custom_call" in ln
+               for ln in text.splitlines()) >= 3 * 12
+
+
+@pytest.mark.parametrize("rows", [2560, 10240])
+@pytest.mark.parametrize("k,n", [(2048, 512), (512, 2048)])
+def test_grouped_matmul_compiles_at_the_small_groups_shapes(one_chip, rows,
+                                                            k, n):
+    """`qwen3n.decode`'s expert products under a prompt's rows (128 held
+    experts of 2 MB matrices, 10 choices a token of a 256- or 1,024-token
+    bucket): the Pallas kernel fits its fast memory at these tiles and
+    reads the table as the device keeps it."""
+    from bigdl_tpu.ops.grouped import _pallas
+    text = _compile(
+        lambda x, w, g: _pallas(x, w, g, False),
+        _aval((rows, k), jnp.bfloat16, one_chip),
+        _aval((128, k, n), jnp.bfloat16, one_chip),
+        _aval((128,), jnp.int32, one_chip))
+    entry = _entry(text)
+    assert sum("tpu_custom_call" in ln for ln in entry) == 1
+    assert not any(" copy(" in ln and "bf16[128," in ln for ln in entry)

@@ -61,3 +61,28 @@ def test_the_shape_and_the_backend_decide():
     assert _tiles(768, 2688, 1856) == (128, 2688, 512)      # up, a step
     assert _tiles(768, 1856, 2688) == (128, 1856, 384)      # down: 7 x 384
     assert _tiles(6144, 2688, 1856) == (256, 2688, 512)     # a prompt
+
+
+@pytest.mark.parametrize("m,k,n,kernel", [
+    (1920, 2048, 512, False),     # qwen3n.decode's step: few rows
+    (2560, 2048, 512, True),      # its shortest prompt's rows: the kernel
+    (10240, 512, 2048, True),
+    (4096, 5120, 1536, False),    # dsv2.decode's matrices are not small
+    (768, 2688, 1856, True)])     # nemo3.decode's tile badly: as before
+def test_many_small_groups_under_a_prompts_rows_take_the_kernel(
+        monkeypatch, m, k, n, kernel):
+    """On a TPU (said here by the test) the shape alone decides: a matrix
+    of at most 4 MiB under more than 2,048 rows goes to the Pallas grouped
+    matmul (PERF.md, PR 41), every other well-tiling shape to the ragged
+    dot."""
+    import bigdl_tpu.ops.grouped as g
+    took = []
+    monkeypatch.setattr(g.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(g, "_pallas", lambda *a: took.append("kernel"))
+    monkeypatch.setattr(g.lax, "ragged_dot",
+                        lambda *a, **kw: took.append("ragged"))
+    x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((4, n, k) if k == 2688 else (4, k, n),
+                             jnp.bfloat16)
+    g.grouped_matmul(x, w, None, transposed=k == 2688)
+    assert took == ["kernel" if kernel else "ragged"]
