@@ -192,6 +192,16 @@ def grow_cache(model, caches, length: int, mesh=None):
     return tuple(grown)
 
 
+def _positions(a, at):
+    """Row i of ``a [n, P, ...]`` at its own position ``at[i]``: ``[n, 1,
+    ...]`` (as it is where one position is all it holds: an outer
+    Sequential asks again for what an inner one has cut)."""
+    if a.shape[1] == 1:
+        return a
+    return jnp.take_along_axis(
+        a, at.reshape((-1,) + (1,) * (a.ndim - 1)), axis=1)
+
+
 class _Walk:
     """One pass of some positions through a module tree with decode state
     (module docstring).  ``visit(module, params, x, cache) -> (y, cache)``
@@ -199,11 +209,12 @@ class _Walk:
     place, and ``tally`` gathers what the layers without leaves report of
     the call (an expert layer: the tokens each held expert took, and the
     experts each position's router chose).  With
-    ``last`` set (a prefill: all positions of one prompt at once),
-    everything past the last layer that keeps leaves is position-wise, so a
-    Sequential outside any ConcatTable keeps only position ``last`` from
-    there on: the rest of the model (the last block's MLP, a final norm,
-    the head, LogSoftMax) runs on [1, 1, E]."""
+    ``last`` set (a prefill: all positions of a group's prompts at once;
+    ``[n]``, a position a row), everything past the last layer that keeps
+    leaves is position-wise, so a Sequential outside any ConcatTable keeps
+    only each row's position ``last`` from there on: the rest of the model
+    (the last block's MLP, a final norm, the head, LogSoftMax) runs on [n,
+    1, E]."""
 
     def __init__(self, caches, visit, last=None):
         self.caches, self.visit = caches, visit
@@ -238,8 +249,7 @@ class _Walk:
                 x, layer = self.walk(m, p, s, x, layer, in_table)
                 if self.last is not None and not in_table \
                         and before < layer == len(self.caches):
-                    x = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(
-                        a, self.last, 1, axis=1), x)
+                    x = jax.tree.map(lambda a: _positions(a, self.last), x)
             return x, layer
         if isinstance(module, ConcatTable):
             outs = []
@@ -320,30 +330,33 @@ def _step(module, params, state, x, caches, slot, pos):
 
 
 def _prefill(model, params, state, toks, caches, slot, t0):
-    """One-pass prefill of one sequence into cache row `slot`: `toks` is
-    the prompt, [P] with pads past its `t0` real tokens (P no longer than
-    the cache).  Returns the [V] logits of position t0 - 1, the caches,
-    and what the expert layers report (None for a model that has none):
-    the token counts of the t0 real tokens, and a tuple of the experts each
-    layer's router chose, `[positions, k]` int32 a layer: the P positions
-    of the bucket, pads included, or, past the last layer that keeps
-    leaves, the one position t0 - 1.
+    """One-pass prefill of a group of n sequences, row i into cache row
+    `slot[i]`: `toks` is `[n, P]`, each row a prompt with pads past its
+    `t0[i]` real tokens (P no longer than the cache); `slot` and `t0` are
+    `[n]`.  One pass over the weights serves the whole group.  A row whose
+    `slot` lies past the cache's rows (with `t0` 0) fills the program up: it
+    writes nothing and is counted nowhere.  Returns the `[n, V]` logits of
+    each row's position `t0 - 1`, the caches, and what the expert layers
+    report (None for a model that has none): the token counts of the
+    group's real tokens, and a tuple of the experts each layer's router
+    chose, `[n, positions, k]` int32 a layer: the P positions of the
+    bucket, pads included, or, past the last layer that keeps leaves, the
+    one position `t0 - 1`.
 
-    In a leaf with a length axis, rows t0..P-1 of the slot take the pads'
+    In a leaf with a length axis, rows t0..P-1 of a slot take the pads'
     state: finite, and masked by `<= pos` in every later step until the
     sequence overwrites them, like a previous occupant's stale rows.  A
-    leaf of fixed size is written whole, as it is after position t0 - 1
-    (each layer's `decode_prefill` sees `t0` and keeps its pads out)."""
+    leaf of fixed size is written whole a row, as it is after position t0 -
+    1 (each layer's `decode_prefill` sees every row's `t0` and keeps its
+    pads out)."""
+    last = jnp.maximum(t0 - 1, 0)
     w = _Walk(list(caches),
               lambda m, p, x, c: m.decode_prefill(p, x, c, slot, t0),
-              last=t0 - 1)
-    y, _ = w.walk(model, params, state, toks[None])
+              last=last)
+    y, _ = w.walk(model, params, state, toks)
     if y.shape[1] != 1:  # nothing follows the last stateful layer
-        y = jax.lax.dynamic_slice_in_dim(y, t0 - 1, 1, axis=1)
-    report = w.report()
-    if report is not None:
-        report = report[0], tuple(i[0] for i in report[1])
-    return y[0, 0], tuple(w.caches), report
+        y = _positions(y, last)
+    return y[:, 0], tuple(w.caches), w.report()
 
 
 def _slot_step(model, params, state, tok, caches, pos):

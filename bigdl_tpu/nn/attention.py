@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from ..common import get_policy
 from .initialization import compute_fans, default_weight_init
-from .module import Module, StateLeaf
+from .module import Module, StateLeaf, prefill_rows, write_prompt_rows
 from .normalization import rms_norm
 from .rotary import (apply_rope, apply_rope_half, rope_angles, rope_inv_freq,
                      yarn_mscale)
@@ -268,25 +268,27 @@ class MultiHeadAttention(Module):
             .reshape(B, Q, G * R * D)
 
     def decode_prefill(self, params, x, cache, slot, length):
-        """x: [1, P, E], a whole prompt from position 0 entering the fresh
-        cache row `slot`; returns ([1, P, E], new_cache).  (`length`, the
-        prompt's real positions, is not needed here: the pads are computed
-        and masked later.)
+        """x: [n, P, E], a group of whole prompts from position 0, row i
+        entering the fresh cache row `slot[i]`; returns ([n, P, E],
+        new_cache).  (`length`, each prompt's real positions, is not needed
+        here: the pads are computed and masked later.)
 
-        The prompt attends causally over itself with `decode_step`'s
+        Each prompt attends causally over itself with `decode_step`'s
         float32 score path and exact-zero masked weights; k and v of all P
-        positions go into the cache, ``[1, P, H_kv * D]`` at ``(slot, 0,
-        0)``, by one write each."""
+        positions go into the cache, ``[n, P, H_kv * D]`` at rows `slot`
+        from position 0, by one scatter of n windows a leaf
+        (`write_prompt_rows`; a fill-up row's is dropped)."""
         self._require_causal()
         P = x.shape[1]
+        slot, _ = prefill_rows(x, slot, length)
         q, k, v = (self._proj(params, x, n) for n in "qkv")
         gate = None
         if self._shaped:
             q, k, gate = self._shape(params, q, k, jnp.arange(P)[None])
         # attend over what the cache will hold: k and v in the cache's dtype
         k, v = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
-        ck = jax.lax.dynamic_update_slice(cache["k"], k, (slot, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], v, (slot, 0, 0))
+        ck = write_prompt_rows(cache["k"], slot, k)
+        cv = write_prompt_rows(cache["v"], slot, v)
         mask = jnp.arange(P)[None, :] <= jnp.arange(P)[:, None]
         o = self._attend(q, k, v, mask, x.dtype)
         if gate is not None:
@@ -498,20 +500,20 @@ class LatentAttention(Module):
                                     "latent_cache")}
 
     def decode_prefill(self, params, x, cache, slot, length):
-        """x: [1, P, hidden], a whole prompt from position 0 of which
-        ``length`` positions are real: the expanded form over what the
-        cache will hold, and one write a leaf."""
+        """x: [n, P, hidden], a group of whole prompts from position 0, of
+        row i ``length[i]`` positions real: the expanded form over what the
+        cache will hold, and one scatter of n windows a leaf."""
         P = x.shape[1]
+        slot, length = prefill_rows(x, slot, length)
         q_nope, q_rope, c_kv, k_rope = self._project(
             params, x, jnp.arange(P)[None])
         c_kv = c_kv.astype(cache["c_kv"].dtype)
         k_rope = k_rope.astype(cache["k_rope"].dtype)
-        new = {"c_kv": jax.lax.dynamic_update_slice(
-                   cache["c_kv"], c_kv, (slot, 0, 0)),
-               "k_rope": jax.lax.dynamic_update_slice(
-                   cache["k_rope"], k_rope, (slot, 0, 0))}
+        new = {"c_kv": write_prompt_rows(cache["c_kv"], slot, c_kv),
+               "k_rope": write_prompt_rows(cache["k_rope"], slot, k_rope)}
+        # a block of queries is skipped where no row has a real one in it
         return self._expanded(params, q_nope, q_rope, c_kv, k_rope,
-                              length), new
+                              jnp.max(length)), new
 
     def decode_step(self, params, x, cache, pos):
         """x: [S, 1, hidden], pos: [S]: the absorbed form.  Each row's

@@ -51,8 +51,9 @@ import jax.numpy as jnp
 
 from ..common import get_policy
 from .initialization import compute_fans, default_weight_init
-from .mamba import causal_conv, causal_windows, conv_tail, matmul_f32
-from .module import Module, StateLeaf
+from .mamba import (causal_conv, causal_windows, conv_tail, matmul_f32,
+                    real_positions)
+from .module import Module, StateLeaf, prefill_rows, write_prompt_rows
 
 __all__ = ["GatedDeltaNet"]
 
@@ -221,10 +222,11 @@ class GatedDeltaNet(Module):
         return o.transpose(1, 0, 3, 2, 4).reshape(B_, T, H, self.dv), last
 
     def _scan(self, params, u, length=None):
-        """u [B, T, d_model] from a zero state; positions ``>= length``
-        (traced; None: all real) move nothing.  Returns (out [B, T,
-        d_model], state [B, Hv, dk, dv] float32 after the last real
-        position, qkv [B, T, channels] before the convolution)."""
+        """u [B, T, d_model] from a zero state; row b's positions ``>=
+        length[b]`` (traced, a scalar for every row; None: all real) move
+        nothing.  Returns (out [B, T, d_model], state [B, Hv, dk, dv]
+        float32 after each row's last real position, qkv [B, T, channels]
+        before the convolution)."""
         T, K, Q = u.shape[1], self.conv_kernel, self.chunk
         qkv, z, beta, g = self._project(params, u)
         q, k, v = self._split(causal_conv(causal_windows(qkv, K),
@@ -234,7 +236,7 @@ class GatedDeltaNet(Module):
             # row of the system is the identity's and its correction zero,
             # so the scan's last state is the state after position
             # length - 1
-            real = (jnp.arange(T) < length)[None, :, None]
+            real = real_positions(length, T)
             beta, g = jnp.where(real, beta, 0.0), jnp.where(real, g, 0.0)
         pad = -T % Q
         if pad:
@@ -263,19 +265,17 @@ class GatedDeltaNet(Module):
                                    self.conv_dim), None, "latent_cache")}
 
     def decode_prefill(self, params, x, cache, slot, length):
-        """x [1, P, d_model], a prompt of which ``length`` positions are
-        real: the chunked form from a zero state, whatever the slot held;
-        the pads move nothing (``_scan``); the convolution's window is
-        inputs ``length - K + 1 .. length - 1`` (zeros before the start);
-        both leaves of row ``slot`` are written whole."""
+        """x [n, P, d_model], a group of prompts, of row i ``length[i]``
+        positions real: the chunked form from a zero state, whatever the
+        slots held; a row's pads move nothing (``_scan``); its convolution's
+        window is inputs ``length - K + 1 .. length - 1`` (zeros before the
+        start); both leaves of row ``slot[i]`` are written whole (a fill-up
+        row's not at all: ``write_prompt_rows``)."""
+        slot, length = prefill_rows(x, slot, length)
         y, ssm, qkv = self._scan(params, x, length)
         tail = conv_tail(qkv, length, self.conv_kernel)
-        return y, {"ssm": jax.lax.dynamic_update_slice(
-                       cache["ssm"], ssm.astype(cache["ssm"].dtype),
-                       (slot, 0, 0, 0)),
-                   "conv": jax.lax.dynamic_update_slice(
-                       cache["conv"], tail.astype(cache["conv"].dtype),
-                       (slot, 0, 0))}
+        return y, {"ssm": write_prompt_rows(cache["ssm"], slot, ssm),
+                   "conv": write_prompt_rows(cache["conv"], slot, tail)}
 
     def decode_step(self, params, x, cache, pos):
         """x [S, 1, d_model]: the recurrence in float32, one position a row,

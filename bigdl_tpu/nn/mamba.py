@@ -42,10 +42,10 @@ import jax.numpy as jnp
 
 from ..common import get_policy
 from .initialization import compute_fans, default_weight_init
-from .module import Module, StateLeaf
+from .module import Module, StateLeaf, prefill_rows, write_prompt_rows
 
 __all__ = ["Mamba2Mixer", "causal_conv", "causal_windows", "conv_tail",
-           "matmul_f32"]
+           "matmul_f32", "real_positions"]
 
 F32 = jnp.float32
 
@@ -79,13 +79,21 @@ def causal_windows(x, taps: int):
     return jnp.stack([padded[:, k:k + T] for k in range(taps)], axis=2)
 
 
+def real_positions(length, T: int):
+    """``[B or 1, T, 1]`` boolean: row b's positions before ``length[b]``
+    (traced; a scalar stands for every row)."""
+    return (jnp.arange(T) < jnp.reshape(length, (-1, 1)))[..., None]
+
+
 def conv_tail(x, length, taps: int):
-    """What a convolution keeps of a prompt ``x [B, T, channels]`` whose
-    first ``length`` (traced) positions are real: inputs ``length - K + 1
-    .. length - 1``, zeros before the start, ``[B, K - 1, channels]``."""
-    return jax.lax.dynamic_slice_in_dim(
-        jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))), length, taps - 1,
-        axis=1)
+    """What a convolution keeps of prompts ``x [B, T, channels]`` of which
+    row b's first ``length[b]`` (traced; a scalar stands for every row)
+    positions are real: inputs ``length - K + 1 .. length - 1``, zeros
+    before the start, ``[B, K - 1, channels]``."""
+    at = jnp.reshape(length, (-1, 1)) + jnp.arange(taps - 1)     # [B|1, K-1]
+    at = jnp.broadcast_to(at, (x.shape[0], taps - 1))
+    return jnp.take_along_axis(
+        jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))), at[..., None], axis=1)
 
 
 class Mamba2Mixer(Module):
@@ -186,10 +194,11 @@ class Mamba2Mixer(Module):
                            params["conv_bias"])
 
     def _scan(self, params, u, length=None):
-        """u [B, T, d_model] from a zero state; positions ``>= length``
-        (traced; None: all real) move nothing.  Returns (out [B, T,
-        d_model], ssm state [B, H, P, N] float32 after the last real
-        position, xBC [B, T, channels] before the convolution)."""
+        """u [B, T, d_model] from a zero state; row b's positions ``>=
+        length[b]`` (traced, a scalar for every row; None: all real) move
+        nothing.  Returns (out [B, T, d_model], ssm state [B, H, P, N]
+        float32 after each row's last real position, xBC [B, T, channels]
+        before the convolution)."""
         c = get_policy().compute_dtype
         B_, T, _ = u.shape
         H, P, G, N, K, Q = (self.heads, self.head_dim, self.groups,
@@ -201,7 +210,7 @@ class Mamba2Mixer(Module):
             # a pad has dt = 0: its decay is exp(0) = 1 and its input term
             # zero, so the scan's last state is the state after position
             # length - 1
-            dt = jnp.where((jnp.arange(T) < length)[None, :, None], dt, 0.0)
+            dt = jnp.where(real_positions(length, T), dt, 0.0)
         pad = -T % Q
         if pad:
             # whole chunks; the added positions have dt = 0 as well
@@ -268,20 +277,17 @@ class Mamba2Mixer(Module):
                                    self.conv_dim), None, "latent_cache")}
 
     def decode_prefill(self, params, x, cache, slot, length):
-        """x [1, P, d_model], a prompt of which ``length`` positions are
-        real: the chunked form from a zero state, whatever the slot held;
-        the pads move nothing (``_scan``); the convolution's window is
-        inputs ``length - K + 1 .. length - 1`` (zeros before the start);
-        both leaves of row ``slot`` are written whole."""
-        K = self.conv_kernel
+        """x [n, P, d_model], a group of prompts, of row i ``length[i]``
+        positions real: the chunked form from a zero state, whatever the
+        slots held; a row's pads move nothing (``_scan``); its convolution's
+        window is inputs ``length - K + 1 .. length - 1`` (zeros before the
+        start); both leaves of row ``slot[i]`` are written whole (a fill-up
+        row's not at all: ``write_prompt_rows``)."""
+        slot, length = prefill_rows(x, slot, length)
         y, ssm, xbc = self._scan(params, x, length)
-        tail = conv_tail(xbc, length, K)
-        return y, {"ssm": jax.lax.dynamic_update_slice(
-                       cache["ssm"], ssm.astype(cache["ssm"].dtype),
-                       (slot, 0, 0, 0)),
-                   "conv": jax.lax.dynamic_update_slice(
-                       cache["conv"], tail.astype(cache["conv"].dtype),
-                       (slot, 0, 0))}
+        tail = conv_tail(xbc, length, self.conv_kernel)
+        return y, {"ssm": write_prompt_rows(cache["ssm"], slot, ssm),
+                   "conv": write_prompt_rows(cache["conv"], slot, tail)}
 
     def decode_step(self, params, x, cache, pos):
         """x [S, 1, d_model]: the recurrence, one position a row, both

@@ -40,7 +40,8 @@ import numpy as np
 
 from ..common import get_policy, next_rng_key
 
-__all__ = ["Module", "Container", "Criterion", "StateLeaf"]
+__all__ = ["Module", "Container", "Criterion", "StateLeaf", "prefill_rows",
+           "write_prompt_rows"]
 
 
 class StateLeaf(NamedTuple):
@@ -60,6 +61,32 @@ class StateLeaf(NamedTuple):
     length_axis: Optional[int]
     role: str
     dtype: Any = None
+
+
+def prefill_rows(x, slot, length):
+    """A prefill's ``slot`` and ``length`` as int32 ``[n]``, one of each a
+    row of ``x [n, P, ...]`` (a scalar stands for every row)."""
+    return tuple(jnp.broadcast_to(jnp.asarray(a, jnp.int32), x.shape[:1])
+                 for a in (slot, length))
+
+
+def write_prompt_rows(cache, slot, new):
+    """How a prefill puts a group's rows into its donated state: ``new [n,
+    ...]`` into rows ``slot [n]`` of ``cache [S, ...]``, from the start of
+    every later axis (a leaf with a length axis takes the P positions
+    computed, a leaf of fixed size the whole row): one scatter of n windows
+    a leaf.  A row whose slot lies past ``S`` is dropped: it writes
+    nothing.  A lone row (n == 1, known at trace time) is its call's one
+    request, never a fill-up row, and is written by a dynamic-update-slice:
+    the compiler fuses that into the product that computes the window,
+    where a scatter of one window stays an operation of its own."""
+    new = new.astype(cache.dtype)
+    if new.shape[0] == 1:
+        return jax.lax.dynamic_update_slice(
+            cache, new, (slot[0],) + (0,) * (new.ndim - 1))
+    at = (slot,) + tuple(slice(0, d) for d in new.shape[1:])
+    return cache.at[at].set(new, mode="drop")
+
 
 _uid_counter = itertools.count()
 
@@ -197,10 +224,13 @@ class Module:
         return None
 
     def decode_prefill(self, params, x, cache, slot, length):
-        """A whole prompt from position 0, ``x [1, P, ...]`` of which the
-        first ``length`` positions are real and the rest pads, entering
-        row ``slot`` of ``cache`` (this layer's leaves); returns (y,
-        cache)."""
+        """A group of n whole prompts from position 0, ``x [n, P, ...]``;
+        of row i the first ``length[i]`` positions are real and the rest
+        pads, and it enters row ``slot[i]`` of ``cache`` (this layer's
+        leaves; ``slot`` and ``length`` int32 ``[n]``, or a scalar for
+        every row: ``prefill_rows``).  A row whose slot lies past the
+        cache's rows fills the program up and writes nothing
+        (``write_prompt_rows``).  Returns (y, cache)."""
         raise NotImplementedError(type(self).__name__)
 
     def decode_step(self, params, x, cache, pos):

@@ -456,10 +456,12 @@ class GatedMoE(Module):
         return {}
 
     def decode_prefill(self, params, x, cache, slot, length):
-        """x [1, P, D]: a prompt's positions 0..P-1 of which the first
-        ``length`` are real, or (P == 1) its last real position alone."""
-        return self._forward(params, x,
-                             (jnp.arange(x.shape[1]) < length)[None])
+        """x [n, P, D]: a group of prompts' positions 0..P-1, of row i the
+        first ``length[i]`` real (a scalar: of every row), or (P == 1) each
+        row's last real position alone; a row of length 0 fills the program
+        up and counts nowhere."""
+        return self._forward(
+            params, x, jnp.arange(x.shape[1]) < jnp.reshape(length, (-1, 1)))
 
     def decode_step(self, params, x, cache, pos):
         return self._forward(params, x, (pos >= 0)[:, None])

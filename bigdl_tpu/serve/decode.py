@@ -17,8 +17,8 @@ took; PAPERS.md):
   asserts).
 - **The loop runs one tick ahead of its reads.**  The slots' last tokens
   live on the device as one ``int32[slots]`` vector: a prefill takes it
-  and returns it with its slot's row set to the prompt's first token, a
-  step takes it as its input tokens and leaves the next one.  A tick
+  and returns it with its group's rows set to their prompts' first
+  tokens, a step takes it as its input tokens and leaves the next one.  A tick
   calls, then reads: free slots are known from counts (``_Seq.called``),
   requests are taken, their prefills called, the step called, and only
   then one ``jax.device_get`` brings down what the calls of the tick
@@ -43,15 +43,16 @@ took; PAPERS.md):
 - **Prefill and decode are separate jitted executables** with separate
   compile cards and AOT cache entries, keyed like the
   ``_ShardedForward`` buckets (module fingerprint + base fingerprint +
-  shape dims through utils/aot.get_or_compile).  Prefill admits one new
-  sequence into a free KV-cache slot in ONE pass: the padded prompt
-  bucket goes through the model as ``[1, bucket, E]`` (every weight
-  read once a prompt, every product matrix-matrix), each layer with
-  decode state writes all positions into the slot by one in-place
-  update a leaf, and past the last such layer only the prompt's last
-  real position goes on to the head (models/decode ``_prefill``; one
-  compile per (prompt-bucket, slots, cache-page), the prompt's length
-  traced).  Both programs are one walk (models/decode ``_Walk``) over
+  shape dims through utils/aot.get_or_compile).  Prefill admits a GROUP
+  of new sequences into free KV-cache slots in ONE pass: the padded
+  prompt buckets go through the model as ``[rows, bucket, E]`` (every
+  weight read once a group, every product matrix-matrix), each layer
+  with decode state writes all positions into the group's slots by one
+  in-place scatter a leaf, and past the last such layer only each
+  prompt's last real position goes on to the head (models/decode
+  ``_prefill``; one compile per (rows, prompt-bucket, slots,
+  cache-page), the slots and the prompts' lengths traced).  Both
+  programs are one walk (models/decode ``_Walk``) over
   what each layer declares (``Module.decode_state``, ``decode_prefill``,
   ``decode_step``): ``MultiHeadAttention`` keeps ``{k, v}`` a key-value
   head, ``LatentAttention`` a latent and one rotary key for all heads,
@@ -66,7 +67,7 @@ took; PAPERS.md):
   programs compute, beside the logits, the index of each row's largest
   entry (the first among equals, ``np.argmax``'s rule on the same
   bfloat16 values) and return the slots' ``int32[slots]`` vector with
-  it: every row from the step, the admitted slot's from the prefill.
+  it: every row from the step, the admitted slots' from the prefill.
   The host fetches those and what the expert layers report, and
   the ``[slots, vocabulary]`` array stays on the device.  What a request
   says decides its row's way, nothing else: ``temperature`` 0 takes the
@@ -83,6 +84,30 @@ took; PAPERS.md):
   declared role (parallel/layout.py ``kv_cache``: slots over data x
   fsdp, heads over tp; ``latent_cache``: slots alone), so tp-sharded
   models serve decode through the existing mesh machinery unchanged.
+- **Admission in groups.**  A pass's requests enter in the order they
+  were taken, and those of one prompt bucket share prefill calls: n
+  prompts pay the weights once, where a call a prompt streams them n
+  times.  How many rows a call may have is read off declared shapes
+  (``_prefill_programs``), as one budget of positions a call (rows x
+  bucket; ``_call_positions``): as many as keep the call's multiply-adds
+  (a position's, counted from the one-row program's own trace,
+  utils/flops) from outweighing the weight bytes it streams, and no more
+  than the longest single prompt the engine must take anyway needs beside
+  the cache; and no group is wider than half the requests a backlogged
+  pass may wait for.  A bucket has its one-row program and, where its
+  widest group has four rows or more, one program of that many (a power
+  of two; every program costs seconds of every start); a smaller group
+  fills that program up with rows that write nothing and are counted
+  nowhere; both are compiled when the bucket first is, so a later burst
+  compiles nothing.  When to wait is read off
+  the queue (``_take_now``): while no more requests wait than slots are
+  free, whatever arrived goes at once (a lone request on an idle engine
+  is called in the pass that takes it); under a backlog freed slots are
+  held until the pass can take as many requests as keep ``_HELD_SHARE``
+  of the slots free on average (``_take_cap``: a take spreads over the
+  buckets in use, and a fuller take makes fuller groups), and no longer
+  than the taken slots' own remaining counts said that would need.  No setting
+  chooses any of it.
 - Admission rides :class:`~bigdl_tpu.serve.batcher.DecodeQueue`:
   bounded queue, per-sequence deadline (= time-to-LAST-token), priority
   eviction and tenant quotas all apply per-sequence; ``note_service``
@@ -90,7 +115,8 @@ took; PAPERS.md):
   token budget.
 - Telemetry: a working pass of the loop is one ``decode.tick`` span
   (``active``: slots carried over from the pass before, ``admitted``) with
-  ``decode.admit`` (one an admission), ``decode.step`` and
+  ``decode.admit`` (one a prefill call: ``rows`` requests of one
+  ``bucket``, ``prompt_len`` their real tokens), ``decode.step`` and
   ``decode.sample`` inside it; in the first two, ``decode.call`` is the
   host's part up to the executable's return; ``decode.fetch``, in
   ``decode.step`` after its call (in the tick itself where a pass calls
@@ -104,7 +130,11 @@ took; PAPERS.md):
   ``decode.first_token``, ``resolve``) whatever its length, and
   ``serve.request`` carries ``queue_wait_ms``, ``ttft_ms``, ``prompt_len``
   and ``tokens``.  The ``serve.decode`` counter track emits tokens/s,
-  active-slot fill, prefill-vs-decode step fractions, the share of the
+  active-slot fill, prefill-vs-decode step fractions (``prefill_steps``
+  counts device calls of the prefill, ``prefill_rows`` the requests
+  they admitted: ``prefill_group`` is rows a call), the share of the
+  slots' steps kept free for a fuller group (``held_share``:
+  ``slot_steps_held`` over slots x steps), the share of the
   prefills' positions that were padding (``prefill_pad_frac``), cache
   bytes/slot and the part of them that is of fixed size
   (``state_bytes_per_slot``; ``state_bytes_fixed`` is that part over all
@@ -173,6 +203,7 @@ import jax.numpy as jnp
 from ..models import decode as kv
 from ..models.transformer_lm import PositionalEmbedding, sample_next
 from ..utils import aot as aot_mod
+from ..utils import flops as flops_mod
 from ..utils import chaos, config, hlostats, metrics_export, telemetry
 from .batcher import DecodeQueue, PendingRequest, ServeError
 from .control import TenantQuotas
@@ -212,6 +243,28 @@ def _prompt_bucket(t0: int) -> int:
     return b
 
 
+#: multiply-adds a call may spend for every byte of weights it streams
+#: before its arithmetic outweighs them: up to here part of a row's work
+#: hides under the weights' streaming, past it a call's time grows by a
+#: row's whole work and a wider group saves little.  Read on the chip by
+#: (rows, bucket) in three models (tools/prefill_rows.py; PERF.md, PR 42:
+#: a call's time follows its positions, whatever their split into rows,
+#: with a knee at 100 and 128 where one is seen; a model whose one prompt
+#: counts 226 does not group).
+_FLOPS_PER_WEIGHT_BYTE = 128.0
+
+#: the share of the slots a backlogged engine may keep free, on average,
+#: while it waits for a pass's take to fill its groups
+_HELD_SHARE = 1.0 / 32
+
+#: the narrowest group that gets a program of its own.  Not read off the
+#: chip's table of calls but off what a program costs to bring up: seconds
+#: of every start, cold or warm (trace, lower, load: 3.3 s warm where a
+#: start is 44 s, PERF.md, PR 42), for a pair that saves at most half a
+#: call's weights
+_MIN_GROUP_ROWS = 4
+
+
 class _Seq:
     """Host-side state of one in-flight sequence (one slot).  ``called``
     counts the tokens asked of the device (its prefill and every step that
@@ -246,9 +299,10 @@ class _Seq:
 class _Call:
     """One device call whose results the host has not read: the pass that
     made it, its program, the rows it computed as ``(sequence, position)``
-    (a prefill's one row has no position), and what it left on
-    the device: the log-probabilities, the ``int32[slots]`` tokens after
-    it and what the expert layers report."""
+    (a prefill's rows have no position: they are its group's, in the
+    program's order), and what it left on the device: the
+    log-probabilities, the ``int32[slots]`` tokens after it and what the
+    expert layers report."""
 
     __slots__ = ("tick", "program", "rows", "logits", "tokens", "report")
 
@@ -380,10 +434,23 @@ class DecodeEngine:
         self._recorder = None
         self._thread: Optional[threading.Thread] = None
         self._lock = threading.Lock()
+        # admission in groups (``_prefill_programs``, ``_take_now``): the
+        # one guard on a call's memory (the positions it may hold beside
+        # the cache are the longest single prompt's the engine must take
+        # anyway), and the weights a call streams
+        self._group_positions = min(_prompt_bucket(self.max_len - 1),
+                                    self.max_len)
+        self._weight_bytes = sum(a.nbytes for a in
+                                 jax.tree.leaves(self._params))
+        self._rows: dict = {}        # (bucket, cache_len) -> rows ladder
+        self._hold_until = None      # decode_steps at which a hold ends
         # cumulative counters (stats(); serve.decode telemetry track)
-        self.prefill_steps = 0
+        self.prefill_steps = 0       # device calls of the prefill
+        self.prefill_rows = 0        # requests they admitted
+        self.slot_steps_held = 0     # slots x steps kept free for a group
         self.prompt_tokens = 0       # real prompt tokens prefilled
-        self.prefill_positions = 0   # positions computed for them (pads too)
+        self.prefill_positions = 0   # positions computed for them (pads and
+        #                              fill-up rows too)
         self.decode_steps = 0
         # steps called with a call before them unread: the device held its
         # next program when it ended the last one
@@ -580,49 +647,112 @@ class DecodeEngine:
         self._exe[memo] = exe
         return exe
 
-    def _prefill_exe(self, prompt_bucket: int, cache_len: int):
-        """The prefill executable for the (prompt_bucket, slots,
-        cache_len) bucket: one new sequence enters ONE slot in one pass
-        (models/decode._prefill).  The padded bucket, cut to the cache
-        where it is longer, goes through the model as [1, P, E]: every
-        weight is read once a prompt, each stateful layer's leaves land
-        in the slot by one write each, and past the last such layer only
-        the prompt's last real position goes on to the head.  Every prompt
-        length in the bucket shares this compile (t0 is traced)."""
-        memo = ("prefill", prompt_bucket, self.slots, cache_len)
-        exe = self._exe.get(memo)
-        if exe is not None:
-            return exe
+    def _prefill_fn(self, rows: int, prompt_bucket: int, cache_len: int):
+        """The prefill's jitted function and the avals it is traced at, for
+        a group of up to ``rows`` prompts of one bucket."""
         model, pin = self.model, self._pin_tokens
 
         # ``jit_decode_prefill`` on the device trace's ``XLA Modules`` line
         @partial(jax.jit, donate_argnums=(2,))
         def decode_prefill(params, state, caches, tokens, toks, slot, t0):
-            # the slots' token vector comes back with row ``slot`` set to
-            # the prompt's first token: the same pass's step takes it there
+            # the slots' token vector comes back with the group's rows set
+            # to their prompts' first tokens: the same pass's step takes
+            # them there (a fill-up row's slot is dropped)
             logits, token, caches, report = _with_tokens(*kv._prefill(
                 model, params, state, toks, caches, slot, t0))
-            return logits, pin(tokens.at[slot].set(token)), caches, report
+            return (logits, pin(tokens.at[slot].set(token, mode="drop")),
+                    caches, report)
 
+        P = min(prompt_bucket, cache_len)   # cut to the cache where longer
+        return decode_prefill, (
+            self._params, self._state, self._cache_avals(cache_len),
+            self._tokens_aval(), jax.ShapeDtypeStruct((rows, P), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32))
+
+    def _prefill_exe(self, rows: int, prompt_bucket: int, cache_len: int,
+                     traced=None):
+        """The prefill executable for the (rows, prompt_bucket, slots,
+        cache_len) bucket: a group of up to ``rows`` new sequences enters
+        as many slots in one pass (models/decode._prefill).  The padded
+        bucket, cut to the cache where it is longer, goes through the model
+        as [rows, P, E]: every weight is read once a GROUP, each stateful
+        layer's leaves land in the group's slots by one scatter each, and
+        past the last such layer only each prompt's last real position goes
+        on to the head.  Every prompt length in the bucket and every group
+        of up to ``rows`` requests shares this compile (``slot`` and ``t0``
+        are traced; a row past the group's requests has a slot past the
+        cache's rows and writes nothing).  ``traced``: the function as it
+        was traced already, where it was."""
+        memo = ("prefill", rows, prompt_bucket, self.slots, cache_len)
+        exe = self._exe.get(memo)
+        if exe is not None:
+            return exe
+        decode_prefill, avals = self._prefill_fn(rows, prompt_bucket,
+                                                 cache_len)
         # ``body``: the key holds the program's name, not its text, and the
-        # per-position prefill before this one had the same name
+        # prefills before this one (a position a call, then one prompt a
+        # call) had the same name
         exe = aot_mod.get_or_compile(
             self._key_fields("decode.prefill", decode_prefill,
                              slots=self.slots, cache_len=cache_len,
-                             prompt_bucket=prompt_bucket, body="one_pass",
+                             prompt_bucket=prompt_bucket, rows=rows,
+                             body="one_pass_group",
                              dtype=jnp.dtype(self.cache_dtype).name),
-            lambda: decode_prefill.lower(
-                self._params, self._state, self._cache_avals(cache_len),
-                self._tokens_aval(),
-                jax.ShapeDtypeStruct((min(prompt_bucket, cache_len),),
-                                     jnp.int32),
-                jax.ShapeDtypeStruct((), jnp.int32),
-                jax.ShapeDtypeStruct((), jnp.int32)),
+            lambda: (traced or decode_prefill.trace(*avals)).lower(),
             label="decode.prefill",
             card_extra={"slots": self.slots, "cache_len": cache_len,
-                        "prompt_bucket": prompt_bucket})
+                        "prompt_bucket": prompt_bucket, "rows": rows})
         self._exe[memo] = exe
         return exe
+
+    def _call_positions(self, prompt_bucket: int, cache_len: int):
+        """The positions (rows x bucket) one prefill call of this bucket may
+        carry, and the one-row program as it was traced to count them: as
+        many as keep the call's arithmetic (one position's multiply-adds,
+        counted from that trace, utils/flops) from outweighing the weight
+        bytes it streams (``_FLOPS_PER_WEIGHT_BYTE``), and no more than the
+        memory guard allows (``_group_positions``)."""
+        fn, avals = self._prefill_fn(1, prompt_bucket, cache_len)
+        traced = fn.trace(*avals)
+        a_position = max(flops_mod.jaxpr_flops(traced.jaxpr), 1.0) \
+            / min(prompt_bucket, cache_len)
+        return min(self._group_positions,
+                   int(self._weight_bytes * _FLOPS_PER_WEIGHT_BYTE
+                       / a_position)), traced
+
+    def _prefill_programs(self, prompt_bucket: int, cache_len: int) -> tuple:
+        """The rows a call of this bucket may have: 1, and where the bucket
+        groups the widest group's (a smaller group fills that program up).
+        Both are compiled by the time this returns: a bucket's group
+        program is compiled when the bucket first is, so a burst later
+        compiles nothing.  The widest group is a power of two read off what
+        the engine can observe, never a setting.  First what needs no trace:
+        it is no wider than half the requests a backlogged pass may wait for
+        (``_take_cap``: a take spreads over the buckets in use, and a
+        fill-up row's positions cost what a request's do), nor than the
+        memory guard's positions over the bucket's; where that already
+        leaves fewer than ``_MIN_GROUP_ROWS`` the bucket has its one-row
+        program and nothing is traced for the rule.  Else one budget of
+        positions a call (``_call_positions``) decides."""
+        key = (prompt_bucket, cache_len)
+        ladder = self._rows.get(key)
+        if ladder is None:
+            P = min(prompt_bucket, cache_len)
+            rows, traced = min(self._take_cap() // 2,
+                               self._group_positions // P), None
+            if rows >= _MIN_GROUP_ROWS:
+                positions, traced = self._call_positions(prompt_bucket,
+                                                         cache_len)
+                rows = min(rows, positions // P)
+            self._prefill_exe(1, prompt_bucket, cache_len, traced)
+            ladder = (1,)
+            if rows >= _MIN_GROUP_ROWS:
+                rows = 1 << (rows.bit_length() - 1)
+                self._prefill_exe(rows, prompt_bucket, cache_len)
+                ladder = (1, rows)
+            self._rows[key] = ladder
+        return ladder
 
     # -- (slots, cache-page) ladder -------------------------------------
 
@@ -817,14 +947,14 @@ class DecodeEngine:
                             active=sum(len(c.rows) for c in calls)):
             for c, (tokens, (counts, chosen)) in zip(calls, got):
                 self._count_experts(counts)
-                for seq, pos in c.rows:
+                for i, (seq, pos) in enumerate(c.rows):
                     if seq.over:
                         # it ended at an EOS the call before, or failed:
                         # the row was computed for nobody
                         continue
-                    if pos is None:
-                        self._prompt_routing(seq, chosen)
-                        row = _LogitRow(tokens[seq.slot], c.logits)
+                    if pos is None:          # row i of a prefill's group
+                        self._prompt_routing(seq, chosen, i)
+                        row = _LogitRow(tokens[seq.slot], c.logits, i)
                     else:
                         if chosen is not None:
                             seq.routed[:, pos] = chosen[:, seq.slot]
@@ -838,10 +968,11 @@ class DecodeEngine:
         that needs a token the host alone can choose."""
         self._take(self._fetch(len(self._unread)))
 
-    def _prompt_routing(self, seq: _Seq, chosen) -> None:
-        """A prefill's report of the experts its positions chose, into the
-        sequence's ``[layers, positions, k]``: a layer saw the whole bucket
-        (its pads go) or the prompt's last position alone."""
+    def _prompt_routing(self, seq: _Seq, chosen, row: int) -> None:
+        """Row ``row`` of a prefill's report of the experts its positions
+        chose (``[rows, positions, k]`` a layer), into the sequence's
+        ``[layers, positions, k]``: a layer saw the whole bucket (its pads
+        go) or the prompt's last position alone."""
         if chosen is None:
             return
         t0 = seq.t0
@@ -849,6 +980,7 @@ class DecodeEngine:
             (len(chosen), t0 + seq.max_tokens, chosen[0].shape[-1]),
             -1, np.int32)
         for layer, a in enumerate(chosen):
+            a = a[row]
             if len(a) == 1:
                 seq.routed[layer, t0 - 1] = a[0]
             else:
@@ -889,51 +1021,82 @@ class DecodeEngine:
             if seq.called >= seq.max_tokens:
                 self._slots[seq.slot] = None
 
-    def _admit(self, req: PendingRequest, s: int) -> None:
-        """Call the prefill of one request into slot ``s``; its first token
-        is read a call later."""
+    def _enter(self, req: PendingRequest, s: int) -> Optional[_Seq]:
+        """The request holds slot ``s`` from here on; None where the slot's
+        chaos point faulted it (it fails alone, typed)."""
         p = req.payload
-        prompt = p["prompt"]
-        t0 = len(prompt)
         rng = jax.random.PRNGKey(p.get("seed", 0)) \
             if p.get("temperature", 0.0) > 0 else None
-        seq = _Seq(req, s, prompt, p["max_tokens"], p.get("eos"),
+        seq = _Seq(req, s, p["prompt"], p["max_tokens"], p.get("eos"),
                    p.get("temperature", 0.0), p.get("top_k", 0), rng)
         self._slots[s] = seq
         self._stamp_admitted(req)
         if req.rid is not None:
             telemetry.flow_step(req.rid, hop="decode.admit", slot=s,
-                                prompt_len=t0)
+                                prompt_len=seq.t0)
         try:
             chaos.fire(f"serve.decode@{s}", thread_exc=SlotFault)
         except Exception as e:  # noqa: BLE001 — typed per-sequence fail
             self._fail(seq, e)
-            return
-        pb = _prompt_bucket(t0)
-        with telemetry.span("decode.admit", cat="serve", prompt_len=t0,
-                            bucket=pb, slot=s,
+            return None
+        return seq
+
+    def _admit(self, reqs, free) -> None:
+        """A pass's requests enter the free slots, in the order they were
+        taken, and their prefills are called: the requests of one prompt
+        bucket share calls, as many a call as the bucket's widest program
+        has rows (``_prefill_programs``); first tokens are read a call
+        later."""
+        buckets: dict = {}
+        for req in reqs:
+            seq = self._enter(req, free.pop(0))
+            if seq is not None:
+                buckets.setdefault(_prompt_bucket(seq.t0), []).append(seq)
+        for pb, seqs in buckets.items():
+            ladder = self._prefill_programs(pb, self._cache_len)
+            for i in range(0, len(seqs), ladder[-1]):
+                group = seqs[i:i + ladder[-1]]
+                self._prefill(pb, group,
+                              min(r for r in ladder if r >= len(group)))
+
+    def _prefill(self, pb: int, group, rows: int) -> None:
+        """One prefill call for ``group``, sequences of prompt bucket
+        ``pb``, by the program of ``rows`` rows (no fewer than the group):
+        the rows past the group fill the program up, with a slot past the
+        cache's, and write nothing.  A call that fails fails its group."""
+        P = min(pb, self._cache_len)   # the bucket, cut to the cache
+        real = sum(seq.t0 for seq in group)
+        with telemetry.span("decode.admit", cat="serve", prompt_len=real,
+                            bucket=pb, slot=group[0].slot, rows=len(group),
                             state_bytes=self._state_bytes):
             # the host's part, up to the executable's return
             with telemetry.span("decode.call", cat="serve",
                                 program="decode_prefill"):
-                # the bucket, cut to the cache where it is longer (t0 fits)
-                toks = np.zeros(min(pb, self._cache_len), np.int32)
-                toks[:t0] = prompt
-                exe = self._prefill_exe(pb, self._cache_len)
+                toks = np.zeros((rows, P), np.int32)
+                slot = np.full(rows, self.slots, np.int32)
+                t0 = np.zeros(rows, np.int32)
+                for i, seq in enumerate(group):
+                    toks[i, :seq.t0] = seq.buf[:seq.t0]
+                    slot[i], t0[i] = seq.slot, seq.t0
+                exe = self._prefill_exe(rows, pb, self._cache_len)
                 try:
                     logits, tokens, self._caches, report = exe(
                         self._params, self._state, self._caches,
-                        self._tokens, jnp.asarray(toks), jnp.int32(s),
-                        jnp.int32(t0))
+                        self._tokens, jnp.asarray(toks), jnp.asarray(slot),
+                        jnp.asarray(t0))
                 except Exception as e:  # noqa: BLE001
-                    self._fail(seq, SlotFault(f"decode: prefill failed "
-                                              f"in slot {s}: {e!r}"))
+                    for seq in group:
+                        self._fail(seq, SlotFault(
+                            f"decode: prefill failed in slot {seq.slot}: "
+                            f"{e!r}"))
                     return
             self._called(_Call(self._ticks, "decode_prefill",
-                               [(seq, None)], logits, tokens, report))
+                               [(seq, None) for seq in group], logits,
+                               tokens, report))
             self.prefill_steps += 1
-            self.prompt_tokens += t0
-            self.prefill_positions += len(toks)
+            self.prefill_rows += len(group)
+            self.prompt_tokens += real
+            self.prefill_positions += toks.size
 
     def _step(self, rows) -> bool:
         """Call the decode step for ``rows``, the sequence of every slot
@@ -973,7 +1136,7 @@ class DecodeEngine:
         n_active = self.slots - len(free)
         incoming: List[PendingRequest] = []
         if free and (self.admission == "continuous" or n_active == 0):
-            incoming = q.take(len(free))
+            incoming = q.take(self._take_now(len(free), q.depth()))
         if n_active == 0 and not incoming and not self._unread:
             if q.closed and q.depth() == 0:
                 return False
@@ -983,6 +1146,45 @@ class DecodeEngine:
                             admitted=len(incoming)):
             self._work(q, free, n_active, incoming)
         return True
+
+    def _take_cap(self) -> int:
+        """The most requests a backlogged pass may wait for: slots freed
+        one after another wait (n - 1) / 2 steps each for the n-th, and
+        that may keep no more than ``_HELD_SHARE`` of the slots free on
+        average."""
+        return 1 + int(2 * _HELD_SHARE * self.slots)
+
+    def _group_target(self) -> int:
+        """How many requests a backlogged pass waits to take at once: as
+        many as ``_take_cap`` allows where some prompt bucket has a group
+        program (at this cache length), since a take spreads over the
+        buckets in use and a fuller take makes fuller groups; 1 where none
+        groups: nothing is held."""
+        groups = any(cache_len == self._cache_len and len(ladder) > 1
+                     for (_pb, cache_len), ladder in self._rows.items())
+        return self._take_cap() if groups else 1
+
+    def _take_now(self, free: int, waiting: int) -> int:
+        """How many requests this pass takes, with ``free`` slots free and
+        ``waiting`` requests queued.  No backlog (no more wait than slots
+        are free): all of them, at once, so a lone request on an idle engine
+        is called in the pass that takes it.  Under a backlog the free slots
+        are held for a fuller take (``_group_target``), but no longer than
+        the taken slots' own remaining counts (``max_tokens - called``) said
+        the take would need to fill when the hold began; 0: held."""
+        want = min(self._group_target(), waiting)
+        if free >= want:              # all that wait, or a full take
+            self._hold_until = None
+            return free
+        if self._hold_until is None:
+            left = sorted(seq.max_tokens - seq.called
+                          for seq in self._slots if seq is not None)
+            self._hold_until = self.decode_steps + left[want - free - 1]
+        if self.decode_steps >= self._hold_until:
+            self._hold_until = None
+            return free
+        self.slot_steps_held += free
+        return 0
 
     def _work(self, q, free, n_active, incoming) -> None:
         """What one pass does once there is something to do (the
@@ -1001,8 +1203,7 @@ class DecodeEngine:
             self._ensure_cache(need, idle=(n_active == 0))
         # the calls of the passes before: read once this pass has called
         old = len(self._unread)
-        for r in incoming:
-            self._admit(r, free.pop(0))
+        self._admit(incoming, free)
         # every slot still taken (a freshly prefilled one too: its first
         # token lies in the token vector) goes one position forward
         rows = [seq for seq in self._slots if seq is not None]
@@ -1050,6 +1251,9 @@ class DecodeEngine:
             logit_rows_fetched=self.logit_rows_fetched,
             fill=n_active / self.slots,
             prefill_frac=self.prefill_steps / max(steps, 1),
+            prefill_group=self.prefill_rows / max(self.prefill_steps, 1),
+            held_share=self.slot_steps_held
+            / max(self.decode_steps * self.slots, 1),
             prefill_pad_frac=1.0 - self.prompt_tokens
             / max(self.prefill_positions, 1),
             decode_frac=self.decode_steps / max(steps, 1),
@@ -1083,6 +1287,8 @@ class DecodeEngine:
             "state_bytes_per_position": self._position_bytes,
             "cache_grows": self.cache_grows,
             "prefill_steps": self.prefill_steps,
+            "prefill_rows": self.prefill_rows,
+            "slot_steps_held": self.slot_steps_held,
             "prompt_tokens": self.prompt_tokens,
             "prefill_positions": self.prefill_positions,
             "decode_steps": self.decode_steps,

@@ -9,7 +9,9 @@ benchmark divides its configurations' own counts (checked against this one
 in tests/benchmark) by a device time and by `benchmark/peaks.json`, the one
 table of peaks.
 
-Conventions: a dot_general counts 2*M*N*K (multiply+add); a conv counts
+Conventions: a dot_general counts 2*M*N*K (multiply+add), a ragged dot the
+same over its rows (each meets one group's matrix), a Pallas call what its
+`cost_estimate` states; a conv counts
 2 * prod(out_shape) * (in_features / feature_group_count) * prod(kernel_spatial).
 Elementwise ops are ignored (matmul/conv dominate on the MXU).  `scan` bodies
 are multiplied by trip count; `while_loop` bodies are counted once (trip count
@@ -48,12 +50,22 @@ def _eqn_flops(eqn) -> float:
         in_f = rhs[dn.rhs_spec[1]]
         k_spatial = _prod(rhs[d] for d in dn.rhs_spec[2:])
         return 2.0 * _prod(out) * in_f * k_spatial
+    if name == "ragged_dot_general":
+        # every row meets one group's matrix: [m, k] x [g, k, n] -> [m, n]
+        lhs = eqn.invars[0].aval.shape
+        return 2.0 * _prod(eqn.outvars[0].aval.shape) * lhs[-1]
+    if name == "pallas_call":
+        # a kernel states its own count (its body is one grid step's)
+        cost = eqn.params.get("cost_estimate")
+        return float(cost.flops) if cost is not None else 0.0
     return 0.0
 
 
 def _sub_jaxprs(eqn):
     """Yield (jaxpr, multiplier) for every sub-jaxpr in an equation."""
     name = eqn.primitive.name
+    if name == "pallas_call":
+        return
     for pname, val in eqn.params.items():
         mult = 1.0
         if name == "scan" and pname == "jaxpr":

@@ -55,7 +55,9 @@ inert until a tracer is active):
   step) ``decode.fetch`` (the host blocked on the device and the
   transfer, for the calls of the tick before: ``tick``), ``decode.idle``
   (serve/batcher.py: the engine asleep with nothing to do), and the
-  ``serve.decode`` counter track (``ran_ahead`` a step);
+  ``serve.decode`` counter track (``ran_ahead`` a step; ``prefill_group``,
+  requests a prefill call, and ``held_share``, the slots' steps kept free
+  for a fuller group, since start);
 - file_io: ``ckpt.write``/``ckpt.read`` spans (write+verify),
   ``ckpt.retention`` spans, and an ``io.retry`` instant per remote-IO
   retry attempt;

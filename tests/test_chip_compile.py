@@ -329,6 +329,77 @@ def test_hybrid_decode_step_compiles_at_the_cells_sizes(one_chip,
     assert text.count(" scatter(") >= 2 * 2             # keys and values
 
 
+def _compile_decode_prefill(workload, rows, bucket, one_chip):
+    """A decode cell's prefill for a group of `rows` prompts of `bucket`
+    positions at its real sizes, as `DecodeEngine._prefill_exe` builds it;
+    returns (compiled, bytes of the state)."""
+    from bigdl_tpu.common import get_policy, set_policy
+    from bigdl_tpu.models import decode as kv
+    from bigdl_tpu.serve.decode import _with_tokens
+    from benchmark import harness
+    cell = harness.Cell(workload)
+    cm, cfg, tr = cell.cfg_mod, cell.cfg, cell.traffic
+    prior = get_policy()
+    try:
+        cm.set_policy(cfg)
+        model = cm.build_model(cfg)
+        on = lambda t: jax.tree.map(
+            lambda a: _aval(a.shape, a.dtype, one_chip), t)
+        params, state = on(jax.eval_shape(model.init, jax.random.key(0)))
+        slots, length = tr["slots"], tr["max_len"]
+        caches = on(kv.cache_avals(model, slots, length, jnp.bfloat16))
+
+        def prefill(p, s, c, tokens, toks, slot, t0):
+            logits, token, c, report = _with_tokens(*kv._prefill(
+                model, p, s, toks, c, slot, t0))
+            return logits, tokens.at[slot].set(token, mode="drop"), c, report
+
+        compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, state, caches, _aval((slots,), jnp.int32, one_chip),
+            _aval((rows, bucket), jnp.int32, one_chip),
+            _aval((rows,), jnp.int32, one_chip),
+            _aval((rows,), jnp.int32, one_chip)).compile()
+    finally:
+        set_policy(prior)
+    cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for c in caches for a in c.values())
+    return compiled, cache_bytes
+
+
+def test_hybrid_group_prefill_compiles_at_the_cells_sizes(one_chip,
+                                                          monkeypatch):
+    """`nemo3.decode`'s prefill for a group of four 256-position prompts
+    (ISSUE 42: the widest group the engine reckons for that bucket): it
+    fits one chip beside the weights with no more temporaries than the
+    longest single prompt's; every leaf is written in place under the
+    donation, the group's rows of a leaf with a length by a loop of four
+    windows and of a recurrent state by one scatter, not by a copy of the
+    leaf; the expert products are the grouped matmul kernel over the
+    group's 6,144 rows, and no expert table is copied."""
+    import bigdl_tpu.parallel.expert as ep
+    from bigdl_tpu.ops.grouped import _pallas
+    monkeypatch.setattr(ep, "grouped_matmul",
+                        lambda x, w, sizes, transposed=False:
+                        _pallas(x, w, sizes, transposed))
+    compiled, cache_bytes = _compile_decode_prefill("nemo3.decode", 4, 256,
+                                                    one_chip)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+    entry = _entry(text)
+    # (the convolutions' 2.4 MB leaves are laid out anew around their
+    # scatter, twice a layer: 28 MB a call)
+    for leaf in ("f32[128,32,64,128]", "bf16[128,1536,128]"):
+        assert not any(" copy(" in ln and leaf in ln for ln in entry), leaf
+    assert not any(" copy(" in ln and "bf16[64," in ln for ln in entry)
+    assert sum("tpu_custom_call" in ln for ln in entry) == 2 * 6
+    # four rows of logits leave the program, and four tokens enter the
+    # slots' vector
+    root = [ln for ln in entry if ln.startswith("ROOT ")][0]
+    assert "bf16[4,65536]" in root and "s32[128]" in root
+
+
 def test_delta_rule_decode_step_compiles_at_the_cells_sizes(one_chip):
     """`qwen3n.decode`'s step at its real sizes (192 slots, 9 linear layers
     of `f32[192,8,128,128]` matrix state, 3 full layers of `[192, 1536,

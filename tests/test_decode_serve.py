@@ -238,9 +238,9 @@ def test_both_programs_return_tokens_and_their_keys_say_so(lm, monkeypatch):
     seen = _spy_on_compiles(monkeypatch)
     eng = DecodeEngine(lm, slots=2, page=16)
     eng._step_exe(16)
-    eng._prefill_exe(8, 16)
+    eng._prefill_exe(1, 8, 16)
     for label, logits in (("decode.step", "tensor<2x64xf32>"),
-                          ("decode.prefill", "tensor<64xf32>")):
+                          ("decode.prefill", "tensor<1x64xf32>")):
         fields, text = seen[label]
         main = [ln for ln in text.splitlines() if "func.func public @main"
                 in ln][0]
@@ -300,7 +300,8 @@ def test_the_next_step_is_called_before_the_last_ones_tokens_are_read(lm):
 
     eng = DecodeEngine(lm, slots=2, page=16)
     eng._step_exe = lambda cache_len: fake("step")
-    eng._prefill_exe = lambda bucket, cache_len: fake("prefill")
+    eng._prefill_exe = lambda rows, bucket, cache_len: fake("prefill")
+    eng._prefill_programs = lambda bucket, cache_len: (1,)
     prompts = _prompts(2, seed=31)
     handles = _run_queued(eng, [(p, 5, {}) for p in prompts])
     st = eng.stats()
@@ -499,13 +500,14 @@ def test_prefill_logits_and_cache_match_the_per_position_oracle(lm, t0):
     eng = DecodeEngine(lm, slots=3, page=16, cache_dtype=np.float32)
     toks = np.zeros(8, np.int32)
     toks[:t0] = prompt
-    logits, tokens, caches, _counts = eng._prefill_exe(8, 16)(
+    logits, tokens, caches, _counts = eng._prefill_exe(1, 8, 16)(
         eng._params, eng._state, eng._fresh_caches(16),
-        np.array([5, 6, 7], np.int32), toks, np.int32(1), np.int32(t0))
-    assert logits.shape == (64,)
+        np.array([5, 6, 7], np.int32), toks[None], np.array([1], np.int32),
+        np.array([t0], np.int32))
+    assert logits.shape == (1, 64)
     # the slots' token vector comes back with this slot's row set
-    np.testing.assert_array_equal(tokens, [5, np.argmax(logits), 7])
-    np.testing.assert_allclose(logits, ref[0], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(tokens, [5, np.argmax(logits[0]), 7])
+    np.testing.assert_allclose(logits[0], ref[0], rtol=1e-5, atol=1e-5)
     for got, want in zip(caches, ref_caches):
         for n in "kv":
             arr = np.asarray(got[n])
@@ -522,7 +524,7 @@ def test_prefill_program_has_no_loop_and_one_row_at_the_head(lm_odd,
                                                              monkeypatch):
     seen = _spy_on_compiles(monkeypatch)
     eng = DecodeEngine(lm_odd, slots=2, page=16)
-    eng._prefill_exe(8, 16)
+    eng._prefill_exe(1, 8, 16)
     fields, text = seen["decode.prefill"]
     hist = hlostats.op_histogram(text)
     assert "while" not in hist and hist["dot_general"] > 0
@@ -535,7 +537,7 @@ def test_prefill_program_has_no_loop_and_one_row_at_the_head(lm_odd,
     assert any("dot_general" in ln and "-> tensor<1x8x32x" in ln
                for ln in text.splitlines())
     # the key tells this program from the per-position one of the same name
-    assert fields["body"] == "one_pass"
+    assert fields["body"] == "one_pass_group" and fields["rows"] == 1
     assert aot_mod.fingerprint(fields) != aot_mod.fingerprint(
         {k: v for k, v in fields.items() if k != "body"})
 
@@ -548,7 +550,9 @@ def test_stats_count_prompt_tokens_and_prefill_positions(lm):
         for h in [eng.submit(p, 2) for p in prompts]:
             h.result(120.0)
         st = eng.stats()
-    assert st["prefill_steps"] == len(lens)
+    # two slots: a take of one fills no group, so every call carries one
+    # request and no row fills a program up
+    assert st["prefill_rows"] == st["prefill_steps"] == len(lens)
     assert st["prompt_tokens"] == sum(lens) == 57
     assert st["prefill_positions"] == 8 + 8 + 8 + 16 + 16 + 32
 
@@ -848,3 +852,162 @@ def test_decode_counter_track_promotes_to_report_section(lm):
         eng.generate(_prompts(1, seed=11)[0], 2)
         st = eng.stats()
     assert st["tokens_per_s"] > 0 and st["cache_bytes_per_slot"] > 0
+
+
+# ---------------------------------------------------------------------------
+# admission in groups: a pass's prompts of one bucket share a call (ISSUE 42)
+# ---------------------------------------------------------------------------
+
+def _mixed(n, seed):
+    """n requests over three prompt buckets (8, 16, 32) and output budgets
+    that free the slots at different steps."""
+    r = np.random.default_rng(seed)
+    return [(r.integers(1, 64, size=int(r.choice([3, 7, 8, 9, 14, 20])))
+             .astype(np.int32), int(r.integers(2, 9)), {})
+            for _ in range(n)]
+
+
+@pytest.mark.parametrize("slots,n", [(2, 7), (128, 60)])
+def test_grouped_admission_yields_the_oracles_tokens(lm, slots, n):
+    # whatever groups the passes form (two slots: none, a take that small
+    # fills nothing; 128 slots: groups of up to four, programs filled up),
+    # every request gets generate()'s row
+    requests = _mixed(n, seed=50 + slots)
+    eng = DecodeEngine(lm, slots=slots, page=64, queue_limit=64)
+    handles = _run_queued(eng, requests)
+    for (p, mt, _kw), h in zip(requests, handles):
+        np.testing.assert_array_equal(h.result(1.0), _oracle(lm, p, mt))
+    st = eng.stats()
+    assert st["prefill_rows"] == n == st["seqs_done"]
+    # some call carried several, where the engine is wide enough to group
+    assert (st["prefill_steps"] < n) == (slots == 128)
+
+
+def test_a_lone_request_is_called_in_the_pass_that_takes_it(lm):
+    eng = DecodeEngine(lm, slots=128, page=64)
+    assert eng._prefill_programs(8, 64) == (1, 4)      # a group program
+    h = eng.submit(_prompts(1, seed=61)[0], 3)
+    assert eng._tick()                      # the engine's own pass, by hand
+    assert eng._group_target() == eng._take_cap() == 9
+    st = eng.stats()
+    # nothing waited for company: its prefill and its first step are called
+    assert st["prefill_steps"] == st["prefill_rows"] == 1
+    assert st["decode_steps"] == 1 and st["slot_steps_held"] == 0
+    eng.queue.close(drain=True)
+    while eng._tick():
+        pass
+    assert len(h.result(1.0)) == len(h.payload["prompt"]) + 3
+    # two more arrive together on the idle engine: no more wait than slots
+    # are free, so both go at once, in one call
+    eng = DecodeEngine(lm, slots=128, page=64)
+    for p in _prompts(2, lo=3, hi=8, seed=62):
+        eng.submit(p, 3)
+    eng._tick()
+    st = eng.stats()
+    assert (st["prefill_steps"], st["prefill_rows"]) == (1, 2)
+    assert st["slot_steps_held"] == 0
+
+
+def test_a_backlog_fills_groups_and_holds_no_more_than_its_share(lm):
+    from bigdl_tpu.serve.decode import _HELD_SHARE
+    # one bucket, 320 requests for 128 slots, budgets of 24-56 tokens: slots
+    # come free a few a step with more requests waiting than slots free
+    r = np.random.default_rng(71)
+    requests = [(r.integers(1, 64, size=int(r.integers(5, 9)))
+                 .astype(np.int32), int(r.integers(24, 57)), {})
+                for _ in range(320)]
+    eng = DecodeEngine(lm, slots=128, page=64, queue_limit=512)
+    _run_queued(eng, requests)
+    st = eng.stats()
+    assert st["seqs_done"] == st["prefill_rows"] == 320
+    assert st["prefill_rows"] / st["prefill_steps"] > 2.0
+    # a take of n slots freed one after another holds (n - 1) / 2 on
+    # average: the cap on a take is sized so that this stays under the share
+    assert eng._take_cap() == eng._group_target() == 9
+    held = st["slot_steps_held"] / (st["decode_steps"] * 128)
+    assert 0 < held <= _HELD_SHARE, held
+    assert eng._hold_until is None
+
+
+def test_a_hold_ends_when_the_remaining_counts_said_it_would(lm):
+    eng = DecodeEngine(lm, slots=128, page=64, queue_limit=256)
+    eng._prefill_programs(8, 64)
+    # 128 requests take every slot; one of them is short
+    ps = _prompts(136, lo=4, hi=8, seed=81)
+    eng.submit(ps[0], 3)
+    for p in ps[1:128]:
+        eng.submit(p, 30)
+    eng._tick()
+    assert eng.stats()["active"] == 128
+    for p in ps[128:]:                      # eight more wait: a backlog
+        eng.submit(p, 2)                    # (fewer than the cap's nine)
+    eng._tick()                             # the short one's last call
+    eng._tick()                             # one slot free, want 8: held
+    assert eng._hold_until is not None and eng.slot_steps_held == 1
+    # the next slots free 27 steps on: the hold is given up there and then,
+    # with the one request that fits
+    until = eng._hold_until
+    assert until - eng.decode_steps >= 20
+    while eng._hold_until is not None:
+        eng._tick()
+    assert eng.decode_steps <= until + 1 and eng.prefill_rows >= 129
+    eng.queue.close(drain=True)
+    while eng._tick():
+        pass
+    assert eng.stats()["seqs_done"] == 136
+
+
+def test_a_burst_after_one_request_a_bucket_compiles_nothing(lm):
+    eng = DecodeEngine(lm, slots=128, page=64, queue_limit=64)
+    with eng:
+        # what a deployment's warm-up does: one request a bucket, one at a
+        # time; the engine compiles each bucket's group program with it
+        for n in (5, 12):
+            eng.submit(np.arange(1, n + 1, dtype=np.int32), 2).result(120.0)
+        before, programs = eng.stats()["aot"], set(eng._exe)
+        assert {k[1:3] for k in programs if k[0] == "prefill"} == {
+            (1, 8), (4, 8), (1, 16), (4, 16)}
+        burst = [eng.submit(p, 4) for p in _prompts(40, lo=3, hi=16, seed=91)]
+        for h in burst:
+            h.result(120.0)
+        st = eng.stats()
+    assert st["aot"] == before and set(eng._exe) == programs
+    assert st["prefill_rows"] == 42 and st["prefill_steps"] < 30
+
+
+def test_the_rows_of_a_bucket_follow_from_the_shapes(lm, monkeypatch):
+    import bigdl_tpu.serve.decode as sd
+    from bigdl_tpu.utils.flops import jaxpr_flops
+    eng = DecodeEngine(lm, slots=256, page=64)
+    # positions a call: the longest single prompt the engine must take
+    assert eng._group_positions == 64 and eng._take_cap() == 17
+    assert eng._prefill_programs(8, 64) == (1, 8)
+    assert eng._prefill_programs(16, 64) == (1, 4)
+    # a pair gets no program of its own: every program costs set-up
+    assert eng._prefill_programs(32, 64) == (1,)
+    assert eng._prefill_programs(64, 64) == (1,)
+    # a bucket cut to a shorter cache counts as what is computed
+    assert eng._prefill_programs(32, 16) == (1, 4)
+    # no group is wider than half the requests a backlogged pass may wait
+    # for: an engine of few slots holds none and compiles no group program
+    assert DecodeEngine(lm, slots=128, page=64)._prefill_programs(8, 64) \
+        == (1, 4)
+    small = DecodeEngine(lm, slots=32, page=64)
+    small._call_positions = None            # decided before any trace
+    assert small._take_cap() == 3 and small._prefill_programs(8, 64) == (1,)
+    # a call whose one row's arithmetic outweighs the weights it streams
+    # does not group (the count is the one-row program's own); the budget
+    # is in positions, and the memory guard is part of it
+    positions, traced = eng._call_positions(8, 64)
+    assert positions == eng._group_positions == 64
+    flops = jaxpr_flops(traced.jaxpr)
+    assert flops > 0 and eng._weight_bytes == sum(
+        a.nbytes for a in jax.tree.leaves(lm.params))
+    monkeypatch.setattr(sd, "_FLOPS_PER_WEIGHT_BYTE",
+                        4.5 * flops / eng._weight_bytes)
+    assert DecodeEngine(lm, slots=256, page=64)._prefill_programs(8, 64) \
+        == (1, 4)
+    monkeypatch.setattr(sd, "_FLOPS_PER_WEIGHT_BYTE", 0.0)
+    wide = DecodeEngine(lm, slots=256, page=64)
+    assert wide._prefill_programs(8, 64) == (1,)
+    assert wide._group_target() == 1        # nothing groups: nothing is held

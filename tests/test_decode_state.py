@@ -256,11 +256,11 @@ def test_both_programs_choose_the_first_of_the_largest_entries(make):
     eng = DecodeEngine(m, slots=3, page=16, max_len=32)
     toks = np.zeros(8, np.int32)
     toks[:5] = [3, 9, 4, 7, 11]
-    logits, tokens, caches, _report = eng._prefill_exe(8, 16)(
+    logits, tokens, caches, _report = eng._prefill_exe(1, 8, 16)(
         eng._params, eng._state, eng._fresh_caches(16),
-        jnp.asarray([5, 40, 17], jnp.int32), jnp.asarray(toks),
-        np.int32(1), np.int32(5))
-    row = np.asarray(logits)
+        jnp.asarray([5, 40, 17], jnp.int32), jnp.asarray(toks)[None],
+        np.array([1], np.int32), np.array([5], np.int32))
+    row = np.asarray(logits)[0]
     assert tokens.shape == (3,) and tokens.dtype == jnp.int32
     assert (row == row.max()).sum() >= 2
     # the slots' tokens come back with this slot's row set, the others' kept

@@ -142,10 +142,12 @@ def test_prefill_then_decode_through_the_engines_programs():
         nonlocal caches
         toks = np.zeros(bucket, np.int32)
         toks[:t0] = r.integers(0, 211, t0)
-        lp, _toks, caches, (counts, _chosen) = eng._prefill_exe(bucket, L)(
+        lp, _toks, caches, (counts, _chosen) = eng._prefill_exe(
+            1, bucket, L)(
             eng._params, eng._state, caches, jnp.zeros(3, jnp.int32),
-            jnp.asarray(toks), np.int32(slot), np.int32(t0))
-        seqs[slot] = {"toks": list(toks[:t0]), "lp": [np.asarray(lp)]}
+            jnp.asarray(toks)[None], np.array([slot], np.int32),
+            np.array([t0], np.int32))
+        seqs[slot] = {"toks": list(toks[:t0]), "lp": [np.asarray(lp)[0]]}
         return np.asarray(counts)
 
     def step(active):

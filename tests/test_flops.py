@@ -109,3 +109,24 @@ def test_model_train_step_flops_sane():
     fwd_conv1 = 2 * (8 * 24 * 24 * 6) * (5 * 5 * 1)
     assert got > fwd_conv1          # counts more than one layer
     assert got < 1e12               # and is not absurd for batch-8 LeNet
+
+
+def test_ragged_dot_counts_every_row_once():
+    # [m, k] rows, each against its group's [k, n] matrix
+    def f(x, w, sizes):
+        return jax.lax.ragged_dot(x, w, sizes,
+                                  preferred_element_type=jnp.float32)
+    got = fn_flops(f, jnp.zeros((48, 16)), jnp.zeros((4, 16, 32)),
+                   jnp.zeros((4,), jnp.int32))
+    assert got == 2 * 48 * 16 * 32
+
+
+def test_a_pallas_call_counts_what_it_states():
+    # the grouped matmul kernel states 2 m k n; its body, one grid step's
+    # tile product, is not counted besides
+    from bigdl_tpu.ops.grouped import _pallas
+    got = fn_flops(lambda x, w, s: _pallas(x, w, s, False, True),
+                   jnp.zeros((256, 256), jnp.bfloat16),
+                   jnp.zeros((4, 256, 256), jnp.bfloat16),
+                   jnp.zeros((4,), jnp.int32))
+    assert got == 2 * 256 * 256 * 256

@@ -270,9 +270,11 @@ def test_the_full_attention_layer_against_the_reference():
 #: sha256 (16 hex digits) of ``str(jax.make_jaxpr(...))`` of the default
 #: layers' programs on the parent commit 5ff0caa (jax 0.9.0, float32
 #: policy, matmul precision "highest" as this file's fixture sets it): the
-#: options this PR adds leave them as they were
+#: options this PR adds leave them as they were (``mha_prefill`` is PR 42's:
+#: a prefill takes a slot a row, ``[1]`` here where the parent's took a
+#: scalar; a lone row's write is the parent's dynamic-update-slice)
 PARENT_JAXPRS = {"mha_apply": "39c8efd041ce5c13",
-                 "mha_prefill": "ae9a875aba5db671",
+                 "mha_prefill": "f45dd2f0f63c7160",
                  "mha_step": "eee4ce298f101713", "rms": "ee212ca3d95968f2",
                  "moe": "b8f19d60c3022956"}
 

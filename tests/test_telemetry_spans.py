@@ -245,7 +245,7 @@ def test_decode_programs_have_their_own_names_and_aot_keys(lm, monkeypatch):
     monkeypatch.setattr(aot_mod, "get_or_compile", spy)
     eng = DecodeEngine(lm, slots=2, page=16)
     eng._step_exe(16)
-    eng._prefill_exe(8, 16)
+    eng._prefill_exe(1, 8, 16)
     step_fields, step_text = seen["decode.step"]
     pre_fields, pre_text = seen["decode.prefill"]
     assert "jit_decode_step" in step_text and "jit_fn" not in step_text
@@ -269,13 +269,17 @@ def test_decode_tick_has_admit_step_and_sample_children(tracer, lm):
     admits = _spans(tracer, "decode.admit")
     steps = _spans(tracer, "decode.step")
     samples = _spans(tracer, "decode.sample")
-    assert len(admits) == 5 and ticks
+    # one span a prefill call: `rows` requests of one bucket, `prompt_len`
+    # their real tokens together
+    assert sum(a["args"]["rows"] for a in admits) == 5 and ticks
+    assert len(admits) == eng.prefill_steps <= 5
     assert len(steps) == len(samples) == eng.decode_steps
     assert sum(t["args"]["admitted"] for t in ticks) == 5
     assert all(0 <= t["args"]["active"] <= 2 for t in ticks)
-    by_len = sorted(len(p) for p in prompts)
-    assert sorted(a["args"]["prompt_len"] for a in admits) == by_len
-    assert all(a["args"]["bucket"] >= a["args"]["prompt_len"]
+    assert sum(a["args"]["prompt_len"] for a in admits) \
+        == sum(len(p) for p in prompts)
+    assert all(a["args"]["bucket"] * a["args"]["rows"]
+               >= a["args"]["prompt_len"]
                and a["args"]["slot"] in (0, 1) for a in admits)
     tid = {e["tid"] for e in ticks}
     assert len(tid) == 1
@@ -381,6 +385,9 @@ def test_decode_counter_arguments_wait_for_a_reader(lm, monkeypatch):
     assert len(track) == eng.decode_steps + 1
     assert track[-1]["args"]["cache_bytes_per_slot"] \
         == eng.cache_bytes_per_slot()
+    # requests a prefill call, and the slots' steps kept free for a group
+    assert track[-1]["args"]["prefill_group"] == 1.0
+    assert track[-1]["args"]["held_share"] == 0.0
     # `ran_ahead` a step: each was called with the call before it unread
     assert [e["args"].get("ran_ahead") for e in track] \
         == [1.0] * eng.decode_steps + [None]
@@ -389,8 +396,9 @@ def test_decode_counter_arguments_wait_for_a_reader(lm, monkeypatch):
 
 def test_no_tracer_no_telemetry_call_per_token(lm, monkeypatch):
     """Tracing off, the telemetry calls of a run are those of its ticks and
-    its admissions: a second request decoded beside the first adds one
-    admission's three spans and nothing for its tokens."""
+    its prefill calls: a second request decoded beside the first adds one
+    prefill call's spans where its prompt is of another bucket (none where
+    the two share a call) and nothing for its tokens."""
     monkeypatch.setattr(metrics_export, "_REGISTRY", None)
     calls = []
     for name in ("span", "counter", "complete", "instant", "flow_start",
@@ -403,9 +411,10 @@ def test_no_tracer_no_telemetry_call_per_token(lm, monkeypatch):
     del calls[:]
     eng, rows = _run_closed(lm, _prompts(2, seed=3), n)
     assert eng.tokens_out == 2 * n and eng.decode_steps == n - 1
-    # an admission is called and not waited for: no fetch of its own
-    admission = ["span:decode.admit", "span:decode.call",
-                 "counter:serve"]                        # submit's depth
+    # a prefill is called and not waited for: no fetch of its own
+    assert eng.prefill_rows == 2
+    admission = ["span:decode.admit", "span:decode.call"] \
+        * (eng.prefill_steps - 1) + ["counter:serve"]    # submit's depth
     assert sorted(calls) == sorted(one + admission)
     # a pass that calls a step: itself, step > call; each but the first
     # then reads the pass before (step > fetch, sample), and a last pass
@@ -413,7 +422,8 @@ def test_no_tracer_no_telemetry_call_per_token(lm, monkeypatch):
     called = ["span:decode.tick", "span:decode.step", "span:decode.call"]
     read = ["span:decode.fetch", "span:decode.sample"]
     assert sorted(one) == sorted(called * (n - 1) + read * (n - 1)
-                                 + ["span:decode.tick"] + admission)
+                                 + ["span:decode.tick", "span:decode.admit",
+                                    "span:decode.call", "counter:serve"])
 
 
 def _inside(child, parent, slack=0.2):
