@@ -244,7 +244,8 @@ class _Walk:
                                                self.caches[layer])
             return y, layer + 1
         if isinstance(module, Sequential):
-            for m, p, s in zip(module.modules, params, state):
+            for m, p, s in zip(module.modules, module.child_params(params),
+                               state):
                 before = layer
                 x, layer = self.walk(m, p, s, x, layer, in_table)
                 if self.last is not None and not in_table \
@@ -311,7 +312,8 @@ def _step(module, params, state, x, caches, slot, pos):
             jnp.full((x.shape[0],), pos, jnp.int32))
         return y, slot + 1
     if isinstance(module, Sequential):
-        for m, p, s in zip(module.modules, params, state):
+        for m, p, s in zip(module.modules, module.child_params(params),
+                           state):
             x, slot = _step(m, p, s, x, caches, slot, pos)
         return x, slot
     if isinstance(module, ConcatTable):

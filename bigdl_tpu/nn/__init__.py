@@ -6,8 +6,8 @@ Reference inventory: BigDL `nn/` (151 files, 26,212 LoC — SURVEY.md §2.3).
 from .module import Module, Container, Criterion
 from .initialization import (Zeros, Ones, ConstInitMethod, RandomUniform,
                              RandomNormal, Xavier, MsraFiller, BilinearFiller)
-from .containers import (Sequential, Concat, ConcatTable, ParallelTable,
-                         MapTable, Identity, Echo, Bottle)
+from .containers import (Sequential, TiedSequential, Concat, ConcatTable,
+                         ParallelTable, MapTable, Identity, Echo, Bottle)
 from .graph import Graph, Input, ModuleNode
 from .activation import (ReLU, ReLU6, PReLU, RReLU, LeakyReLU, ELU, GELU,
                          Tanh, TanhShrink, Sigmoid, SoftMax, SoftMin,
@@ -55,6 +55,6 @@ from .criterion import (
     SmoothL1CriterionWithWeights, SoftMarginCriterion, SoftmaxWithCriterion,
     TimeDistributedCriterion)
 from .attention import LatentAttention, MultiHeadAttention
-from .mamba import Mamba2Mixer
+from .mamba import Mamba2Mixer, MambaMixer
 from .deltanet import GatedDeltaNet
 from .fused import ConvBN, ConvBNAddReLU, fuse_conv_bn

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from .module import Container, Module
 
-__all__ = ["Sequential", "Concat", "ConcatTable", "ParallelTable", "MapTable",
+__all__ = ["Sequential", "TiedSequential", "Concat", "ConcatTable", "ParallelTable", "MapTable",
            "Identity", "Echo", "Bottle"]
 
 
@@ -29,10 +29,45 @@ class Sequential(Container):
         rngs = self._split_rng(rng)
         new_states = []
         x = input
-        for m, p, s, k in zip(self.modules, params, state, rngs):
+        for m, p, s, k in zip(self.modules, self.child_params(params), state,
+                              rngs):
             x, ns = m.apply(p, s, x, training=training, rng=k)
             new_states.append(ns)
         return x, new_states
+
+
+class TiedSequential(Sequential):
+    """A ``Sequential`` in which a child reads another child's parameters:
+    one table for the embedding and the head (``tie_word_embeddings``).
+
+    ``tie(reader, owner)``: child ``reader`` has no parameters of its own
+    (an empty dict holds its place in the list, so every walk that pairs
+    children with their slots still lines up, and the tree has one leaf for
+    the table); whoever applies the children takes their parameters from
+    ``child_params``, which hands the reader the owner's.  The two modules
+    must agree on the shared dict: a ``Linear(d, V, with_bias=False)`` keeps
+    ``weight [V, d]`` as a ``LookupTable(V, d)`` does.  A gradient through
+    either use lands on the one leaf."""
+
+    def __init__(self, *modules: Module):
+        super().__init__(*modules)
+        self._ties = {}         # reader's index -> owner's index
+
+    def tie(self, reader: Module, owner: Module):
+        self._ties[self.modules.index(reader)] = self.modules.index(owner)
+        return self
+
+    def init(self, rng):
+        params, state = super().init(rng)
+        for reader in self._ties:
+            params[reader] = {}
+        return params, state
+
+    def child_params(self, params):
+        out = list(params)
+        for reader, owner in self._ties.items():
+            out[reader] = params[owner]
+        return out
 
 
 class Concat(Container):

@@ -1,6 +1,10 @@
-"""Mamba-2 mixer (Dao & Gu, "Transformers are SSMs", arXiv:2405.21060), the
-state-space layer of the hybrid models (``models/nemotron.py``).
+"""The selective state-space mixers of the hybrid models: Mamba-2
+(``Mamba2Mixer``, ``models/nemotron.py``) and Mamba-1 (``MambaMixer``,
+``models/jamba.py``), with what the recurrent layers share (this file's two
+and ``nn/deltanet.GatedDeltaNet``): the causal convolution, its windows and
+its tail, the real positions of a padded group, the projections' product.
 
+**Mamba-2** (Dao & Gu, "Transformers are SSMs", arXiv:2405.21060).
 For an input ``u [T, d_model]``, with ``H`` heads of width ``P``, ``G``
 groups of state size ``N`` (head ``h`` reads group ``h // (H / G)``) and a
 causal depthwise convolution of ``K`` taps:
@@ -30,6 +34,12 @@ position a row.
 with their ``G heads_held / H`` groups: those columns of ``W_in``, channels of
 the convolution and rows of ``W_out``; its output is that share's term of the
 sum (a group of the gated norm lies within a state group, so it is whole).
+
+**Mamba-1** (Gu & Dao, "Mamba", arXiv:2312.00752, with the Jamba family's
+norms on ``dt``, ``B`` and ``C``): ``MambaMixer``'s docstring.  Its decay
+``exp(Delta_t[d] A[d, n])`` differs by channel *and* by state index, so no
+choice of the chunked form's numbers gives it: a whole sequence is a true
+scan over time.
 """
 
 from __future__ import annotations
@@ -43,9 +53,11 @@ import jax.numpy as jnp
 from ..common import get_policy
 from .initialization import compute_fans, default_weight_init
 from .module import Module, StateLeaf, prefill_rows, write_prompt_rows
+from .normalization import rms_norm
+from ..ops.ssm import recur as ssm_recur, selective_scan
 
-__all__ = ["Mamba2Mixer", "causal_conv", "causal_windows", "conv_tail",
-           "matmul_f32", "real_positions"]
+__all__ = ["Mamba2Mixer", "MambaMixer", "causal_conv", "causal_windows",
+           "conv_tail", "matmul_f32", "real_positions"]
 
 F32 = jnp.float32
 
@@ -309,3 +321,184 @@ class Mamba2Mixer(Module):
             + params["D"].astype(F32)[:, None] * xh
         return self._out(params, y, z)[:, None], {
             "ssm": S_.astype(cache["ssm"].dtype), "conv": window[:, 1:]}
+
+
+class MambaMixer(Module):
+    """Mamba-1's selective state-space mixer, [B, T, d_model] -> [B, T,
+    d_model], as the Jamba family runs it.  For an input ``u [T, d_model]``,
+    ``d_inner`` channels, a state of ``N`` a channel, a step size through a
+    projection of rank ``R`` and a causal depthwise convolution of ``K``
+    taps:
+
+        [x, z] = u W_in                    widths d_inner, d_inner
+        x = silu(conv_K(x) + b)            over time, a channel at a time
+        [dt, B, C] = x W_x                 widths R, N, N
+        dt, B, C = RMSNorm_dt(dt), RMSNorm_B(B), RMSNorm_C(C)
+        Delta = softplus(dt W_dt + b_dt)   [d_inner]
+        A = -exp(A_log)                    [N, d_inner]
+        h_t[n, d] = exp(Delta_t[d] A[n, d]) h_(t-1)[n, d]
+                    + Delta_t[d] B_t[n] x_t[d]
+        y_t[d] = sum_n h_t[n, d] C_t[n] + D[d] x_t[d]
+        y = y * silu(z);  out = y W_out
+
+    No bias but the convolution's and ``b_dt``.  ``h``, with the
+    convolution's last ``K - 1`` inputs, is all the layer keeps of the past.
+
+    **The axis order is this layer's own**: ``A_log`` and the state are kept
+    ``[N, d_inner]`` (the published checkpoints keep ``A_log [d_inner,
+    N]``), so that the channels lie in the 128 lanes and the 16 state
+    indices in the sublanes: ``N`` = 16 in the minor axis would fill an
+    eighth of a lane row, and the sum over ``n`` is then a sum of rows.
+
+    A whole sequence (``_apply``, ``decode_prefill``) is a scan over time
+    that carries ``h [B, N, d_inner]`` in float32
+    (``ops/ssm.selective_scan``): what is laid out of a prompt is ``[B, T,
+    d_inner]`` and ``[B, T, N]``, never ``[B, T, N, d_inner]``.
+    Decay, state and sum are float32; the four products take compute-dtype
+    operands with float32 accumulation.  ``decode_step`` is the recurrence
+    itself, one position a row."""
+
+    PARAM_ROLES = {"in_proj": "kernel_in", "out_proj": "kernel_out",
+                   "conv_bias": "bias", "dt_norm": "norm_scale",
+                   "B_norm": "norm_scale", "C_norm": "norm_scale",
+                   "*": "elementwise"}
+
+    def __init__(self, d_model: int, d_inner: int, state: int, dt_rank: int,
+                 conv_kernel: int = 4, eps: float = 1e-6,
+                 dt_range=(0.001, 0.1)):
+        super().__init__()
+        self.d_model, self.d_inner, self.state = d_model, d_inner, state
+        self.dt_rank, self.conv_kernel = dt_rank, conv_kernel
+        self.eps, self.dt_range = eps, dt_range
+
+    def _init(self, rng):
+        """Mamba-1's initialisation: ``A[n, d] = n + 1`` (``A_log`` its
+        logarithm), ``Delta`` log-uniform in ``dt_range`` put through the
+        inverse softplus into ``dt_bias``, ``D`` and the norms ones."""
+        ks = jax.random.split(rng, 6)
+        dt = get_policy().param_dtype
+        winit = self.weight_initializer or default_weight_init
+
+        def w(k, shape):
+            fi, fo = compute_fans(shape)
+            return winit(k, shape, fi, fo, dt)
+
+        lo, hi = self.dt_range
+        step = jnp.exp(jax.random.uniform(ks[0], (self.d_inner,), F32)
+                       * (math.log(hi) - math.log(lo)) + math.log(lo))
+        N, C, K = self.state, self.d_inner, self.conv_kernel
+        return {"A_log": jnp.log(jnp.broadcast_to(
+                    jnp.arange(1, N + 1, dtype=F32)[:, None], (N, C))
+                    ).astype(dt),
+                "B_norm": jnp.ones((N,), dt), "C_norm": jnp.ones((N,), dt),
+                "D": jnp.ones((C,), dt),
+                "conv_bias": jnp.zeros((C,), dt),
+                "conv_weight": jax.random.uniform(
+                    ks[1], (K, C), dt, -K ** -0.5, K ** -0.5),
+                "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+                "dt_norm": jnp.ones((self.dt_rank,), dt),
+                "dt_proj": w(ks[2], (self.dt_rank, C)),
+                "in_proj": w(ks[3], (self.d_model, 2 * C)),
+                "out_proj": w(ks[4], (C, self.d_model)),
+                "x_proj": w(ks[5], (C, self.dt_rank + 2 * N))}
+
+    # -- the pieces ------------------------------------------------------
+
+    def _project(self, params, u):
+        """u [..., d_model] -> x (before the convolution), z: [..., d_inner]
+        each, compute dtype."""
+        c = get_policy().compute_dtype
+        y = matmul_f32(u, params["in_proj"]).astype(c)
+        return y[..., :self.d_inner], y[..., self.d_inner:]
+
+    def _conv(self, params, window):
+        """window [..., K, d_inner], the last K inputs oldest first -> the
+        convolution's output at the newest, through SiLU (float32)."""
+        return causal_conv(window, params["conv_weight"],
+                           params["conv_bias"])
+
+    def _norm(self, params, name, v):
+        return rms_norm(v, params[name], self.eps)
+
+    def _selective(self, params, x):
+        """The convolved channels x [..., d_inner] (float32) -> Delta [...,
+        d_inner], B and C [..., N]: float32."""
+        R, N = self.dt_rank, self.state
+        dbc = matmul_f32(x, params["x_proj"])
+        dt = self._norm(params, "dt_norm", dbc[..., :R])
+        B = self._norm(params, "B_norm", dbc[..., R:R + N])
+        C = self._norm(params, "C_norm", dbc[..., R + N:])
+        delta = jax.nn.softplus(matmul_f32(dt, params["dt_proj"])
+                                + params["dt_bias"].astype(F32))
+        return delta, B, C
+
+    def _decay(self, params):
+        """A [N, d_inner] and D [d_inner], float32."""
+        return -jnp.exp(params["A_log"].astype(F32)), params["D"].astype(F32)
+
+    def _out(self, params, y, z):
+        c = get_policy().compute_dtype
+        return matmul_f32(y * jax.nn.silu(z.astype(F32)),
+                          params["out_proj"]).astype(c)
+
+    def _scan(self, params, u, length=None):
+        """u [B, T, d_model] from a zero state; row b's positions ``>=
+        length[b]`` (traced, a scalar for every row; None: all real) move
+        nothing.  Returns (out [B, T, d_model], state [B, N, d_inner]
+        float32 after each row's last real position, x [B, T, d_inner]
+        before the convolution)."""
+        T = u.shape[1]
+        x_in, z = self._project(params, u)
+        x = self._conv(params, causal_windows(x_in, self.conv_kernel))
+        delta, Bm, Cm = self._selective(params, x)
+        if length is not None:
+            # a pad has Delta = 0: its decay is exp(0) = 1 and its input
+            # term zero, so the scan's last state is the state after
+            # position length - 1
+            delta = jnp.where(real_positions(length, T), delta, 0.0)
+        y, last = selective_scan(delta, x, Bm, Cm, *self._decay(params))
+        return self._out(params, y, z), last, x_in
+
+    def _apply(self, params, x):
+        return self._scan(params, x)[0]
+
+    # -- incremental decoding ------------------------------------------
+
+    def decode_state(self, rows: int, length: int):
+        """Two leaves of fixed size a row (``length_axis`` None): the
+        recurrent state ``ssm [rows, N, d_inner]``, float32 whatever the
+        cache's dtype (it is a running sum), and the convolution's last ``K
+        - 1`` inputs ``conv [rows, K - 1, d_inner]``."""
+        return {"ssm": StateLeaf((rows, self.state, self.d_inner), None,
+                                 "ssm_state", F32),
+                "conv": StateLeaf((rows, self.conv_kernel - 1,
+                                   self.d_inner), None, "latent_cache")}
+
+    def decode_prefill(self, params, x, cache, slot, length):
+        """x [n, P, d_model], a group of prompts, of row i ``length[i]``
+        positions real: the scan from a zero state, whatever the slots
+        held; a row's pads move nothing (``_scan``); its convolution's
+        window is inputs ``length - K + 1 .. length - 1`` (zeros before the
+        start); both leaves of row ``slot[i]`` are written whole (a fill-up
+        row's not at all: ``write_prompt_rows``)."""
+        slot, length = prefill_rows(x, slot, length)
+        y, ssm, x_in = self._scan(params, x, length)
+        tail = conv_tail(x_in, length, self.conv_kernel)
+        return y, {"ssm": write_prompt_rows(cache["ssm"], slot, ssm),
+                   "conv": write_prompt_rows(cache["conv"], slot, tail)}
+
+    def decode_step(self, params, x, cache, pos):
+        """x [S, 1, d_model]: the recurrence, one position a row, both
+        leaves updated in place under the step's donation.  ``pos`` is not
+        read: the state carries the order, and an idle row may write
+        anything (its slot's next prefill overwrites the row whole)."""
+        x_in, z = self._project(params, x[:, 0])
+        window = jnp.concatenate(
+            [cache["conv"], x_in[:, None].astype(cache["conv"].dtype)],
+            axis=1)
+        xc = self._conv(params, window)
+        delta, Bm, Cm = self._selective(params, xc)
+        h, y = ssm_recur(cache["ssm"], delta, xc, Bm, Cm,
+                         *self._decay(params))
+        return self._out(params, y, z)[:, None], {
+            "ssm": h.astype(cache["ssm"].dtype), "conv": window[:, 1:]}

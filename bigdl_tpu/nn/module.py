@@ -779,6 +779,12 @@ class Container(Module):
             ss.append(s)
         return ps, ss
 
+    def child_params(self, params):
+        """The parameters each child is applied with, in child order: its
+        own slot of ``params``, except where a container shares one child's
+        with another (``TiedSequential``)."""
+        return params
+
     def _split_rng(self, rng):
         if rng is None:
             return [None] * len(self.modules)

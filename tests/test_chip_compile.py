@@ -446,3 +446,103 @@ def test_grouped_matmul_compiles_at_the_small_groups_shapes(one_chip, rows,
     entry = _entry(text)
     assert sum("tpu_custom_call" in ln for ln in entry) == 1
     assert not any(" copy(" in ln and "bf16[128," in ln for ln in entry)
+
+
+# -------------------- the whole small hybrid: selective scan, state update
+
+@pytest.mark.parametrize("rows,positions", [(4, 256), (8, 128), (1, 64)])
+def test_selective_scan_compiles_at_the_cells_shapes(one_chip, rows,
+                                                     positions):
+    """`jamba2.decode`'s prefill scan (5,120 channels, a state of 16 a
+    channel) for a group of four 256-position prompts, eight of 128 and a
+    lone one of 64: one loop over time whose carry is the `[rows, 16,
+    5120]` state, and the state is laid out for no other position."""
+    import re
+    from bigdl_tpu.ops.ssm import selective_scan
+    f32 = lambda *s: _aval(s, jnp.float32, one_chip)
+    text = _compile(selective_scan, f32(rows, positions, 5120),
+                    f32(rows, positions, 5120), f32(rows, positions, 16),
+                    f32(rows, positions, 16), f32(16, 5120), f32(5120))
+    loops = [ln for ln in _entry(text) if " while(" in ln]
+    assert len(loops) == 1 and f"f32[{rows},16,5120]" in loops[0]
+    assert set(re.findall(r"f32\[([\d,]+),16,5120\]", text)) == {str(rows)}
+
+
+def test_selective_decode_step_compiles_at_the_cells_sizes(one_chip):
+    """`jamba2.decode`'s step at its real sizes (384 slots, 26 Mamba layers
+    of `f32[384,16,5120]` selective state, 2 attention layers of `[384,
+    1024, 128]` keys and values, the table `[65536, 2560]` once): it fits
+    one chip beside its 6.06 GB of weights; every leaf is updated in place
+    under the donation; each selective state crosses HBM once in and once
+    out (one fusion a layer whose result holds the leaf beside the layer's
+    `y`: no Pallas call is needed for it, PERF.md PR 44), and no state is
+    copied."""
+    compiled, cfg, cache_bytes = _compile_decode_step("jamba2.decode",
+                                                      one_chip)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    fixed = 26 * 384 * (16 * 5120 * 4 + 3 * 5120 * 2)
+    assert cache_bytes == fixed + 2 * 2 * 384 * 1024 * 128 * 2
+    assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+    assert mem.argument_size_in_bytes == pytest.approx(
+        2 * 3_029_337_472 + cache_bytes, rel=1e-3)     # the table once
+    entry = _entry(text)
+    leaf = "f32[384,16,5120]"
+    made = [ln for ln in entry if " = " in ln and " fusion(" in ln
+            and leaf in ln.split(" = ")[1].split(" fusion(")[0]]
+    assert len(made) == 26, made
+    assert not any(" copy(" in ln and leaf in ln for ln in entry)
+    assert " while(" not in text
+    root = [ln for ln in entry if ln.startswith("ROOT ")][0]
+    assert "bf16[384,65536]" in root and "s32[384]" in root
+
+
+def test_selective_prefill_compiles_at_the_cells_sizes(one_chip):
+    """`jamba2.decode`'s prefill of one 256-bucket prompt at its real sizes:
+    one loop over time a Mamba layer, whose last state is written into the
+    donated `f32[384,16,5120]` leaf as one row in place (a
+    `dynamic-update-slice` fusion, which `ssm_state_roofline_pct.decode`'s
+    reader leaves out: it counts a step's updates alone), beside 10 GB of
+    weights and state with 0.2 GB of its own."""
+    from bigdl_tpu.common import get_policy, set_policy
+    from bigdl_tpu.models import decode as kv
+    from bigdl_tpu.serve.decode import _with_tokens
+    from benchmark import harness
+    cell = harness.Cell("jamba2.decode")
+    cm, cfg, tr = cell.cfg_mod, cell.cfg, cell.traffic
+    reader = harness.load_module(
+        os.path.join(harness.BENCH_DIR, "layer_metrics",
+                     "ssm_state_roofline_pct.decode.py"), "reader_ssm_c")
+    prior = get_policy()
+    try:
+        cm.set_policy(cfg)
+        model = cm.build_model(cfg)
+        on = lambda t: jax.tree.map(
+            lambda a: _aval(a.shape, a.dtype, one_chip), t)
+        params, state = on(jax.eval_shape(model.init, jax.random.key(0)))
+        slots = tr["slots"]
+        caches = on(kv.cache_avals(model, slots, tr["max_len"],
+                                   jnp.bfloat16))
+        i32 = lambda *s: _aval(s, jnp.int32, one_chip)
+
+        def prefill(p, s, c, tokens, toks, slot, t0):
+            logits, token, c, rep = _with_tokens(
+                *kv._prefill(model, p, s, toks, c, slot, t0))
+            return logits, tokens.at[slot].set(token, mode="drop"), c, rep
+
+        compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, state, caches, i32(slots), i32(1, 256), i32(1),
+            i32(1)).compile()
+    finally:
+        set_policy(prior)
+    mem, entry = compiled.memory_analysis(), _entry(compiled.as_text())
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+    assert sum(" while(" in ln for ln in entry) == 26
+    assert not any("tpu_custom_call" in ln for ln in entry)
+    leaf = cm.ssm_leaf_shape(cfg, slots)
+    writes = [ln for ln in entry if " fusion(" in ln
+              and leaf in reader._result(ln)]
+    assert len(writes) == 26
+    assert all(any(p in ln for p in reader.PART) for ln in writes)
