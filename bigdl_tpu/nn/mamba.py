@@ -39,7 +39,8 @@ sum (a group of the gated norm lies within a state group, so it is whole).
 norms on ``dt``, ``B`` and ``C``): ``MambaMixer``'s docstring.  Its decay
 ``exp(Delta_t[d] A[d, n])`` differs by channel *and* by state index, so no
 choice of the chunked form's numbers gives it: a whole sequence is a true
-scan over time.
+scan over time (``ops/ssm.selective_scan``: a Pallas kernel on a TPU,
+``lax.scan`` elsewhere).
 """
 
 from __future__ import annotations
@@ -352,7 +353,8 @@ class MambaMixer(Module):
 
     A whole sequence (``_apply``, ``decode_prefill``) is a scan over time
     that carries ``h [B, N, d_inner]`` in float32
-    (``ops/ssm.selective_scan``): what is laid out of a prompt is ``[B, T,
+    (``ops/ssm.selective_scan``: a Pallas kernel on a TPU, ``lax.scan``
+    elsewhere, one equation): what is laid out of a prompt is ``[B, T,
     d_inner]`` and ``[B, T, N]``, never ``[B, T, N, d_inner]``.
     Decay, state and sum are float32; the four products take compute-dtype
     operands with float32 accumulation.  ``decode_step`` is the recurrence

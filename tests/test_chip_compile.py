@@ -455,16 +455,22 @@ def test_selective_scan_compiles_at_the_cells_shapes(one_chip, rows,
                                                      positions):
     """`jamba2.decode`'s prefill scan (5,120 channels, a state of 16 a
     channel) for a group of four 256-position prompts, eight of 128 and a
-    lone one of 64: one loop over time whose carry is the `[rows, 16,
-    5120]` state, and the state is laid out for no other position."""
+    lone one of 64, as the Pallas kernel a TPU takes (`ops/ssm._pallas`;
+    here the backend is the CPU's, so the test takes the kernel's own
+    entry): one custom call named `selective_scan` and no loop beside it,
+    and the state is laid out `[rows, 16, 5120]` and for no other
+    position."""
     import re
-    from bigdl_tpu.ops.ssm import selective_scan
+    from bigdl_tpu.ops.ssm import _pallas
     f32 = lambda *s: _aval(s, jnp.float32, one_chip)
-    text = _compile(selective_scan, f32(rows, positions, 5120),
+    text = _compile(_pallas, f32(rows, positions, 5120),
                     f32(rows, positions, 5120), f32(rows, positions, 16),
                     f32(rows, positions, 16), f32(16, 5120), f32(5120))
-    loops = [ln for ln in _entry(text) if " while(" in ln]
-    assert len(loops) == 1 and f"f32[{rows},16,5120]" in loops[0]
+    entry = _entry(text)
+    calls = [ln for ln in entry if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "selective_scan" in calls[0]
+    assert f"f32[{rows},16,5120]" in calls[0]
+    assert " while(" not in text
     assert set(re.findall(r"f32\[([\d,]+),16,5120\]", text)) == {str(rows)}
 
 
@@ -498,51 +504,37 @@ def test_selective_decode_step_compiles_at_the_cells_sizes(one_chip):
     assert "bf16[384,65536]" in root and "s32[384]" in root
 
 
-def test_selective_prefill_compiles_at_the_cells_sizes(one_chip):
-    """`jamba2.decode`'s prefill of one 256-bucket prompt at its real sizes:
-    one loop over time a Mamba layer, whose last state is written into the
-    donated `f32[384,16,5120]` leaf as one row in place (a
-    `dynamic-update-slice` fusion, which `ssm_state_roofline_pct.decode`'s
-    reader leaves out: it counts a step's updates alone), beside 10 GB of
-    weights and state with 0.2 GB of its own."""
-    from bigdl_tpu.common import get_policy, set_policy
-    from bigdl_tpu.models import decode as kv
-    from bigdl_tpu.serve.decode import _with_tokens
-    from benchmark import harness
-    cell = harness.Cell("jamba2.decode")
-    cm, cfg, tr = cell.cfg_mod, cell.cfg, cell.traffic
-    reader = harness.load_module(
-        os.path.join(harness.BENCH_DIR, "layer_metrics",
-                     "ssm_state_roofline_pct.decode.py"), "reader_ssm_c")
-    prior = get_policy()
-    try:
-        cm.set_policy(cfg)
-        model = cm.build_model(cfg)
-        on = lambda t: jax.tree.map(
-            lambda a: _aval(a.shape, a.dtype, one_chip), t)
-        params, state = on(jax.eval_shape(model.init, jax.random.key(0)))
-        slots = tr["slots"]
-        caches = on(kv.cache_avals(model, slots, tr["max_len"],
-                                   jnp.bfloat16))
-        i32 = lambda *s: _aval(s, jnp.int32, one_chip)
-
-        def prefill(p, s, c, tokens, toks, slot, t0):
-            logits, token, c, rep = _with_tokens(
-                *kv._prefill(model, p, s, toks, c, slot, t0))
-            return logits, tokens.at[slot].set(token, mode="drop"), c, rep
-
-        compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
-            params, state, caches, i32(slots), i32(1, 256), i32(1),
-            i32(1)).compile()
-    finally:
-        set_policy(prior)
-    mem, entry = compiled.memory_analysis(), _entry(compiled.as_text())
+def test_selective_prefill_compiles_at_the_cells_sizes(one_chip,
+                                                       monkeypatch):
+    """`jamba2.decode`'s prefill of one 256-bucket prompt at its real sizes
+    with the prompt's scan as a TPU takes it (the Pallas kernel; the test
+    says so, as the hybrid's tests do for the grouped matmul): one custom
+    call named `selective_scan` a Mamba layer and no loop anywhere, the
+    state laid out `f32[1,16,5120]` and for no other position, each layer's
+    last state written into the donated `f32[384,16,5120]` leaf in place,
+    beside 10 GB of weights and state with under 0.5 GB of its own."""
+    import re
+    import bigdl_tpu.nn.mamba as mamba
+    from bigdl_tpu.ops.ssm import _pallas
+    monkeypatch.setattr(mamba, "selective_scan", _pallas)
+    compiled, cache_bytes = _compile_decode_prefill("jamba2.decode", 1, 256,
+                                                    one_chip)
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    entry = _entry(text)
+    assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
     assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.argument_size_in_bytes > 10e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
-    assert sum(" while(" in ln for ln in entry) == 26
-    assert not any("tpu_custom_call" in ln for ln in entry)
-    leaf = cm.ssm_leaf_shape(cfg, slots)
-    writes = [ln for ln in entry if " fusion(" in ln
-              and leaf in reader._result(ln)]
-    assert len(writes) == 26
-    assert all(any(p in ln for p in reader.PART) for ln in writes)
+    # the compiler fuses a layer's kernel with the write of its last state
+    # into the leaf's row: one fusion of the entry a layer, which keeps the
+    # kernel's name (what a trace's event is called) around one custom call
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 26, len(calls)
+    assert all("selective_scan" in ln.split(" = ")[0]
+               and "f32[1,16,5120]" in ln for ln in calls)
+    named = [ln for ln in entry if ln.startswith("%selective_scan")]
+    assert len(named) == 26 and all("kind=kCustom" in ln for ln in named)
+    assert " while(" not in text
+    assert set(re.findall(r"f32\[([\d,]+),16,5120\]", text)) == {"1", "384"}
+    assert not any(" copy(" in ln and "f32[384,16,5120]" in ln
+                   for ln in entry)

@@ -197,13 +197,122 @@ def test_the_scan_equals_the_recurrence(rows, T):
     np.testing.assert_allclose(h1, h, atol=2e-5)
 
 
-def test_a_position_with_no_step_moves_nothing():
-    delta, x, B, C, A, D = _scan_inputs(2, 16, 8, 128, seed=4)
-    delta = delta.at[:, 9:].set(0.0)
-    _, last = ssm.selective_scan(delta, x, B, C, A, D)
-    _, at9 = ssm.selective_scan(delta[:, :9], x[:, :9], B[:, :9], C[:, :9],
-                                A, D)
-    np.testing.assert_allclose(last, at9, atol=1e-6)
+def _kernel(*operands):
+    """The Pallas form, interpreted on the CPU."""
+    return ssm._pallas(*operands, True)
+
+
+FORMS = {"scan": ssm._scan, "kernel": _kernel}
+
+
+@pytest.mark.parametrize("rows,T,channels", [
+    (1, 8, 128),        # one iteration of eight positions
+    (1, 5, 128),        # fewer positions than an iteration
+    (2, 64, 256),       # a whole chunk
+    (2, 130, 256),      # two chunks and part of a third
+    (4, 128, 128),      # a group of four rows, two whole chunks
+    (1, 72, 384),       # three blocks of 128 channels
+    (2, 20, 1280),      # five blocks of 256
+])
+def test_the_kernel_equals_the_scan_and_the_recurrence(rows, T, channels):
+    """The Pallas kernel a TPU takes (interpreted here) against the
+    ``lax.scan`` form and against ``recur`` position by position: ``y`` at
+    every position and the state after the last."""
+    delta, x, B, C, A, D = _scan_inputs(rows, T, 16, channels, seed=T)
+    y_k, h_k = _kernel(delta, x, B, C, A, D)
+    y_s, h_s = ssm._scan(delta, x, B, C, A, D)
+    assert y_k.shape == (rows, T, channels)
+    assert h_k.shape == (rows, 16, channels)
+    h, ys = jnp.zeros((rows, 16, channels), jnp.float32), []
+    for t in range(T):
+        h, y = ssm.recur(h, delta[:, t], x[:, t], B[:, t], C[:, t], A, D)
+        ys.append(y)
+    for y_w, h_w in ((y_s, h_s), (jnp.stack(ys, axis=1), h)):
+        np.testing.assert_allclose(y_k, y_w, atol=2e-5)
+        np.testing.assert_allclose(h_k, h_w, atol=2e-5)
+
+
+@pytest.mark.parametrize("channels,form", [(128, "kernel"), (1280, "kernel"),
+                                           (64, "scan"), (200, "scan")])
+def test_the_backend_and_the_width_choose_the_form(channels, form,
+                                                   monkeypatch):
+    """On a TPU a channel count that fills whole lanes takes the kernel and
+    any other the ``lax.scan`` form; off a TPU every width takes the
+    latter."""
+    taken = []
+    monkeypatch.setattr(ssm, "_pallas", lambda *a, f=ssm._pallas:
+                        taken.append("kernel") or f(*a, True))
+    monkeypatch.setattr(ssm, "_scan",
+                        lambda *a, f=ssm._scan: taken.append("scan") or f(*a))
+    operands = _scan_inputs(1, 8, 4, channels)
+    want = FORMS["scan"](*operands)
+    ssm.selective_scan(*operands)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    got = ssm.selective_scan(*operands)
+    assert taken == ["scan", form]
+    np.testing.assert_allclose(got[0], want[0], atol=2e-5)
+    np.testing.assert_allclose(got[1], want[1], atol=2e-5)
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_a_position_with_no_step_moves_nothing(form):
+    """A prompt's pads have ``delta = 0``: the last state is the state after
+    the last real position (here the ninth of 16, and of 80: past a chunk
+    of the kernel), bit for bit what a scan of the real positions gives."""
+    scan = FORMS[form]
+    for T, real in ((16, 9), (80, 9), (80, 70)):
+        delta, x, B, C, A, D = _scan_inputs(2, T, 8, 128, seed=4)
+        delta = delta.at[:, real:].set(0.0)
+        _, last = scan(delta, x, B, C, A, D)
+        _, at = scan(delta[:, :real], x[:, :real], B[:, :real], C[:, :real],
+                     A, D)
+        np.testing.assert_array_equal(last, at)
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_a_fill_up_row_of_the_scan_moves_nothing(form):
+    """A group's fill-up row has ``delta = 0`` at every position: its state
+    stays zero, its ``y`` is ``D x``, and the rows beside it read what they
+    read alone."""
+    delta, x, B, C, A, D = _scan_inputs(4, 24, 8, 128, seed=6)
+    delta = delta.at[2].set(0.0)
+    y, last = FORMS[form](delta, x, B, C, A, D)
+    np.testing.assert_array_equal(last[2], jnp.zeros_like(last[2]))
+    np.testing.assert_allclose(y[2], D * x[2], atol=1e-6)
+    for b in (0, 1, 3):
+        y_b, last_b = FORMS[form](delta[b:b + 1], x[b:b + 1], B[b:b + 1],
+                                  C[b:b + 1], A, D)
+        np.testing.assert_array_equal(y[b], y_b[0])
+        np.testing.assert_array_equal(last[b], last_b[0])
+
+
+def test_the_kernel_is_differentiated_through_the_scan_form():
+    """A Pallas call has no derivative of its own: the kernel's is the
+    ``lax.scan`` form's, so a model that trains on a TPU still does."""
+    operands = _scan_inputs(2, 20, 8, 128, seed=8)
+
+    def loss(form):
+        def f(*a):
+            y, last = form(*a)
+            return jnp.sum(jnp.square(y)) + jnp.sum(last)
+        return jax.grad(f, argnums=tuple(range(6)))(*operands)
+
+    for got, want in zip(loss(_kernel), loss(ssm._scan)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
+
+
+def test_flops_counts_the_kernel_by_its_stated_cost():
+    """``utils/flops`` reads a Pallas call's ``cost_estimate``: the
+    equation's operations over the padded positions (20 become 24 of eight
+    an iteration; 130 become three chunks of 64), seven a state element and
+    three a channel, where the ``lax.scan`` form's elementwise work counts
+    nothing."""
+    from bigdl_tpu.utils.flops import fn_flops
+    for rows, T, padded in ((2, 20, 24), (1, 130, 192)):
+        operands = _scan_inputs(rows, T, 16, 256)
+        assert fn_flops(_kernel, *operands) == \
+            rows * padded * 256 * (7 * 16 + 3)
+        assert fn_flops(ssm._scan, *operands) == 0.0
 
 
 def test_flops_counts_the_mixer(float32_policy):
