@@ -197,8 +197,9 @@ def _ulysses_local(q, k, v, *, axis_name: str, causal: bool, sm_scale: float):
 
     qh, kh, vh = fwd(q), fwd(k), fwd(v)
     # flash attention keeps memory linear in the gathered sequence length
-    # in BOTH directions (blockwise pallas forward + scanned blockwise
-    # backward, ops/attention._flash_bwd_chunked)
+    # in BOTH directions (blockwise pallas forward, which leaves its row
+    # statistics, + the blockwise pallas backward that rebuilds the
+    # probabilities from them, ops/attention._flash_bwd_pallas)
     from ..ops.attention import flash_attention
     oh = flash_attention(qh, kh, vh, causal=causal, sm_scale=sm_scale)
     return rev(oh)

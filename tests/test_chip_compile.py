@@ -83,16 +83,25 @@ def test_flash_attention_forward_and_grad_compile(one_chip, shape, dtype):
 
     q = _aval(shape, dtype, one_chip)
     assert "tpu_custom_call" in _compile(fwd, q, q, q)
-    # value_and_grad keeps the kernel's forward alive beside the chunked
-    # backward (a bare grad of a sum would dead-code it away)
-    assert "tpu_custom_call" in _compile(
-        jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
+    _assert_backward_is_the_kernels(
+        _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q))
+
+
+def _assert_backward_is_the_kernels(text):
+    """The gradient's program calls the forward kernel and the backward's
+    (`flash_bwd_dkv`, `flash_bwd_dq`) by name and holds no loop: no scan
+    stands in for a kernel."""
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert any("flash_fwd" in name for name in calls), calls
+    assert any("flash_bwd" in name for name in calls), calls
+    assert " while(" not in text
 
 
 def test_flash_attention_inside_shard_map_compiles(topo):
     """parallel/ring_attention.ulysses_attention calls the kernel inside a
-    shard_map body: the pallas_call's output and the backward scan's carry
-    must say which mesh axes they vary over."""
+    shard_map body: the outputs of the forward's and of the backward's
+    pallas_calls must say which mesh axes they vary over."""
     import functools
 
     import bigdl_tpu.ops.attention as att
@@ -112,8 +121,8 @@ def test_flash_attention_inside_shard_map_compiles(topo):
 
         text = _compile(attn, q, q, q)
         assert "tpu_custom_call" in text and "all-to-all" in text
-        assert "tpu_custom_call" in _compile(
-            jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q)
+        _assert_backward_is_the_kernels(
+            _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, q, q))
     finally:
         att.flash_attention = real
 

@@ -3,6 +3,8 @@ the flash-attention op — on the 8-virtual-CPU-device mesh (conftest.py),
 mirroring the reference's simulate-a-cluster-in-one-process test strategy
 (DistriOptimizerSpec.scala:33-41)."""
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -59,8 +61,8 @@ class TestFlashAttention:
                                        atol=2e-5, rtol=2e-5)
 
     def test_grad_pallas_ragged_blocks_and_noncausal(self):
-        """Blockwise bwd edge cases: Tq not a multiple of block_q, and the
-        non-causal mask — both must match dense-reference gradients."""
+        """Backward-kernel edge cases: Tq not a multiple of block_q, and
+        the non-causal mask — both must match dense-reference gradients."""
         r = np.random.default_rng(9)
         q = jnp.asarray(r.normal(size=(2, 2, 21, 8)), jnp.float32)
         k = jnp.asarray(r.normal(size=(2, 2, 21, 8)), jnp.float32)
@@ -138,15 +140,18 @@ class TestFlashAttention:
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_bf16_grad_through_pallas_path(self, causal):
-        """The custom VJP with bfloat16 inputs.  The backward recomputes p
-        in float32 from the bfloat16 q, k, v, so for a loss linear in the
-        output it is the float32 gradient of exact attention on those
-        inputs, rounded once to bfloat16 (2^-8 relative, elementwise).
-        `jax.grad(mha_reference)` on bfloat16 inputs rounds p, dP and each
-        result to bfloat16 on the way (2^-8 each, and `dP - rowsum(dP p)`
-        cancels: taken as at most four times), so against it the gap is
-        held norm-wise at 4 x 4 x 2^-8 = 2^-4; a missing term or a wrong
-        mask reads of order 1."""
+        """The custom VJP with bfloat16 inputs: the backward kernels
+        (`flash_bwd_dkv`, `flash_bwd_dq`) rebuild p from the bfloat16 q, k
+        and the forward's float32 row statistics, and send p and dS to the
+        MXU rounded once to bfloat16, as the forward rounds p (2^-8
+        relative each; the float32 sums and the one rounding of each result
+        add as much again).  Against the float32 gradient of exact
+        attention on the same inputs the gap is held norm-wise at 2^-6 of
+        the gradient's norm.  `jax.grad(mha_reference)` on bfloat16 inputs
+        rounds p, dP and each result to bfloat16 on the way (2^-8 each, and
+        `dP - rowsum(dP p)` cancels: taken as at most four times), so
+        against it the gap is held norm-wise at 4 x 4 x 2^-8 = 2^-4; a
+        missing term or a wrong mask reads of order 1."""
         q, k, v = (x.astype(jnp.bfloat16)
                    for x in _qkv(B=2, H=2, T=21, D=8, seed=6))
         # weights that bfloat16 holds exactly: the output's cotangent is
@@ -170,45 +175,130 @@ class TestFlashAttention:
         for a, b16, b32 in zip(gp, g16, g32):
             assert a.dtype == jnp.bfloat16
             a = np.asarray(a, np.float32)
-            np.testing.assert_allclose(a, np.asarray(b32), rtol=2.0 ** -8,
-                                       atol=1e-6)
+            b32 = np.asarray(b32)
+            assert (np.linalg.norm(a - b32)
+                    <= 2.0 ** -6 * np.linalg.norm(b32))
             b16 = np.asarray(b16, np.float32)
             assert (np.linalg.norm(a - b16)
                     <= 2.0 ** -4 * np.linalg.norm(b16))
 
+    # -- the backward kernels against jax.grad(mha_reference), float32 ---
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("Tq,Tk,blocks", [
+        (32, 32, (16, 16)),
+        (21, 21, (8, 8)),          # ragged: padded rows and masked keys
+        (24, 40, (16, 16)),        # Tq != Tk; causal: a key block no query sees
+        (40, 24, (16, 16)),
+        (40, 40, (8, 16)),         # the two kernels clamp their skipped blocks
+        (40, 40, (16, 8)),         # by another ratio each
+        (40, 40, (None, None)),    # the rule's blocks: one padded tile
+    ])
+    def test_grad_kernels_match_reference(self, causal, Tq, Tk, blocks):
+        r = np.random.default_rng(11)
+        q = jnp.asarray(r.normal(size=(2, 2, Tq, 8)), jnp.float32)
+        k = jnp.asarray(r.normal(size=(2, 2, Tk, 8)), jnp.float32)
+        v = jnp.asarray(r.normal(size=(2, 2, Tk, 8)), jnp.float32)
+
+        def f_pallas(q, k, v):
+            out = flash_attention(q, k, v, causal=causal, use_pallas=True,
+                                  interpret=True, block_q=blocks[0],
+                                  block_k=blocks[1])
+            return jnp.sum(jnp.sin(out))
+
+        def f_ref(q, k, v):
+            return jnp.sum(jnp.sin(mha_reference(q, k, v, causal=causal)))
+
+        gp = jax.grad(f_pallas, argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gp, gr):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=3e-5, rtol=3e-5)
+
+    @pytest.mark.parametrize("blocks", [(16, 16), (8, 16), (16, 8)])
+    def test_grad_skips_blocks_no_query_sees(self, blocks):
+        """Causal with more keys than queries: the key blocks wholly above
+        the diagonal hold NaN.  A skipped block is not read into any sum,
+        so the output and every gradient the mask allows are those of the
+        keys that can be seen, and the unseen keys' gradients are zero."""
+        Tq, Tk = 16, 48
+        q, k, v = _qkv(B=1, H=2, T=Tk, D=8, seed=12)
+        q = q[:, :, :Tq]
+        poison = jnp.arange(Tk)[:, None] >= Tq
+        kn, vn = (jnp.where(poison, jnp.nan, x) for x in (k, v))
+
+        def f(attend, q, k, v):
+            return jnp.sum(jnp.sin(attend(q, k, v)))
+
+        def kernel(q, k, v):
+            return flash_attention(q, k, v, causal=True, use_pallas=True,
+                                   interpret=True, block_q=blocks[0],
+                                   block_k=blocks[1])
+
+        def seen(q, k, v):
+            return mha_reference(q, k, v, causal=True)
+
+        out = kernel(q, kn, vn)
+        assert np.isfinite(np.asarray(out)).all()
+        gp = jax.grad(functools.partial(f, kernel), argnums=(0, 1, 2))(
+            q, kn, vn)
+        gr = jax.grad(functools.partial(f, seen), argnums=(0, 1, 2))(
+            q, k[:, :, :Tq], v[:, :, :Tq])
+        np.testing.assert_allclose(np.asarray(gp[0]), np.asarray(gr[0]),
+                                   atol=3e-5, rtol=3e-5)
+        for a, b in zip(gp[1:], gr[1:]):
+            np.testing.assert_allclose(np.asarray(a[:, :, :Tq]),
+                                       np.asarray(b), atol=3e-5, rtol=3e-5)
+            assert not np.asarray(a[:, :, Tq:]).any()
+
+    @pytest.mark.parametrize("rule", [
+        {}, {"grad": True}, {"backward": True}],
+        ids=["forward", "forward-lse", "backward"])
     @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
     @pytest.mark.parametrize("Tk", [1, 17, 1024, 4096, 131072])
     @pytest.mark.parametrize("Tq", [1, 17, 1024, 4096, 131072])
-    def test_block_rule(self, Tq, Tk, dtype):
+    def test_block_rule(self, Tq, Tk, dtype, rule):
         """Blocks chosen from the shape are whole tiles (so Mosaic takes
         them), pad a length by under one tile a block, never exceed the
-        sweep's cap, and are reckoned to fit VMEM."""
+        sweep's cap, and are reckoned to fit VMEM.  Under differentiation
+        the rows' statistics are lane-dense, so a query block that is a
+        part of the length is whole 128-lane tiles."""
         from bigdl_tpu.ops import attention as att
 
         rows = 32 // jnp.dtype(dtype).itemsize
         for D in (64, 128, 256):
-            block_q, block_k = att._choose_blocks(Tq, Tk, D, dtype)
-            assert block_q % rows == 0 and block_k % 128 == 0
+            block_q, block_k = att._choose_blocks(Tq, Tk, D, dtype, **rule)
+            q_tile = 128 if rule and block_q < Tq else rows
+            assert block_q % q_tile == 0 and block_k % 128 == 0
             assert 0 < block_q <= att._BLOCK_CAP
             assert 0 < block_k <= att._BLOCK_CAP
-            for T, block, tile in ((Tq, block_q, rows), (Tk, block_k, 128)):
+            for T, block, tile in ((Tq, block_q, q_tile),
+                                   (Tk, block_k, 128)):
                 n_blocks = -(-T // block)         # as _flash_pallas pads
                 assert n_blocks * block - T < n_blocks * tile
-            assert att._vmem_bytes(block_q, block_k, D, dtype) \
+            assert att._vmem_bytes(block_q, block_k, D, dtype,
+                                   rule.get("backward", False)) \
                 <= att._VMEM_BUDGET
 
-    def test_block_rule_shrinks_to_the_budget(self):
-        """A head size nobody swept still gets blocks that fit."""
+    @pytest.mark.parametrize("backward", [False, True])
+    def test_block_rule_shrinks_to_the_budget(self, backward):
+        """A head size nobody swept still gets blocks that fit (the
+        backward holds more tiles a step and its query block is at least
+        128 rows: a quarter of the head size brings it to small blocks)."""
         from bigdl_tpu.ops import attention as att
 
         for D, dtype in ((2048, jnp.float32), (8192, jnp.bfloat16)):
-            bq, bk = att._choose_blocks(4096, 4096, D, dtype)
+            D //= 4 if backward else 1
+            bq, bk = att._choose_blocks(4096, 4096, D, dtype,
+                                        backward=backward)
             assert bq * bk < att._BLOCK_CAP ** 2
-            assert att._vmem_bytes(bq, bk, D, dtype) <= att._VMEM_BUDGET
+            assert att._vmem_bytes(bq, bk, D, dtype, backward) \
+                <= att._VMEM_BUDGET
 
     def test_grad_with_default_blocks(self):
-        """With the blocks left to the rule the backward scan keeps its
-        own chunk: the gradients match those of explicit blocks."""
+        """With the blocks left to the rule (one padded tile each way
+        here) the gradients match those of explicit blocks."""
         q, k, v = _qkv(B=1, H=2, T=40, D=8, seed=8)
 
         def f(q, k, v, **kw):
