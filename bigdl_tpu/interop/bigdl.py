@@ -571,22 +571,6 @@ def _w_module(dc: _DescCache, m, params, state) -> JavaObject:
                      JavaArray(dc.array("[I"),
                                np.asarray([m.dim + 1], np.int32))
                      if m.dim is not None else None)])
-    if isinstance(m, nn.ConvBNAddReLU):
-        # de-fuse to the reference residual-block shape: the tail fusion
-        # is a TPU-local rewrite (nn/fused.py), not a reference class —
-        # the wire carries ConcatTable(branch, shortcut) -> CAddTable ->
-        # ReLU with the params re-nested to match
-        head, conv, bn, shortcut = m.modules
-        branch = nn.Sequential(*head.modules).add(conv).add(bn)
-        seq = (nn.Sequential()
-               .add(nn.ConcatTable().add(branch).add(shortcut))
-               .add(nn.CAddTable())
-               .add(nn.ReLU()))
-        hp, cp, bp, sp = params
-        hs, cs, bs, ss = state
-        return _w_module(dc, seq,
-                         [[list(hp) + [cp, bp], sp], {}, {}],
-                         [[list(hs) + [cs, bs], ss], {}, {}])
     if isinstance(m, (nn.Sequential, nn.Concat, nn.ConcatTable,
                       nn.ParallelTable)):
         kids = [_w_module(dc, c, p, s)
@@ -601,8 +585,8 @@ def _w_module(dc: _DescCache, m, params, state) -> JavaObject:
             cd = dc.get(_PKG + "Concat", [("I", "dimension", None)])
             return JavaObject(cd, {"dimension": 2, "modules": buf,
                                    **_scales(m)})
-        # fused subclasses (nn.ConvBN) are a TPU-local optimization, not a
-        # reference class: serialize as the plain Sequential they subclass
+        # a subclass of Sequential is no reference class: it goes out as
+        # the plain Sequential it subclasses
         short = ("Sequential" if isinstance(m, nn.Sequential)
                  else type(m).__name__)
         cd = dc.get(_PKG + short, [])

@@ -57,4 +57,3 @@ from .criterion import (
 from .attention import LatentAttention, MultiHeadAttention
 from .mamba import Mamba2Mixer, MambaMixer
 from .deltanet import GatedDeltaNet
-from .fused import ConvBN, ConvBNAddReLU, fuse_conv_bn

@@ -25,8 +25,6 @@ row may hold several names: each after the first is written without the
 | BIGDL_TPU_RNN_HOIST_MAX_ELEMENTS | (net-new: ConvLSTM hoist cap) | 2^28 |
 | BIGDL_TPU_XLA_CACHE | (net-new: persistent compile cache on/off; it lives at JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache — utils/platform.py) | 1 |
 | BIGDL_TPU_CONV_PAD_MIN_CIN | (net-new: tiny-channel conv pad, nn/conv.py) | 8 |
-| BIGDL_TPU_BN_IMPL / _BN_FUSED_VJP / _BN_STAT_ROWS | (net-new: BN variants, nn/normalization.py) | off |
-| BIGDL_TPU_BN_BATCH | (net-new: bn_experiment batch) | 256 |
 | BIGDL_TPU_RING_ATTN | (net-new: MultiHeadAttention(seq_parallel=) takes the ring-attention path, nn/attention.py) | 0 (off) |
 | BIGDL_TPU_NO_DONATE | (net-new: keep the train step's inputs alive instead of donating params/state/slots, optim/optimizer._build_step) | 0 (donate) |
 | BIGDL_TPU_FSDP_MIN_SIZE | (net-new: leaves under this many elements stay replicated under fsdp, parallel/layout.py) | 4096 |

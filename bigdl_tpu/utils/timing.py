@@ -17,7 +17,7 @@ other is unsafe.
 Role in the reference: DistriOptimizer's per-iteration wall timing
 (optim/DistriOptimizer.scala:293-297) is host-side around a synchronous Spark
 job, so it never had this problem; a compiled async backend needs explicit
-sync discipline.  Used by `bigdl_tpu/tools/{perf,scaling,bn_experiment}.py`
+sync discipline.  Used by `bigdl_tpu/tools/{perf,scaling}.py`
 and `utils/profiling.py`; the benchmark (`benchmark/`) has its own clock.
 """
 

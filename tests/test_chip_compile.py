@@ -1,5 +1,6 @@
-"""The main path's Pallas kernels, compiled by the TPU's compiler for a
-described v5e at the real widths — no chip, nothing runs.
+"""The main path's Pallas kernels, and the batch norm `resnet50.train` runs
+(XLA's own fusions), compiled by the TPU's compiler for a described v5e at
+the real widths — no chip, nothing runs.
 
 Interpret mode (every other kernel test) cannot see what the chip's compiler
 refuses: a slice off the tiling, too much fast memory, a kernel that cannot
@@ -129,6 +130,34 @@ def test_flash_attention_inside_shard_map_compiles(topo):
 
 # ------------------------------------------------------------ batch norm
 
+def _compile_train_forward_and_grad(module, x, one_chip):
+    """What `resnet50.train`'s step holds of `module`: its training forward
+    and the gradients to its parameters and its input, XLA's own fusions
+    (the cell runs no kernel here).  Returns the two compiled texts."""
+    params, state = jax.tree.map(
+        lambda a: _aval(a.shape, a.dtype, one_chip),
+        jax.eval_shape(module.init, jax.random.key(0)))
+
+    def fwd(params, state, x):
+        return module.apply(params, state, x, training=True)
+
+    def loss(params, state, x):
+        return fwd(params, state, x)[0].astype(jnp.float32).sum()
+
+    texts = (_compile(fwd, params, state, x),
+             _compile(jax.grad(loss, argnums=(0, 2)), params, state, x))
+    for text in texts:
+        assert "tpu_custom_call" not in text
+    return texts
+
+
+def _results(text):
+    """The result types of a compiled module, from its first line's
+    `entry_computation_layout={(...)->...}`."""
+    return text.split("entry_computation_layout={", 1)[1].split(
+        "\n", 1)[0].split("->", 1)[1]
+
+
 _BN_SHAPES = [
     pytest.param((256, 56, 56, 64), id="256x56x56x64"),
     pytest.param((256, 112, 112, 64), id="256x112x112x64"),
@@ -138,56 +167,51 @@ _BN_SHAPES = [
 
 @pytest.mark.parametrize("shape", _BN_SHAPES)
 def test_bn_train_forward_and_grad_compile(one_chip, shape):
-    """ResNet-50's BN shapes at batch 256, bf16 activations."""
-    from bigdl_tpu.ops.batchnorm import bn_train
+    """ResNet-50's BN shapes at batch 256, bf16 activations: the statistics
+    are float32 reductions, the output is bf16."""
+    import bigdl_tpu.nn as nn
 
     c = shape[-1]
-
-    def fwd(x, w, b):
-        return bn_train(x, w, b, 1e-5)
-
-    def loss(x, w, b):
-        return fwd(x, w, b)[0].astype(jnp.float32).sum()
-
-    x = _aval(shape, jnp.bfloat16, one_chip)
-    w = _aval((c,), jnp.float32, one_chip)
-    assert "tpu_custom_call" in _compile(fwd, x, w, w)
-    assert "tpu_custom_call" in _compile(
-        jax.grad(loss, argnums=(0, 1, 2)), x, w, w)
+    fwd, grad = _compile_train_forward_and_grad(
+        nn.SpatialBatchNormalization(c),
+        _aval(shape, jnp.bfloat16, one_chip), one_chip)
+    assert f"f32[{c}]" in fwd and "reduce(" in fwd
+    dims = ",".join(map(str, shape))
+    assert f"bf16[{dims}]" in _results(fwd)
+    assert f"bf16[{dims}]" in _results(grad)
 
 
-# ------------------------------------------------- conv-epilogue BN stats
+# ------------------------------------------- 1x1 convolution + batch norm
 
-_CONVBN_SHAPES = [
-    pytest.param((802816, 64, 256), id="802816x64x256"),
-    pytest.param((12544, 2048, 512), id="12544x2048x512"),
-    pytest.param((50176, 1024, 256), id="50176x1024x256"),
-    pytest.param((802816, 64, 64), id="802816x64x64"),
+_CONVBN_SHAPES = [   # (rows, c_in, c_out) with rows = 256 images x H x W
+    pytest.param((56, 64, 256), id="802816x64x256"),
+    pytest.param((7, 2048, 512), id="12544x2048x512"),
+    pytest.param((14, 1024, 256), id="50176x1024x256"),
+    pytest.param((56, 64, 64), id="802816x64x64"),
 ]
 
 
-@pytest.mark.parametrize("mkn", _CONVBN_SHAPES)
-def test_fused_conv_bn_forward_and_grad_compile(one_chip, mkn):
-    """ResNet-50's 1x1 convs as [M, K] x [K, N] with the BN statistics in
-    the matmul's epilogue (ops/convbn.py), bf16."""
-    from bigdl_tpu.ops.convbn import fused_conv_bn_train, matmul_stats
+@pytest.mark.parametrize("hkn", _CONVBN_SHAPES)
+def test_conv_bn_forward_and_grad_compile(one_chip, hkn):
+    """ResNet-50's 1x1 convolutions with the batch norm behind each, as the
+    model builds the pair, bf16 compute over float32 parameters."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu.common import DTypePolicy, get_policy, set_policy
 
-    m, k, n = mkn
-    x = _aval((m, k), jnp.bfloat16, one_chip)
-    w = _aval((k, n), jnp.bfloat16, one_chip)
-    g = _aval((n,), jnp.float32, one_chip)
-    assert "tpu_custom_call" in _compile(
-        lambda x, w: matmul_stats(x, w), x, w)
-
-    def fwd(x, w, g, b):
-        return fused_conv_bn_train(x, w, None, g, b, 1e-5)
-
-    def loss(x, w, g, b):
-        return fwd(x, w, g, b)[0].astype(jnp.float32).sum()
-
-    assert "tpu_custom_call" in _compile(fwd, x, w, g, g)
-    assert "tpu_custom_call" in _compile(
-        jax.grad(loss, argnums=(0, 1, 2, 3)), x, w, g, g)
+    hw, k, n = hkn
+    pair = (nn.Sequential()
+            .add(nn.SpatialConvolution(k, n, 1, 1))
+            .add(nn.SpatialBatchNormalization(n)))
+    prev = get_policy()
+    set_policy(DTypePolicy(compute_dtype=jnp.bfloat16))
+    try:
+        fwd, grad = _compile_train_forward_and_grad(
+            pair, _aval((256, hw, hw, k), jnp.bfloat16, one_chip), one_chip)
+    finally:
+        set_policy(prev)
+    assert "convolution(" in fwd and "convolution(" in grad
+    assert f"bf16[256,{hw},{hw},{n}]" in _results(fwd)
+    assert f"f32[1,1,{k},{n}]" in _results(grad)
 
 
 # ------------------------------------------------ the cells' decode steps

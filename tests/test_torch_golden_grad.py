@@ -95,20 +95,9 @@ def test_linear_grads_match_torch():
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("variant", ["baseline", "fused_vjp",
-                                     "pallas_interpret"])
-def test_batchnorm_train_mode_grads_match_torch(variant, monkeypatch):
-    """Backward through the BATCH statistics — the exact program the
-    resnet bench's BN-bandwidth analysis times (docs/benchmarking.md);
-    torch differentiates through mean/var the same way.  Every
-    implementation variant (autodiff baseline, hand-written fused VJP,
-    Pallas kernel) must produce the SAME grads and running-stat updates —
-    identical numerics is the contract that lets the bench swap them
-    freely (nn/normalization.py)."""
-    if variant == "fused_vjp":
-        monkeypatch.setenv("BIGDL_TPU_BN_FUSED_VJP", "1")
-    elif variant == "pallas_interpret":
-        monkeypatch.setenv("BIGDL_TPU_BN_IMPL", "pallas_interpret")
+def test_batchnorm_train_mode_grads_match_torch():
+    """Backward through the BATCH statistics, the program `resnet50.train`
+    runs; torch differentiates through mean/var the same way."""
     m = nn.SpatialBatchNormalization(6, eps=1e-5, momentum=0.1).build(rng())
     bn = torch.nn.BatchNorm2d(6, eps=1e-5, momentum=0.1)
     with torch.no_grad():
