@@ -9,6 +9,7 @@ from .decode import beam_generate, cached_generate, init_kv_cache
 from .deepseek import DeepSeekV2LM
 from .jamba import JambaLM
 from .lenet import LeNet5
+from .mellum import MellumLM
 from .nemotron import NemotronHLM
 from .qwen3_next import Qwen3NextLM
 from .resnet import ResNet, ShortcutType
@@ -24,7 +25,7 @@ from .widedeep import WideDeep
 __all__ = [
     "AlexNet", "Autoencoder", "DeepSeekV2LM", "Inception_Layer_v1", "Inception_Layer_v2",
     "Inception_v1", "Inception_v1_NoAuxClassifier", "Inception_v2",
-    "Inception_v2_NoAuxClassifier", "JambaLM", "LeNet5", "NemotronHLM", "PTBModel",
+    "Inception_v2_NoAuxClassifier", "JambaLM", "LeNet5", "MellumLM", "NemotronHLM", "PTBModel",
     "PositionalEmbedding", "Qwen3NextLM", "ResNet", "ShortcutType", "SimpleRNN",
     "TextClassifier", "TransformerBlock", "TransformerLM",
     "TreeLSTMSentiment", "beam_generate", "cached_generate",

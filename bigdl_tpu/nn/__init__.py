@@ -55,5 +55,6 @@ from .criterion import (
     SmoothL1CriterionWithWeights, SoftMarginCriterion, SoftmaxWithCriterion,
     TimeDistributedCriterion)
 from .attention import LatentAttention, MultiHeadAttention
+from .window_attention import RotaryAttention, WindowAttention
 from .mamba import Mamba2Mixer, MambaMixer
 from .deltanet import GatedDeltaNet

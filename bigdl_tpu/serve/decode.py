@@ -139,7 +139,10 @@ took; PAPERS.md):
   bytes/slot and the part of them that is of fixed size
   (``state_bytes_per_slot``; ``state_bytes_fixed`` is that part over all
   slots, and ``state_bytes_per_position`` what a slot holds of each
-  position, so the two kinds of cache read side by side), and how often the device's token was taken
+  position, so the two kinds of cache read side by side; ``mean_position``
+  is how far the last step's rows stood, + 1: what an attention layer that
+  grows reads of each, where ``slot_positions`` in ``stats()`` sums it over
+  rows and steps), and how often the device's token was taken
   (``tokens_device_sampled``) against rows of log-probabilities brought
   to the host for requests that sample (``logit_rows_fetched``: 0 under
   greedy traffic), and ``ran_ahead`` (1 where the pass's step was called
@@ -452,6 +455,11 @@ class DecodeEngine:
         self.prefill_positions = 0   # positions computed for them (pads and
         #                              fill-up rows too)
         self.decode_steps = 0
+        # the positions the decode steps' rows stood at, + 1 each (what a
+        # row's attention may read), summed over rows and steps; and their
+        # mean over the last step's rows
+        self.slot_positions = 0
+        self._mean_position = 0.0
         # steps called with a call before them unread: the device held its
         # next program when it ended the last one
         self.steps_ahead = 0
@@ -1124,6 +1132,9 @@ class DecodeEngine:
         self._called(_Call(self._ticks, "decode_step", at, logits, tokens,
                            report))
         self.decode_steps += 1
+        reach = sum(pos + 1 for _seq, pos in at)
+        self.slot_positions += reach
+        self._mean_position = reach / len(at)
         self.steps_ahead += ahead
         return ahead
 
@@ -1261,6 +1272,7 @@ class DecodeEngine:
             state_bytes_per_slot=self._state_bytes,
             state_bytes_fixed=self.slots * self._state_bytes,
             state_bytes_per_position=self._position_bytes,
+            mean_position=self._mean_position,
             cache_len=self._cache_len)
         reg = metrics_export._REGISTRY
         if reg is not None:
@@ -1292,6 +1304,7 @@ class DecodeEngine:
             "prompt_tokens": self.prompt_tokens,
             "prefill_positions": self.prefill_positions,
             "decode_steps": self.decode_steps,
+            "slot_positions": self.slot_positions,
             "steps_ahead": self.steps_ahead,
             "tokens_out": self.tokens_out,
             "tokens_device_sampled": self.tokens_device_sampled,

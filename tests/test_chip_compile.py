@@ -571,3 +571,38 @@ def test_selective_prefill_compiles_at_the_cells_sizes(one_chip,
     assert set(re.findall(r"f32\[([\d,]+),16,5120\]", text)) == {"1", "384"}
     assert not any(" copy(" in ln and "f32[384,16,5120]" in ln
                    for ln in entry)
+
+
+def test_window_decode_step_compiles_at_the_cells_sizes(one_chip,
+                                                        monkeypatch):
+    """`mellum2.decode`'s step at its real sizes (192 slots, 21 window
+    layers' rings of `[192, 1024, 128]` and 7 full layers of `[192, 5120,
+    128]` bfloat16 keys and values, 16 held experts a layer of `[2304,
+    896]`): it fits one chip beside its 6.08 GB of weights; every leaf is
+    written in place under the donation and none is copied or widened to
+    float32 whole on its way to the scores; the expert products are the
+    Pallas grouped matmul, three a layer (896 is no multiple of 256; the
+    rule asks the backend, so the test says so, as the hybrid's does)."""
+    import bigdl_tpu.parallel.expert as ep
+    from bigdl_tpu.ops.grouped import _pallas
+    monkeypatch.setattr(ep, "grouped_matmul",
+                        lambda x, w, sizes, transposed=False:
+                        _pallas(x, w, sizes, transposed))
+    compiled, cfg, cache_bytes = _compile_decode_step("mellum2.decode",
+                                                      one_chip)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert cache_bytes == 192 * (11_010_048 + 3_584 * 5120)
+    assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
+    assert mem.temp_size_in_bytes < 0.5e9
+    assert mem.argument_size_in_bytes == pytest.approx(
+        2 * 3_040_674_048 + cache_bytes, rel=1e-3)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+    entry = _entry(text)
+    for leaf in ("[192,1024,128]", "[192,5120,128]"):
+        assert not any(" copy(" in ln and "bf16" + leaf in ln
+                       for ln in entry), leaf
+        assert "f32" + leaf not in text
+    assert sum("tpu_custom_call" in ln for ln in entry) == 3 * 28
+    assert text.count(" scatter(") >= 2 * 28
+    root = [ln for ln in entry if ln.startswith("ROOT ")][0]
+    assert "bf16[192,24576]" in root and "s32[192]" in root

@@ -11,10 +11,15 @@ rehearse size).  This plants one and hands the rest of the command line to
         state_carry --workload jamba2.decode --seed 11 --seconds 40 --trace 0
 
 The run's ``check`` lines show each number beside its limit; its last line
-should read ``"correct": false``.
+should read ``"correct": false``.  ``--set key=json`` (any number of them,
+before ``run.py``'s own arguments) overrides a key of the cell's traffic, as
+``benchmark/control.py``'s does: what ``correct`` compares does not depend on
+the load, so a fault can be read with fewer callers and slots than the cell
+times (``--set clients=48 --set slots=32``).
 """
 
 import importlib.util
+import json
 import os
 import sys
 
@@ -29,7 +34,20 @@ def main(argv) -> int:
     tests = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tests)
     tests.PLANTERS[name](pytest.MonkeyPatch())
-    from benchmark import run
+    from benchmark import harness, run
+    over = {}
+    while rest[:1] == ["--set"]:
+        key, value = rest[1].split("=", 1)
+        over[key] = json.loads(value)
+        rest = rest[2:]
+    if over:
+        init = harness.Run.__init__
+
+        def with_overrides(self, *args, **kwargs):
+            init(self, *args, **kwargs)
+            self.traffic.update(over)
+
+        harness.Run.__init__ = with_overrides
     return run.main(rest)
 
 
